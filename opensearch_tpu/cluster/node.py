@@ -460,10 +460,7 @@ class Node:
     @staticmethod
     def _device_count() -> int:
         import jax
-        try:
-            return len(jax.devices())
-        except RuntimeError:
-            return 1
+        return len(jax.devices())
 
     # ---------------- index lifecycle ----------------
 

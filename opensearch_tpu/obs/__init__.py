@@ -7,7 +7,7 @@ breaker-charge path — oslint OSL506), per-query device cost accounting
 `_nodes/stats/history`, oslint OSL509), and the SLO burn-rate engine
 (`slo.py` — declared objectives over sliding windows, `GET /_slo`).
 docs/OBSERVABILITY.md documents the event schema, dump triggers, tenant
-taxonomy, cost-model formulas, and the fleet/SLO model."""
+catalogue, cost-model formulas, and the fleet/SLO model."""
 
 from .flight_recorder import (FlightRecorder, RECORDER, current,
                               reset_current, set_current)

@@ -61,7 +61,7 @@ from . import flight_recorder as _fr
 
 __all__ = ["Allocation", "HBMLedger", "LEDGER"]
 
-# tenant taxonomy (docs/OBSERVABILITY.md "memory and cost"): free-form
+# tenant kinds (docs/OBSERVABILITY.md "memory and cost"): free-form
 # strings are accepted, but the known kinds keep dashboards stable
 KINDS = (
     "segment_columns",      # Segment.device_arrays full pytree

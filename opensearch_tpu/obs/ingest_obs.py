@@ -32,7 +32,7 @@ the `indexing.` prefix. This module owns the pieces they share:
   Fleet percentiles are never averages of per-node percentiles.
 
 docs/OBSERVABILITY.md "Ingest observatory" documents the metric and
-stage taxonomy; oslint OSL605 (devtools/oslint/ingest_obs_rules.py)
+stage catalogue; oslint OSL605 (devtools/oslint/ingest_obs_rules.py)
 patrols the emission discipline inside `index/` + `ingest/` hot loops.
 """
 

@@ -169,163 +169,6 @@ def _flat_shift_up(x, fill):
 
 
 # ---------------------------------------------------------------------
-# the kernel
-# ---------------------------------------------------------------------
-
-def _bm25_kernel(T: int, L: int, K: int,
-                 starts_ref, lens_ref, weights_ref, msm_ref,
-                 docs_hbm, norms_hbm, out_scores, out_docs, out_totals,
-                 docs_v, norms_v, sems):
-    q = pl.program_id(0)
-
-    # ---- DMA all term posting ranges HBM -> VMEM ----
-    # HBM arrays are [P/128, 128]; starts are element offsets aligned to
-    # HBM_ALIGN so row starts/extents satisfy the (8, 128) tiling
-    rows_per_term = L // LANES
-    dmas = []
-    for t in range(T):
-        row_start = pl.multiple_of(starts_ref[t, q] // LANES, HBM_ALIGN // LANES)
-        d1 = pltpu.make_async_copy(docs_hbm.at[pl.ds(row_start, rows_per_term)],
-                                   docs_v.at[t], sems.at[2 * t])
-        d2 = pltpu.make_async_copy(norms_hbm.at[pl.ds(row_start, rows_per_term)],
-                                   norms_v.at[t], sems.at[2 * t + 1])
-        d1.start()
-        d2.start()
-        dmas.extend((d1, d2))
-    for d in dmas:
-        d.wait()
-
-    # ---- mask tails, apply per-term weights ----
-    R = (T * L) // LANES
-    docs2 = docs_v[:].reshape(R, LANES)
-    norms2 = norms_v[:].reshape(R, LANES)
-    rows, lanes = _ids((R, LANES))
-    term_of_row = rows // rows_per_term
-    pos_in_term = (rows % rows_per_term) * LANES + lanes
-
-    # per-row scalars from SMEM (loop over T is static & tiny)
-    w_row = jnp.zeros((R, LANES), jnp.float32)
-    len_row = jnp.zeros((R, LANES), jnp.int32)
-    for t in range(T):
-        sel = term_of_row == t
-        w_row = jnp.where(sel, weights_ref[t, q], w_row)
-        len_row = jnp.where(sel, lens_ref[t, q], len_row)
-    valid = pos_in_term < len_row
-    keys = jnp.where(valid, docs2, INT_SENTINEL)
-    contrib = jnp.where(valid, w_row * norms2, 0.0)
-
-    # ---- merge the T doc-sorted runs (each of length L) ----
-    half = L
-    while half < T * L:
-        keys, contrib = _merge_pairs(keys, contrib, half)
-        half *= 2
-
-    # ---- dedup: runs of equal doc have length <= T ----
-    score = contrib
-    kk = keys
-    cc = contrib
-    count = jnp.ones((R, LANES), jnp.float32)
-    for _ in range(T - 1):
-        kk = _flat_shift_down(kk, INT_SENTINEL)
-        cc = _flat_shift_down(cc, 0.0)
-        eq = (kk == keys) & (keys < INT_SENTINEL)
-        score = score + jnp.where(eq, cc, 0.0)
-        count = count + jnp.where(eq, 1.0, 0.0)
-    knext = _flat_shift_up(keys, INT_SENTINEL)
-    is_last = (knext != keys) & (keys < INT_SENTINEL)
-    msm = msm_ref[0, q]
-    final = jnp.where(is_last & (count >= msm), score, NEG_INF)
-
-    # exact total hits (track_total_hits): one doc survives per dedup run
-    total = jnp.sum((final > NEG_INF).astype(jnp.int32))
-    out_totals[q, :] = jnp.full((LANES,), total, jnp.int32)
-
-    # ---- iterative top-K extraction ----
-    acc_s = jnp.full((1, LANES), NEG_INF, jnp.float32)
-    acc_d = jnp.full((1, LANES), -1, jnp.int32)
-    out_lane = jax.lax.broadcasted_iota(jnp.int32, (1, LANES), 1)
-    for j in range(K):
-        best = jnp.max(final)
-        sel = final == best
-        bdoc = jnp.min(jnp.where(sel, keys, INT_SENTINEL))
-        # scalar selects first: scalar-bool & vector-bool hits a Mosaic
-        # truncation bug, so fold `got` into scalars
-        got = best > NEG_INF
-        best_or = jnp.where(got, best, NEG_INF)
-        bdoc_or = jnp.where(got, bdoc, -1)
-        hit = out_lane == j
-        acc_s = jnp.where(hit, best_or, acc_s)
-        acc_d = jnp.where(hit, bdoc_or, acc_d)
-        final = jnp.where(sel & (keys == bdoc), NEG_INF, final)
-    out_scores[q, :] = acc_s[0]
-    out_docs[q, :] = acc_d[0]
-
-
-@functools.partial(jax.jit, static_argnames=("T", "L", "K"))
-def fused_bm25_topk(docs_hbm: jnp.ndarray, norms_hbm: jnp.ndarray,
-                    starts: jnp.ndarray, lens: jnp.ndarray,
-                    weights: jnp.ndarray, msm: jnp.ndarray,
-                    T: int, L: int, K: int):
-    """Batched fused BM25 top-k.
-
-    docs_hbm  i32[P] — doc ids, CSR-flat, rows 128-aligned, >= L tail margin
-    norms_hbm f32[P] — per-posting eager impacts tf/(tf+K_d) (BM25S-style)
-    starts    i32[QB, T] — 128-aligned row starts (absent term: any aligned
-              offset with lens=0)
-    lens      i32[QB, T]
-    weights   f32[QB, T] — query-time idf * boost (collection-wide stats)
-    msm       f32[QB, 1] — minimum matching terms (1=OR, T=AND)
-    Returns (scores f32[QB, 128], doc_ids i32[QB, 128], totals i32[QB, 128])
-    — first K lanes of scores/doc_ids valid; totals[q, 0] is the exact hit
-    count (docs matching >= msm terms).
-    """
-    QB = starts.shape[0]
-    # SMEM operands are lane-padded to 128 in their last dim: keep QB (large)
-    # last and T (tiny) first so prefetch stays a few KB
-    starts = starts.T
-    lens = lens.T
-    weights = weights.T
-    msm = msm.T
-    assert docs_hbm.shape[0] % LANES == 0
-    docs_hbm = docs_hbm.reshape(-1, LANES)
-    norms_hbm = norms_hbm.reshape(-1, LANES)
-    kernel = functools.partial(_bm25_kernel, T, L, K)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
-        grid=(QB,),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-        ],
-        out_specs=[
-            # whole-array blocks: each program writes its own row q (TPU grid
-            # steps are sequential; (1, 128) blocks violate the (8, 128)
-            # min-tile rule)
-            pl.BlockSpec(memory_space=pltpu.VMEM),
-            pl.BlockSpec(memory_space=pltpu.VMEM),
-            pl.BlockSpec(memory_space=pltpu.VMEM),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((T, L // LANES, LANES), jnp.int32),
-            pltpu.VMEM((T, L // LANES, LANES), jnp.float32),
-            pltpu.SemaphoreType.DMA((2 * T,)),
-        ],
-    )
-    out_shape = [
-        jax.ShapeDtypeStruct((QB, LANES), jnp.float32),
-        jax.ShapeDtypeStruct((QB, LANES), jnp.int32),
-        jax.ShapeDtypeStruct((QB, LANES), jnp.int32),
-    ]
-    scores, doc_ids, totals = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=out_shape,
-        compiler_params=pltpu.CompilerParams(has_side_effects=True),
-    )(starts, lens, weights, msm, docs_hbm, norms_hbm)
-    return scores, doc_ids, totals
-
-
-# ---------------------------------------------------------------------
 # production variant: packed (tf, dl) postings + per-term DMA buckets
 # ---------------------------------------------------------------------
 
@@ -517,8 +360,8 @@ def fused_bm25_topk_tfdl(docs_hbm: jnp.ndarray, tfdl_hbm: jnp.ndarray,
         num_scalar_prefetch=9,
         grid=(QB,),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=[
             pl.BlockSpec(memory_space=pltpu.VMEM),
@@ -765,9 +608,9 @@ def fused_bm25_bool_topk(docs_hbm: jnp.ndarray, tfdl_hbm: jnp.ndarray,
         num_scalar_prefetch=10,
         grid=(QB,),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=[
             pl.BlockSpec(memory_space=pltpu.VMEM),
@@ -960,8 +803,8 @@ def fused_bm25_topk_impact(docs_hbm: jnp.ndarray, imp_hbm: jnp.ndarray,
         num_scalar_prefetch=8,
         grid=(QB,),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=[
             pl.BlockSpec(memory_space=pltpu.VMEM),

@@ -40,19 +40,6 @@ from ..index.segment import Segment, next_pow2
 INT32_SENTINEL = np.int32(2**31 - 1)
 
 
-def _shard_map(f, mesh, in_specs, out_specs, check_vma=False):
-    """`jax.shard_map` across jax versions: older releases only ship
-    `jax.experimental.shard_map` whose replication check is spelled
-    `check_rep` instead of `check_vma`."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as esm
-    return esm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=check_vma)
-
-
 def make_mesh(n_replica: int = 1, n_shard: Optional[int] = None,
               devices: Optional[list] = None) -> Mesh:
     devices = devices if devices is not None else jax.devices()
@@ -361,8 +348,6 @@ def build_distributed_search(mesh: Mesh, bucket: int, ndocs_pad: int, k: int,
         gdocs = all_gids.reshape(all_gids.shape[0], S * kk)
         return gdocs, gvals, totals
 
-    shard_map = _shard_map
-
     tree_spec = {k_: P("shard") for k_ in
                  ("starts", "doc_ids", "tfs", "dl", "live", "doc_base",
                   "doc_count", "sum_dl", "field_dc")}
@@ -370,9 +355,9 @@ def build_distributed_search(mesh: Mesh, bucket: int, ndocs_pad: int, k: int,
                 P("replica"), P("replica"))
     if filtered:
         in_specs = in_specs + (P("shard"),)
-    fn = shard_map(per_device, mesh=mesh, in_specs=in_specs,
-                   out_specs=(P("replica"), P("replica"), P("replica")),
-                   check_vma=False)
+    fn = jax.shard_map(per_device, mesh=mesh, in_specs=in_specs,
+                       out_specs=(P("replica"), P("replica"), P("replica")),
+                       check_vma=False)
     jitted = jax.jit(fn)
 
     def call(tree, rows, boosts, msm, cscore=None, fmask=None):
@@ -436,7 +421,6 @@ def build_distributed_metrics(mesh: Mesh, bucket: int, ndocs_pad: int,
                     jax.lax.psum(part[:, 3], "shard"),
                 ], axis=1))
 
-    shard_map = _shard_map
     tree_spec = {k_: P("shard") for k_ in
                  ("starts", "doc_ids", "tfs", "dl", "live", "doc_base",
                   "doc_count", "sum_dl", "field_dc")}
@@ -444,8 +428,8 @@ def build_distributed_metrics(mesh: Mesh, bucket: int, ndocs_pad: int,
                 P("replica"), P("replica"), P("shard"), P("shard"))
     if filtered:
         in_specs = in_specs + (P("shard"),)
-    fn = shard_map(per_device, mesh=mesh, in_specs=in_specs,
-                   out_specs=P("replica"), check_vma=False)
+    fn = jax.shard_map(per_device, mesh=mesh, in_specs=in_specs,
+                       out_specs=P("replica"), check_vma=False)
     return jax.jit(fn)
 
 
@@ -495,7 +479,6 @@ def build_distributed_terms_agg(mesh: Mesh, bucket: int, ndocs_pad: int,
         part = jax.vmap(one)(rows, boosts, msm, cscore, df_global)  # [QB,V]
         return jax.lax.psum(part, "shard")
 
-    shard_map = _shard_map
     tree_spec = {k_: P("shard") for k_ in
                  ("starts", "doc_ids", "tfs", "dl", "live", "doc_base",
                   "doc_count", "sum_dl", "field_dc")}
@@ -503,8 +486,8 @@ def build_distributed_terms_agg(mesh: Mesh, bucket: int, ndocs_pad: int,
                 P("replica"), P("replica"), P("shard"), P("shard"))
     if filtered:
         in_specs = in_specs + (P("shard"),)
-    fn = shard_map(per_device, mesh=mesh, in_specs=in_specs,
-                   out_specs=P("replica"), check_vma=False)
+    fn = jax.shard_map(per_device, mesh=mesh, in_specs=in_specs,
+                       out_specs=P("replica"), check_vma=False)
     return jax.jit(fn)
 
 
@@ -545,7 +528,6 @@ def build_distributed_bincount(mesh: Mesh, bucket: int, ndocs_pad: int,
         part = jax.vmap(one)(rows, boosts, msm, cscore, df_global)
         return jax.lax.psum(part, "shard")
 
-    shard_map = _shard_map
     tree_spec = {k_: P("shard") for k_ in
                  ("starts", "doc_ids", "tfs", "dl", "live", "doc_base",
                   "doc_count", "sum_dl", "field_dc")}
@@ -553,8 +535,8 @@ def build_distributed_bincount(mesh: Mesh, bucket: int, ndocs_pad: int,
                 P("replica"), P("replica"), P("shard"))
     if filtered:
         in_specs = in_specs + (P("shard"),)
-    fn = shard_map(per_device, mesh=mesh, in_specs=in_specs,
-                   out_specs=P("replica"), check_vma=False)
+    fn = jax.shard_map(per_device, mesh=mesh, in_specs=in_specs,
+                       out_specs=P("replica"), check_vma=False)
     return jax.jit(fn)
 
 
@@ -627,7 +609,6 @@ def build_distributed_pair_metrics(mesh: Mesh, bucket: int, ndocs_pad: int,
                     jax.lax.psum(part[:, :, 3], "shard"),
                 ], axis=2))
 
-    shard_map = _shard_map
     tree_spec = {k_: P("shard") for k_ in
                  ("starts", "doc_ids", "tfs", "dl", "live", "doc_base",
                   "doc_count", "sum_dl", "field_dc")}
@@ -636,8 +617,8 @@ def build_distributed_pair_metrics(mesh: Mesh, bucket: int, ndocs_pad: int,
                 P("shard"), P("shard"))
     if filtered:
         in_specs = in_specs + (P("shard"),)
-    fn = shard_map(per_device, mesh=mesh, in_specs=in_specs,
-                   out_specs=P("replica"), check_vma=False)
+    fn = jax.shard_map(per_device, mesh=mesh, in_specs=in_specs,
+                       out_specs=P("replica"), check_vma=False)
     return jax.jit(fn)
 
 
@@ -694,7 +675,6 @@ def build_distributed_range_metrics(mesh: Mesh, bucket: int, ndocs_pad: int,
                     jax.lax.psum(part[:, :, 3], "shard"),
                 ], axis=2))
 
-    shard_map = _shard_map
     tree_spec = {k_: P("shard") for k_ in
                  ("starts", "doc_ids", "tfs", "dl", "live", "doc_base",
                   "doc_count", "sum_dl", "field_dc")}
@@ -703,8 +683,8 @@ def build_distributed_range_metrics(mesh: Mesh, bucket: int, ndocs_pad: int,
                 P(), P(), P("shard"), P("shard"))
     if filtered:
         in_specs = in_specs + (P("shard"),)
-    fn = shard_map(per_device, mesh=mesh, in_specs=in_specs,
-                   out_specs=P("replica"), check_vma=False)
+    fn = jax.shard_map(per_device, mesh=mesh, in_specs=in_specs,
+                       out_specs=P("replica"), check_vma=False)
     return jax.jit(fn)
 
 
@@ -777,7 +757,6 @@ def build_distributed_cardinality(mesh: Mesh, bucket: int, ndocs_pad: int,
         part = jax.vmap(one)(rows, boosts, msm, cscore, df_global)
         return jax.lax.pmax(part, "shard")
 
-    shard_map = _shard_map
     tree_spec = {k_: P("shard") for k_ in
                  ("starts", "doc_ids", "tfs", "dl", "live", "doc_base",
                   "doc_count", "sum_dl", "field_dc")}
@@ -790,8 +769,8 @@ def build_distributed_cardinality(mesh: Mesh, bucket: int, ndocs_pad: int,
                     P("replica"), P("replica"), P("shard"), P("shard"))
     if filtered:
         in_specs = in_specs + (P("shard"),)
-    fn = shard_map(per_device, mesh=mesh, in_specs=in_specs,
-                   out_specs=P("replica"), check_vma=False)
+    fn = jax.shard_map(per_device, mesh=mesh, in_specs=in_specs,
+                       out_specs=P("replica"), check_vma=False)
     return jax.jit(fn)
 
 
@@ -829,7 +808,6 @@ def build_distributed_ddsketch(mesh: Mesh, bucket: int, ndocs_pad: int,
         part = jax.vmap(one)(rows, boosts, msm, cscore, df_global)
         return jax.lax.psum(part, "shard")
 
-    shard_map = _shard_map
     tree_spec = {k_: P("shard") for k_ in
                  ("starts", "doc_ids", "tfs", "dl", "live", "doc_base",
                   "doc_count", "sum_dl", "field_dc")}
@@ -837,8 +815,8 @@ def build_distributed_ddsketch(mesh: Mesh, bucket: int, ndocs_pad: int,
                 P("replica"), P("replica"), P("shard"), P("shard"))
     if filtered:
         in_specs = in_specs + (P("shard"),)
-    fn = shard_map(per_device, mesh=mesh, in_specs=in_specs,
-                   out_specs=P("replica"), check_vma=False)
+    fn = jax.shard_map(per_device, mesh=mesh, in_specs=in_specs,
+                       out_specs=P("replica"), check_vma=False)
     return jax.jit(fn)
 
 
@@ -880,7 +858,6 @@ def build_distributed_weighted_avg(mesh: Mesh, bucket: int, ndocs_pad: int,
         part = jax.vmap(one)(rows, boosts, msm, cscore, df_global)
         return jax.lax.psum(part, "shard")
 
-    shard_map = _shard_map
     tree_spec = {k_: P("shard") for k_ in
                  ("starts", "doc_ids", "tfs", "dl", "live", "doc_base",
                   "doc_count", "sum_dl", "field_dc")}
@@ -889,8 +866,8 @@ def build_distributed_weighted_avg(mesh: Mesh, bucket: int, ndocs_pad: int,
                 P("shard"), P("shard"))
     if filtered:
         in_specs = in_specs + (P("shard"),)
-    fn = shard_map(per_device, mesh=mesh, in_specs=in_specs,
-                   out_specs=P("replica"), check_vma=False)
+    fn = jax.shard_map(per_device, mesh=mesh, in_specs=in_specs,
+                       out_specs=P("replica"), check_vma=False)
     return jax.jit(fn)
 
 
@@ -947,7 +924,6 @@ def build_distributed_geo_stat(mesh: Mesh, bucket: int, ndocs_pad: int,
             jax.lax.psum(part[:, 6], "shard"),
         ], axis=1)
 
-    shard_map = _shard_map
     tree_spec = {k_: P("shard") for k_ in
                  ("starts", "doc_ids", "tfs", "dl", "live", "doc_base",
                   "doc_count", "sum_dl", "field_dc")}
@@ -956,8 +932,8 @@ def build_distributed_geo_stat(mesh: Mesh, bucket: int, ndocs_pad: int,
                 P("shard"))
     if filtered:
         in_specs = in_specs + (P("shard"),)
-    fn = shard_map(per_device, mesh=mesh, in_specs=in_specs,
-                   out_specs=P("replica"), check_vma=False)
+    fn = jax.shard_map(per_device, mesh=mesh, in_specs=in_specs,
+                       out_specs=P("replica"), check_vma=False)
     return jax.jit(fn)
 
 
@@ -999,7 +975,6 @@ def build_distributed_range_counts(mesh: Mesh, bucket: int, ndocs_pad: int,
         part = jax.vmap(one)(rows, boosts, msm, cscore, df_global)
         return jax.lax.psum(part, "shard")
 
-    shard_map = _shard_map
     tree_spec = {k_: P("shard") for k_ in
                  ("starts", "doc_ids", "tfs", "dl", "live", "doc_base",
                   "doc_count", "sum_dl", "field_dc")}
@@ -1008,8 +983,8 @@ def build_distributed_range_counts(mesh: Mesh, bucket: int, ndocs_pad: int,
                 P(), P())
     if filtered:
         in_specs = in_specs + (P("shard"),)
-    fn = shard_map(per_device, mesh=mesh, in_specs=in_specs,
-                   out_specs=P("replica"), check_vma=False)
+    fn = jax.shard_map(per_device, mesh=mesh, in_specs=in_specs,
+                       out_specs=P("replica"), check_vma=False)
     return jax.jit(fn)
 
 
@@ -1161,7 +1136,6 @@ def build_distributed_phrase(mesh: Mesh, bucket: int, ndocs_pad: int,
         return (all_gids.reshape(all_gids.shape[0], S * kk),
                 all_vals.reshape(all_vals.shape[0], S * kk), totals)
 
-    shard_map = _shard_map
     tree_spec = {k_: P("shard") for k_ in
                  ("starts", "doc_ids", "tfs", "dl", "live", "doc_base",
                   "doc_count", "sum_dl", "field_dc")}
@@ -1171,9 +1145,9 @@ def build_distributed_phrase(mesh: Mesh, bucket: int, ndocs_pad: int,
                 P("replica"), P("replica"))
     if filtered:
         in_specs = in_specs + (P("shard"),)
-    fn = shard_map(per_device, mesh=mesh, in_specs=in_specs,
-                   out_specs=(P("replica"), P("replica"), P("replica")),
-                   check_vma=False)
+    fn = jax.shard_map(per_device, mesh=mesh, in_specs=in_specs,
+                       out_specs=(P("replica"), P("replica"), P("replica")),
+                       check_vma=False)
     return jax.jit(fn)
 
 
@@ -1205,13 +1179,11 @@ def build_term_sharded_score(mesh: Mesh, bucket: int, ndocs_pad: int, k: int,
         vals, idx = jax.lax.top_k(masked, min(k, ndocs_pad))
         return vals, idx
 
-    shard_map = _shard_map
-
-    fn = shard_map(per_device, mesh=mesh,
-                   in_specs=(P("shard"), P("shard"), P("shard"),
-                             P(), P(), P(), P(), P(), P(), P(), P()),
-                   out_specs=(P(), P()),
-                   check_vma=False)
+    fn = jax.shard_map(per_device, mesh=mesh,
+                       in_specs=(P("shard"), P("shard"), P("shard"),
+                                 P(), P(), P(), P(), P(), P(), P(), P()),
+                       out_specs=(P(), P()),
+                       check_vma=False)
     return jax.jit(fn)
 
 

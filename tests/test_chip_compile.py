@@ -1,0 +1,263 @@
+"""What the chip's compiler and the real kernels say, without a chip.
+
+Two families, both in this ONE file (only one process at a time may load
+the TPU's library; a second file could land on another xdist worker and
+skip in silence):
+
+* the three live Pallas kernels and `exact_rescore_batch` COMPILED for a
+  described (not attached) `v5e:2x2` device at real buckets — what the
+  compiler refuses here costs no chip time. The topology, the sharding
+  and the shapes are built inside module-scoped fixtures that skip when
+  the topology cannot be described: nothing at import, no child process,
+  the persistent compilation cache off around them (such a compile can be
+  written to it but not read back without a chip).
+* the REAL tfdl, bool and impact kernels under
+  `pltpu.force_tpu_interpret_mode()` at tiny shapes against the numpy
+  simulators that stand in for them in tests/test_pruned.py — so the
+  stand-ins the rest of tier-1 trusts are themselves checked.
+"""
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import SingleDeviceSharding
+
+from opensearch_tpu.ops.pallas_bm25 import (DL_BITS, HBM_ALIGN, INT_SENTINEL,
+                                            LANES, REQ_W,
+                                            fused_bm25_bool_topk,
+                                            fused_bm25_topk_impact,
+                                            fused_bm25_topk_tfdl)
+from opensearch_tpu.ops.rescore import exact_rescore_batch
+from tests.test_pruned import (sim_fused_bm25_topk_impact,
+                               sim_fused_bm25_topk_tfdl)
+
+K1, B = 1.2, 0.75
+P_REAL = 1 << 27        # a 2.2M-doc MS-MARCO-shaped shard's aligned plane
+
+
+# ---------------------------------------------------------------------
+# compiled for a described v5e
+# ---------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def topo():
+    import os
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:      # noqa: BLE001 — any failure to describe
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def shape_on_chip(topo):
+    """(shape, dtype) -> ShapeDtypeStruct placed on the first described
+    chip, with the persistent compilation cache off while the module's
+    compile tests run."""
+    from jax.experimental.compilation_cache import compilation_cache
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                    sharding=one_chip)
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _per_query(S, QB, T, with_avgdl=True):
+    """rowstarts, nrows, lens, skips, weights, msm[, avgdl], dlo, dhi."""
+    i32, f32 = jnp.int32, jnp.float32
+    out = [S((QB, T), i32)] * 4 + [S((QB, T), f32), S((QB, 1), f32)]
+    if with_avgdl:
+        out.append(S((QB, 1), f32))
+    return out + [S((QB, 1), i32), S((QB, 1), i32)]
+
+
+def _assert_mosaic(compiled):
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("QB,T,L,K", [(32, 2, 4096, 16), (8, 4, 4096, 16)])
+def test_tfdl_kernel_compiles_for_v5e(shape_on_chip, QB, T, L, K):
+    S = shape_on_chip
+    planes = [S((P_REAL,), jnp.int32)] * 2
+    _assert_mosaic(fused_bm25_topk_tfdl.lower(
+        *planes, *_per_query(S, QB, T), T=T, L=L, K=K, k1=K1, b=B
+    ).compile())
+
+
+@pytest.mark.parametrize("QB,T,L,K", [(32, 2, 8192, 128), (8, 4, 4096, 16)])
+def test_impact_kernel_compiles_for_v5e(shape_on_chip, QB, T, L, K):
+    S = shape_on_chip
+    planes = [S((P_REAL,), jnp.int32)] * 2
+    _assert_mosaic(fused_bm25_topk_impact.lower(
+        *planes, *_per_query(S, QB, T, with_avgdl=False), T=T, L=L, K=K
+    ).compile())
+
+
+@pytest.mark.parametrize("QB,TS,L,K,filtered", [(8, 4, 4096, 16, False),
+                                                (8, 2, 4096, 16, True)])
+def test_bool_kernel_compiles_for_v5e(shape_on_chip, QB, TS, L, K, filtered):
+    S = shape_on_chip
+    i32, f32 = jnp.int32, jnp.float32
+    T = 2 * TS if filtered else TS
+    _assert_mosaic(fused_bm25_bool_topk.lower(
+        S((P_REAL,), i32), S((P_REAL,), i32), S((1 << 22,), i32),
+        *[S((QB, T), i32)] * 4, S((QB, TS), f32), S((QB, T), f32),
+        S((QB, 1), f32), S((QB, 1), f32), S((QB, 1), i32), S((QB, 1), i32),
+        TS=TS, L=L, K=K, k1=K1, b=B, filtered=filtered).compile())
+
+
+@pytest.mark.parametrize("QB,T,C", [(8, 2, 256), (64, 4, 2048)])
+def test_exact_rescore_compiles_for_v5e(shape_on_chip, QB, T, C):
+    S = shape_on_chip
+    i32, f32 = jnp.int32, jnp.float32
+    compiled = exact_rescore_batch.lower(
+        S((P_REAL,), i32), S((P_REAL,), i32), S((QB, T), i32),
+        S((QB, T), i32), S((QB, T), f32), S((QB, 1), f32), S((QB, C), i32),
+        T=T, C=C, k1=K1, b=B).compile()
+    # the [QB, T, C] probe intermediates fit the chip beside the planes
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 << 30
+
+
+# ---------------------------------------------------------------------
+# the real kernels, interpreted, against their numpy stand-ins
+# ---------------------------------------------------------------------
+
+NDOCS, T, L, K, QB = 3000, 2, 2 * HBM_ALIGN, 16, 4
+
+
+@pytest.fixture(scope="module")
+def tiny():
+    """Three doc-ascending posting rows (one longer than an HBM tile) in
+    the aligned layout the fastpath builds, plus per-query windows that
+    start mid-tile (non-zero `skips`) and one absent term."""
+    rng = np.random.default_rng(5)
+    row_lens = [1500, 700, 40]
+    starts, docs, tfdl, imp = [], [], [], []
+    at = 0
+    for n in row_lens:
+        d = np.sort(rng.choice(NDOCS, n, replace=False)).astype(np.int32)
+        tf = rng.integers(1, 6, n)
+        dl = rng.integers(8, 120, n)
+        pad = -(-n // LANES) * LANES - n
+        starts.append(at)
+        docs += [d, np.full(pad, INT_SENTINEL, np.int32)]
+        tfdl += [((tf << DL_BITS) | dl).astype(np.int32),
+                 np.zeros(pad, np.int32)]
+        imp += [rng.integers(1, 256, n).astype(np.int32),
+                np.zeros(pad, np.int32)]
+        at += n + pad
+    margin = [np.full(2 * L, INT_SENTINEL, np.int32)]
+    docs = np.concatenate(docs + margin)
+    tfdl = np.concatenate(tfdl + [np.zeros(2 * L, np.int32)])
+    imp = np.concatenate(imp + [np.zeros(2 * L, np.int32)])
+
+    # queries: (row0,row1) (row1,row2) (row2,absent) (row0,row2)
+    pairs = [(0, 1), (1, 2), (2, -1), (0, 2)]
+    rowstarts = np.zeros((QB, T), np.int32)
+    nrows = np.zeros((QB, T), np.int32)
+    lens = np.zeros((QB, T), np.int32)
+    skips = np.zeros((QB, T), np.int32)
+    for q, rows in enumerate(pairs):
+        for t, r in enumerate(rows):
+            if r < 0:
+                continue
+            dma = (starts[r] // HBM_ALIGN) * HBM_ALIGN
+            skip = starts[r] - dma
+            need = -(-(skip + row_lens[r]) // LANES)
+            nr = HBM_ALIGN // LANES
+            while nr < need:
+                nr *= 2
+            rowstarts[q, t], nrows[q, t] = dma // LANES, nr
+            lens[q, t], skips[q, t] = row_lens[r], skip
+    assert skips.max() > 0      # the mid-tile window is exercised
+    weights = rng.uniform(0.5, 3.0, (QB, T)).astype(np.float32)
+    return dict(docs=docs, tfdl=tfdl, imp=imp, rowstarts=rowstarts,
+                nrows=nrows, lens=lens, skips=skips, weights=weights,
+                msm=np.array([[1], [2], [1], [1]], np.float32),
+                avgdl=np.full((QB, 1), 50.0, np.float32),
+                dlo=np.zeros((QB, 1), np.int32),
+                dhi=np.array([[NDOCS], [NDOCS], [NDOCS], [2000]], np.int32))
+
+
+def _assert_same(got, want):
+    """Kernel output vs simulator output: totals and doc ids exact, scores
+    to f32 rounding (the simulator accumulates in f64)."""
+    gs, gd, gt = (np.asarray(a) for a in got)
+    ws, wd, wt = want
+    np.testing.assert_array_equal(gt[:, 0], wt[:, 0])
+    np.testing.assert_array_equal(gd[:, :K], wd[:, :K])
+    hit = wd[:, :K] >= 0
+    np.testing.assert_allclose(gs[:, :K][hit], ws[:, :K][hit], rtol=1e-6)
+    assert np.all(np.isneginf(gs[:, :K][~hit]))
+
+
+def test_tfdl_kernel_matches_its_simulator(tiny):
+    q = tiny
+    args = (q["docs"], q["tfdl"], q["rowstarts"], q["nrows"], q["lens"],
+            q["skips"], q["weights"], q["msm"], q["avgdl"], q["dlo"],
+            q["dhi"])
+    with pltpu.force_tpu_interpret_mode():
+        got = fused_bm25_topk_tfdl(*args, T=T, L=L, K=K, k1=K1, b=B)
+    _assert_same(got, sim_fused_bm25_topk_tfdl(*args, T, L, K, K1, B))
+
+
+def test_impact_kernel_matches_its_simulator(tiny):
+    q = tiny
+    args = (q["docs"], q["imp"], q["rowstarts"], q["nrows"], q["lens"],
+            q["skips"], q["weights"], q["msm"], q["dlo"], q["dhi"])
+    with pltpu.force_tpu_interpret_mode():
+        got = fused_bm25_topk_impact(*args, T=T, L=L, K=K)
+    _assert_same(got, sim_fused_bm25_topk_impact(*args, T, L, K))
+
+
+@pytest.mark.parametrize("filtered", [False, True])
+def test_bool_kernel_matches_the_tfdl_simulator(tiny, filtered):
+    """The bool kernel has no stand-in of its own in tier-1; with every
+    slot optional (cw 1, thresh = msm) it must answer exactly what the
+    tfdl simulator does — and, filtered, what that simulator answers once
+    docs outside the filter list are dropped (every slot required)."""
+    q = tiny
+    rng = np.random.default_rng(6)
+    pad = np.zeros((QB, T), np.int32)
+    if filtered:
+        # AND of both terms AND the filter: cw REQ_W each, thresh 3*REQ_W
+        keep = np.sort(rng.choice(NDOCS, 1800, replace=False)
+                       ).astype(np.int32)
+        filt = np.concatenate([keep, np.full(
+            2 * L - len(keep) % (2 * L) + L, INT_SENTINEL, np.int32)])
+        slot = lambda a, v: np.concatenate(                 # noqa: E731
+            [a, np.full((QB, 1), v, a.dtype), pad[:, : T - 1]], axis=1)
+        rowstarts, skips = slot(q["rowstarts"], 0), slot(q["skips"], 0)
+        nrows = slot(q["nrows"], L // LANES)
+        lens = slot(q["lens"], len(keep))
+        cw = np.where(lens > 0, np.float32(REQ_W), np.float32(0.0))
+        thresh = np.full((QB, 1), 3 * REQ_W, np.float32)
+        msm = np.full((QB, 1), 2.0, np.float32)
+    else:
+        filt = np.full(LANES, INT_SENTINEL, np.int32)
+        rowstarts, nrows, lens, skips = (q["rowstarts"], q["nrows"],
+                                         q["lens"], q["skips"])
+        cw = (lens > 0).astype(np.float32)
+        thresh = msm = q["msm"]
+    with pltpu.force_tpu_interpret_mode():
+        got = fused_bm25_bool_topk(
+            q["docs"], q["tfdl"], filt, rowstarts, nrows, lens, skips,
+            q["weights"], cw, thresh, q["avgdl"], q["dlo"], q["dhi"],
+            TS=T, L=L, K=K, k1=K1, b=B, filtered=filtered)
+    docs = q["docs"]
+    if filtered:
+        # the reference sees only postings whose doc is in the filter
+        docs = np.where(np.isin(docs, keep), docs, INT_SENTINEL)
+        docs = np.where(docs == INT_SENTINEL, np.int32(-1), docs)
+    want = sim_fused_bm25_topk_tfdl(
+        docs, q["tfdl"], q["rowstarts"], q["nrows"], q["lens"], q["skips"],
+        q["weights"], msm, q["avgdl"], q["dlo"], q["dhi"], T, L, K, K1, B)
+    _assert_same(got, want)
