@@ -995,62 +995,65 @@ class Node:
         current — direct engine callers, tests — this entry point owns
         one for the duration of the search, so every downstream event
         (scheduler, mesh, fastpath ladder) lands on a journal."""
-        # per-lane SLIs (docs/OBSERVABILITY.md "fleet"): every search
-        # lands one requests/errors/rejected count and one latency sample
-        # under its lane — the counters the time-series sampler windows
-        # and the SLO burn-rate engine judges (obs/slo.py). Recorded at
-        # THIS boundary so cache hits, scheduler 429s and host-loop
-        # fallbacks all count exactly once.
-        from ..obs import insights as _ins
-        from ..utils.metrics import METRICS as _m
-        from ..utils.wlm import PressureRejectedException as _rej
-        lane = sli_lane or wlm_lane or "interactive"
-        _t0 = time.monotonic()
-        _rec = self.flight_recorder
-        tl = _fr.current() if _rec.enabled else 0
-        token = None
-        if _rec.enabled and not tl:
-            tl = _rec.start("search", index=expression,
-                            node=self.node_name)
-            token = _fr.set_current(tl)
-        # query insights (obs/insights.py): fingerprint the body at THIS
-        # boundary — the same place the per-lane SLIs land — so cache
-        # hits, rejections, errors and host-ladder attribution all
-        # aggregate under one bounded query shape
-        obs, ins_token = _ins.begin(body if isinstance(body, dict)
-                                    else {}, lane)
-        try:
-            resp = self._search_recorded(expression, body, phase_hook,
-                                         phase_ctx, copy_protect,
-                                         wlm_lane, tl)
-        except _rej:
-            _m.counter(f"search.lane.{lane}.rejected").inc()
-            _ins.finish(ins_token, obs, rejected=True, timeline_id=tl)
-            raise
-        except BaseException as e:
-            # client-side 4xx API errors (bad query, missing index) are
-            # the caller's fault, not lost availability — only server
-            # faults burn the error budget
-            if getattr(e, "status", 500) >= 500:
-                _m.counter(f"search.lane.{lane}.errors").inc()
-                _ins.finish(ins_token, obs, error=True, timeline_id=tl)
-            else:
-                _ins.finish(ins_token, obs, timeline_id=tl)
-            raise
-        finally:
-            if token is not None:
-                _fr.reset_current(token)
-        _m.counter(f"search.lane.{lane}.requests").inc()
-        took_ms = (time.monotonic() - _t0) * 1000.0
-        if _m.enabled:
-            _m.histogram(f"search.lane.{lane}.latency_ms").record(
-                took_ms)
-        _ins.finish(ins_token, obs, latency_ms=took_ms, timeline_id=tl)
-        return resp
+        with self.tracer.span("indices:data/read/search",
+                              index=expression) as span:
+            # per-lane SLIs (docs/OBSERVABILITY.md "fleet"): every search
+            # lands one requests/errors/rejected count and one latency sample
+            # under its lane — the counters the time-series sampler windows
+            # and the SLO burn-rate engine judges (obs/slo.py). Recorded at
+            # THIS boundary so cache hits, scheduler 429s and host-loop
+            # fallbacks all count exactly once.
+            from ..obs import insights as _ins
+            from ..utils.metrics import METRICS as _m
+            from ..utils.wlm import PressureRejectedException as _rej
+            lane = sli_lane or wlm_lane or "interactive"
+            _t0 = time.monotonic()
+            _rec = self.flight_recorder
+            tl = _fr.current() if _rec.enabled else 0
+            token = None
+            if _rec.enabled and not tl:
+                tl = _rec.start("search", index=expression,
+                                node=self.node_name)
+                token = _fr.set_current(tl)
+            # query insights (obs/insights.py): fingerprint the body at THIS
+            # boundary — the same place the per-lane SLIs land — so cache
+            # hits, rejections, errors and host-ladder attribution all
+            # aggregate under one bounded query shape
+            obs, ins_token = _ins.begin(body if isinstance(body, dict)
+                                        else {}, lane)
+            try:
+                resp = self._search_recorded(expression, body, phase_hook,
+                                             phase_ctx, copy_protect,
+                                             wlm_lane, tl, span)
+            except _rej:
+                _m.counter(f"search.lane.{lane}.rejected").inc()
+                _ins.finish(ins_token, obs, rejected=True, timeline_id=tl)
+                raise
+            except BaseException as e:
+                # client-side 4xx API errors (bad query, missing index) are
+                # the caller's fault, not lost availability — only server
+                # faults burn the error budget
+                if getattr(e, "status", 500) >= 500:
+                    _m.counter(f"search.lane.{lane}.errors").inc()
+                    _ins.finish(ins_token, obs, error=True, timeline_id=tl)
+                else:
+                    _ins.finish(ins_token, obs, timeline_id=tl)
+                raise
+            finally:
+                if token is not None:
+                    _fr.reset_current(token)
+            _m.counter(f"search.lane.{lane}.requests").inc()
+            took_ms = (time.monotonic() - _t0) * 1000.0
+            if _m.enabled:
+                _m.histogram(f"search.lane.{lane}.latency_ms").record(
+                    took_ms)
+            _ins.finish(ins_token, obs, latency_ms=took_ms, timeline_id=tl)
+            return resp
 
     def _search_recorded(self, expression: str, body: dict, phase_hook,
                          phase_ctx: Optional[dict], copy_protect: bool,
-                         wlm_lane: Optional[str], tl: int) -> dict:
+                         wlm_lane: Optional[str], tl: int,
+                         root_span) -> dict:
         # a body the mesh already declined in this request (msearch batch
         # decline -> per-body retry) skips the mesh: one logical search
         # counts at most one mesh fallback, and the retry does no wasted
@@ -1115,76 +1118,74 @@ class Node:
         # each other's windows; the trace span carries the exact story)
         from ..search import fastpath as _fp
         rungs_before = dict(_fp.STATS)
-        root_span = None
+        if root_span is not None:
+            root_span.attributes["shards"] = len(searchers)
         try:
-            with self.tracer.span("indices:data/read/search",
-                                  index=expression,
-                                  shards=len(searchers)) as root_span:
-                if _rec.enabled and tl and root_span is not None:
-                    # key the timeline to the existing trace context, so
-                    # journals and span trees cross-reference
-                    _rec.annotate(tl, trace_root_id=root_span.span_id,
-                                  task_id=task.id)
-                resp = None
-                if (len(names) == 1 and not remote_parts
-                        and phase_hook is None
-                        and self.indices[names[0]].mappings.star_trees):
-                    # star-tree composite index: eligible size=0 agg
-                    # requests answer from the pre-aggregated cubes
-                    from ..search import startree
-                    resp = startree.try_answer(
-                        searchers, body,
-                        self.indices[names[0]].mappings.star_trees)
-                if (resp is None and not mesh_declined and len(names) == 1
-                        and not remote_parts and phase_hook is None):
-                    svc0 = self.indices[names[0]]
-                    sched = self.serving
-                    if sched is not None and sched.enabled:
-                        # serving scheduler: coalesce this request with
-                        # concurrent eligible ones into a single batched
-                        # program invocation; non-coalescable shapes
-                        # bypass unchanged
-                        if sched.accepts(body):
-                            resp = sched.execute(names[0], svc0, body,
-                                                 task=task,
-                                                 lane=wlm_lane
-                                                 or "interactive")
-                        else:
-                            sched.note_bypass()
-                            if self.mesh_service is not None:
-                                resp = self.mesh_service.try_search(
-                                    names[0], svc0, body)
-                    elif self.mesh_service is not None:
-                        resp = self.mesh_service.try_search(names[0], svc0,
-                                                            body)
-                    body.pop("_mesh_declined", None)
-                if resp is None:
-                    all_names = list(names) + [
-                        f"{a}:{rn}" for a, _n, rns in remote_parts
-                        for rn in rns]
-                    # bit-consistency gate: when an SPMD mesh owns this
-                    # node's hot path, OR replica read copies round-robin
-                    # with the primary, a host-loop execution (decline,
-                    # scheduler bypass, degradation, replica pick) must
-                    # stay byte-identical to its XLA-domain siblings —
-                    # the codec-v2 impact ladder serves the host-oracle
-                    # f32 domain instead, so it only engages when this
-                    # node's serving is single-domain
-                    # (search/impactpath.py)
-                    replicated = any(
-                        getattr(self.indices[n], "replica_searchers",
-                                None)
-                        for n in names)
-                    tok = impactpath.mesh_attached_token(
-                        self.mesh_service is not None or replicated)
-                    try:
-                        resp = search_shards(searchers, body,
-                                             index_name=",".join(all_names),
+            if _rec.enabled and tl and root_span is not None:
+                # key the timeline to the existing trace context, so
+                # journals and span trees cross-reference
+                _rec.annotate(tl, trace_root_id=root_span.trace_id,
+                              task_id=task.id)
+            resp = None
+            if (len(names) == 1 and not remote_parts
+                    and phase_hook is None
+                    and self.indices[names[0]].mappings.star_trees):
+                # star-tree composite index: eligible size=0 agg
+                # requests answer from the pre-aggregated cubes
+                from ..search import startree
+                resp = startree.try_answer(
+                    searchers, body,
+                    self.indices[names[0]].mappings.star_trees)
+            if (resp is None and not mesh_declined and len(names) == 1
+                    and not remote_parts and phase_hook is None):
+                svc0 = self.indices[names[0]]
+                sched = self.serving
+                if sched is not None and sched.enabled:
+                    # serving scheduler: coalesce this request with
+                    # concurrent eligible ones into a single batched
+                    # program invocation; non-coalescable shapes
+                    # bypass unchanged
+                    if sched.accepts(body):
+                        resp = sched.execute(names[0], svc0, body,
                                              task=task,
-                                             phase_hook=phase_hook,
-                                             phase_ctx=phase_ctx)
-                    finally:
-                        impactpath.reset_mesh_attached(tok)
+                                             lane=wlm_lane
+                                             or "interactive")
+                    else:
+                        sched.note_bypass()
+                        if self.mesh_service is not None:
+                            resp = self.mesh_service.try_search(
+                                names[0], svc0, body)
+                elif self.mesh_service is not None:
+                    resp = self.mesh_service.try_search(names[0], svc0,
+                                                        body)
+                body.pop("_mesh_declined", None)
+            if resp is None:
+                all_names = list(names) + [
+                    f"{a}:{rn}" for a, _n, rns in remote_parts
+                    for rn in rns]
+                # bit-consistency gate: when an SPMD mesh owns this
+                # node's hot path, OR replica read copies round-robin
+                # with the primary, a host-loop execution (decline,
+                # scheduler bypass, degradation, replica pick) must
+                # stay byte-identical to its XLA-domain siblings —
+                # the codec-v2 impact ladder serves the host-oracle
+                # f32 domain instead, so it only engages when this
+                # node's serving is single-domain
+                # (search/impactpath.py)
+                replicated = any(
+                    getattr(self.indices[n], "replica_searchers",
+                            None)
+                    for n in names)
+                tok = impactpath.mesh_attached_token(
+                    self.mesh_service is not None or replicated)
+                try:
+                    resp = search_shards(searchers, body,
+                                         index_name=",".join(all_names),
+                                         task=task,
+                                         phase_hook=phase_hook,
+                                         phase_ctx=phase_ctx)
+                finally:
+                    impactpath.reset_mesh_attached(tok)
         except BaseException as e:
             if _rec.enabled and tl:
                 _rec.record(tl, "search.error", error=type(e).__name__)
@@ -1242,45 +1243,47 @@ class Node:
         invocation per group (multi-shard indices on a pod); the remainder
         fuse into grouped Pallas kernel launches (grid over queries).
         Returns None when wholly ineligible — caller falls back per-body."""
-        from .admin import check_open
-        names = check_open(self, self.metadata.resolve(expression),
-                           expression)
-        searchers = []
-        for name in names:
-            searchers.extend(self.indices[name].searchers)
-        resps: Optional[List[Optional[dict]]] = None
-        if self.mesh_service is not None and len(names) == 1:
-            # ALWAYS consult the mesh — including single-shard indices it
-            # will decline: try_msearch attributes the decline
-            # (fallback_shapes["single_shard"]) and marks the bodies
-            # `_mesh_declined`, exactly like the direct per-request path,
-            # so scheduler/msearch traffic and direct traffic report
-            # identical mesh attribution (and the per-body retry derives
-            # identical request-cache keys — the marker is popped before
-            # key derivation)
-            svc = self.indices[names[0]]
-            resps = self.mesh_service.try_msearch(names[0], svc, bodies)
-            if all(r is None for r in resps):
-                resps = None
-        if resps is None or any(r is None for r in resps):
-            todo = ([i for i, r in enumerate(resps) if r is None]
-                    if resps is not None else list(range(len(bodies))))
-            batched = msearch_batched(searchers,
-                                      [bodies[i] for i in todo],
-                                      index_name=",".join(names))
-            if batched is not None:
-                if resps is None:
-                    resps = [None] * len(bodies)
-                for i, r in zip(todo, batched):
-                    if resps[i] is None:
-                        resps[i] = r
-        if resps is not None and len(names) == 1:
-            for resp in resps:
-                if resp is None:
-                    continue       # caller runs this body per-body
-                for h in resp["hits"]["hits"]:
-                    h["_index"] = names[0]
-        return resps
+        with self.tracer.span("node.msearch", index=expression,
+                              bodies=len(bodies)):
+            from .admin import check_open
+            names = check_open(self, self.metadata.resolve(expression),
+                               expression)
+            searchers = []
+            for name in names:
+                searchers.extend(self.indices[name].searchers)
+            resps: Optional[List[Optional[dict]]] = None
+            if self.mesh_service is not None and len(names) == 1:
+                # ALWAYS consult the mesh — including single-shard indices it
+                # will decline: try_msearch attributes the decline
+                # (fallback_shapes["single_shard"]) and marks the bodies
+                # `_mesh_declined`, exactly like the direct per-request path,
+                # so scheduler/msearch traffic and direct traffic report
+                # identical mesh attribution (and the per-body retry derives
+                # identical request-cache keys — the marker is popped before
+                # key derivation)
+                svc = self.indices[names[0]]
+                resps = self.mesh_service.try_msearch(names[0], svc, bodies)
+                if all(r is None for r in resps):
+                    resps = None
+            if resps is None or any(r is None for r in resps):
+                todo = ([i for i, r in enumerate(resps) if r is None]
+                        if resps is not None else list(range(len(bodies))))
+                batched = msearch_batched(searchers,
+                                          [bodies[i] for i in todo],
+                                          index_name=",".join(names))
+                if batched is not None:
+                    if resps is None:
+                        resps = [None] * len(bodies)
+                    for i, r in zip(todo, batched):
+                        if resps[i] is None:
+                            resps[i] = r
+            if resps is not None and len(names) == 1:
+                for resp in resps:
+                    if resp is None:
+                        continue       # caller runs this body per-body
+                    for h in resp["hits"]["hits"]:
+                        h["_index"] = names[0]
+            return resps
 
     def stats(self) -> dict:
         out = {

@@ -383,6 +383,7 @@ def fused_bm25_topk_tfdl(docs_hbm: jnp.ndarray, tfdl_hbm: jnp.ndarray,
         kernel,
         grid_spec=grid_spec,
         out_shape=out_shape,
+        name="fused_bm25_topk_tfdl",
         compiler_params=pltpu.CompilerParams(has_side_effects=True),
     )(rowstarts, nrows, lens, skips, weights, msm, avgdl, dlo, dhi,
       docs_hbm, tfdl_hbm)
@@ -632,6 +633,7 @@ def fused_bm25_bool_topk(docs_hbm: jnp.ndarray, tfdl_hbm: jnp.ndarray,
         kernel,
         grid_spec=grid_spec,
         out_shape=out_shape,
+        name="fused_bm25_bool_topk",
         compiler_params=pltpu.CompilerParams(has_side_effects=True),
     )(rowstarts, nrows, lens, skips, weights, cw, thresh, avgdl, dlo, dhi,
       docs_hbm, tfdl_hbm, filt_hbm)
@@ -826,6 +828,7 @@ def fused_bm25_topk_impact(docs_hbm: jnp.ndarray, imp_hbm: jnp.ndarray,
         kernel,
         grid_spec=grid_spec,
         out_shape=out_shape,
+        name="fused_bm25_topk_impact",
         compiler_params=pltpu.CompilerParams(has_side_effects=True),
     )(rowstarts, nrows, lens, skips, weights, msm, dlo, dhi,
       docs_hbm, imp_hbm)

@@ -401,27 +401,28 @@ class RestClient:
 
     def search(self, index: str = "_all", body: Optional[dict] = None,
                scroll: Optional[str] = None, **kw) -> dict:
-        body = dict(body or {})
-        body.update({k: v for k, v in kw.items() if v is not None})
-        # request deadline: the budget is anchored HERE, at REST accept,
-        # so scheduler queue wait and every downstream stage spend from
-        # the same clock (utils/deadline.py; docs/RESILIENCE.md)
-        from ..utils import deadline as _ddl
-        _dl_token = None
-        if _ddl.current() is None:
+        with self.node.tracer.span("rest.search", index=index):
+            body = dict(body or {})
+            body.update({k: v for k, v in kw.items() if v is not None})
+            # request deadline: the budget is anchored HERE, at REST accept,
+            # so scheduler queue wait and every downstream stage spend from
+            # the same clock (utils/deadline.py; docs/RESILIENCE.md)
+            from ..utils import deadline as _ddl
+            _dl_token = None
+            if _ddl.current() is None:
+                try:
+                    _dl_obj = _ddl.Deadline.from_body(body)
+                except ValueError as e:
+                    raise ApiError(400, "parsing_exception", str(e))
+                if _dl_obj is not None:
+                    _dl_token = _ddl.set_current(_dl_obj)
             try:
-                _dl_obj = _ddl.Deadline.from_body(body)
-            except ValueError as e:
-                raise ApiError(400, "parsing_exception", str(e))
-            if _dl_obj is not None:
-                _dl_token = _ddl.set_current(_dl_obj)
-        try:
-            return self._search_deadlined(index, body, scroll)
-        except _ddl.PartialResultsUnacceptable as e:
-            raise ApiError(503, "search_phase_execution_exception", str(e))
-        finally:
-            if _dl_token is not None:
-                _ddl.reset_current(_dl_token)
+                return self._search_deadlined(index, body, scroll)
+            except _ddl.PartialResultsUnacceptable as e:
+                raise ApiError(503, "search_phase_execution_exception", str(e))
+            finally:
+                if _dl_token is not None:
+                    _ddl.reset_current(_dl_token)
 
     def _search_deadlined(self, index: str, body: dict,
                           scroll: Optional[str]) -> dict:
@@ -759,64 +760,65 @@ class RestClient:
         return resp
 
     def msearch(self, body: List[dict], index: Optional[str] = None) -> dict:
-        pairs = []
-        i = 0
-        while i < len(body):
-            header = body[i]; i += 1
-            search_body = body[i]; i += 1
-            pairs.append((header.get("index", index or "_all"), search_body))
-        # batched TPU path: one index expression -> fast-path-eligible
-        # bodies fuse into grouped Pallas kernel launches (grid over
-        # queries); the rest come back as None and run per-body below.
-        # A search pipeline (explicit or index default) forces the
-        # per-body path so each body gets its processors applied
-        partial: List[Optional[dict]] = [None] * len(pairs)
-        if (pairs and len({idx for idx, _ in pairs}) == 1
-                and not any("search_pipeline" in b or "_workload_group" in b
-                            for _, b in pairs)
-                and not self._default_search_pipeline(pairs[0][0])):
-            try:
-                resps = self.node.msearch(pairs[0][0],
-                                          [b for _, b in pairs])
-            except (dsl.QueryParseError, IndexNotFoundError, IndexClosedError,
-                    KeyError, TypeError, ValueError, CircuitBreakingException):
-                # fall back to the per-body path, which maps errors into
-                # per-response error objects
-                resps = None
-            if resps is not None:
-                partial = list(resps)
-        todo = [i for i, r in enumerate(partial) if r is None]
+        with self.node.tracer.span("rest.msearch", lines=len(body)):
+            pairs = []
+            i = 0
+            while i < len(body):
+                header = body[i]; i += 1
+                search_body = body[i]; i += 1
+                pairs.append((header.get("index", index or "_all"), search_body))
+            # batched TPU path: one index expression -> fast-path-eligible
+            # bodies fuse into grouped Pallas kernel launches (grid over
+            # queries); the rest come back as None and run per-body below.
+            # A search pipeline (explicit or index default) forces the
+            # per-body path so each body gets its processors applied
+            partial: List[Optional[dict]] = [None] * len(pairs)
+            if (pairs and len({idx for idx, _ in pairs}) == 1
+                    and not any("search_pipeline" in b or "_workload_group" in b
+                                for _, b in pairs)
+                    and not self._default_search_pipeline(pairs[0][0])):
+                try:
+                    resps = self.node.msearch(pairs[0][0],
+                                              [b for _, b in pairs])
+                except (dsl.QueryParseError, IndexNotFoundError, IndexClosedError,
+                        KeyError, TypeError, ValueError, CircuitBreakingException):
+                    # fall back to the per-body path, which maps errors into
+                    # per-response error objects
+                    resps = None
+                if resps is not None:
+                    partial = list(resps)
+            todo = [i for i, r in enumerate(partial) if r is None]
 
-        def run_one(i: int) -> dict:
-            idx, search_body = pairs[i]
-            try:
-                return self.search(idx, search_body)
-            except (ApiError, IndexNotFoundError) as e:
-                return {"error": {"type": type(e).__name__,
-                                  "reason": str(e)}}
+            def run_one(i: int) -> dict:
+                idx, search_body = pairs[i]
+                try:
+                    return self.search(idx, search_body)
+                except (ApiError, IndexNotFoundError) as e:
+                    return {"error": {"type": type(e).__name__,
+                                      "reason": str(e)}}
 
-        if len(todo) > 1:
-            # concurrent per-body fallback (reference
-            # TransportMultiSearchAction runs items concurrently too):
-            # device steps serialize but host work and device round trips
-            # overlap across bodies. Runs on the node's named "search"
-            # pool (utils/threadpool.py) instead of a throwaway executor —
-            # bounded node-wide, counted in _nodes/stats, and the pool's
-            # contextvars carry the request's trace span into the workers
-            futs = [(i, self.node.thread_pools.pool("search").submit(
-                run_one, i)) for i in todo]
-            for i, fut in futs:
-                partial[i] = fut.result()
-        else:
-            for i in todo:
-                partial[i] = run_one(i)
-        for _, b in pairs:
-            if isinstance(b, dict):
-                # internal mesh-decline marker must not leak into the
-                # caller's body dicts (bodies served by the batched kernel
-                # path never traverse Node.search, which pops it)
-                b.pop("_mesh_declined", None)
-        return {"took": 0, "responses": partial}
+            if len(todo) > 1:
+                # concurrent per-body fallback (reference
+                # TransportMultiSearchAction runs items concurrently too):
+                # device steps serialize but host work and device round trips
+                # overlap across bodies. Runs on the node's named "search"
+                # pool (utils/threadpool.py) instead of a throwaway executor —
+                # bounded node-wide, counted in _nodes/stats, and the pool's
+                # contextvars carry the request's trace span into the workers
+                futs = [(i, self.node.thread_pools.pool("search").submit(
+                    run_one, i)) for i in todo]
+                for i, fut in futs:
+                    partial[i] = fut.result()
+            else:
+                for i in todo:
+                    partial[i] = run_one(i)
+            for _, b in pairs:
+                if isinstance(b, dict):
+                    # internal mesh-decline marker must not leak into the
+                    # caller's body dicts (bodies served by the batched kernel
+                    # path never traverse Node.search, which pops it)
+                    b.pop("_mesh_declined", None)
+            return {"took": 0, "responses": partial}
 
     # ------ _remotestore/_restore (reference RestoreRemoteStoreAction) -----
 

@@ -39,6 +39,7 @@ from ..models.similarity import Similarity, resolve_similarity
 from ..ops import aggs as agg_ops
 from ..ops import scoring as ops
 from ..script import painless_lite as pl
+from ..utils.trace import TRACER
 from . import query_dsl as dsl
 from .aggregations import AggNode
 
@@ -2442,7 +2443,7 @@ def _build_join_scatter(gsize: int, need: Tuple[str, ...]):
     join slot space (padding/unresolved slots are -1 -> sentinel -> dropped)."""
     import jax
 
-    def run(gslot, scores, matched):
+    def join_program(gslot, scores, matched):
         import jax.numpy as jnp
 
         ok = (gslot >= 0) & (matched > 0)
@@ -2462,7 +2463,7 @@ def _build_join_scatter(gsize: int, need: Tuple[str, ...]):
                 jnp.where(ok, scores, 3.4e38), mode="drop")
         return out
 
-    return jax.jit(run)
+    return jax.jit(join_program)
 
 
 def _join_prepass(child: LNode, ji, need: Tuple[str, ...], ctx: ShardContext,
@@ -4965,10 +4966,10 @@ def _purge_masks_for_uid(uid: int) -> None:
 def _build_mask_executor(spec):
     import jax
 
-    def run(seg_arrays, params):
+    def mask_program(seg_arrays, params):
         return emit(spec, seg_arrays, params).matched
 
-    return jax.jit(run)
+    return jax.jit(mask_program)
 
 
 # =====================================================================
@@ -5028,7 +5029,7 @@ def build_rescore_program(T: int, C: int, k1: float, b: float):
 def build_impact_program(B: int, bucket: int, C: int, bits: int):
     import jax
 
-    def run(d_docs, d_impacts, live, bstart, blen, bweight, msm):
+    def impact_program(d_docs, d_impacts, live, bstart, blen, bweight, msm):
         import jax.numpy as jnp
         ndocs_pad = live.shape[0]
         sm = ops.impact_score_blocks(d_docs, d_impacts, live, bstart,
@@ -5040,7 +5041,7 @@ def build_impact_program(B: int, bucket: int, C: int, bits: int):
         vals, idx = jax.lax.top_k(masked, kk)
         return vals, idx, total
 
-    return jax.jit(run)
+    return jax.jit(impact_program)
 
 
 # spec kinds whose second element is a node id (everything `prepare`
@@ -5252,7 +5253,7 @@ def _executor_run_fn(full_spec):
     (query_spec, sort_spec, agg_specs, k_pad, named_specs, has_after,
      collapse_spec) = full_spec
 
-    def run(seg_arrays, params):
+    def executor_program(seg_arrays, params):
         import jax.numpy as jnp
 
         sm = emit(query_spec, seg_arrays, params)
@@ -5296,7 +5297,7 @@ def _executor_run_fn(full_spec):
             out["named"] = named
         return out
 
-    return run
+    return executor_program
 
 
 def launch_segment_batch(prepared: list, seg_arrays: dict):
@@ -5323,7 +5324,8 @@ def launch_segment_batch(prepared: list, seg_arrays: dict):
         pending.append(exe(seg_arrays, cparams))   # invocation, no sync
 
     def _fetch():
-        return jax.device_get(pending)
+        with TRACER.span("device.wait", program="executor"):
+            return jax.device_get(pending)
 
     return _fetch
 
@@ -5360,12 +5362,12 @@ def _build_gather_executor(query_spec):
     reference `search/rescore/QueryRescorer.java`)."""
     import jax
 
-    def run(seg_arrays, params):
+    def gather_program(seg_arrays, params):
         sm = emit(query_spec, seg_arrays, params)
         docs = params["gather_docs"]
         return sm.scores[docs], sm.matched[docs]
 
-    return jax.jit(run)
+    return jax.jit(gather_program)
 
 
 def run_gather_scores(query_spec, seg_arrays: dict, params: dict, docs: np.ndarray):
@@ -5385,7 +5387,7 @@ def _build_agg_executor(key):
 
     query_spec, agg_spec = key
 
-    def run(seg_arrays, params):
+    def agg_program(seg_arrays, params):
         import jax.numpy as jnp
 
         sm = emit(query_spec, seg_arrays, params)
@@ -5393,7 +5395,7 @@ def _build_agg_executor(key):
         match_f = sm.matched.astype(jnp.float32) * jnp.where(live > 0, 1.0, 0.0)
         return emit_agg(agg_spec, seg_arrays, params, match_f, sm.scores)
 
-    return jax.jit(run)
+    return jax.jit(agg_program)
 
 
 def run_agg_only(query_spec, agg_spec, seg_arrays: dict, params: dict):

@@ -24,6 +24,7 @@ from ..obs import flight_recorder as _flight
 from ..obs import query_cost as _qcost
 from ..script.painless_lite import ScriptError as _ScriptError
 from ..utils import deadline as _dl
+from ..utils.trace import TRACER
 from . import compiler as C
 from . import fastpath
 from . import impactpath
@@ -258,85 +259,86 @@ class ShardSearcher:
         if segments is None:
             segments = (list(self.replica.segments) if self.replica is not None
                         else list(self.engine.segments))
-        ctx = stats_ctx or C.ShardContext(self.engine.mappings, segments,
-                                          self.similarity, self.field_similarities)
-        # derived (runtime) fields: mapping-level + search-body defs
-        # materialize into per-segment columns before rewrite sees them
-        ddefs = dict(getattr(ctx.mappings, "derived", {}) or {})
-        if body.get("derived"):
-            from . import derived as derived_mod
-            try:
-                req_defs = derived_mod.parse_defs(body["derived"])
-                derived_mod.check_conflicts(ctx.mappings, req_defs)
-                ddefs.update(req_defs)
-            except ValueError as e:
-                raise dsl.QueryParseError(str(e))
-            import copy as _copy
-            ctx = _copy.copy(ctx)
-            ctx.mappings = derived_mod.MappingsOverlay(ctx.mappings, ddefs)
-        if ddefs:
-            from . import derived as derived_mod
-            names = derived_mod.referenced(ddefs, body)
-            if names:
-                from ..script.painless_lite import ScriptError
+        with TRACER.span("search.plan"):
+            ctx = stats_ctx or C.ShardContext(self.engine.mappings, segments,
+                                              self.similarity, self.field_similarities)
+            # derived (runtime) fields: mapping-level + search-body defs
+            # materialize into per-segment columns before rewrite sees them
+            ddefs = dict(getattr(ctx.mappings, "derived", {}) or {})
+            if body.get("derived"):
+                from . import derived as derived_mod
                 try:
-                    for seg in segments:
-                        derived_mod.ensure(seg, ctx.mappings, ddefs, names)
-                except (ScriptError, ValueError) as e:
-                    raise dsl.QueryParseError(f"derived field: {e}")
-        query = compose_knn_query(body)
-        lroot = C.rewrite(query, ctx, scoring=True)
-        ctx._current_lroot = lroot  # children/parent aggs join against it
+                    req_defs = derived_mod.parse_defs(body["derived"])
+                    derived_mod.check_conflicts(ctx.mappings, req_defs)
+                    ddefs.update(req_defs)
+                except ValueError as e:
+                    raise dsl.QueryParseError(str(e))
+                import copy as _copy
+                ctx = _copy.copy(ctx)
+                ctx.mappings = derived_mod.MappingsOverlay(ctx.mappings, ddefs)
+            if ddefs:
+                from . import derived as derived_mod
+                names = derived_mod.referenced(ddefs, body)
+                if names:
+                    from ..script.painless_lite import ScriptError
+                    try:
+                        for seg in segments:
+                            derived_mod.ensure(seg, ctx.mappings, ddefs, names)
+                    except (ScriptError, ValueError) as e:
+                        raise dsl.QueryParseError(f"derived field: {e}")
+            query = compose_knn_query(body)
+            lroot = C.rewrite(query, ctx, scoring=True)
+            ctx._current_lroot = lroot  # children/parent aggs join against it
 
-        size = int(body.get("size", 10))
-        frm = int(body.get("from", 0))
-        sort_specs = _norm_sort_specs(body)
-        is_field_sort = bool(sort_specs) and sort_specs[0]["field"] not in ("_score",)
-        # oversample: host tie-refinement + multi-key sorting need slack
-        window = frm + size
-        oversample = 2 if (is_field_sort or len(sort_specs) > 1) else 1
-        agg_nodes = parse_aggs(body.get("aggs", body.get("aggregations")))
-        named_nodes = _collect_named(lroot)
-        rescores = body.get("rescore")
-        if rescores is not None and not isinstance(rescores, list):
-            rescores = [rescores]
-        min_score = body.get("min_score")
-        search_after = body.get("search_after")
-        collapse = body.get("collapse")
-        if collapse:
-            if not isinstance(collapse, dict) or not collapse.get("field"):
-                raise dsl.QueryParseError("[collapse] requires [field]")
-            if sort_specs and sort_specs[0]["field"] == "_script":
-                raise dsl.QueryParseError(
-                    "cannot use [collapse] with a primary _script sort")
+            size = int(body.get("size", 10))
+            frm = int(body.get("from", 0))
+            sort_specs = _norm_sort_specs(body)
+            is_field_sort = bool(sort_specs) and sort_specs[0]["field"] not in ("_score",)
+            # oversample: host tie-refinement + multi-key sorting need slack
+            window = frm + size
+            oversample = 2 if (is_field_sort or len(sort_specs) > 1) else 1
+            agg_nodes = parse_aggs(body.get("aggs", body.get("aggregations")))
+            named_nodes = _collect_named(lroot)
+            rescores = body.get("rescore")
+            if rescores is not None and not isinstance(rescores, list):
+                rescores = [rescores]
+            min_score = body.get("min_score")
+            search_after = body.get("search_after")
+            collapse = body.get("collapse")
+            if collapse:
+                if not isinstance(collapse, dict) or not collapse.get("field"):
+                    raise dsl.QueryParseError("[collapse] requires [field]")
+                if sort_specs and sort_specs[0]["field"] == "_script":
+                    raise dsl.QueryParseError(
+                        "cannot use [collapse] with a primary _script sort")
 
-        # per-shard doc budget (reference terminate_after) + the ambient
-        # request deadline (utils/deadline.py): both are enforced at
-        # segment granularity — one segment is one device program, the
-        # natural cancellation point — and both mark the result partial
-        # (`terminated_early` / `timed_out`) with honest `gte` totals
-        ta = int(body.get("terminate_after") or 0)
-        deadline = _dl.current()
+            # per-shard doc budget (reference terminate_after) + the ambient
+            # request deadline (utils/deadline.py): both are enforced at
+            # segment granularity — one segment is one device program, the
+            # natural cancellation point — and both mark the result partial
+            # (`terminated_early` / `timed_out`) with honest `gte` totals
+            ta = int(body.get("terminate_after") or 0)
+            deadline = _dl.current()
 
-        result = ShardQueryResult(shard=shard_ord, segments=segments)
-        ran_segs: List[Segment] = []
+            result = ShardQueryResult(shard=shard_ord, segments=segments)
+            ran_segs: List[Segment] = []
 
-        # Pallas fast path: plain BM25 term-group top-k AND bool/filtered
-        # shapes go through the fused kernels (search/fastpath.py); anything
-        # they can't serve falls back to the general XLA plan per segment
-        fast_spec = (fastpath.make_spec(lroot, sort_specs, agg_nodes,
-                                        named_nodes, search_after, window,
-                                        body)
-                     if fastpath.enabled() and self.device is None else None)
-        # codec-v2 eager-impact path (search/impactpath.py): the same pure
-        # BM25 top-k shape class served from the quantized impact plane
-        # with host block-max pruning — XLA, so it engages on every
-        # backend. Segments decline per-segment (v1 codec, no plane), and
-        # a failed serve certificate falls through to the exact program.
-        imp_spec = (impactpath.make_spec(lroot, sort_specs, agg_nodes,
-                                         named_nodes, search_after, window,
-                                         body)
-                    if self.device is None else None)
+            # Pallas fast path: plain BM25 term-group top-k AND bool/filtered
+            # shapes go through the fused kernels (search/fastpath.py); anything
+            # they can't serve falls back to the general XLA plan per segment
+            fast_spec = (fastpath.make_spec(lroot, sort_specs, agg_nodes,
+                                            named_nodes, search_after, window,
+                                            body)
+                         if fastpath.enabled() and self.device is None else None)
+            # codec-v2 eager-impact path (search/impactpath.py): the same pure
+            # BM25 top-k shape class served from the quantized impact plane
+            # with host block-max pruning — XLA, so it engages on every
+            # backend. Segments decline per-segment (v1 codec, no plane), and
+            # a failed serve certificate falls through to the exact program.
+            imp_spec = (impactpath.make_spec(lroot, sort_specs, agg_nodes,
+                                             named_nodes, search_after, window,
+                                             body)
+                        if self.device is None else None)
 
         # concurrent segment search, TPU-style: a many-segment shard runs
         # as ONE kernel launch over the concatenated shard view instead of
@@ -400,57 +402,60 @@ class ShardSearcher:
                                        is_field_sort, ctx)
                     continue
             if imp_spec is not None:
-                iout = impactpath.segment_search(seg, ctx, imp_spec, window)
+                with TRACER.span("impactpath.serve"):
+                    iout = impactpath.segment_search(seg, ctx, imp_spec,
+                                                     window)
                 if iout is not None:
                     ran_segs.append(seg)
                     self._collect_topk(result, iout, seg, seg_ord,
                                        shard_ord, sort_specs, rescores,
                                        min_score, is_field_sort, ctx)
                     continue
-            tief = getattr(seg, "tie_ranks", None)
-            tie_aware = tief is not None and tief() is not None
-            if sort_specs and sort_specs[0]["field"] == "_script":
-                # script order is host-computed: collect the full segment
-                # window so the host re-sort sees every matching doc
-                k_pad = seg.ndocs_pad
-            else:
-                k_pad = min(next_pow2(max(window * oversample, 16)), seg.ndocs_pad)
-                if tie_aware:
-                    # BP-reordered segment: seed the window deep enough
-                    # that a saturated all-distinct extraction already
-                    # holds >= window*oversample strictly-better lanes
-                    # above its deepest key — otherwise the widen loop
-                    # below pays a second launch with zero ties present
-                    k_pad = min(next_pow2(max(window * oversample * 2, 32)),
-                                seg.ndocs_pad)
-            params: Dict[str, Any] = {}
-            qspec = C.prepare(lroot, seg, ctx, params)
-            qc = _qcost.current()
-            if qc is not None:
-                # actual launched-shape cost of the XLA path: the program
-                # gathers the spec's pow2 buckets (ops.gather_postings)
-                # and extracts a k_pad top-k window
-                gb, slots = _qcost.spec_gather_shape(qspec)
-                qc.note_actual(gb, slots, k_pad, path="xla", segment=seg)
-            sspec = C.prepare_sort(sort_specs, seg, params)
-            agg_specs = []
-            for i, an in enumerate(agg_nodes):
-                if an.kind == "top_hits":
-                    continue  # resolved from this segment's top-k below
-                agg_specs.append((an.name, C.prepare_agg(an, seg, ctx, params, f"a{i}")))
-            named_specs = []
-            for nm, nnode in named_nodes:
-                nparams: Dict[str, Any] = {}
-                nspec = C.prepare(nnode, seg, ctx, params)
-                named_specs.append((nm, nspec))
-            has_after = search_after is not None
-            if has_after:
+            with TRACER.span("search.prepare"):
+                tief = getattr(seg, "tie_ranks", None)
+                tie_aware = tief is not None and tief() is not None
                 if sort_specs and sort_specs[0]["field"] == "_script":
-                    raise dsl.QueryParseError(
-                        "search_after is not supported with a primary _script sort")
-                params["after_key"] = np.float32(
-                    _after_key_value(search_after, sort_specs, seg))
-            cspec = C.prepare_collapse(collapse, seg, ctx, params)
+                    # script order is host-computed: collect the full segment
+                    # window so the host re-sort sees every matching doc
+                    k_pad = seg.ndocs_pad
+                else:
+                    k_pad = min(next_pow2(max(window * oversample, 16)), seg.ndocs_pad)
+                    if tie_aware:
+                        # BP-reordered segment: seed the window deep enough
+                        # that a saturated all-distinct extraction already
+                        # holds >= window*oversample strictly-better lanes
+                        # above its deepest key — otherwise the widen loop
+                        # below pays a second launch with zero ties present
+                        k_pad = min(next_pow2(max(window * oversample * 2, 32)),
+                                    seg.ndocs_pad)
+                params: Dict[str, Any] = {}
+                qspec = C.prepare(lroot, seg, ctx, params)
+                qc = _qcost.current()
+                if qc is not None:
+                    # actual launched-shape cost of the XLA path: the program
+                    # gathers the spec's pow2 buckets (ops.gather_postings)
+                    # and extracts a k_pad top-k window
+                    gb, slots = _qcost.spec_gather_shape(qspec)
+                    qc.note_actual(gb, slots, k_pad, path="xla", segment=seg)
+                sspec = C.prepare_sort(sort_specs, seg, params)
+                agg_specs = []
+                for i, an in enumerate(agg_nodes):
+                    if an.kind == "top_hits":
+                        continue  # resolved from this segment's top-k below
+                    agg_specs.append((an.name, C.prepare_agg(an, seg, ctx, params, f"a{i}")))
+                named_specs = []
+                for nm, nnode in named_nodes:
+                    nparams: Dict[str, Any] = {}
+                    nspec = C.prepare(nnode, seg, ctx, params)
+                    named_specs.append((nm, nspec))
+                has_after = search_after is not None
+                if has_after:
+                    if sort_specs and sort_specs[0]["field"] == "_script":
+                        raise dsl.QueryParseError(
+                            "search_after is not supported with a primary _script sort")
+                    params["after_key"] = np.float32(
+                        _after_key_value(search_after, sort_specs, seg))
+                cspec = C.prepare_collapse(collapse, seg, ctx, params)
             while True:
                 try:
                     out = C.run_segment(qspec, sspec, agg_specs,
@@ -461,9 +466,10 @@ class ShardSearcher:
                 except _ScriptError as e:
                     # device-script trace failures are user errors (HTTP 400)
                     raise dsl.QueryParseError(f"script compile error: {e}")
-                keys = np.asarray(out["topk_key"])
-                idx = np.asarray(out["topk_idx"])
-                scores = np.asarray(out["topk_scores"])
+                with TRACER.span("device.wait", program="executor"):
+                    keys = np.asarray(out["topk_key"])
+                    idx = np.asarray(out["topk_idx"])
+                    scores = np.asarray(out["topk_scores"])
                 valid = keys > -np.inf
                 if not tie_aware or sort_specs:
                     # widen only for score sorts: a field sort's primary
@@ -494,39 +500,44 @@ class ShardSearcher:
                 k_pad = min(next_pow2(k_pad * 2), seg.ndocs_pad)
 
             ran_segs.append(seg)
-            result.total += int(out["total"])
-            ms = float(out["max_score"])
-            if ms > result.max_score:
-                result.max_score = ms
+            with TRACER.span("search.collect"):
+                with TRACER.span("device.wait", program="executor"):
+                    # each scalar / array read is its own device->host hop
+                    total = int(out["total"])
+                    ms = float(out["max_score"])
+                    named_np = {nm: np.asarray(v)
+                                for nm, v in out.get("named", {}).items()}
+                result.total += total
+                if ms > result.max_score:
+                    result.max_score = ms
 
-            named_np = {nm: np.asarray(v) for nm, v in out.get("named", {}).items()}
-            for name, aspec in agg_specs:
-                node = next(a for a in agg_nodes if a.name == name)
-                partial = _device_agg_to_partial(node, aspec,
-                                                 out.get("aggs", {}).get(name), seg, ctx)
-                result.agg_partials.setdefault(name, []).append(partial)
+                for name, aspec in agg_specs:
+                    node = next(a for a in agg_nodes if a.name == name)
+                    partial = _device_agg_to_partial(node, aspec,
+                                                     out.get("aggs", {}).get(name), seg, ctx)
+                    result.agg_partials.setdefault(name, []).append(partial)
 
-            # rescore second pass over this segment's window
-            if rescores:
-                scores = self._apply_rescores(rescores, ctx, seg, idx, valid, scores)
+                # rescore second pass over this segment's window
+                if rescores:
+                    scores = self._apply_rescores(rescores, ctx, seg, idx, valid, scores)
 
-            for j in _tie_collect_order(keys, idx, valid, seg):
-                d = int(idx[j])
-                if d >= seg.ndocs:
-                    continue
-                sc = float(scores[j])
-                if min_score is not None and not is_field_sort and sc < min_score:
-                    continue
-                sort_vals, raw_vals = _host_sort_values(sort_specs, seg, d, sc)
-                cand = Candidate(shard_ord, seg_ord, d, sc, sort_vals, raw_vals)
-                if collapse:
-                    cand.collapse_key = _collapse_key_value(
-                        seg, ctx.mappings.aliases.get(collapse["field"],
-                                                      collapse["field"]), d)
-                result.candidates.append(cand)
-                names = [nm for nm, arr in named_np.items() if arr[j]]
-                if names:
-                    result.named_by_doc[(seg_ord, d)] = names
+                for j in _tie_collect_order(keys, idx, valid, seg):
+                    d = int(idx[j])
+                    if d >= seg.ndocs:
+                        continue
+                    sc = float(scores[j])
+                    if min_score is not None and not is_field_sort and sc < min_score:
+                        continue
+                    sort_vals, raw_vals = _host_sort_values(sort_specs, seg, d, sc)
+                    cand = Candidate(shard_ord, seg_ord, d, sc, sort_vals, raw_vals)
+                    if collapse:
+                        cand.collapse_key = _collapse_key_value(
+                            seg, ctx.mappings.aliases.get(collapse["field"],
+                                                          collapse["field"]), d)
+                    result.candidates.append(cand)
+                    names = [nm for nm, arr in named_np.items() if arr[j]]
+                    if names:
+                        result.named_by_doc[(seg_ord, d)] = names
 
         if ta and result.total >= ta:
             # the budget was crossed (possibly exactly on the final
@@ -552,6 +563,7 @@ class ShardSearcher:
         result.took_ms = (time.monotonic() - t0) * 1000.0
         return result
 
+    @TRACER.spanned("search.collect")
     def _collect_view_topk(self, result: ShardQueryResult, view, out: dict,
                            shard_ord: int, sort_specs, min_score,
                            ctx) -> None:
@@ -581,6 +593,7 @@ class ShardSearcher:
                 Candidate(shard_ord, seg_ord, local, sc, sort_vals,
                           raw_vals))
 
+    @TRACER.spanned("search.collect")
     def _collect_topk(self, result: ShardQueryResult, out: dict, seg: Segment,
                       seg_ord: int, shard_ord: int, sort_specs, rescores,
                       min_score, is_field_sort: bool, ctx) -> None:
@@ -1010,7 +1023,6 @@ def search_shards(searchers: List[ShardSearcher], body: dict,
     body["_index_name"] = index_name
     stats = _global_stats_contexts(searchers)
     from ..utils.metrics import METRICS
-    from ..utils.trace import TRACER
     if body.get("profile"):
         # jit-attribution baseline: the profile response reports the
         # DELTA this request caused (compiles triggered, cache traffic)
@@ -1111,22 +1123,23 @@ def launch_msearch_batched(searchers: List[ShardSearcher],
     stats = _global_stats_contexts(searchers)
     nb = len(bodies)
     parsed: List[Optional[tuple]] = []
-    for body in bodies:
-        body = dict(body)
-        body["_index_name"] = index_name
-        if (body.get("aggs") or body.get("aggregations") or body.get("rescore")
-                or body.get("search_after") is not None or body.get("min_score")
-                is not None or body.get("profile")
-                or body.get("explain") == "device_plan"):
-            parsed.append(None)
-            continue
-        try:
-            query = compose_knn_query(body)
-        except (dsl.QueryParseError, KeyError, TypeError, ValueError):
-            parsed.append(None)     # slow path surfaces the error per body
-            continue
-        parsed.append((body, query, _norm_sort_specs(body),
-                       int(body.get("from", 0)) + int(body.get("size", 10))))
+    with TRACER.span("search.plan", bodies=nb):
+        for body in bodies:
+            body = dict(body)
+            body["_index_name"] = index_name
+            if (body.get("aggs") or body.get("aggregations") or body.get("rescore")
+                    or body.get("search_after") is not None or body.get("min_score")
+                    is not None or body.get("profile")
+                    or body.get("explain") == "device_plan"):
+                parsed.append(None)
+                continue
+            try:
+                query = compose_knn_query(body)
+            except (dsl.QueryParseError, KeyError, TypeError, ValueError):
+                parsed.append(None)     # slow path surfaces the error per body
+                continue
+            parsed.append((body, query, _norm_sort_specs(body),
+                           int(body.get("from", 0)) + int(body.get("size", 10))))
 
     t0 = time.monotonic()
     ok = [p is not None for p in parsed]
@@ -1145,35 +1158,36 @@ def launch_msearch_batched(searchers: List[ShardSearcher],
         segments = list(s.engine.segments)
         fspecs: List[Optional[Any]] = [None] * nb
         kroots: List[Optional[Any]] = [None] * nb
-        for bi, p in enumerate(parsed):
-            if not ok[bi]:
-                continue
-            body, query, sort_specs, window = p
-            try:
-                lroot = C.rewrite(query, ctx, scoring=True)
-            except dsl.QueryParseError:
-                ok[bi] = False
-                continue
-            if _collect_named(lroot):
-                ok[bi] = False
-                continue
-            fspecs[bi] = (fastpath.make_spec(lroot, sort_specs, [], [],
-                                             None, window, body)
-                          if fp_on else None)
-            if fspecs[bi] is None:
-                # pure-knn route: a lone LKnn root (query.knn, or the
-                # ES-style top-level knn section with no query) batches
-                # through the vmapped twin of the SAME general program
-                # the direct path runs — first-class vector serving
-                # (ISSUE 15), byte-identical per query by construction
-                if isinstance(lroot, C.LKnn) \
-                        and _knn_batch_body_ok(sort_specs, body, window):
-                    kroots[bi] = lroot
-                else:
-                    if isinstance(lroot, C.LKnn):
-                        from ..search import fusion as _fusion
-                        _fusion.STATS.inc("knn_batch_declined")
+        with TRACER.span("search.plan", shard=i):
+            for bi, p in enumerate(parsed):
+                if not ok[bi]:
+                    continue
+                body, query, sort_specs, window = p
+                try:
+                    lroot = C.rewrite(query, ctx, scoring=True)
+                except dsl.QueryParseError:
                     ok[bi] = False
+                    continue
+                if _collect_named(lroot):
+                    ok[bi] = False
+                    continue
+                fspecs[bi] = (fastpath.make_spec(lroot, sort_specs, [], [],
+                                                 None, window, body)
+                              if fp_on else None)
+                if fspecs[bi] is None:
+                    # pure-knn route: a lone LKnn root (query.knn, or the
+                    # ES-style top-level knn section with no query) batches
+                    # through the vmapped twin of the SAME general program
+                    # the direct path runs — first-class vector serving
+                    # (ISSUE 15), byte-identical per query by construction
+                    if isinstance(lroot, C.LKnn) \
+                            and _knn_batch_body_ok(sort_specs, body, window):
+                        kroots[bi] = lroot
+                    else:
+                        if isinstance(lroot, C.LKnn):
+                            from ..search import fusion as _fusion
+                            _fusion.STATS.inc("knn_batch_declined")
+                        ok[bi] = False
         live_bis = [bi for bi in range(nb)
                     if ok[bi] and fspecs[bi] is not None]
         knn_bis = [bi for bi in range(nb) if ok[bi] and kroots[bi] is not None]
@@ -1358,7 +1372,6 @@ def _finish_search(searchers: List[ShardSearcher],
     """Coordinator reduce + fetch + response assembly (the tail of
     query-then-fetch, shared by search and batched msearch)."""
     from ..utils.metrics import METRICS
-    from ..utils.trace import TRACER
     with TRACER.span("reduce"), METRICS.timer("search.reduce"):
         reduced = reduce_shard_results(results, body, agg_nodes=agg_nodes,
                                        defer_pipelines=bool(agg_nodes))
@@ -1376,120 +1389,121 @@ def _finish_search(searchers: List[ShardSearcher],
                                                stats_ctx=stats[i])
             for c, h in zip(sel, fetched):
                 hits_by_key[(c.shard, c.seg_ord, c.local_doc)] = h
-    hits = [hits_by_key[(c.shard, c.seg_ord, c.local_doc)] for c in reduced["selected"]
-            if (c.shard, c.seg_ord, c.local_doc) in hits_by_key]
+    with TRACER.span("search.respond"):
+        hits = [hits_by_key[(c.shard, c.seg_ord, c.local_doc)] for c in reduced["selected"]
+                if (c.shard, c.seg_ord, c.local_doc) in hits_by_key]
 
-    collapse = body.get("collapse")
-    if collapse:
-        _apply_collapse_inner_hits(searchers, body, index_name, collapse,
-                                   reduced["selected"], hits_by_key)
+        collapse = body.get("collapse")
+        if collapse:
+            _apply_collapse_inner_hits(searchers, body, index_name, collapse,
+                                       reduced["selected"], hits_by_key)
 
-    if reduced["aggs"]:
-        # bucket refinement: ordinal bucket aggs execute complex sub-trees
-        # (terms>terms, bucket top_hits, cardinality-under-terms, ...) as one
-        # recursive sub-search per top bucket — the device pass only fuses
-        # the stats-family metrics into the ordinal bincount
-        for an in agg_nodes:
-            _refine_complex_subs(searchers, body, index_name, an,
-                                 reduced["aggs"].get(an.name),
-                                 body.get("query"), [])
-        for an in agg_nodes:
-            _apply_deferred_tree(an, reduced["aggs"].get(an.name))
+        if reduced["aggs"]:
+            # bucket refinement: ordinal bucket aggs execute complex sub-trees
+            # (terms>terms, bucket top_hits, cardinality-under-terms, ...) as one
+            # recursive sub-search per top bucket — the device pass only fuses
+            # the stats-family metrics into the ordinal bincount
+            for an in agg_nodes:
+                _refine_complex_subs(searchers, body, index_name, an,
+                                     reduced["aggs"].get(an.name),
+                                     body.get("query"), [])
+            for an in agg_nodes:
+                _apply_deferred_tree(an, reduced["aggs"].get(an.name))
 
-    track = body.get("track_total_hits", True)
-    relation = reduced.get("total_rel", "eq")
-    total = reduced["total"]
-    if track is not True and track is not False:
-        track_n = int(track)
-        if total > track_n:
-            total, relation = track_n, "gte"
-    took_ms = (time.monotonic() - t0) * 1000.0
-    METRICS.histogram("search.total").record(took_ms)
-    timed_out = any(r.timed_out for r in results)
-    terminated_early = any(r.terminated_early for r in results)
-    if body.get("allow_partial_search_results", True) is False \
-            and timed_out:
-        # reference parity: partial pages refused -> whole-request error
-        # (the REST facade maps this to a 503
-        # search_phase_execution_exception)
-        raise _dl.PartialResultsUnacceptable(
-            "request timed out with allow_partial_search_results=false")
-    # track_scores (reference): a field-sorted request normally reports
-    # max_score null; track_scores=true opts the rollup back in (the
-    # engine computes scores regardless — they are free on device)
-    show_max = not body.get("sort") or bool(body.get("track_scores"))
-    resp = {
-        "took": int(took_ms),
-        "timed_out": timed_out,
-        "_shards": {"total": len(searchers), "successful": len(searchers),
-                    "skipped": 0, "failed": 0},
-        "hits": {"total": {"value": total, "relation": relation},
-                 "max_score": reduced["max_score"] if show_max else None,
-                 "hits": hits},
-    }
-    if terminated_early:
-        resp["terminated_early"] = True
-    if reduced["aggs"]:
-        resp["aggregations"] = reduced["aggs"]
-    if body.get("suggest"):
-        from .suggest import run_suggest
-        segs = [g for s in searchers for g in s.engine.segments
-                if g.live_count > 0]
-        mappings = searchers[0].engine.mappings if searchers else None
-        resp["suggest"] = run_suggest(body["suggest"], segs, mappings)
-    if body.get("profile"):
-        # per-plan-node breakdown (reference search/profile/): the plan tree
-        # with type/description per node. One honesty note a TPU engine owes
-        # its users: XLA fuses the whole plan into one program, so per-node
-        # device times are not separable — node entries carry the tree and
-        # the root carries the measured phase time (children fused=true).
-        try:
-            plan_tree = C.describe_plan(
-                C.rewrite(dsl.parse_query(body.get("query")),
-                          stats[0], scoring=True)) if stats else None
-        except Exception:
-            plan_tree = None
-        # device attribution: what this request cost the jit layer (cache
-        # traffic + compiles triggered, the DELTA vs the pre-request
-        # baseline search_shards stashed) and which phase-2 rescore path
-        # is active — the per-plan-node "why was this slow" the reference
-        # gets from search/profile/
-        from .fastpath import rescore_mode
-        device_attr = {"rescore_path": rescore_mode(),
-                       "jit": _jit_delta(body.pop("_jit_before", None),
-                                         C.jit_attribution())}
-        shards_profile = []
-        for r in results:
-            entry: dict = {"id": f"[shard][{r.shard}]",
-                           "query_ms": r.took_ms,
-                           "device": device_attr,
-                           "searches": [{"query": [], "rewrite_time": 0,
-                                         "collector": [{
-                                             "name": "SimpleTopKCollector",
-                                             "reason": "search_top_hits",
-                                             "time_in_nanos": int(
-                                                 r.took_ms * 1e6)}]}]}
-            if plan_tree is not None:
-                root = dict(plan_tree)
-                root["time_in_nanos"] = int(r.took_ms * 1e6)
-                root["device"] = device_attr
-                entry["searches"][0]["query"] = [root]
-            shards_profile.append(entry)
-        resp["profile"] = {"shards": shards_profile}
-        qc = _qcost.current()
-        if qc is not None:
-            # per-query device cost: plan-time prediction (CSR stats)
-            # reconciled against the launched program shapes — the byte
-            # domain the north star's ≥20× claim is argued in
-            resp["profile"]["cost"] = qc.snapshot()
-    if body.get("explain") == "device_plan":
-        # device-plan search view: the cost rollup + per-segment
-        # predicted/actual entries, without per-hit _explanation trees
-        qc = _qcost.current()
-        if qc is not None:
-            resp["device_plan"] = {"cost": qc.snapshot(),
-                                   "segments": list(qc.segments)}
-    return resp
+        track = body.get("track_total_hits", True)
+        relation = reduced.get("total_rel", "eq")
+        total = reduced["total"]
+        if track is not True and track is not False:
+            track_n = int(track)
+            if total > track_n:
+                total, relation = track_n, "gte"
+        took_ms = (time.monotonic() - t0) * 1000.0
+        METRICS.histogram("search.total").record(took_ms)
+        timed_out = any(r.timed_out for r in results)
+        terminated_early = any(r.terminated_early for r in results)
+        if body.get("allow_partial_search_results", True) is False \
+                and timed_out:
+            # reference parity: partial pages refused -> whole-request error
+            # (the REST facade maps this to a 503
+            # search_phase_execution_exception)
+            raise _dl.PartialResultsUnacceptable(
+                "request timed out with allow_partial_search_results=false")
+        # track_scores (reference): a field-sorted request normally reports
+        # max_score null; track_scores=true opts the rollup back in (the
+        # engine computes scores regardless — they are free on device)
+        show_max = not body.get("sort") or bool(body.get("track_scores"))
+        resp = {
+            "took": int(took_ms),
+            "timed_out": timed_out,
+            "_shards": {"total": len(searchers), "successful": len(searchers),
+                        "skipped": 0, "failed": 0},
+            "hits": {"total": {"value": total, "relation": relation},
+                     "max_score": reduced["max_score"] if show_max else None,
+                     "hits": hits},
+        }
+        if terminated_early:
+            resp["terminated_early"] = True
+        if reduced["aggs"]:
+            resp["aggregations"] = reduced["aggs"]
+        if body.get("suggest"):
+            from .suggest import run_suggest
+            segs = [g for s in searchers for g in s.engine.segments
+                    if g.live_count > 0]
+            mappings = searchers[0].engine.mappings if searchers else None
+            resp["suggest"] = run_suggest(body["suggest"], segs, mappings)
+        if body.get("profile"):
+            # per-plan-node breakdown (reference search/profile/): the plan tree
+            # with type/description per node. One honesty note a TPU engine owes
+            # its users: XLA fuses the whole plan into one program, so per-node
+            # device times are not separable — node entries carry the tree and
+            # the root carries the measured phase time (children fused=true).
+            try:
+                plan_tree = C.describe_plan(
+                    C.rewrite(dsl.parse_query(body.get("query")),
+                              stats[0], scoring=True)) if stats else None
+            except Exception:
+                plan_tree = None
+            # device attribution: what this request cost the jit layer (cache
+            # traffic + compiles triggered, the DELTA vs the pre-request
+            # baseline search_shards stashed) and which phase-2 rescore path
+            # is active — the per-plan-node "why was this slow" the reference
+            # gets from search/profile/
+            from .fastpath import rescore_mode
+            device_attr = {"rescore_path": rescore_mode(),
+                           "jit": _jit_delta(body.pop("_jit_before", None),
+                                             C.jit_attribution())}
+            shards_profile = []
+            for r in results:
+                entry: dict = {"id": f"[shard][{r.shard}]",
+                               "query_ms": r.took_ms,
+                               "device": device_attr,
+                               "searches": [{"query": [], "rewrite_time": 0,
+                                             "collector": [{
+                                                 "name": "SimpleTopKCollector",
+                                                 "reason": "search_top_hits",
+                                                 "time_in_nanos": int(
+                                                     r.took_ms * 1e6)}]}]}
+                if plan_tree is not None:
+                    root = dict(plan_tree)
+                    root["time_in_nanos"] = int(r.took_ms * 1e6)
+                    root["device"] = device_attr
+                    entry["searches"][0]["query"] = [root]
+                shards_profile.append(entry)
+            resp["profile"] = {"shards": shards_profile}
+            qc = _qcost.current()
+            if qc is not None:
+                # per-query device cost: plan-time prediction (CSR stats)
+                # reconciled against the launched program shapes — the byte
+                # domain the north star's ≥20× claim is argued in
+                resp["profile"]["cost"] = qc.snapshot()
+        if body.get("explain") == "device_plan":
+            # device-plan search view: the cost rollup + per-segment
+            # predicted/actual entries, without per-hit _explanation trees
+            qc = _qcost.current()
+            if qc is not None:
+                resp["device_plan"] = {"cost": qc.snapshot(),
+                                       "segments": list(qc.segments)}
+        return resp
 
 
 # =====================================================================

@@ -754,6 +754,7 @@ def _chunk_slices(al: AlignedPostings, pb, rows: np.ndarray, ndocs: int
                         len(rows))
 
 
+@TRACER.spanned("fastpath.prepare")
 def _prepare_vqueries(seg: Segment, ctx, lts: Sequence, avgdl_cache: dict,
                       prune: Optional[Sequence[bool]] = None
                       ) -> Optional[List[List[_VQuery]]]:
@@ -972,7 +973,8 @@ def _fetch_pure_groups(pending: list, K: int,
     # is its own synchronizing round trip — per-array fetches would
     # multiply the batch-1 latency floor
     import jax
-    fetched = jax.device_get([arrs for _gvqs, _kl, arrs in pending])
+    with TRACER.span("device.wait", program="frontier"):
+        fetched = jax.device_get([arrs for _gvqs, _kl, arrs in pending])
     results = {}
     for (gvqs, K_launch, _), (scores, docs, totals) in zip(pending,
                                                            fetched):
@@ -1346,9 +1348,10 @@ def _rescore_many_device(seg: Segment, jobs: List[tuple]) -> List[tuple]:
                 weights[qj] = vq.weights
                 avgdl[qj, 0] = vq.avgdl
                 cands[qj, : len(cand)] = cand.astype(np.int32)
-            exact, counts = jax.device_get(
-                run(al.d_docs, al.d_tfdl, starts, lens, weights, avgdl,
-                    cands))
+            launched = run(al.d_docs, al.d_tfdl, starts, lens, weights,
+                           avgdl, cands)
+            with TRACER.span("device.wait", program="rescore"):
+                exact, counts = jax.device_get(launched)
             for qj, j in enumerate(part):
                 n = len(jobs[j][1])
                 out[j] = (exact[qj, :n], counts[qj, :n].astype(np.int64))
@@ -1847,6 +1850,7 @@ def _run_pure(seg: Segment, ctx, lts: Sequence, specs: Sequence[FastSpec],
     return _finish_pure(seg, ctx, lts, specs, K, state)
 
 
+@TRACER.spanned("fastpath.assemble")
 def _assemble(vq_lists, results: dict, K: int, transform=None,
               seg=None, exact_ids=frozenset()) -> List[Optional[dict]]:
     """Reassemble per-query outputs from per-kernel-row results (chunked
@@ -2220,6 +2224,7 @@ class _BVQuery:
             setattr(self, k, v)
 
 
+@TRACER.spanned("fastpath.prepare")
 def _prepare_bool_vqueries(seg: Segment, ctx, specs: Sequence[FastSpec],
                            avgdl_cache: dict
                            ) -> List[Optional[List[_BVQuery]]]:
@@ -2355,7 +2360,8 @@ def _finish_bool(specs: Sequence[FastSpec], K: int, state: tuple,
     groups, then boost/const-score transform and assembly."""
     vq_lists, pending = state
     import jax
-    fetched = jax.device_get([arrs for _gvqs, arrs in pending])
+    with TRACER.span("device.wait", program="frontier_bool"):
+        fetched = jax.device_get([arrs for _gvqs, arrs in pending])
     results = {}
     for (gvqs, _), (scores, docs, totals) in zip(pending, fetched):
         for j, vq in enumerate(gvqs):
@@ -2378,8 +2384,9 @@ def _finish_bool(specs: Sequence[FastSpec], K: int, state: tuple,
 
 def _run_bool(seg: Segment, ctx, specs: Sequence[FastSpec], K: int
               ) -> List[Optional[dict]]:
-    return _finish_bool(specs, K, _launch_bool(seg, ctx, specs, K),
-                        seg=seg)
+    with TRACER.span("fastpath.frontier", queries=len(specs)):
+        state = _launch_bool(seg, ctx, specs, K)
+    return _finish_bool(specs, K, state, seg=seg)
 
 
 def segment_search(seg: Segment, ctx, spec: FastSpec, k: int

@@ -639,9 +639,11 @@ def segment_search(seg: Segment, ctx, spec: ImpactSpec, k: int
     with TRACER.span("impactpath.gather", blocks=int(len(offs)),
                      bucket=bucket), METRICS.timer("impactpath.gather"):
         prog = C.build_impact_program(B_pad, bucket, Ccand, plane.bits)
-        vals, idx, total = jax.device_get(prog(
+        launched = prog(
             post["doc_ids"], post["impacts"], arrs["live"], bstart, blen,
-            bweight, np.float32(1.0 if pruned else msm)))
+            bweight, np.float32(1.0 if pruned else msm))
+        with TRACER.span("device.wait", program="impact"):
+            vals, idx, total = jax.device_get(launched)
     vals = np.asarray(vals)
     idx = np.asarray(idx)
     nvalid = int((vals > -np.inf).sum())

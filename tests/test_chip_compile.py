@@ -17,6 +17,8 @@ skip in silence):
   stand-ins the rest of tier-1 trusts are themselves checked.
 """
 
+import re
+
 import numpy as np
 import pytest
 
@@ -79,8 +81,14 @@ def _per_query(S, QB, T, with_avgdl=True):
     return out + [S((QB, 1), i32), S((QB, 1), i32)]
 
 
-def _assert_mosaic(compiled):
-    assert "tpu_custom_call" in compiled.as_text()
+def _assert_mosaic(compiled, name):
+    """A Mosaic custom call whose HLO instruction carries the kernel's
+    pinned `name=`: `benchmark/trace_reduce.py` finds the kernels on the
+    device trace by the `%fused_bm25_` prefix of that instruction."""
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert name.startswith("fused_bm25_")
+    assert re.search(rf"%{name}[.\d]* = .* custom-call\(", text)
 
 
 @pytest.mark.parametrize("QB,T,L,K", [(32, 2, 4096, 16), (8, 4, 4096, 16)])
@@ -89,7 +97,7 @@ def test_tfdl_kernel_compiles_for_v5e(shape_on_chip, QB, T, L, K):
     planes = [S((P_REAL,), jnp.int32)] * 2
     _assert_mosaic(fused_bm25_topk_tfdl.lower(
         *planes, *_per_query(S, QB, T), T=T, L=L, K=K, k1=K1, b=B
-    ).compile())
+    ).compile(), "fused_bm25_topk_tfdl")
 
 
 @pytest.mark.parametrize("QB,T,L,K", [(32, 2, 8192, 128), (8, 4, 4096, 16)])
@@ -98,7 +106,7 @@ def test_impact_kernel_compiles_for_v5e(shape_on_chip, QB, T, L, K):
     planes = [S((P_REAL,), jnp.int32)] * 2
     _assert_mosaic(fused_bm25_topk_impact.lower(
         *planes, *_per_query(S, QB, T, with_avgdl=False), T=T, L=L, K=K
-    ).compile())
+    ).compile(), "fused_bm25_topk_impact")
 
 
 @pytest.mark.parametrize("QB,TS,L,K,filtered", [(8, 4, 4096, 16, False),
@@ -111,7 +119,8 @@ def test_bool_kernel_compiles_for_v5e(shape_on_chip, QB, TS, L, K, filtered):
         S((P_REAL,), i32), S((P_REAL,), i32), S((1 << 22,), i32),
         *[S((QB, T), i32)] * 4, S((QB, TS), f32), S((QB, T), f32),
         S((QB, 1), f32), S((QB, 1), f32), S((QB, 1), i32), S((QB, 1), i32),
-        TS=TS, L=L, K=K, k1=K1, b=B, filtered=filtered).compile())
+        TS=TS, L=L, K=K, k1=K1, b=B, filtered=filtered).compile(),
+        "fused_bm25_bool_topk")
 
 
 @pytest.mark.parametrize("QB,T,C", [(8, 2, 256), (64, 4, 2048)])

@@ -99,10 +99,13 @@ class TestTracing:
         traces = client.get_traces()["traces"]
         assert traces, "no trace recorded"
         root = traces[0]
-        assert root["name"] == "indices:data/read/search"
-        names = {c["name"] for c in root.get("children", [])}
+        assert root["name"] == "rest.search"
+        (coord,) = root["children"]
+        assert coord["name"] == "indices:data/read/search"
+        assert coord["trace_id"] == root["trace_id"] == root["span_id"]
+        names = {c["name"] for c in coord.get("children", [])}
         assert "query_phase" in names
-        assert root["duration_ms"] >= 0
+        assert root["duration_ms"] >= coord["duration_ms"] >= 0
 
     def test_tracer_stats_in_node_stats(self, client):
         st = client.nodes_stats()["nodes"][client.node.node_name]

@@ -12,8 +12,13 @@ Covers the PR's contract surface:
   `/_metrics` Prometheus endpoint, and slowlog rung/trace attribution
 - the overhead guard: disabled-telemetry cost on the hot path stays
   bounded
+- the span spine (ISSUE 25): one `rest.search` root per request, every
+  descendant on its `trace_id`, self times that partition the root, no
+  `Span` from a disabled tracer, no `jax` at import of `utils.trace`
 """
 
+import subprocess
+import sys
 import threading
 import time
 
@@ -130,6 +135,13 @@ class TestRegistry:
         assert "ostpu_search_total_ms_count 1" in text
 
 
+def _walk(span: dict):
+    """Every span of a `to_dict` tree, depth first."""
+    yield span
+    for ch in span.get("children", []):
+        yield from _walk(ch)
+
+
 # ----------------------------------------------------------------------
 # tracer thread-safety (the context-carrying submit)
 # ----------------------------------------------------------------------
@@ -174,6 +186,38 @@ class TestTracerThreads:
         # generous CI bound: <75us per site-pair (observed ~1-2us)
         assert dt < n * 75e-6, f"disabled-telemetry overhead {dt:.3f}s"
         assert reg.snapshot()["histograms"] == {}
+
+    def test_disabled_tracer_builds_no_span(self):
+        t = Tracer(enabled=False)
+        with t.span("x", a=1) as s:
+            assert s is None
+        assert t.span("x") is t.span("y")       # one shared no-op
+        assert t.stats()["spans"] == 0 and t.traces() == []
+
+        @t.spanned("z")
+        def f(v):
+            return v + 1
+        assert f(1) == 2 and t.stats()["spans"] == 0
+
+    def test_span_count_is_exact_across_reads(self):
+        t = Tracer()
+        for _ in range(3):
+            with t.span("a"):
+                with t.span("b"):
+                    pass
+            assert t.stats()["spans"] % 2 == 0      # a read burns no span
+        assert t.stats() == {"enabled": True, "spans": 6,
+                             "retained_traces": 3}
+
+    def test_importing_trace_does_not_import_jax(self):
+        # the annotation class is resolved at the first span: importing
+        # the tracer must never initialise (or even load) a backend
+        code = ("import sys; import opensearch_tpu.utils.trace as t; "
+                "assert 'jax' not in sys.modules, 'jax at import'; "
+                "t.TRACER.span('x').__enter__(); "
+                "assert 'jax' in sys.modules")
+        subprocess.run([sys.executable, "-c", code], check=True,
+                       timeout=120)
 
 
 # ----------------------------------------------------------------------
@@ -243,6 +287,36 @@ class TestEndToEnd:
         finally:
             srv.stop()
 
+    def test_rest_search_is_one_tree_on_one_trace_id(self, client):
+        TRACER._traces.clear()
+        client.search("tel", {"query": {"match": {"body": "alpha w3"}}})
+        (root,) = TRACER.traces()
+        assert root["name"] == "rest.search"
+        assert root["trace_id"] == root["span_id"]
+        spans = list(_walk(root))
+        assert {"indices:data/read/search", "query_phase", "search.plan",
+                "search.collect", "device.wait", "reduce", "fetch_phase",
+                "search.respond"} <= {s["name"] for s in spans}
+        assert {s["trace_id"] for s in spans} == {root["trace_id"]}
+        assert len({s["span_id"] for s in spans}) == len(spans)
+
+    def test_self_times_partition_the_root(self, client):
+        TRACER._traces.clear()
+        client.search("tel", {"query": {"match": {"body": "beta w5"}}})
+        (root,) = TRACER._traces
+
+        def walk(span):
+            yield span
+            for ch in span.children:
+                yield from walk(ch)
+        spans = list(walk(root))
+        assert len(spans) >= 8 and all(s.end_ns for s in spans)
+        # one thread, so no two children of a span overlap: exact in ns
+        assert sum(s.self_ns() for s in spans) == root.duration_ns()
+        for s in spans:
+            assert all(s.start_ns <= c.start_ns and c.end_ns <= s.end_ns
+                       for c in s.children)
+
     def test_slowlog_rung_and_trace_attribution(self, client):
         client.search("tel", {"query": {"match": {"body": "alpha"}}})
         entries = client.node.indices["tel"].search_slowlog.entries
@@ -292,13 +366,8 @@ class TestDistributedTrace:
             # remote spans live INSIDE each phase's subtree — since the
             # scatter went parallel (utils/legs.py) they sit one level
             # down, under the member's legs.leg span, on both arms
-            def walk(span):
-                yield span
-                for ch in span.get("children", []):
-                    yield from walk(ch)
-
             remote = [ch for ph in ("dist.dfs", "dist.query", "dist.fetch")
-                      for ch in walk(phases[ph])
+                      for ch in _walk(phases[ph])
                       if ch.get("attributes", {}).get("node") == "b"]
             assert remote, "no remote spans nested under coordinator"
             # remote spans carry the propagated wire context
