@@ -18,13 +18,32 @@ the whole escalation queue:
     candidate ids — then gather packed (tf, dl), decode, and accumulate
     exact f32 BM25 + per-term match counts.
 
+PROBE DEPTH: a lower_bound over a window of `len` postings is done after
+exactly `len.bit_length()` halvings; deeper changes no byte. Where the plane
+lives decides the form (`plane_in_vmem`, a static property of the plane):
+  * a plane too large for VMEM (a 2.2M-document shard: 460 MB) is probed
+    from HBM, where a launch's time IS its gather count (24-29 ns a
+    gathered element at 8,192 to 262,144 elements a gather, the same in a
+    `while` as unrolled). Each term slot `t` runs its own `lax.fori_loop`
+    (one `while`, trip count the traced operand `rounds[t]`) over its
+    [QB, C] bounds: as deep as the longest row any query of the launch has
+    in that slot (`probe_rounds`), not at all for a slot no query has. The
+    depth is an operand, never a compile key.
+  * a plane of up to 112 MiB (a 171k-document collection: 97 MB) is
+    prefetched into VMEM once a launch, but only for gathers in the entry
+    computation: 7 ns an element unrolled, 26 ns inside a `while`. There
+    the search stays unrolled to the plane's own bit length over
+    [QB, T, C]; halving the probes at 3.7 x the cost each would lose.
+`RESCORE_STATS["device_probe_elems"]` counts what the searches gathered,
+QB * C * sum(rounds) a launch, in either form.
+
 Why `jnp` and not a Pallas kernel: the access pattern is C·T independent
-binary searches (log P dependent random gathers each) — there is no
-contiguous DMA window to stage into VMEM, which is the only thing the fused
-scorer's Pallas formulation buys. XLA compiles the probe loop into log2(P)
-batched gathers over [QB, T, C]; the arithmetic after the search is plain
-VPU work XLA fuses fine. A Pallas upgrade would only pay if the probe
-gathers dominate on silicon — measure first (docs/FASTPATH.md).
+binary searches (dependent random gathers each) — there is no contiguous
+DMA window to stage into VMEM, which is the only thing the fused scorer's
+Pallas formulation buys. On a v5e the probes were 86% of a (T 4, C 8192)
+launch over the large plane at the plane's 27 rounds and are 67% at the
+rows' own 10-14 (PERF.md, PR 26); what stays is the two [QB, T, C] gathers
+after the search and the launch itself.
 
 BIT-PARITY CONTRACT: the accumulation mirrors `fastpath._exact_rescore`
 op-for-op in f32 (same expression shapes, same term order, weak-typed
@@ -50,7 +69,7 @@ from .pallas_bm25 import DL_BITS, DL_MASK, INT_SENTINEL, TF_MAX
 def exact_rescore_batch(docs_hbm: jnp.ndarray, tfdl_hbm: jnp.ndarray,
                         starts: jnp.ndarray, lens: jnp.ndarray,
                         weights: jnp.ndarray, avgdl: jnp.ndarray,
-                        cand: jnp.ndarray,
+                        cand: jnp.ndarray, rounds: jnp.ndarray,
                         T: int, C: int, k1: float, b: float):
     """Exact BM25 scores + match counts of candidate docs vs full rows.
 
@@ -62,23 +81,43 @@ def exact_rescore_batch(docs_hbm: jnp.ndarray, tfdl_hbm: jnp.ndarray,
     weights   f32[QB, T] — query-time idf * boost
     avgdl     f32[QB, 1]
     cand      i32[QB, C] — candidate doc ids, INT_SENTINEL padded
+    rounds    i32[T] — `probe_rounds(lens, P)`: the probe depth per term
+              slot of a plane in HBM (0 = no query of the launch has the
+              slot; deeper than needed changes no byte); not read where the
+              plane is small enough for VMEM
     k1, b     static similarity params (b pre-zeroed when norms are off)
     Returns (exact f32[QB, C], counts i32[QB, C]) — 0 on padding slots.
     """
     P = docs_hbm.shape[0]
-    # lower_bound over [start, start+len): branchless bisection, static
-    # probe count from the (static) buffer length. mid = lo + (hi-lo)//2
-    # keeps i32 safe for buffers past 2^30 elements.
-    lo = jnp.broadcast_to(starts[:, :, None], starts.shape + (C,))
-    hi = lo + lens[:, :, None]
-    end = hi
     c = cand[:, None, :]
-    for _ in range(max(int(P).bit_length(), 1)):
+
+    def halve(lo, hi, ids):
+        # mid = lo + (hi-lo)//2 keeps i32 safe for buffers past 2^30 elements
         mid = lo + (hi - lo) // 2
-        v = docs_hbm[jnp.clip(mid, 0, P - 1)]
-        go = v < c
-        lo = jnp.where(go, mid + 1, lo)
-        hi = jnp.where(go, hi, mid)
+        go = docs_hbm[jnp.clip(mid, 0, P - 1)] < ids
+        return jnp.where(go, mid + 1, lo), jnp.where(go, hi, mid)
+
+    # lower_bound over [start, start+len): branchless bisection. A window of
+    # `len` is empty after exactly `len.bit_length()` halvings, and further
+    # ones do not move `pos_c` below.
+    if plane_in_vmem(P):
+        # every gather in the entry computation, so XLA prefetches the plane
+        # into VMEM once: the plane's own depth over [QB, T, C], unrolled
+        lo = jnp.broadcast_to(starts[:, :, None], starts.shape + (C,))
+        hi = lo + lens[:, :, None]
+        for _ in range(int(P).bit_length()):
+            lo, hi = halve(lo, hi, c)
+    else:
+        # one `while` per term slot over that slot's [QB, C] bounds,
+        # `rounds[t]` trips deep
+        los = []
+        for t in range(T):
+            lo_t = jnp.broadcast_to(starts[:, t, None], cand.shape)
+            los.append(jax.lax.fori_loop(
+                0, rounds[t], lambda _, lo_hi: halve(*lo_hi, cand),
+                (lo_t, lo_t + lens[:, t, None]))[0])
+        lo = jnp.stack(los, axis=1)
+    end = (starts + lens)[:, :, None]
     # mirror the host's clamped probe: pos_c = min(pos, row_end - 1)
     pos_c = jnp.clip(jnp.minimum(lo, end - 1), 0, P - 1)
     found = ((docs_hbm[pos_c] == c) & (lens[:, :, None] > 0)
@@ -111,6 +150,29 @@ def exact_rescore_batch(docs_hbm: jnp.ndarray, tfdl_hbm: jnp.ndarray,
         exact = exact + contrib.astype(jnp.float32)
         counts = counts + foundt.astype(jnp.int32)
     return exact, counts
+
+
+# XLA prefetches an entry parameter of up to this many bytes into VMEM for
+# the whole launch (v5e: its 128 MiB less the 16 MiB scoped to kernels), but
+# only for uses in the entry computation, never for a `while` body's. A
+# gather from a plane there costs a quarter of one from HBM (7 against 26 ns
+# an element), which no probe depth in a `while` wins back.
+VMEM_PLANE_BYTES = 112 << 20
+
+
+def plane_in_vmem(P: int) -> bool:
+    return 4 * P <= VMEM_PLANE_BYTES
+
+
+def probe_rounds(lens: np.ndarray, P: int) -> np.ndarray:
+    """`rounds` of a launch from its `lens` i32[QB, T] over a plane of `P`
+    elements: per term slot, the bit length of the longest row any query of
+    the launch has there; the plane's own bit length in every slot where the
+    launch takes the unrolled form (`plane_in_vmem`)."""
+    if plane_in_vmem(P):
+        return np.full(lens.shape[1], int(P).bit_length(), np.int32)
+    return np.asarray([int(n).bit_length() for n in lens.max(axis=0)],
+                      np.int32)
 
 
 def rescore_elem_budget(T: int, C: int, max_elems: int = 1 << 24) -> int:

@@ -5005,9 +5005,9 @@ def build_rescore_program(T: int, C: int, k1: float, b: float):
     shape of ops/rescore.exact_rescore_batch."""
     from ..ops.rescore import exact_rescore_batch
 
-    def run(d_docs, d_tfdl, starts, lens, weights, avgdl, cand):
+    def run(d_docs, d_tfdl, starts, lens, weights, avgdl, cand, rounds):
         return exact_rescore_batch(d_docs, d_tfdl, starts, lens, weights,
-                                   avgdl, cand, T=T, C=C, k1=k1, b=b)
+                                   avgdl, cand, rounds, T=T, C=C, k1=k1, b=b)
 
     return run
 

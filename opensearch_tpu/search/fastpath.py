@@ -87,7 +87,7 @@ STATS = CounterGroup(METRICS, "fastpath", {
 RESCORE_STATS = CounterGroup(METRICS, "fastpath.rescore", {
     "host_calls": 0, "host_wall_ms": 0.0,
     "device_launches": 0, "device_queries": 0,
-    "device_cands": 0, "device_wall_ms": 0.0})
+    "device_cands": 0, "device_probe_elems": 0, "device_wall_ms": 0.0})
 
 _rescore_override: Optional[str] = None   # tests/scripts pin a path
 
@@ -1305,7 +1305,7 @@ def _rescore_many_device(seg: Segment, jobs: List[tuple]) -> List[tuple]:
     import jax
 
     from . import compiler as C
-    from ..ops.rescore import rescore_elem_budget
+    from ..ops.rescore import probe_rounds, rescore_elem_budget
 
     t0 = time.perf_counter()
     out: List[Optional[tuple]] = [None] * len(jobs)
@@ -1348,8 +1348,9 @@ def _rescore_many_device(seg: Segment, jobs: List[tuple]) -> List[tuple]:
                 weights[qj] = vq.weights
                 avgdl[qj, 0] = vq.avgdl
                 cands[qj, : len(cand)] = cand.astype(np.int32)
+            rounds = probe_rounds(lens, al.d_docs.shape[0])
             launched = run(al.d_docs, al.d_tfdl, starts, lens, weights,
-                           avgdl, cands)
+                           avgdl, cands, rounds)
             with TRACER.span("device.wait", program="rescore"):
                 exact, counts = jax.device_get(launched)
             for qj, j in enumerate(part):
@@ -1359,6 +1360,8 @@ def _rescore_many_device(seg: Segment, jobs: List[tuple]) -> List[tuple]:
             RESCORE_STATS.inc("device_queries", len(part))
             RESCORE_STATS.inc("device_cands", int(
                 sum(len(jobs[j][1]) for j in part)))
+            RESCORE_STATS.inc("device_probe_elems",
+                              QB * cb * int(rounds.sum()))
     t_host = 0.0
     for j in host_jobs:
         vq, cand = jobs[j]

@@ -32,12 +32,13 @@ from opensearch_tpu.ops.pallas_bm25 import (DL_BITS, HBM_ALIGN, INT_SENTINEL,
                                             fused_bm25_bool_topk,
                                             fused_bm25_topk_impact,
                                             fused_bm25_topk_tfdl)
-from opensearch_tpu.ops.rescore import exact_rescore_batch
+from opensearch_tpu.ops.rescore import exact_rescore_batch, plane_in_vmem
 from tests.test_pruned import (sim_fused_bm25_topk_impact,
                                sim_fused_bm25_topk_tfdl)
 
 K1, B = 1.2, 0.75
 P_REAL = 1 << 27        # a 2.2M-doc MS-MARCO-shaped shard's aligned plane
+P_SMALL = 24_359_552    # a 171k-doc TREC-COVID-shaped collection's
 
 
 # ---------------------------------------------------------------------
@@ -123,14 +124,27 @@ def test_bool_kernel_compiles_for_v5e(shape_on_chip, QB, TS, L, K, filtered):
         "fused_bm25_bool_topk")
 
 
-@pytest.mark.parametrize("QB,T,C", [(8, 2, 256), (64, 4, 2048)])
-def test_exact_rescore_compiles_for_v5e(shape_on_chip, QB, T, C):
+@pytest.mark.parametrize("P,QB,T,C", [(P_REAL, 8, 2, 256),
+                                      (P_REAL, 64, 4, 2048),
+                                      (P_SMALL, 1, 8, 32768)])
+def test_exact_rescore_compiles_for_v5e(shape_on_chip, P, QB, T, C):
     S = shape_on_chip
     i32, f32 = jnp.int32, jnp.float32
     compiled = exact_rescore_batch.lower(
-        S((P_REAL,), i32), S((P_REAL,), i32), S((QB, T), i32),
+        S((P,), i32), S((P,), i32), S((QB, T), i32),
         S((QB, T), i32), S((QB, T), f32), S((QB, 1), f32), S((QB, C), i32),
-        T=T, C=C, k1=K1, b=B).compile()
+        S((T,), i32), T=T, C=C, k1=K1, b=B).compile()
+    text = compiled.as_text()
+    if plane_in_vmem(P):
+        # the reason for the unrolled form: XLA prefetches the plane into
+        # VMEM (memory space 1) for every probe round
+        assert "while(" not in text
+        assert len(re.findall(rf"s32\[{P}\]\{{[^}}]*S\(1\)\}}", text)) \
+            >= int(P).bit_length()
+    else:
+        # the probe depth is an operand: one `while` a term slot, not
+        # bit_length(P) unrolled gather rounds
+        assert len(re.findall(r"\bwhile\(", text)) == T
     # the [QB, T, C] probe intermediates fit the chip beside the planes
     assert compiled.memory_analysis().temp_size_in_bytes < 4 << 30
 

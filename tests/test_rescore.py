@@ -15,14 +15,29 @@ from opensearch_tpu.index.engine import Engine
 from opensearch_tpu.index.mappings import Mappings
 from opensearch_tpu.ops.pallas_bm25 import (DL_BITS, INT_SENTINEL, LANES,
                                             align_csr_rows)
+from opensearch_tpu.ops import rescore
 from opensearch_tpu.ops.rescore import (exact_rescore_batch,
-                                        host_exact_rescore_batch)
+                                        host_exact_rescore_batch,
+                                        probe_rounds)
 from opensearch_tpu.search import compiler as C
 from opensearch_tpu.search import fastpath
 from opensearch_tpu.search import query_dsl as dsl
 from opensearch_tpu.search.executor import ShardSearcher
 from tests.test_pruned import (sim_fused_bm25_topk_impact,
                                sim_fused_bm25_topk_tfdl)
+
+
+@pytest.fixture(params=["plane_in_vmem", "plane_in_hbm"])
+def plane(request, monkeypatch):
+    """Both forms of the search over the tests' small planes: unrolled to
+    the plane's depth (a plane XLA keeps in VMEM), and one `while` a term
+    slot (a plane in HBM, here by calling every plane too large). The form
+    is fixed when the program is traced, so traces of the other one go."""
+    if request.param == "plane_in_hbm":
+        monkeypatch.setattr(rescore, "VMEM_PLANE_BYTES", 0)
+    exact_rescore_batch.clear_cache()
+    yield request.param
+    exact_rescore_batch.clear_cache()
 
 
 class TestKernelParity:
@@ -46,7 +61,7 @@ class TestKernelParity:
         return a_starts, a_docs, a_tfdl, nterms
 
     @pytest.mark.parametrize("seed", [3, 17])
-    def test_bitwise_equal(self, seed):
+    def test_bitwise_equal(self, seed, plane):
         rng = np.random.default_rng(seed)
         a_starts, a_docs, a_tfdl, nterms = self._mk(rng)
         T, CC, QB = 4, 256, 4
@@ -71,12 +86,113 @@ class TestKernelParity:
         for k1, b in ((1.2, 0.75), (0.9, 0.0)):
             dx, dc = exact_rescore_batch(
                 jnp.asarray(a_docs), jnp.asarray(a_tfdl), starts, lens,
-                weights, avgdl, cand, T=T, C=CC, k1=k1, b=b)
+                weights, avgdl, cand, probe_rounds(lens, len(a_docs)),
+                T=T, C=CC, k1=k1, b=b)
             hx, hc = host_exact_rescore_batch(
                 a_docs, a_tfdl, starts, lens, weights, avgdl, cand,
                 k1=k1, b=b)
             assert np.asarray(dx).tobytes() == hx.tobytes()
             assert (np.asarray(dc) == hc).all()
+
+
+NDOCS_PD = 6000
+
+
+def _planes(rng, row_lens, first=0):
+    """Aligned planes of rows with the given posting counts, 128-aligned so
+    a row of 256 postings is followed at once by the next row's first doc."""
+    starts, docs, tfdl = [0], [], []
+    for n in row_lens:
+        ids = first + np.sort(rng.choice(NDOCS_PD, size=n, replace=False))
+        docs.append(ids.astype(np.int32))
+        tfdl.append(((rng.integers(1, 30, n).astype(np.int64) << DL_BITS)
+                     | rng.integers(1, 500, n)).astype(np.int32))
+        starts.append(starts[-1] + n)
+    return align_csr_rows(np.asarray(starts, np.int64), np.concatenate(docs),
+                          np.concatenate(tfdl), margin=1024, alignment=LANES)
+
+
+def _launch(rng, row_lens, slots, cand_ids, first=0):
+    """Operands of one launch: `slots[q][t]` is a row index or None (absent
+    term), `cand_ids(q)` the query's candidate ids."""
+    a_starts, a_docs, a_tfdl = _planes(rng, row_lens, first)
+    QB, T, CC = len(slots), len(slots[0]), 1024
+    starts = np.zeros((QB, T), np.int32)
+    lens = np.zeros((QB, T), np.int32)
+    weights = rng.uniform(0.1, 4.0, (QB, T)).astype(np.float32)
+    avgdl = rng.uniform(1.0, 300.0, (QB, 1)).astype(np.float32)
+    cand = np.full((QB, CC), INT_SENTINEL, np.int32)
+    for q, row in enumerate(slots):
+        for t, r in enumerate(row):
+            if r is not None:
+                starts[q, t], lens[q, t] = a_starts[r], row_lens[r]
+        ids = np.unique(cand_ids(q))[:CC]
+        cand[q, : len(ids)] = ids
+    return a_docs, a_tfdl, starts, lens, weights, avgdl, cand
+
+
+def _every_doc(first=0):
+    return lambda q: first + np.arange(0, NDOCS_PD, 7)
+
+
+PROBE_DEPTH_CASES = {
+    # rows of 1, 2^k - 1, 2^k, 2^k + 1 postings in one launch
+    "rows_1_255_256_257": dict(
+        row_lens=[1, 255, 256, 257], slots=[[0, 1, 2, 3]],
+        want_rounds=[1, 8, 9, 9]),
+    "rows_1_3_4_5": dict(
+        row_lens=[1, 3, 4, 5], slots=[[0, 1, 2, 3]],
+        want_rounds=[1, 2, 3, 3]),
+    # a slot no query of the launch has: rounds 0, nothing probed there
+    "absent_slot": dict(
+        row_lens=[300, 2000], slots=[[0, None, 1, None]],
+        want_rounds=[9, 0, 11, 0]),
+    # QB 2, each slot's depth set by a different query
+    "qb2_maxima_from_different_queries": dict(
+        row_lens=[3000, 40, 17, 1500], slots=[[0, 2], [1, 3]],
+        want_rounds=[12, 11]),
+    # every candidate below the row's first / above its last doc id (ids
+    # start at 1000; the 256-long row abuts the next row's first doc)
+    "candidates_below_first": dict(
+        row_lens=[256, 100], slots=[[0, 1]], first=1000,
+        cand_ids=lambda q: np.arange(0, 1000, 3), want_rounds=[9, 7]),
+    "candidates_above_last": dict(
+        row_lens=[256, 100], slots=[[0, 1]], first=1000,
+        cand_ids=lambda q: 1000 + NDOCS_PD + np.arange(500),
+        want_rounds=[9, 7]),
+    # deeper than needed (the retired static depth of a 2.2M-doc plane)
+    "rounds_larger_than_needed": dict(
+        row_lens=[1, 256, 2000, 31], slots=[[0, 1, 2, 3], [3, 2, 1, 0]],
+        rounds=[27, 27, 27, 27], want_rounds=[5, 11, 11, 5]),
+}
+
+
+@pytest.mark.parametrize("plane", ["plane_in_hbm"], indirect=True)
+class TestProbeDepth:
+    """Over a plane in HBM the probe depth follows the rows of the launch,
+    per term slot; the scores stay the host mirror's, byte for byte."""
+
+    @pytest.mark.parametrize("case", sorted(PROBE_DEPTH_CASES))
+    def test_depth_per_slot_is_bitwise_the_host(self, case, plane):
+        spec = PROBE_DEPTH_CASES[case]
+        first = spec.get("first", 0)
+        a_docs, a_tfdl, starts, lens, weights, avgdl, cand = _launch(
+            np.random.default_rng(29), spec["row_lens"], spec["slots"],
+            spec.get("cand_ids", _every_doc(first)), first)
+        assert probe_rounds(lens, len(a_docs)).tolist() \
+            == spec["want_rounds"]
+        rounds = np.asarray(spec.get("rounds", spec["want_rounds"]),
+                            np.int32)
+        T = lens.shape[1]
+        dx, dc = exact_rescore_batch(
+            jnp.asarray(a_docs), jnp.asarray(a_tfdl), starts, lens, weights,
+            avgdl, cand, rounds, T=T, C=cand.shape[1], k1=1.2, b=0.75)
+        hx, hc = host_exact_rescore_batch(
+            a_docs, a_tfdl, starts, lens, weights, avgdl, cand,
+            k1=1.2, b=0.75)
+        assert np.asarray(dx).tobytes() == hx.tobytes()
+        assert (np.asarray(dc) == hc).all()
+        assert hc.any() == ("candidates" not in case)
 
 
 @pytest.fixture(scope="module")
@@ -124,7 +240,8 @@ QUERIES = [
 
 
 class TestOracleParity:
-    def test_rescore_many_matches_exact_rescore(self, corpus, small_head):
+    def test_rescore_many_matches_exact_rescore(self, corpus, small_head,
+                                                plane):
         """The batched device dispatcher returns EXACTLY what the per-query
         host oracle returns for the same (vq, candidate-union) jobs."""
         seg, ctx = corpus
@@ -219,6 +336,53 @@ class TestOracleParity:
         ci2 = C.build_rescore_program.cache_info()
         assert ci2.currsize == ci1.currsize
         assert ci2.hits > ci1.hits
+
+    def test_probe_elems_counted_and_no_program_per_row_length(
+            self, corpus, small_head, plane):
+        """`device_probe_elems` grows by exactly QB * C * sum(rounds) of a
+        launch (per slot the bit length of its longest row over a plane in
+        HBM, the plane's own in every slot over one in VMEM), and a launch
+        whose rows have other lengths reuses the program: the depth is an
+        operand, not a key."""
+        seg, ctx = corpus
+        seg.__dict__.pop("_fastpath_aligned", None)
+        al = fastpath.get_aligned(seg, "body")
+        pb = seg.postings["body"]
+
+        def jobs_of(*texts):
+            nodes = [C.rewrite(dsl.parse_query({"match": {"body": t}}), ctx,
+                               scoring=True) for t in texts]
+            vqs = fastpath._prepare_vqueries(seg, ctx, nodes, {},
+                                             [True] * len(nodes))
+            return [(v[0], fastpath._p2_candidates(v[0], pb,
+                                                   al.head_ids.get))
+                    for v in vqs]
+
+        def launch(jobs):
+            """-> (counter's growth, QB * C * sum of per-slot depths)"""
+            deepest = np.max([[int(al.lens[int(r)]) for r in vq.rows]
+                              for vq, _cand in jobs], axis=0)
+            if plane == "plane_in_vmem":
+                deepest[:] = al.d_docs.shape[0]
+            assert {C.rescore_cand_bucket(len(c)) for _vq, c in jobs} \
+                == {C.RESCORE_C_MIN}
+            before = fastpath.RESCORE_STATS["device_probe_elems"]
+            fastpath._rescore_many_device(seg, jobs)
+            return (fastpath.RESCORE_STATS["device_probe_elems"] - before,
+                    len(jobs) * C.RESCORE_C_MIN
+                    * sum(int(n).bit_length() for n in deepest))
+
+        grew, want = launch(jobs_of("common half0", "half1 rare7"))
+        assert grew == want > 0
+        built = C.build_rescore_program.cache_info()
+        traced = exact_rescore_batch._cache_size()
+        grew2, want2 = launch(jobs_of("rare3 rare5", "half0 rare9"))
+        assert grew2 == want2 > 0
+        assert (want2 < want) == (plane == "plane_in_hbm")
+        after = C.build_rescore_program.cache_info()
+        assert (after.currsize, after.misses) == \
+            (built.currsize, built.misses)
+        assert exact_rescore_batch._cache_size() == traced
 
     def test_bucket_canonicalization(self):
         assert C.rescore_cand_bucket(1) == C.RESCORE_C_MIN

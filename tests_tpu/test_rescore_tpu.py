@@ -12,8 +12,10 @@ import jax.numpy as jnp
 
 from opensearch_tpu.ops.pallas_bm25 import (DL_BITS, INT_SENTINEL, LANES,
                                             align_csr_rows)
+from opensearch_tpu.ops import rescore
 from opensearch_tpu.ops.rescore import (exact_rescore_batch,
-                                        host_exact_rescore_batch)
+                                        host_exact_rescore_batch,
+                                        probe_rounds)
 from opensearch_tpu.rest.client import RestClient
 from opensearch_tpu.search import fastpath
 
@@ -21,8 +23,19 @@ pytestmark = pytest.mark.skipif(jax.default_backend() != "tpu",
                                 reason="needs a real TPU chip")
 
 
+@pytest.fixture(params=["plane_in_vmem", "plane_in_hbm"])
+def plane(request, monkeypatch):
+    """Both forms of the search over a small plane: unrolled (XLA keeps the
+    plane in VMEM) and one `while` a term slot (as over a plane in HBM)."""
+    if request.param == "plane_in_hbm":
+        monkeypatch.setattr(rescore, "VMEM_PLANE_BYTES", 0)
+    exact_rescore_batch.clear_cache()
+    yield request.param
+    exact_rescore_batch.clear_cache()
+
+
 @pytest.mark.parametrize("seed", [5, 23])
-def test_kernel_bitwise_parity_on_silicon(seed):
+def test_kernel_bitwise_parity_on_silicon(seed, plane):
     """Raw kernel vs numpy mirror over the same padded operands — exact
     f32 byte equality (the _tie_serves/theta32 contract), not allclose."""
     rng = np.random.default_rng(seed)
@@ -61,7 +74,8 @@ def test_kernel_bitwise_parity_on_silicon(seed):
     for k1, b in ((1.2, 0.75), (0.9, 0.0)):
         dx, dc = exact_rescore_batch(
             jnp.asarray(a_docs), jnp.asarray(a_tfdl), starts, lens,
-            weights, avgdl, cand, T=T, C=C, k1=k1, b=b)
+            weights, avgdl, cand, probe_rounds(lens, len(a_docs)), T=T, C=C,
+            k1=k1, b=b)
         hx, hc = host_exact_rescore_batch(
             a_docs, a_tfdl, starts, lens, weights, avgdl, cand, k1=k1, b=b)
         assert np.asarray(dx).tobytes() == hx.tobytes()
