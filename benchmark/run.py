@@ -4,25 +4,30 @@
     python3 benchmark/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 The cell is an entry of `BENCHMARK.json`'s `workloads`; its configuration is
-`benchmark/configs/<config>.json`, its traffic `benchmark/traffic/<traffic>.json`
-and each per-layer metric `benchmark/layer_metrics/<metric>.py`, all found by
-name: a later PR adds cells, configurations, traffic mixes and metrics as new
-files and new entries (README.md).
+`benchmark/configs/<config>.json`, its traffic `benchmark/traffic/<traffic>.json`,
+each per-layer metric `benchmark/layer_metrics/<metric>.py`, and what differs
+between kinds of deployment (the data and its index, the request stream, the
+reference with its rule, the program's counters)
+`benchmark/deployments/<deployment_kind>.py`, all found by name: a later PR
+adds cells, configurations, traffic mixes, metrics and kinds as new files
+and new entries (README.md).
 
 One process, which holds the chip itself. It exits non-zero with no result
 unless JAX reports a TPU with exactly the cell's `chips` devices. Then:
-set-up (the configuration's corpus built on the host, segment planted under
-an index the client created, aligned planes promoted to HBM, one warm-up
-pass over the window's own pool of queries with every query's terms in
-another order), the measured window (one caller in a closed loop, through
+set-up (the kind's `build`: the configuration's data made on the host,
+indexed under an index the client created, promoted to HBM; then one
+warm-up pass over the window's own pool of requests, every query as its
+twin), the measured window (one caller in a closed loop, through
 `RestClient.search` / `RestClient.msearch` in this process, until
 `--seconds` are up or the pool is sent), and after the window the check: a
 seeded sample of the answered queries, and fresh queries drawn from
-`--seed`, held to the numpy dense reference.
+`--seed`, held to the kind's plain reference by the kind's rule.
 Read-outs go to stdout as one JSON object a line; the LAST line is the
 result the contract fixes: with `--trace 0` the cell's end-to-end metrics,
 with `--trace 1` its per-layer metrics, `device.busy_s` / `window_s` and a
-`breakdown` from the profiler's trace of the window's first slice."""
+`breakdown` from the profiler's trace of the window's first slice; either
+way `compared` comes last: every number the check compared, beside its
+limit (the same go to stderr as the run's last lines)."""
 
 from __future__ import annotations
 
@@ -47,6 +52,10 @@ for p in (HERE, ROOT):
         sys.path.insert(0, p)
 
 INDEX = "bench"
+DEFAULT_KIND = "bm25_match"     # a configuration without `deployment_kind`
+KIND_MEMBERS = ("build", "stream", "hold", "counters")
+# where `load_kind` looks; the tests add the directory of their fixture kind
+KIND_DIRS = [os.path.join(HERE, "deployments")]
 
 
 def emit(obj: dict) -> None:
@@ -64,6 +73,30 @@ def _load_json(*parts) -> dict:
         raise SystemExit(f"benchmark: {path} is missing")
     with open(path) as f:
         return json.load(f)
+
+
+def _load_module(path: str, alias: str):
+    spec = importlib.util.spec_from_file_location(alias, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def load_kind(name: str):
+    """The module `<name>.py` of the first of `KIND_DIRS` that has it: a
+    kind of deployment (README.md, "a deployment kind")."""
+    paths = [os.path.join(d, name + ".py") for d in KIND_DIRS]
+    path = next((p for p in paths if os.path.isfile(p)), None)
+    if path is None:
+        raise SystemExit(f"benchmark: no deployment kind {name!r} "
+                         f"(looked for {', '.join(paths)})")
+    mod = _load_module(path, f"deployment_kind_{name}")
+    lacks = [m for m in KIND_MEMBERS if not callable(getattr(mod, m, None))]
+    if lacks:
+        raise SystemExit(f"benchmark: deployment kind {name!r} at {path} "
+                         f"lacks {', '.join(lacks)} (a kind exposes "
+                         f"{', '.join(KIND_MEMBERS)})")
+    return mod
 
 
 def load_cell(name: str) -> dict:
@@ -141,13 +174,10 @@ class CompileMeter:
         return {k: self.v[k] - mark[k] for k in self.v}
 
 
-def counters(client) -> dict:
-    """The program's own counters the per-layer metrics read: the serving
-    ladder's, the device rescore's, the request cache's."""
-    from opensearch_tpu.search import fastpath
-    out = {f"fastpath.{k}": v for k, v in dict(fastpath.STATS).items()}
-    out.update({f"fastpath.rescore.{k}": v
-                for k, v in fastpath.rescore_stats().items()})
+def counters(deployment, client) -> dict:
+    """The program's own counters the per-layer metrics read: the kind's,
+    and the request cache's (whatever the kind, it may answer nothing)."""
+    out = dict(deployment.counters(client))
     out.update({f"request_cache.{k}": v
                 for k, v in client.node.request_cache.stats().items()
                 if isinstance(v, (int, float))})
@@ -168,43 +198,6 @@ def memory_peak_bytes() -> int:
 # set-up
 # ---------------------------------------------------------------------
 
-def build(config: dict, seed: int) -> dict:
-    """The configuration's corpus on the host (from its `corpus_seed`: the
-    collection is fixed, like a data set), columns from `seed`, planted as
-    a segment, promoted to HBM."""
-    import jax
-
-    import corpus
-    from opensearch_tpu.rest.client import RestClient
-    from opensearch_tpu.search import fastpath
-
-    t0 = time.time()
-    starts, doc_ids, tfs, dl, df = corpus.from_config(config)
-    rng = np.random.default_rng([seed, 0])
-    ndocs = len(dl)
-    status = rng.integers(0, len(corpus.STATUSES), ndocs).astype(np.int32)
-    price = rng.integers(0, 1000, ndocs).astype(np.int64)
-    vocab = corpus.vocab_strings(len(df))
-    client = RestClient()
-    seg = corpus.plant_index(client, INDEX, (starts, doc_ids, tfs), vocab,
-                             dl, status, price, config["index_settings"])
-    build_s = time.time() - t0
-    note(f"{ndocs}-doc segment built on the host ({build_s:.1f} s)")
-
-    t0 = time.time()
-    al = fastpath.get_aligned(seg, "body")
-    if al is not None:          # None off the TPU backend (tests only)
-        jax.block_until_ready([a for a in (al.d_docs, al.d_tfdl, al.d_imp)
-                               if a is not None])
-    promote_s = time.time() - t0
-    note(f"aligned planes on the device ({promote_s:.1f} s)")
-    return {"client": client, "csr": (starts, doc_ids, tfs), "dl": dl,
-            "df": df, "vocab": vocab,
-            "postings": int(len(doc_ids)), "build_s": build_s,
-            "promote_s": promote_s,
-            "aligned_bytes": int(al.nbytes) if al is not None else 0}
-
-
 def send(client, kind: str, specs: list) -> list:
     """One request. -> its responses, one per query spec."""
     if kind == "search":
@@ -218,18 +211,19 @@ def send(client, kind: str, specs: list) -> list:
 def warm_up(client, stream, traffic: dict, pool: list,
             meter: CompileMeter) -> dict:
     """One pass over the window's own pool, request by request in the
-    window's grouping, every query as its twin (`QueryStream.permuted`: the
-    same terms in another order). The program's compiled shapes follow the
-    terms' posting lengths and, in a batch, how many of its queries climb
-    which rung, so only the window's own requests are sure to compile what
-    the window will use; the twins are other bodies, so the request cache
-    answers nothing in the window. -> what it compiled, and which requests
-    compiled (the late ones tell how rare a shape is)."""
+    window's grouping, every query as its twin (the kind's
+    `stream.twin`: another body of the same compiled shapes). The
+    program's compiled shapes follow what a request touches (for a `match`
+    the terms' posting lengths and, in a batch, how many of its queries
+    climb which rung), so only the window's own requests are sure to
+    compile what the window will use; the twins are other bodies, so the
+    request cache answers nothing in the window. -> what it compiled, and
+    which requests compiled (the late ones tell how rare a shape is)."""
     batch, kind = int(traffic["batch"]), traffic["request"]
     m0, t0, sent, compiled_at = meter.mark(), time.time(), 0, []
     for lo in range(0, len(pool) - batch + 1, batch):
         m1, t1 = meter.mark(), time.time()
-        send(client, kind, [stream.permuted(q) for q in pool[lo: lo + batch]])
+        send(client, kind, [stream.twin(q) for q in pool[lo: lo + batch]])
         new = meter.since(m1)["programs"]
         if new:
             compiled_at.append([sent, new, round(time.time() - t1, 3)])
@@ -312,10 +306,10 @@ class Window:
                "window_s": self.window_s, "ended_by": self.ended_by,
                "pool_requests": len(self.requests),
                "latency_samples": int(len(lat)),
-               # where a stall sat: [start s, latency ms, terms] of the
-               # slowest requests, and the collector's pauses
-               "slowest": [[r[0], r[1] * 1e3, sum(len(q["terms"])
-                                                   for q in r[2])]
+               # where a stall sat: [start s, latency ms, weight] of the
+               # slowest requests (a `match`'s weight is its term count),
+               # and the collector's pauses
+               "slowest": [[r[0], r[1] * 1e3, sum(q["weight"] for q in r[2])]
                            for r in slow],
                "gc": {"collections": len(self.gc_pauses),
                       "pause_ms_total": 1e3 * sum(p[1] for p in
@@ -388,11 +382,7 @@ def read_layer_metric(name: str, ctx: dict):
     if not os.path.isfile(path):
         raise SystemExit(f"benchmark: per-layer metric {name!r} has no "
                          f"reader at {path}")
-    spec = importlib.util.spec_from_file_location(f"layer_metric_{name}",
-                                                  path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read(ctx)
+    return _load_module(path, f"layer_metric_{name}").read(ctx)
 
 
 def end_to_end(summary: dict, setup_s: float, wanted: list) -> dict:
@@ -408,13 +398,12 @@ def end_to_end(summary: dict, setup_s: float, wanted: list) -> dict:
             for m in wanted if m["name"] in have}
 
 
-def check(client, window: Window, stream, built: dict, config: dict,
-          traffic: dict, seed: int) -> dict:
-    """After the window, held to the dense reference by the rule: a seeded
+def check(deployment, client, window: Window, stream, built: dict,
+          config: dict, traffic: dict, seed: int) -> dict:
+    """After the window, held to the kind's reference by its rule: a seeded
     sample of the window's answered queries, and `check_fresh` requests of
     queries drawn from `--seed` that the pool does not hold, sent now
     (untimed) through the window's own entry, index and programs."""
-    import reference
     pairs = window.answered()
     n = min(int(traffic["check_sample"]), len(pairs))
     pick = np.random.default_rng([seed, 3]).choice(len(pairs), n,
@@ -428,12 +417,8 @@ def check(client, window: Window, stream, built: dict, config: dict,
         except Exception as e:
             resps = [{"error": f"{type(e).__name__}: {e}"}] * len(specs)
         held += list(zip(specs, resps))
-    g = config["guarantees"]
-    ref = reference.Reference(built["csr"], built["dl"], k1=g["bm25_k1"],
-                              b=g["bm25_b"])
     t0 = time.time()
-    out = reference.hold(held, ref, int(traffic["size"]), int(g["page"]),
-                         float(g["score_rtol"]))
+    out = deployment.hold(held, built, config, traffic)
     out.update(from_the_window=n, fresh=len(held) - n,
                reference_s=time.time() - t0)
     return out
@@ -442,14 +427,16 @@ def check(client, window: Window, stream, built: dict, config: dict,
 def run_cell(loaded: dict, seed: int, seconds: float, trace: bool,
              device: dict, meter: CompileMeter, out_dir: str) -> dict:
     """Everything after the look for the chip. -> the result object."""
-    import queries
+    from opensearch_tpu.rest.client import RestClient
     config, traffic = loaded["config"], loaded["traffic"]
-    built = build(config, seed)
-    client = built["client"]
+    deployment = load_kind(config.get("deployment_kind", DEFAULT_KIND))
+    client = RestClient()
+    built = deployment.build(config, seed, client, INDEX)
+    note(f"{config['name']} built ({built['build_s']:.1f} s) and on the "
+         f"device ({built['promote_s']:.1f} s)")
     # the pool is the traffic file's own (`pool_seed`): every seed sends the
     # same requests, in another order
-    stream = queries.QueryStream(built["df"], built["vocab"],
-                                 int(traffic["pool_seed"]), traffic)
+    stream = deployment.stream(built, traffic, int(traffic["pool_seed"]))
     n, batch = int(traffic["pool_requests"]), int(traffic["batch"])
     drawn = stream.take(n * batch)
     pool = [q for r in np.random.default_rng([seed, 1]).permutation(n)
@@ -459,24 +446,24 @@ def run_cell(loaded: dict, seed: int, seconds: float, trace: bool,
     tracer = Tracer(os.path.join(out_dir, "trace"),
                     traffic["trace"]) if trace else None
     window = Window(client, traffic, pool, seconds, tracer)
-    c0, m0 = counters(client), meter.mark()
+    c0, m0 = counters(deployment, client), meter.mark()
     if tracer:
         tracer.start()
     setup_s = time.time() - T_START
     window.run()
     if tracer:
         tracer.stop()
-    in_window = {"counters": delta(counters(client), c0),
+    in_window = {"counters": delta(counters(deployment, client), c0),
                  "compile": meter.since(m0)}
     summary = window.summary()
     note(f"window: {summary['requests']} requests in "
          f"{summary['window_s']:.2f} s; checking")
     emit({"readout": "window", **summary, **in_window,
           "warmup": warm, "build_s": built["build_s"],
-          "promote_s": built["promote_s"], "postings": built["postings"],
-          "aligned_bytes": built["aligned_bytes"]})
+          "promote_s": built["promote_s"], **built["readout"]})
 
-    verdict = check(client, window, stream, built, config, traffic, seed)
+    verdict = check(deployment, client, window, stream, built, config,
+                    traffic, seed)
     emit({"readout": "check", **verdict})
     device = dict(device, memory_peak_bytes=memory_peak_bytes())
     result = {"correct": verdict["correct"],
@@ -485,6 +472,7 @@ def run_cell(loaded: dict, seed: int, seconds: float, trace: bool,
         result["metrics"] = end_to_end(summary, setup_s,
                                        loaded["end_to_end"])
         result["device"] = device
+        result["compared"] = verdict["numbers"]
         return result
 
     import trace_reduce
@@ -510,6 +498,7 @@ def run_cell(loaded: dict, seed: int, seconds: float, trace: bool,
     result["device"] = dict(device, busy_s=reduced["busy_s"],
                             window_s=reduced["window_s"])
     result["breakdown"] = reduced["breakdown"]
+    result["compared"] = verdict["numbers"]
     return result
 
 
@@ -539,6 +528,9 @@ def main(argv=None) -> None:
     result = run_cell(loaded, args.seed, args.seconds, bool(args.trace),
                       device, meter, out_dir)
     emit(result)
+    for name, (value, limit) in result["compared"].items():
+        print(f"compared {name} {value!r} limit {limit!r}", file=sys.stderr)
+    print(f"correct {result['correct']}", file=sys.stderr, flush=True)
 
 
 if __name__ == "__main__":

@@ -43,6 +43,10 @@ def test_configs_and_cells():
         assert all(NAME.match(k) and k in body for k in c["reduced"])
         assert {"source", "assumed", "guarantees", "generator",
                 "corpus_seed", "ndocs"} <= set(body)
+        # the kind of deployment: optional, a name, and it finds its file
+        # with the four members (`load_kind` exits otherwise)
+        kind = body.get("deployment_kind", run.DEFAULT_KIND)
+        assert NAME.match(kind) and run.load_kind(kind)
     assert len({c["file"] for c in SPEC["configs"]}) == len(cfgs)
     cells = SPEC["workloads"]
     assert 1 <= len(cells) <= 24
@@ -81,12 +85,27 @@ def test_metrics():
     for m in every:
         assert NAME.match(m["name"]) and UNIT.match(m["unit"])
         assert m["better"] in ("lower", "higher")
-        assert set(m.get("workloads", cells)) <= cells
+        assert set(m.get("workloads", cells)) <= cells  # each is a cell
+        assert len(set(m.get("workloads", []))) == len(m.get("workloads", []))
     for cell in cells:      # every cell reports setup_s, another, a layer
         mine = [m["name"] for m in SPEC["end_to_end"]
                 if cell in m.get("workloads", cells)]
         assert "setup_s" in mine and len(mine) >= 2
         assert run.load_cell(cell)["per_layer"]
+
+
+def test_the_ladders_metrics_are_listed_by_cell():
+    """Metrics of a mechanism only `bm25_match` cells enter name their
+    cells, so a cell of another kind does not report their zeros."""
+    ladder = {"kernel_served_share", "ladder_escalated_share",
+              "rescore_wall_ms_per_query", "rescore_probe_kelems_per_query",
+              "kernel_ms_per_query"}
+    bm25 = {w["name"] for w in SPEC["workloads"]
+            if run.load_cell(w["name"])["config"].get(
+                "deployment_kind", run.DEFAULT_KIND) == "bm25_match"}
+    for m in SPEC["per_layer"]:
+        if m["name"] in ladder:
+            assert set(m["workloads"]) <= bm25
 
 
 def test_peaks_name_their_source_and_an_unknown_kind_is_missing():

@@ -4,11 +4,13 @@ steered from here (backend flag, interpret mode, head size, sizes), never
 through an option of the harness or the program."""
 
 import copy
+import hashlib
 import json
 import os
 import subprocess
 import sys
 
+import numpy as np
 import pytest
 
 import run
@@ -171,6 +173,169 @@ def test_a_dropped_hit_is_not_correct(as_on_chip, meter, tmp_path,
     result = run.run_cell(small(*SPARE[0]), 7, 0.5, False, DEVICE, meter,
                           str(tmp_path))
     assert result["correct"] is False
+
+
+# sha256 over what a run sends and holds, in order (`sent_and_held`), as the
+# parent's `run.py` gave it at PR 26 (commit dd6daa9, before the deployment
+# kinds): computed there first with this same function, pinned here. The
+# requests are a count that repeats exactly; `bm25_match` is that path moved.
+PINNED = {
+    ("treccovid.search1.long", 11):
+        "746b133b04249b324ef63ddc4f754ca0b4f1e5df1a26da9bbc029ca348b58dc1",
+    ("treccovid.search1.long", 3000000012):
+        "c80ea359e3066ff36e93ed0ddbe883beac5c63e4644ad466c8150839862673ea",
+    ("msmarco.search1.selective", 11):
+        "c8ca8056d56444d84907063fa730be085431c314bc7ed297449bd65415e0fea0",
+    ("msmarco.search1.selective", 3000000012):
+        "44cd4385bd478dceaa28bb5191b44a6854822e45bf469755654ef20e1a2fc63e",
+}
+
+
+def sent_and_held(loaded, seed, meter, tmp_path, monkeypatch) -> str:
+    """One run's bodies in the order sent (warm-up twins, pool, fresh), the
+    `status` / `price` columns it planted and the bodies its check held."""
+    import corpus
+    import reference
+    log = []
+    real_send, real_plant = run.send, corpus.plant_index
+    real_hold = reference.hold
+
+    def send(client, kind, specs):
+        log.append([q["body"] for q in specs])
+        return real_send(client, kind, specs)
+
+    def plant(client, index, csr, vocab, dl, status, price, settings):
+        log.append(["columns", hashlib.sha256(
+            np.asarray(status).tobytes()
+            + np.asarray(price).tobytes()).hexdigest()])
+        return real_plant(client, index, csr, vocab, dl, status, price,
+                          settings)
+
+    def hold(pairs, *a, **kw):
+        log.append(["held"] + [s["body"] for s, _r in pairs])
+        return real_hold(pairs, *a, **kw)
+    monkeypatch.setattr(run, "send", send)
+    monkeypatch.setattr(corpus, "plant_index", plant)
+    monkeypatch.setattr(reference, "hold", hold)
+    run.run_cell(loaded, seed, 60, False, DEVICE, meter, str(tmp_path))
+    assert len(log) == 12       # 3 twins, 3 of the pool, 4 fresh, 2 marks
+    return hashlib.sha256(json.dumps(log).encode()).hexdigest()
+
+
+@pytest.mark.parametrize("cell,seed", sorted(PINNED))
+def test_a_run_sends_and_holds_what_the_parent_did(cell, seed, as_on_chip,
+                                                   meter, tmp_path,
+                                                   monkeypatch):
+    assert sent_and_held(small(cell), seed, meter, tmp_path,
+                         monkeypatch) == PINNED[cell, seed]
+
+
+# ---------------------------------------------------------------------
+# the seam: a deployment kind is a file found by name
+# ---------------------------------------------------------------------
+
+TOY_DIR = os.path.join(run.HERE, "tests", "data", "deployments")
+
+
+def test_an_unknown_kind_names_the_file_looked_for():
+    want = os.path.join(run.HERE, "deployments", "http_logs.py")
+    with pytest.raises(SystemExit) as e:
+        run.load_kind("http_logs")
+    assert want in str(e.value)
+
+
+@pytest.mark.parametrize("member", run.KIND_MEMBERS)
+def test_a_kind_that_lacks_a_member_names_it_and_its_file(member, tmp_path,
+                                                          monkeypatch):
+    rest = [m for m in run.KIND_MEMBERS if m != member]
+    path = tmp_path / "partial.py"
+    path.write_text("".join(f"def {m}(*a):\n    pass\n" for m in rest))
+    monkeypatch.setattr(run, "KIND_DIRS", [str(tmp_path)])
+    with pytest.raises(SystemExit) as e:
+        run.load_kind("partial")
+    assert str(path) in str(e.value) and f"lacks {member}" in str(e.value)
+
+
+def test_a_run_looks_for_kinds_in_deployments_alone():
+    assert run.KIND_DIRS == [os.path.join(run.HERE, "deployments")]
+    for cell in CELLS:      # `load_kind` exits on a kind it cannot take
+        assert run.load_kind(run.load_cell(cell)["config"].get(
+            "deployment_kind", run.DEFAULT_KIND))
+
+
+def toy() -> dict:
+    """A cell of the fixture kind `columns_toy`: 2,000 rows, the three
+    request shapes dealt in turn, everything else as a committed cell."""
+    spec = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
+    return {"cell": {"name": "toy.search1.columns", "chips": 1},
+            "config": {"name": "columns-toy", "ndocs": 2000,
+                       "deployment_kind": "columns_toy",
+                       "index_settings": {"number_of_shards": 1,
+                                          "number_of_replicas": 0}},
+            "traffic": {"request": "search", "batch": 1, "loop": "closed",
+                        "clients": 1, "size": 10, "pool_seed": 7,
+                        "pool_requests": 9, "check_sample": 9,
+                        "check_fresh": 6},
+            "end_to_end": spec["end_to_end"], "per_layer": [], "peaks": {}}
+
+
+@pytest.fixture()
+def toy_kind(monkeypatch):
+    monkeypatch.setattr(run, "KIND_DIRS", run.KIND_DIRS + [TOY_DIR])
+    monkeypatch.setattr(run, "memory_peak_bytes", lambda: 0)
+
+
+def test_a_second_kind_runs_a_cells_phases(toy_kind, meter, tmp_path,
+                                           capsys):
+    """Set-up, the warm-up of twins, the window and the check of a kind with
+    no `match` in it, through `run_cell` as it is."""
+    sent, real = [], run.send
+
+    def logged(client, kind, specs):
+        sent.extend(json.dumps(q["body"], sort_keys=True) for q in specs)
+        return real(client, kind, specs)
+    run.send = logged
+    try:
+        result = run.run_cell(toy(), 3000000021, 60, False, DEVICE, meter,
+                              str(tmp_path))
+    finally:
+        run.send = real
+    assert result["correct"] is True and result["failed"] == 0
+    assert {"qps", "p50_ms", "setup_s"} <= set(result["metrics"])
+    assert list(result)[-1] == "compared"
+    assert all(v == [0, 0] for v in result["compared"].values())
+    assert len(sent) == len(set(sent)) == 9 + 9 + 6
+    assert sum('"aggs"' in b for b in sent) == 8
+    assert sum('"sort"' in b for b in sent) == 8
+    lines = [json.loads(x) for x in capsys.readouterr().out.splitlines()]
+    window = [x for x in lines if x.get("readout") == "window"][0]
+    assert window["ended_by"] == "pool" and window["requests"] == 9
+    assert window["rows"] == 2000
+    assert window["counters"] == {"request_cache.hit_count": 0,
+                                  "request_cache.miss_count": 9,
+                                  "request_cache.entries": 9}
+    assert window["compile"]["programs"] == 0
+    check = [x for x in lines if x.get("readout") == "check"][0]
+    assert check["from_the_window"] == 9 and check["fresh"] == 6
+
+
+def test_a_second_kinds_broken_path_is_not_correct(toy_kind, meter, tmp_path,
+                                                   monkeypatch):
+    """One bucket's count moved by one where it is produced."""
+    from opensearch_tpu.rest.client import RestClient
+    real = RestClient.search
+
+    def one_too_many(self, *a, **kw):
+        resp = real(self, *a, **kw)
+        for agg in resp.get("aggregations", {}).values():
+            agg["buckets"][0]["doc_count"] += 1
+        return resp
+    monkeypatch.setattr(RestClient, "search", one_too_many)
+    result = run.run_cell(toy(), 8, 60, False, DEVICE, meter, str(tmp_path))
+    assert result["correct"] is False
+    assert result["compared"]["bucket_mismatches"][0] > 0
+    assert result["compared"]["total_mismatches"] == [0, 0]
+    assert result["compared"]["rank_mismatches"] == [0, 0]
 
 
 def test_run_py_refuses_the_cpu(tmp_path):
