@@ -395,13 +395,20 @@ class NumericColumn:
     present: np.ndarray       # bool[ndocs]
 
     _sort_ords: Optional[np.ndarray] = None
+    _min_max: Optional[Tuple[float, float]] = None
 
     @property
     def min_max(self) -> Tuple[float, float]:
-        if not self.present.any():
-            return (0.0, 0.0)
-        vals = self.values[self.present]
-        return (float(vals.min()), float(vals.max()))
+        """(min, max) of the present values, computed once: the column is
+        immutable, and `compiler.can_match` asks on every range of every
+        request (two passes over the column: 0.45 s at 49M rows)."""
+        if self._min_max is None:
+            if not self.present.any():
+                self._min_max = (0.0, 0.0)
+            else:
+                vals = self.values[self.present]
+                self._min_max = (float(vals.min()), float(vals.max()))
+        return self._min_max
 
     def sort_ords(self) -> np.ndarray:
         """Per-doc rank of the value among the segment's distinct values —

@@ -74,6 +74,8 @@ KINDS = (
     "filter_list",          # cached FilterList device doc lists
     "quality_tier",         # static-pruning view masks/doc lists
     "nested_sort",          # compiler _nested_sort_values columns
+    "sort_rank_plane",      # compiler prepare_sort: i32 ranks per (segment, field)
+    "agg_bucket_plane",     # compiler _date_bucket_plane: i32 bucket ids
     "phrase_pairs",         # resident phrase (doc, pos) pair arrays
     "mesh_postings",        # SPMD stacked per-shard postings/pairs
     "mesh_columns",         # SPMD stacked agg columns/ordinals/masks

@@ -22,11 +22,21 @@ def _gather_match(match: jnp.ndarray, docs: jnp.ndarray) -> jnp.ndarray:
     return jnp.where(docs < match.shape[0], match[safe], 0.0)
 
 
+def bucket_counts(bucket_ids: jnp.ndarray, w: jnp.ndarray,
+                  nbuckets: int) -> jnp.ndarray:
+    """Documents per bucket, i32[nbuckets]: `w` is a 0/1 weight per row and
+    ids outside [0, nbuckets) are dropped. Counts accumulate in int32: a
+    float32 count stops at 2^24 = 16,777,216, and one bucket of a large
+    segment can hold more."""
+    return jnp.zeros(nbuckets, jnp.int32).at[bucket_ids].add(
+        (w > 0).astype(jnp.int32), mode="drop")
+
+
 def terms_counts(kw: dict, match: jnp.ndarray, nvocab_pad: int) -> jnp.ndarray:
     """Keyword terms agg: per-ordinal doc counts (reference
-    GlobalOrdinalsStringTermsAggregator). Returns f32[nvocab_pad]."""
-    w = _gather_match(match, kw["doc_of_value"])
-    return jnp.zeros(nvocab_pad, jnp.float32).at[kw["ords"]].add(w, mode="drop")
+    GlobalOrdinalsStringTermsAggregator). Returns i32[nvocab_pad]."""
+    return bucket_counts(kw["ords"], _gather_match(match, kw["doc_of_value"]),
+                         nvocab_pad)
 
 
 def terms_sub_metric(kw: dict, match: jnp.ndarray, values_f32: jnp.ndarray,
@@ -56,17 +66,17 @@ def histogram_counts(values_f32: jnp.ndarray, present: jnp.ndarray, match: jnp.n
     b = jnp.floor((values_f32 - offset) / interval).astype(jnp.int32) - min_bucket
     w = match * jnp.where(present, 1.0, 0.0)
     b = jnp.where((b >= 0) & (b < nbuckets), b, nbuckets)  # OOB -> dropped
-    return jnp.zeros(nbuckets, jnp.float32).at[b].add(w, mode="drop")
+    return bucket_counts(b, w, nbuckets)
 
 
 def range_counts(values_f32: jnp.ndarray, present: jnp.ndarray, match: jnp.ndarray,
                  lows: jnp.ndarray, highs: jnp.ndarray):
     """range agg: [low, high) per reference RangeAggregator. lows/highs are
-    f32[nranges] traced arrays; returns f32[nranges] counts."""
+    f32[nranges] traced arrays; returns i32[nranges] counts."""
     v = values_f32[None, :]
     in_range = (v >= lows[:, None]) & (v < highs[:, None])
-    w = (match * jnp.where(present, 1.0, 0.0))[None, :]
-    return jnp.sum(jnp.where(in_range, w, 0.0), axis=1)
+    ok = ((match > 0) & present)[None, :]
+    return jnp.sum((in_range & ok).astype(jnp.int32), axis=1)
 
 
 def stats_agg(values_f32: jnp.ndarray, present: jnp.ndarray, match: jnp.ndarray):
@@ -130,7 +140,7 @@ def ord_counts(ords: jnp.ndarray, match: jnp.ndarray, nord_pad: int
     """Doc-major single-valued ordinal bincount (multi_terms combined ords,
     grid ords): ord < 0 = missing -> dropped."""
     o = jnp.where(ords >= 0, ords, nord_pad)
-    return jnp.zeros(nord_pad, jnp.float32).at[o].add(match, mode="drop")
+    return bucket_counts(o, match, nord_pad)
 
 
 def cardinality_keyword(kw: dict, match: jnp.ndarray, nvocab_pad: int) -> jnp.ndarray:

@@ -39,6 +39,7 @@ from ..models.similarity import Similarity, resolve_similarity
 from ..ops import aggs as agg_ops
 from ..ops import scoring as ops
 from ..script import painless_lite as pl
+from ..utils.metrics import METRICS, CounterGroup
 from ..utils.trace import TRACER
 from . import query_dsl as dsl
 from .aggregations import AggNode
@@ -63,6 +64,17 @@ HLL_LOG2M = 14
 # per-phase breakdowns also make.
 
 _JIT_FAMILIES = ("executor", "mask", "gather", "agg", "rescore", "join")
+
+# what a launch of `executor_program` is handed from the host (every numpy
+# array or scalar among its params is one host->device copy a request),
+# and the per-segment planes that stay on the device so that it is handed
+# none of `ndocs_pad` elements: a date_histogram's bucket ids and a field
+# sort's ranks (builds / hits of the per-segment caches, bytes built)
+EXECUTOR_STATS = CounterGroup(METRICS, "executor", {"params_h2d_bytes": 0})
+BUCKET_PLANE_STATS = CounterGroup(METRICS, "aggs.bucket_plane",
+                                  {"builds": 0, "hits": 0, "bytes": 0})
+RANK_PLANE_STATS = CounterGroup(METRICS, "sort.rank_plane",
+                                {"builds": 0, "hits": 0, "bytes": 0})
 
 
 class _TimedProgram:
@@ -3366,8 +3378,6 @@ def prepare_sort(sort_specs: List[dict], seg: Segment, params: dict):
     """Bind sort to a segment. Device ranks by the PRIMARY key exactly (rank
     ordinals for numerics — see NumericColumn.sort_ords); the executor
     re-orders the k-window on the host with the full key tuple."""
-    import jax.numpy as jnp
-
     if not sort_specs:
         return ("score",)
     primary = sort_specs[0]
@@ -3407,15 +3417,10 @@ def prepare_sort(sort_specs: List[dict], seg: Segment, params: dict):
         params["sort_ords"] = _jnp.asarray(pad)
         return ("field_ord", desc, missing_last)
     if field in seg.numeric_cols:
-        cache = getattr(seg, "_sort_dev_cache", None)
-        if cache is None:
-            cache = seg._sort_dev_cache = {}
-        if field not in cache:
-            ords = seg.numeric_cols[field].sort_ords()
-            pad = np.full(seg.ndocs_pad, -1, dtype=np.int32)
-            pad[: seg.ndocs] = ords
-            cache[field] = jnp.asarray(pad)
-        params["sort_ords"] = cache[field]
+        params["sort_ords"], = _segment_plane(
+            seg, "_sort_dev_cache", (field,), "sort_rank_plane",
+            RANK_PLANE_STATS,
+            lambda: (seg.numeric_cols[field].sort_ords(),))
         return ("field_ord", desc, missing_last)
     if field in seg.keyword_cols:
         return ("kw_ord", field, desc, missing_last)
@@ -3464,60 +3469,106 @@ def emit_sort_key(sort_spec, seg_arrays: dict, params: dict, scores):
 # aggregations: prepare + emit
 # =====================================================================
 
-def _host_date_buckets(seg: Segment, field: str, interval_ms: int, offset_ms: int,
-                       calendar: Optional[str]) -> Tuple[np.ndarray, int, int]:
-    """Exact date bucketing on host i64 (cached per segment): returns
-    (bucket_id i32[ndocs], min_bucket, nbuckets). Calendar intervals walk real
-    calendars (reference Rounding.Builder)."""
-    cache = getattr(seg, "_date_bucket_cache", None)
-    if cache is None:
-        cache = seg._date_bucket_cache = {}
-    key = (field, interval_ms, offset_ms, calendar)
-    if key in cache:
+def _segment_plane(seg: Segment, cache_name: str, key, kind: str, stats,
+                   build: Callable[[], tuple]) -> tuple:
+    """One i32[ndocs_pad] plane of per-document ids (-1 = none) kept on the
+    device for the segment's lifetime, with whatever `build` returns after
+    its host ids: -> (device plane, *rest). Cached under
+    `seg.<cache_name>[key]` (a tuple that starts with the field) and attributed in the HBM ledger as `kind`;
+    `derived._purge_query_caches` drops a rematerialized field's planes
+    and the segment's GC the rest. `stats` counts builds, hits and bytes.
+    The per-segment lock keeps two first requests from building (and
+    charging) one plane twice."""
+    cache = seg.__dict__.setdefault(cache_name, {})
+    hit = cache.get(key)
+    if hit is not None:
+        stats.inc("hits")
+        return hit
+    lock = seg.__dict__.setdefault("_plane_build_lock",
+                                   __import__("threading").Lock())
+    with lock:
+        hit = cache.get(key)
+        if hit is not None:
+            stats.inc("hits")
+            return hit
+        import jax.numpy as jnp
+
+        from ..obs.hbm_ledger import LEDGER
+        ids, *rest = build()
+        pad = np.full(seg.ndocs_pad, -1, dtype=np.int32)
+        pad[: len(ids)] = ids
+        plane = jnp.asarray(pad)
+        alloc = LEDGER.register(kind, pad.nbytes, owner=seg, segment=seg,
+                                label=f"{kind}[{seg.name}][{key}]")
+        seg.__dict__.setdefault("_plane_allocs", {})[cache_name, key] = alloc
+        stats.inc("builds")
+        stats.inc("bytes", pad.nbytes)
+        cache[key] = (plane, *rest)
         return cache[key]
-    col = seg.numeric_cols.get(field)
-    if col is None or not col.present.any():
-        res = (np.full(seg.ndocs, -1, np.int32), 0, 1)
-        cache[key] = res
-        return res
-    vals = col.values.astype(np.int64)
-    if calendar is None:
-        b = np.floor_divide(vals - offset_ms, interval_ms)
-    else:
-        b = _calendar_bucket_ids(vals, calendar)
-    b = np.where(col.present, b, np.int64(-(1 << 40)))
-    bp = b[col.present]
-    mn, mx = int(bp.min()), int(bp.max())
-    out = (b - mn).astype(np.int64)
-    out = np.where(col.present, out, -1).astype(np.int32)
-    res = (out, mn, int(mx - mn + 1))
-    cache[key] = res
-    return res
+
+
+def drop_segment_planes(seg: Segment, field: str) -> None:
+    """Drop `field`'s rank and bucket planes and release their ledger
+    bytes (a rematerialized derived field: `derived._purge_query_caches`)."""
+    from ..obs.hbm_ledger import LEDGER
+    allocs = seg.__dict__.get("_plane_allocs", {})
+    for cache_name in ("_sort_dev_cache", "_date_bucket_cache"):
+        cache = seg.__dict__.get(cache_name, {})
+        for key in [k for k in cache if k[0] == field]:
+            del cache[key]
+            LEDGER.release(allocs.pop((cache_name, key), None))
+
+
+def _date_bucket_plane(seg: Segment, field: str, interval_ms: int,
+                       offset_ms: int, calendar: Optional[str]):
+    """Exact date bucketing on host i64, once per (segment, field, interval,
+    offset, calendar), then resident: -> (bucket ids i32[ndocs_pad] on the
+    device, -1 = no value, min_bucket, nbuckets). Calendar intervals follow
+    real calendars (reference Rounding.Builder)."""
+    def build():
+        col = seg.numeric_cols.get(field)
+        if col is None or not col.present.any():
+            return np.full(seg.ndocs, -1, np.int32), 0, 1
+        vals = col.values.astype(np.int64)
+        if calendar is None:
+            b = np.floor_divide(vals - offset_ms, interval_ms)
+        else:
+            b = _calendar_bucket_ids(vals, calendar)
+        bp = b[col.present]
+        mn, mx = int(bp.min()), int(bp.max())
+        ids = np.where(col.present, b - mn, -1).astype(np.int32)
+        return ids, mn, int(mx - mn + 1)
+    return _segment_plane(seg, "_date_bucket_cache",
+                          (field, interval_ms, offset_ms, calendar),
+                          "agg_bucket_plane", BUCKET_PLANE_STATS, build)
+
+
+_DAY_MS = 86400000
 
 
 def _calendar_bucket_ids(ms: np.ndarray, calendar: str) -> np.ndarray:
-    import datetime as dt
-
-    out = np.empty(len(ms), dtype=np.int64)
-    for i, v in enumerate(ms):
-        d = dt.datetime.fromtimestamp(int(v) / 1000.0, dt.timezone.utc)
-        if calendar in ("month", "1M"):
-            out[i] = (d.year - 1970) * 12 + (d.month - 1)
-        elif calendar in ("year", "1y"):
-            out[i] = d.year - 1970
-        elif calendar in ("quarter", "1q"):
-            out[i] = (d.year - 1970) * 4 + (d.month - 1) // 3
-        elif calendar in ("week", "1w"):
-            out[i] = (int(v) // 86400000 + 3) // 7  # epoch day 0 = Thursday
-        elif calendar in ("day", "1d"):
-            out[i] = int(v) // 86400000
-        elif calendar in ("hour", "1h"):
-            out[i] = int(v) // 3600000
-        elif calendar in ("minute", "1m"):
-            out[i] = int(v) // 60000
-        else:
-            raise ValueError(f"unknown calendar_interval [{calendar}]")
-    return out
+    """Calendar bucket ids of epoch-millisecond values (UTC), as whole
+    columns: fixed-length units by floor division, months and years by
+    numpy's proleptic Gregorian `datetime64`."""
+    ms = np.asarray(ms, dtype=np.int64)
+    if calendar in ("minute", "1m"):
+        return ms // 60000
+    if calendar in ("hour", "1h"):
+        return ms // 3600000
+    if calendar in ("day", "1d"):
+        return ms // _DAY_MS
+    if calendar in ("week", "1w"):
+        return (ms // _DAY_MS + 3) // 7     # epoch day 0 = Thursday
+    if calendar in ("year", "1y"):
+        return ms.astype("datetime64[ms]").astype(
+            "datetime64[Y]").astype(np.int64)
+    months = ms.astype("datetime64[ms]").astype(
+        "datetime64[M]").astype(np.int64)   # since 1970-01
+    if calendar in ("month", "1M"):
+        return months
+    if calendar in ("quarter", "1q"):
+        return months // 3
+    raise ValueError(f"unknown calendar_interval [{calendar}]")
 
 
 _CAL_MS = {"month": None, "1M": None, "year": None, "1y": None, "quarter": None,
@@ -3878,11 +3929,8 @@ def prepare_agg(node: AggNode, seg: Segment, ctx: ShardContext, params: dict,
         offset_ms = (parse_interval_ms(body.get("offset", 0),
                                        allow_negative=True)
                      if body.get("offset") else 0)
-        bucket_ids, min_b, nb = _host_date_buckets(seg, field, max(interval_ms, 1),
-                                                   offset_ms, calendar)
-        pad = np.full(next_pow2(len(bucket_ids)), -1, dtype=np.int32)
-        pad[: len(bucket_ids)] = bucket_ids
-        params[f"{prefix}_dbuckets"] = pad
+        params[f"{prefix}_dbuckets"], min_b, nb = _date_bucket_plane(
+            seg, field, max(interval_ms, 1), offset_ms, calendar)
         subs = tuple(prepare_agg(s, seg, ctx, params, f"{prefix}_{i}",
                                  nest_stack)
                      for i, s in enumerate(node.subs))
@@ -4217,11 +4265,8 @@ def prepare_agg(node: AggNode, seg: Segment, ctx: ShardContext, params: dict,
         target = max(int(body.get("buckets", 10)), 1)
         col = seg.numeric_cols.get(field)
         interval_ms = _auto_interval(col, target)
-        bucket_ids, min_b, nb = _host_date_buckets(seg, field, interval_ms,
-                                                   0, None)
-        pad = np.full(next_pow2(len(bucket_ids)), -1, dtype=np.int32)
-        pad[: len(bucket_ids)] = bucket_ids
-        params[f"{prefix}_dbuckets"] = pad
+        params[f"{prefix}_dbuckets"], min_b, nb = _date_bucket_plane(
+            seg, field, interval_ms, 0, None)
         subs = tuple(prepare_agg(s, seg, ctx, params, f"{prefix}_{i}",
                                  nest_stack)
                      for i, s in enumerate(node.subs))
@@ -4354,13 +4399,10 @@ def _prepare_composite(node: AggNode, seg: Segment, ctx: ShardContext,
             interval_ms = (0 if calendar else
                            parse_interval_ms(scfg.get("fixed_interval",
                                                       scfg.get("interval", "1d"))))
-            bucket_ids, min_b, nb = _host_date_buckets(
+            params[f"{prefix}_s{si}"], min_b, nb = _date_bucket_plane(
                 seg, field, max(interval_ms, 1), 0, calendar)
             if nb <= 0:
                 return ("terms_missing", prefix)
-            pad = np.full(next_pow2(len(bucket_ids)), -1, dtype=np.int32)
-            pad[: len(bucket_ids)] = bucket_ids
-            params[f"{prefix}_s{si}"] = pad
             infos.append(("date", field, nb, min_b,
                           float(max(interval_ms, 1)), calendar or ""))
         else:
@@ -4442,7 +4484,7 @@ def emit_agg(spec, seg_arrays: dict, params: dict, match, scores=None):  # noqa:
         ords = params[f"{prefix}_gords"][:ndocs_pad]
         w = match * (ords >= 0).astype(jnp.float32)
         b = jnp.where(w > 0, ords, nb)
-        out = {"counts": jnp.zeros(nb, jnp.float32).at[b].add(w, mode="drop")}
+        out = {"counts": agg_ops.bucket_counts(b, w, nb)}
         for i, sub in enumerate(subs):
             out.update(_emit_bucketed_sub(jnp, sub, i, b, nb, seg_arrays, match))
         return out
@@ -4541,7 +4583,7 @@ def emit_agg(spec, seg_arrays: dict, params: dict, match, scores=None):  # noqa:
             combined = combined * n + jnp.maximum(o, 0)
         w = valid.astype(jnp.float32)
         b = jnp.where(valid, combined, total)
-        out = {"counts": jnp.zeros(total, jnp.float32).at[b].add(w, mode="drop")}
+        out = {"counts": agg_ops.bucket_counts(b, w, total)}
         for i, sub in enumerate(subs):
             out.update(_emit_bucketed_sub(jnp, sub, i, b, total, seg_arrays,
                                           match * w))
@@ -4588,7 +4630,7 @@ def emit_agg(spec, seg_arrays: dict, params: dict, match, scores=None):  # noqa:
         w = match * jnp.where(col["present"], 1.0, 0.0)
         b = jnp.floor((col["f32"] - offset) / interval).astype(jnp.int32) - min_b
         b = jnp.where((b >= 0) & (b < nb) & (w > 0), b, nb)
-        out = {"counts": jnp.zeros(nb, jnp.float32).at[b].add(w, mode="drop")}
+        out = {"counts": agg_ops.bucket_counts(b, w, nb)}
         for i, sub in enumerate(subs):
             out.update(_emit_bucketed_sub(jnp, sub, i, b, nb, seg_arrays, match))
         return out
@@ -4598,7 +4640,7 @@ def emit_agg(spec, seg_arrays: dict, params: dict, match, scores=None):  # noqa:
         b_all = params[f"{prefix}_dbuckets"][:ndocs_pad]
         w = match * jnp.where(b_all >= 0, 1.0, 0.0)
         b = jnp.where((b_all >= 0) & (w > 0), b_all, nb)
-        out = {"counts": jnp.zeros(nb, jnp.float32).at[b].add(w, mode="drop")}
+        out = {"counts": agg_ops.bucket_counts(b, w, nb)}
         for i, sub in enumerate(subs):
             out.update(_emit_bucketed_sub(jnp, sub, i, b, nb, seg_arrays, match))
         return out
@@ -4895,7 +4937,7 @@ def emit_agg(spec, seg_arrays: dict, params: dict, match, scores=None):  # noqa:
         bucket_ids = params[f"{prefix}_dbuckets"][:ndocs_pad]
         w = match * (bucket_ids >= 0).astype(jnp.float32)
         b = jnp.where(w > 0, bucket_ids, nb)
-        out = {"counts": jnp.zeros(nb, jnp.float32).at[b].add(w, mode="drop")}
+        out = {"counts": agg_ops.bucket_counts(b, w, nb)}
         for i, sub in enumerate(subs):
             out.update(_emit_bucketed_sub(jnp, sub, i, b, nb, seg_arrays,
                                           match))
@@ -5321,6 +5363,7 @@ def launch_segment_batch(prepared: list, seg_arrays: dict):
     pending = []
     for full_spec, cparams in prepared:
         exe = _build_executor(full_spec)
+        _count_params_h2d(cparams)
         pending.append(exe(seg_arrays, cparams))   # invocation, no sync
 
     def _fetch():
@@ -5328,6 +5371,15 @@ def launch_segment_batch(prepared: list, seg_arrays: dict):
             return jax.device_get(pending)
 
     return _fetch
+
+
+def _count_params_h2d(cparams: dict) -> None:
+    """`executor.params_h2d_bytes`: the bytes of every host numpy array or
+    scalar one launch of `executor_program` is handed (each is copied to
+    the device by the call; planes that live there are not counted)."""
+    EXECUTOR_STATS.inc("params_h2d_bytes", sum(
+        v.nbytes for v in cparams.values()
+        if isinstance(v, (np.ndarray, np.generic))))
 
 
 def canon_query(query_spec, sort_spec, k_pad: int, params: dict):
@@ -5353,6 +5405,7 @@ def run_segment(query_spec, sort_spec, agg_specs, named_specs, k_pad: int,
                        mapping)
     cparams = {_canon_param_key(k, mapping): v for k, v in params.items()}
     exe = _build_executor(full)
+    _count_params_h2d(cparams)
     return exe(seg_arrays, cparams)
 
 

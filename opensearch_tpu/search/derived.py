@@ -173,12 +173,11 @@ def _purge_query_caches(seg, names: List[str]) -> None:
     seg.__dict__.get("_fastpath_filters", {}).clear()
     for name in names:
         seg.__dict__.get("_fastpath_aligned", {}).pop(name, None)
-        seg.__dict__.get("_sort_dev_cache", {}).pop(name, None)
-        for cache_name in ("_date_bucket_cache", "_nested_sort_cache"):
-            c = seg.__dict__.get(cache_name)
-            if c:
-                for k in [k for k in c if k[0] == name]:
-                    del c[k]
+        C.drop_segment_planes(seg, name)    # sort ranks, date buckets
+        c = seg.__dict__.get("_nested_sort_cache")
+        if c:
+            for k in [k for k in c if k[0] == name]:
+                del c[k]
 
 
 class _LazyDocCols(dict):

@@ -366,6 +366,7 @@ class ShardSearcher:
                     task.track(device_seconds=result.took_ms / 1000.0)
                 return result
 
+        import jax
         seg_t0 = time.monotonic()
         for seg_ord, seg in enumerate(segments):
             if ta and result.total >= ta:
@@ -439,10 +440,13 @@ class ShardSearcher:
                     qc.note_actual(gb, slots, k_pad, path="xla", segment=seg)
                 sspec = C.prepare_sort(sort_specs, seg, params)
                 agg_specs = []
-                for i, an in enumerate(agg_nodes):
-                    if an.kind == "top_hits":
-                        continue  # resolved from this segment's top-k below
-                    agg_specs.append((an.name, C.prepare_agg(an, seg, ctx, params, f"a{i}")))
+                if agg_nodes:
+                    with TRACER.span("search.aggs.prepare"):
+                        for i, an in enumerate(agg_nodes):
+                            if an.kind == "top_hits":
+                                continue  # from this segment's top-k below
+                            agg_specs.append((an.name, C.prepare_agg(
+                                an, seg, ctx, params, f"a{i}")))
                 named_specs = []
                 for nm, nnode in named_nodes:
                     nparams: Dict[str, Any] = {}
@@ -467,9 +471,13 @@ class ShardSearcher:
                     # device-script trace failures are user errors (HTTP 400)
                     raise dsl.QueryParseError(f"script compile error: {e}")
                 with TRACER.span("device.wait", program="executor"):
-                    keys = np.asarray(out["topk_key"])
-                    idx = np.asarray(out["topk_idx"])
-                    scores = np.asarray(out["topk_scores"])
+                    # one sweep for the window and the scalars: a read
+                    # of its own is a device->host hop of its own, about
+                    # a millisecond each with the device idle
+                    keys, idx, scores, total, ms, named_np = jax.device_get(
+                        (out["topk_key"], out["topk_idx"],
+                         out["topk_scores"], out["total"],
+                         out["max_score"], out.get("named", {})))
                 valid = keys > -np.inf
                 if not tie_aware or sort_specs:
                     # widen only for score sorts: a field sort's primary
@@ -501,21 +509,21 @@ class ShardSearcher:
 
             ran_segs.append(seg)
             with TRACER.span("search.collect"):
-                with TRACER.span("device.wait", program="executor"):
-                    # each scalar / array read is its own device->host hop
-                    total = int(out["total"])
-                    ms = float(out["max_score"])
-                    named_np = {nm: np.asarray(v)
-                                for nm, v in out.get("named", {}).items()}
+                total, ms = int(total), float(ms)
                 result.total += total
                 if ms > result.max_score:
                     result.max_score = ms
 
-                for name, aspec in agg_specs:
-                    node = next(a for a in agg_nodes if a.name == name)
-                    partial = _device_agg_to_partial(node, aspec,
-                                                     out.get("aggs", {}).get(name), seg, ctx)
-                    result.agg_partials.setdefault(name, []).append(partial)
+                if agg_specs:
+                    aggs_out = _fetch_agg_outputs(out.get("aggs", {}))
+                    with TRACER.span("search.aggs.partial"):
+                        for name, aspec in agg_specs:
+                            node = next(a for a in agg_nodes
+                                        if a.name == name)
+                            partial = _device_agg_to_partial(
+                                node, aspec, aggs_out.get(name), seg, ctx)
+                            result.agg_partials.setdefault(
+                                name, []).append(partial)
 
                 # rescore second pass over this segment's window
                 if rescores:
@@ -650,7 +658,8 @@ class ShardSearcher:
                     params: Dict[str, Any] = {}
                     qspec = C.prepare(lroot, seg, ctx, params)
                     aspec = C.prepare_agg(an, seg, ctx, params, "rs")
-                    out = C.run_agg_only(qspec, aspec, seg.device_arrays(self.device), params)
+                    out = _fetch_agg_outputs(C.run_agg_only(
+                        qspec, aspec, seg.device_arrays(self.device), params))
                     new_parts.append(_device_agg_to_partial(an, aspec, out, seg, ctx))
                 result.agg_partials[an.name] = new_parts
             finally:
@@ -2186,6 +2195,17 @@ def _ordinal_buckets(node: AggNode, device_out: dict, vocab) -> dict:
             rec["subs"] = sub_partials
         buckets[vocab[o]] = rec
     return buckets
+
+
+def _fetch_agg_outputs(device_out):
+    """An agg program's output tree read to the host in one sweep, inside
+    a `device.wait` span: `_device_agg_to_partial` then walks numpy arrays
+    and its time is the host's alone."""
+    if not device_out:
+        return device_out
+    import jax
+    with TRACER.span("device.wait", program="executor_aggs"):
+        return jax.device_get(device_out)
 
 
 def _device_agg_to_partial(node: AggNode, aspec, device_out: Optional[dict],

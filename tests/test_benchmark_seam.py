@@ -1,0 +1,81 @@
+"""`BENCHMARK.json` and the files it names, held together in tier-1: the
+benchmark's own suite (`benchmark/tests`) runs by hand, so a PR that breaks
+the seam between the harness and what it finds by name would otherwise
+show only on the chip."""
+
+import json
+import os
+import sys
+
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path[:0] = [p for p in (os.path.join(ROOT, "benchmark"), ROOT)
+                if p not in sys.path]
+
+import queries                  # noqa: E402
+import run as harness           # noqa: E402
+
+SPEC = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
+CELLS = [w["name"] for w in SPEC["workloads"]]
+
+
+def _kind_of(config: dict):
+    return harness.load_kind(config.get("deployment_kind",
+                                        harness.DEFAULT_KIND))
+
+
+def test_benchmark_json_loads_and_names_its_parts():
+    assert SPEC["command"] == ["python3", "benchmark/run.py"]
+    assert SPEC["paths"] == ["benchmark"]
+    assert len(CELLS) == len(set(CELLS)) >= 3
+    assert {c["name"] for c in SPEC["configs"]} \
+        == {w["config"] for w in SPEC["workloads"]}
+
+
+@pytest.mark.parametrize("config", [c["name"] for c in SPEC["configs"]])
+def test_a_configurations_kind_resolves_to_its_four_members(config):
+    entry = next(c for c in SPEC["configs"] if c["name"] == config)
+    body = json.load(open(os.path.join(ROOT, entry["file"])))
+    assert body["name"] == config and body["reduced"] == entry["reduced"]
+    kind = _kind_of(body)       # exits where the file or a member is missing
+    assert all(callable(getattr(kind, m)) for m in harness.KIND_MEMBERS)
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_a_cells_traffic_names_a_generator_that_resolves(cell):
+    """`bm25_match` looks its query generators up in `queries.py`; a kind
+    with generators of its own lists them under `GENERATORS`."""
+    loaded = harness.load_cell(cell)
+    kind, name = _kind_of(loaded["config"]), loaded["traffic"]["generator"]
+    own = getattr(kind, "GENERATORS", None)
+    if own is not None:
+        assert name in own
+    else:
+        assert callable(queries.generator(name))
+    assert isinstance(loaded["traffic"]["params"], dict)
+    # what the cell reports: set-up, another end-to-end metric, a layer
+    e2e = {m["name"] for m in loaded["end_to_end"]}
+    assert "setup_s" in e2e and len(e2e) >= 2 and loaded["per_layer"]
+
+
+@pytest.mark.parametrize("metric", [m["name"] for m in SPEC["per_layer"]])
+def test_a_per_layer_metric_has_its_reader(metric):
+    path = os.path.join(harness.HERE, "layer_metrics", metric + ".py")
+    assert os.path.isfile(path)
+    assert callable(harness._load_module(path, "reader_" + metric).read)
+
+
+def test_the_new_readers_read_nothing_from_a_program_without_their_source():
+    """A per-layer reader returns None where the program has no such
+    counter, span or module (the parent of the PR that added it)."""
+    ctx = {"window": {"queries": 10, "counters": {}},
+           "trace": {"queries": 10, "requests": 10, "module_s": {}}}
+    for name in ("params_h2d_mib_per_query",
+                 "executor_program_ms_per_query"):
+        assert harness.read_layer_metric(name, ctx) is None
+    ctx["window"]["counters"]["executor.params_h2d_bytes"] = 10 << 20
+    ctx["trace"]["module_s"]["jit_executor_program"] = 0.5
+    assert harness.read_layer_metric("params_h2d_mib_per_query", ctx) == 1.0
+    assert harness.read_layer_metric("executor_program_ms_per_query",
+                                     ctx) == 50.0
