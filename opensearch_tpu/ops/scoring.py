@@ -459,14 +459,78 @@ def collapse_topk(key: jnp.ndarray, matched: jnp.ndarray, live: jnp.ndarray,
     return vals, docs
 
 
+# `topk_blocks` cuts a plane only where that hands `lax.top_k` at most
+# 1 / _TOPK_BLOCKED_GAIN of its keys. Read on a v5e (PERF.md section 6,
+# PR 29): the blocked form is never slower, wins from 2^18 keys up, and
+# compiles in 0.2 s where one `lax.top_k` over 2^13 keys takes 1.5 s and
+# over 2^15 or more 12-24 s; at 16 a (2^26, 128) top-k kept an 11 s sort.
+_TOPK_BLOCKED_GAIN = 8
+
+
+def topk_blocks(n: int, k: int):
+    """How a top `k` over a plane of `n` keys is cut: `(R, C)`, R blocks of
+    C consecutive positions, or None for one `lax.top_k` over all of it.
+    A function of the two static sizes alone, so the program and the
+    `executor.topk_keys_sorted` counter cannot drift.
+
+    C is the power of two nearest sqrt(n / k), which minimises the
+    R + k * C keys that go on. None where C does not divide n or the cut
+    does not pay (R + k * C over n / _TOPK_BLOCKED_GAIN), which also
+    covers every shape it cannot be built for: k >= R makes k * C >= n."""
+    if k < 1:
+        return None
+    c = 1 << ((n // k).bit_length() // 2)
+    r, rem = divmod(n, c)
+    if rem or (r + k * c) * _TOPK_BLOCKED_GAIN > n:
+        return None
+    return r, c
+
+
+def topk_keys_sorted(n: int, k: int) -> int:
+    """Keys that `topk_docs` hands to `lax.top_k`, all calls together, for
+    a plane of n keys and a top k (`executor.topk_keys_sorted`)."""
+    k = min(k, n)
+    blocks = topk_blocks(n, k)
+    if blocks is None:
+        return n
+    r, c = blocks
+    return topk_keys_sorted(r, k) + topk_keys_sorted(k * c, k)
+
+
+def _topk_lowest_first(x: jnp.ndarray, k: int):
+    """`lax.top_k(x, k)` (equal keys: the lower position first) without
+    sorting all of x where `topk_blocks` cuts it. One pass reduces each
+    block of C consecutive positions to its maximum; the top k of the R
+    maxima picks k blocks; their rows, laid out in ascending block order
+    so that position stays monotone, give the top k. Both inner top-ks
+    are this function again, so no `lax.top_k` sees a long row.
+
+    Exact, ties included: order elements by (key descending, position
+    ascending) and blocks by their best element; an element of the true
+    top k outside the k best blocks would have k elements of k other
+    blocks before it."""
+    blocks = topk_blocks(x.shape[0], k)
+    if blocks is None:
+        return jax.lax.top_k(x, k)
+    r, c = blocks
+    rows = x.reshape(r, c)
+    _, best = _topk_lowest_first(jnp.max(rows, axis=1), k)
+    best = jnp.sort(best)
+    vals, pos = _topk_lowest_first(rows[best].reshape(k * c), k)
+    return vals, best[pos // c] * c + pos % c
+
+
 def topk_docs(scores: jnp.ndarray, matched: jnp.ndarray, live: jnp.ndarray, k: int):
-    """Masked fused top-k. Ties broken by ascending doc id like Lucene's
-    TopScoreDocCollector (implemented by a tiny monotone doc-id epsilon that
-    cannot reorder distinct f32 scores)."""
+    """Exact masked top-k over one doc-id plane: the k best keys among the
+    matched live docs, best first, and their doc ids.
+
+    Ties break by ascending doc id, like Lucene's TopScoreDocCollector.
+    With fewer than k matches the remaining lanes hold -inf; their ids are
+    unspecified but within [0, n), and every reader drops those lanes by
+    value. Values and ids above -inf are those of `lax.top_k` over the
+    whole masked plane, whichever form `topk_blocks` picks for (n, k)."""
     masked = jnp.where(matched & (live > 0), scores, NEG_INF)
-    k = min(k, scores.shape[0])
-    vals, idx = jax.lax.top_k(masked, k)
-    return vals, idx
+    return _topk_lowest_first(masked, min(k, scores.shape[0]))
 
 
 def total_hits(matched: jnp.ndarray, live: jnp.ndarray) -> jnp.ndarray:

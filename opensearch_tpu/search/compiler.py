@@ -69,8 +69,10 @@ _JIT_FAMILIES = ("executor", "mask", "gather", "agg", "rescore", "join")
 # array or scalar among its params is one host->device copy a request),
 # and the per-segment planes that stay on the device so that it is handed
 # none of `ndocs_pad` elements: a date_histogram's bucket ids and a field
-# sort's ranks (builds / hits of the per-segment caches, bytes built)
-EXECUTOR_STATS = CounterGroup(METRICS, "executor", {"params_h2d_bytes": 0})
+# sort's ranks (builds / hits of the per-segment caches, bytes built);
+# `topk_keys_sorted`: the keys a launch's top-k hands to `lax.top_k`
+EXECUTOR_STATS = CounterGroup(METRICS, "executor", {"params_h2d_bytes": 0,
+                                                    "topk_keys_sorted": 0})
 BUCKET_PLANE_STATS = CounterGroup(METRICS, "aggs.bucket_plane",
                                   {"builds": 0, "hits": 0, "bytes": 0})
 RANK_PLANE_STATS = CounterGroup(METRICS, "sort.rank_plane",
@@ -5363,7 +5365,7 @@ def launch_segment_batch(prepared: list, seg_arrays: dict):
     pending = []
     for full_spec, cparams in prepared:
         exe = _build_executor(full_spec)
-        _count_params_h2d(cparams)
+        _count_launch(full_spec, seg_arrays, cparams)
         pending.append(exe(seg_arrays, cparams))   # invocation, no sync
 
     def _fetch():
@@ -5373,13 +5375,19 @@ def launch_segment_batch(prepared: list, seg_arrays: dict):
     return _fetch
 
 
-def _count_params_h2d(cparams: dict) -> None:
-    """`executor.params_h2d_bytes`: the bytes of every host numpy array or
-    scalar one launch of `executor_program` is handed (each is copied to
-    the device by the call; planes that live there are not counted)."""
+def _count_launch(full_spec, seg_arrays: dict, cparams: dict) -> None:
+    """One launch of `executor_program`, counted. `executor.params_h2d_bytes`:
+    the bytes of every host numpy array or scalar it is handed (each is
+    copied to the device by the call; planes that live there are not
+    counted). `executor.topk_keys_sorted`: the keys its `ops.topk_docs`
+    hands to `lax.top_k` (a collapse launch takes `collapse_topk`: none)."""
     EXECUTOR_STATS.inc("params_h2d_bytes", sum(
         v.nbytes for v in cparams.values()
         if isinstance(v, (np.ndarray, np.generic))))
+    _query, _sort, _aggs, k_pad, _named, _after, collapse_spec = full_spec
+    if collapse_spec is None:
+        EXECUTOR_STATS.inc("topk_keys_sorted", ops.topk_keys_sorted(
+            seg_arrays["live"].shape[0], k_pad))
 
 
 def canon_query(query_spec, sort_spec, k_pad: int, params: dict):
@@ -5405,7 +5413,7 @@ def run_segment(query_spec, sort_spec, agg_specs, named_specs, k_pad: int,
                        mapping)
     cparams = {_canon_param_key(k, mapping): v for k, v in params.items()}
     exe = _build_executor(full)
-    _count_params_h2d(cparams)
+    _count_launch(full, seg_arrays, cparams)
     return exe(seg_arrays, cparams)
 
 

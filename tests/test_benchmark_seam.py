@@ -79,3 +79,15 @@ def test_the_new_readers_read_nothing_from_a_program_without_their_source():
     assert harness.read_layer_metric("params_h2d_mib_per_query", ctx) == 1.0
     assert harness.read_layer_metric("executor_program_ms_per_query",
                                      ctx) == 50.0
+
+
+@pytest.mark.parametrize("keys,want", [(None, None), (0, 0.0),
+                                       (3_072 * 88, 3.072)])
+def test_topk_sorted_kelems_reads_the_counter_or_nothing(keys, want):
+    """`executor.topk_keys_sorted` / queries / 1,000; a program without the
+    counter (the parent of the PR that added it) reports nothing."""
+    ctx = {"window": {"queries": 88, "counters": {}}}
+    if keys is not None:
+        ctx["window"]["counters"]["executor.topk_keys_sorted"] = keys
+    assert harness.read_layer_metric("topk_sorted_kelems_per_query",
+                                     ctx) == want
