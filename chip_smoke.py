@@ -12,21 +12,25 @@ does, through `RestClient` (and `HttpServer` over a real socket):
            document back through a second client on the same data path.
            Compared with the same requests on the XLA path
            (`fastpath.set_enabled(False)`) and a numpy BM25.
-  phase B  real size: bench.py's MS-MARCO-shaped generator at `--ndocs`
-           (default 2.2M = one chip's share of the 8.8M-passage, four-shard
-           north-star index), wrapped as a product segment; a fixed seeded
-           set of 64 queries as `msearch` batches, then 16 singly.
-           Compared with a numpy dense scorer over the same CSR arrays and
-           the native MaxScore scorer where the library built.
+  phase B  real size: the benchmark's MS-MARCO-shaped generator
+           (`benchmark/corpus.py`; vocabulary 200,000, mean length 56) at
+           `--ndocs` (default 2.2M = one chip's share of the 8.8M-passage,
+           four-shard north-star index), planted as a product segment; a
+           fixed seeded set of 64 queries as `msearch` batches, then 16
+           singly. Compared with the benchmark's numpy dense reference
+           (`benchmark/reference.py`) over the same CSR arrays and the
+           native MaxScore scorer where the library built.
   --chips 4  runs ONLY the mesh phase: four segments of ndocs/4 in a
            four-shard index through a `MeshSearchService` node, against a
            `Node(mesh_service=False)` client (the host shard loop).
 
-The comparison rule everywhere: hit totals equal where the response says
-`eq`; scores within 1e-5 relative; doc ids equal wherever the reference's
-score gap to its neighbouring ranks exceeds that tolerance. Every request
-asks for one rank more than the ten it checks, so the gap below the tenth
-rank is known.
+The generator, the reference, the comparison rule and the meters are the
+benchmark's own (`benchmark/corpus.py`, `reference.py`, `run.py`): the smoke
+holds a page to what the yardstick would hold it to. The rule everywhere:
+hit totals equal where the response says `eq`; scores within 1e-5
+relative; doc ids equal wherever the reference's score gap to its
+neighbouring ranks exceeds that tolerance. Every request asks for one rank
+more than the ten it checks, so the gap below the tenth rank is known.
 
 Each phase prints one JSON object of read-outs (seconds, programs
 compiled, counters, device memory: read-outs, not metrics) BEFORE it holds
@@ -52,90 +56,28 @@ import time
 import numpy as np
 
 _REPO = os.path.dirname(os.path.abspath(__file__))
+# the benchmark's modules are top-level names inside benchmark/ (as
+# tests/test_benchmark_seam.py imports them)
+sys.path.insert(0, os.path.join(_REPO, "benchmark"))
+
+import corpus                                   # noqa: E402
+import reference                                # noqa: E402
+from run import (CompileMeter, INDEX, delta, emit,   # noqa: E402
+                 note, require_device)
+
 OUT_DIR = os.path.join(_REPO, "chiprun_out", "chip_smoke")
-K1, B = 1.2, 0.75
+K1, B = reference.K1, reference.B
 PAGE = 10            # ranks checked per response
 SIZE = PAGE + 1      # ranks requested: the 11th gives the 10th its gap
 RTOL = 1e-5
 PHASE_A_NDOCS = 20_000
-STATUSES = ["archived", "draft", "published"]   # bench.make_index's vocab
+STATUSES = corpus.STATUSES
+# phase B's corpus: what the MS-MARCO-shaped configuration's generator takes
+VOCAB, AVG_DL = 200_000, 56
 
 
 class SmokeFailure(AssertionError):
     """A comparison or a required counter failed."""
-
-
-def emit(obj: dict) -> None:
-    """One JSON read-out line on stdout, mirrored under chiprun_out/."""
-    line = json.dumps(obj)
-    print(line, flush=True)
-    os.makedirs(OUT_DIR, exist_ok=True)
-    with open(os.path.join(OUT_DIR, "readouts.jsonl"), "a") as f:
-        f.write(line + "\n")
-
-
-def note(msg: str) -> None:
-    """Progress on stderr: where a run was when a time limit cut it."""
-    print(f"[chip_smoke {time.strftime('%H:%M:%S')}] {msg}",
-          file=sys.stderr, flush=True)
-
-
-# ---------------------------------------------------------------------
-# device, compile accounting, counters
-# ---------------------------------------------------------------------
-
-def require_device(chips: int) -> dict:
-    """The device as JAX reports it; SystemExit(2) unless it is a TPU with
-    exactly `chips` devices — before anything is built."""
-    import jax
-    ds = jax.devices()
-    dev = {"platform": ds[0].platform, "kind": ds[0].device_kind,
-           "count": len(ds)}
-    if dev["platform"] != "tpu" or dev["count"] != chips:
-        print(f"chip_smoke: need a TPU with {chips} device(s); JAX reports "
-              f"{dev}", file=sys.stderr)
-        raise SystemExit(2)
-    return dev
-
-
-class CompileMeter:
-    """Programs compiled (or loaded from the persistent cache) and the
-    seconds that took, from JAX's own monitoring events — every backend
-    compile in the process, the Pallas kernels included. The search
-    compiler's per-family attribution (`jit_attribution`) rides along."""
-
-    _COMPILE = "/jax/core/compile/backend_compile_duration"
-    _HIT = "/jax/compilation_cache/cache_hits"
-
-    def __init__(self):
-        import jax.monitoring as mon
-        self.programs = 0
-        self.seconds = 0.0
-        self.cache_hits = 0
-        mon.register_event_duration_secs_listener(self._duration)
-        mon.register_event_listener(self._event)
-
-    def _duration(self, event, secs, **_kw):
-        if event == self._COMPILE:
-            self.programs += 1
-            self.seconds += secs
-
-    def _event(self, event, **_kw):
-        if event == self._HIT:
-            self.cache_hits += 1
-
-    def mark(self) -> tuple:
-        return (self.programs, self.seconds, self.cache_hits)
-
-    def since(self, mark: tuple) -> dict:
-        from opensearch_tpu.search.compiler import jit_attribution
-        return {"programs": self.programs - mark[0],
-                "compile_s": round(self.seconds - mark[1], 3),
-                "persistent_cache_hits": self.cache_hits - mark[2],
-                "search_jit": {
-                    fam: {"misses": a["cache"]["misses"],
-                          "compile_ms": round(a["compile"]["total_ms"], 1)}
-                    for fam, a in jit_attribution().items()}}
 
 
 def counters() -> dict:
@@ -148,11 +90,6 @@ def counters() -> dict:
     out["serving.batch_errors"] = METRICS.snapshot()["counters"].get(
         "serving.batch_errors", 0)
     return out
-
-
-def delta(after: dict, before: dict) -> dict:
-    return {k: round(after[k] - before.get(k, 0), 3) for k in after
-            if after[k] != before.get(k, 0)}
 
 
 def kernels_lower_to_mosaic() -> bool:
@@ -173,69 +110,53 @@ def kernels_lower_to_mosaic() -> bool:
 
 
 # ---------------------------------------------------------------------
-# the plain reference and the comparison rule
+# the benchmark's reference and rule, as the smoke calls them
 # ---------------------------------------------------------------------
 
-def dense_bm25(csr, terms, msm: int = 1, mask=None) -> dict:
-    """Numpy dense BM25 over CSR postings: every posting of every query
-    term scored, no skipping, top SIZE by (score desc, doc asc)."""
-    starts, doc_ids, tfs, dl = csr
-    n = len(dl)
-    kdoc = (K1 * (1.0 - B + B * dl / (dl.sum() / n))).astype(np.float32)
-    score = np.zeros(n, np.float32)
-    count = np.zeros(n, np.int32)
-    for t in terms:
-        a, e = int(starts[t]), int(starts[t + 1])
-        d, tf = doc_ids[a:e], tfs[a:e]
-        idf = np.float32(np.log1p((n - (e - a) + 0.5) / ((e - a) + 0.5)))
-        score[d] += idf * tf / (tf + kdoc[d])
-        count[d] += 1
-    ok = count >= msm
+def reference_page(ref: reference.Reference, terms, msm: int = 1,
+                   mask=None) -> dict:
+    """The reference's page of SIZE for `terms`. `Reference` knows neither
+    `minimum_should_match` nor a filter; both only decide which documents
+    are hits (a hit's score is the sum over the terms it holds either
+    way), so they are applied here to its ranking of every document that
+    holds a term."""
+    if msm <= 1 and mask is None:
+        return ref.page({"terms": terms}, SIZE)
+    ranked = ref.page({"terms": terms}, ref.n)  # size n: every hit, ranked
+    docs = np.asarray(ranked["ids"], np.int64)
+    keep = np.ones(len(docs), bool)
+    if msm > 1:
+        held = np.bincount(np.concatenate(
+            [ref.doc_ids[ref.starts[t]: ref.starts[t + 1]] for t in terms]),
+            minlength=ref.n)
+        keep &= held[docs] >= msm
     if mask is not None:
-        ok &= mask
-    hits = np.flatnonzero(ok)
-    order = hits[np.lexsort((hits, -score[hits]))][:SIZE]
-    return {"total": len(hits), "relation": "eq",
-            "ids": [str(d) for d in order],
-            "scores": [float(s) for s in score[order]]}
+        keep &= mask[docs]
+    top = np.flatnonzero(keep)[:SIZE]
+    return {"total": int(keep.sum()), "relation": "eq",
+            "ids": [ranked["ids"][i] for i in top],
+            "scores": [ranked["scores"][i] for i in top]}
 
 
-def page_of(resp: dict) -> dict:
+def served_page(resp: dict) -> dict:
     if "error" in resp:
         raise SmokeFailure(f"search answered an error: {resp['error']}")
-    h = resp["hits"]
-    return {"total": h["total"]["value"], "relation": h["total"]["relation"],
-            "ids": [x["_id"] for x in h["hits"]],
-            "scores": [x["_score"] for x in h["hits"]]}
+    return reference.page_of(resp)
 
 
-def compare_page(what: str, got: dict, ref: dict) -> None:
-    """Hold `got` to `ref` by the rule in the module docstring."""
-    if ref["relation"] == "eq":
-        if got["relation"] == "eq" and got["total"] != ref["total"]:
-            raise SmokeFailure(f"{what}: total {got['total']} != "
-                               f"{ref['total']}")
-        if got["relation"] != "eq" and got["total"] > ref["total"]:
-            raise SmokeFailure(f"{what}: lower-bound total {got['total']} "
-                               f"> exact {ref['total']}")
+def hold_page(what: str, got: dict, ref: dict) -> None:
+    """Hold `got` to `ref` by the benchmark's rule: any number
+    `reference.compare_page` finds over its limit raises. A reference with
+    no `ids` decides the total alone; one whose own total is a lower bound
+    (the native scorer after early termination, a served page that says
+    `gte`) decides no total."""
     if "ids" not in ref:
-        return                  # a count-only reference
-    if len(got["ids"]) != len(ref["ids"]):
-        raise SmokeFailure(f"{what}: {len(got['ids'])} hits, reference has "
-                           f"{len(ref['ids'])}")
-    rs = np.asarray(ref["scores"], np.float64)
-    gs = np.asarray(got["scores"], np.float64)
-    tol = RTOL * np.maximum(np.abs(rs), 1e-30)
-    if np.any(np.abs(gs - rs) > tol):
-        raise SmokeFailure(f"{what}: scores {got['scores']} != "
-                           f"{ref['scores']}")
-    for i in range(min(PAGE, len(rs))):
-        gaps = np.abs(np.delete(rs, i) - rs[i])
-        if len(gaps) and gaps.min() <= tol[i]:
-            continue            # tied in the reference: order not decided
-        if got["ids"][i] != ref["ids"][i]:
-            raise SmokeFailure(f"{what}: rank {i} is {got['ids'][i]}, "
-                               f"reference has {ref['ids'][i]}")
+        ref = dict(got, total=ref["total"], relation=ref["relation"])
+    found = reference.compare_page(got, ref, PAGE, RTOL)
+    if ref["relation"] != "eq":
+        found["total_violations"] = 0
+    if found.pop("score_rel_err") > RTOL or any(found.values()):
+        raise SmokeFailure(f"{what}: {got} != {ref}")
 
 
 # ---------------------------------------------------------------------
@@ -243,8 +164,8 @@ def compare_page(what: str, got: dict, ref: dict) -> None:
 # ---------------------------------------------------------------------
 
 def _phase_a_corpus(rng, ndocs: int, nvocab: int = 2000):
-    """Seeded documents + the CSR postings of their `body` for the
-    reference. Words are `w0000`-style so the standard analyzer keeps
+    """Seeded documents + the CSR postings and lengths of their `body` for
+    the reference. Words are `w0000`-style so the standard analyzer keeps
     them whole."""
     dl = rng.integers(6, 31, ndocs)
     terms = rng.zipf(1.2, int(dl.sum()))
@@ -262,9 +183,8 @@ def _phase_a_corpus(rng, ndocs: int, nvocab: int = 2000):
                           return_counts=True)
     starts = np.zeros(nvocab + 1, np.int64)
     np.cumsum(np.bincount(uniq // ndocs, minlength=nvocab), out=starts[1:])
-    csr = (starts, (uniq % ndocs).astype(np.int32), tfs.astype(np.float32),
-           dl.astype(np.int64))
-    return docs, csr, status, price
+    csr = (starts, (uniq % ndocs).astype(np.int32), tfs.astype(np.float32))
+    return docs, csr, dl.astype(np.int64), status, price
 
 
 def _http(port: int, method: str, path: str, body=None, ndjson=None):
@@ -296,7 +216,8 @@ def phase_a(data_dir: str, seed: int, ndocs: int, meter: CompileMeter
     shutil.rmtree(data_dir, ignore_errors=True)   # this script's own dir
     rng = np.random.default_rng(seed)
     c0, m0, t0 = counters(), meter.mark(), time.time()
-    docs, csr, status, price = _phase_a_corpus(rng, ndocs)
+    docs, csr, dl, status, price = _phase_a_corpus(rng, ndocs)
+    ref = reference.Reference(csr, dl)
     client = RestClient(data_path=data_dir)
     client.indices.create("smoke", {
         "settings": {"number_of_shards": 1, "number_of_replicas": 0},
@@ -328,14 +249,15 @@ def phase_a(data_dir: str, seed: int, ndocs: int, meter: CompileMeter
     for i in range(8):
         ts = pick(2)
         reqs.append((f"match{i}", {"query": {"match": {"body": text(ts)}},
-                                   "size": SIZE}, dense_bm25(csr, ts)))
+                                   "size": SIZE}, reference_page(ref, ts)))
     for i in range(4):
         ts, lo = pick(2), 100 * (i + 1)
         reqs.append((f"bool{i}", {"query": {"bool": {
             "must": [{"match": {"body": text(ts)}}],
             "filter": [{"range": {"price": {"gte": lo, "lt": lo + 500}}}]}},
             "size": SIZE},
-            dense_bm25(csr, ts, mask=(price >= lo) & (price < lo + 500))))
+            reference_page(ref, ts,
+                           mask=(price >= lo) & (price < lo + 500))))
     for i in range(4):
         s = i % 3
         # constant score per hit: the rule checks total and scores only
@@ -352,10 +274,10 @@ def phase_a(data_dir: str, seed: int, ndocs: int, meter: CompileMeter
 
     def run(search, get, msearch):
         """-> (pages, agg buckets, probe source, msearch pages)."""
-        pages = [page_of(search(body)) for _n, body, _r in reqs]
+        pages = [served_page(search(body)) for _n, body, _r in reqs]
         buckets = {b["key"]: b["doc_count"] for b in
                    search(agg_body)["aggregations"]["by_status"]["buckets"]}
-        multi = [page_of(r) for r in msearch()["responses"]]
+        multi = [served_page(r) for r in msearch()["responses"]]
         return pages, buckets, get()["_source"], multi
 
     def run_client():
@@ -395,11 +317,11 @@ def phase_a(data_dir: str, seed: int, ndocs: int, meter: CompileMeter
         raise SmokeFailure("HttpServer answers differ from RestClient's")
     for (name, _body, ref), page, mpage, xpage in zip(reqs, pages, multi,
                                                       via_xla[0]):
-        compare_page(f"A/{name} vs numpy", page, ref)
-        compare_page(f"A/{name} vs XLA path", page, xpage)
-        compare_page(f"A/{name} msearch vs search", mpage, page)
+        hold_page(f"A/{name} vs numpy", page, ref)
+        hold_page(f"A/{name} vs XLA path", page, xpage)
+        hold_page(f"A/{name} msearch vs search", mpage, page)
     for (name, _b, _r), mpage, xmpage in zip(reqs, multi, via_xla[3]):
-        compare_page(f"A/{name} msearch vs XLA msearch", mpage, xmpage)
+        hold_page(f"A/{name} msearch vs XLA msearch", mpage, xmpage)
     if not buckets == via_xla[1] == agg_ref:
         raise SmokeFailure(f"A/terms agg {buckets} != {agg_ref}")
     if not source == via_xla[2] == docs[int(probe_id)]:
@@ -416,10 +338,6 @@ def phase_a(data_dir: str, seed: int, ndocs: int, meter: CompileMeter
 # ---------------------------------------------------------------------
 # phase B — real size
 # ---------------------------------------------------------------------
-
-def _vocab(n: int) -> list:
-    return [f"t{i:07d}" for i in range(n)]
-
 
 def _queries(df: np.ndarray, rng, vocab: list) -> list:
     """The fixed seeded set of 64: 24 two-term match, 16 four-term match
@@ -476,10 +394,11 @@ def _send_set(client, index: str, queries: list) -> tuple:
         lines = []
         for q in queries[lo: lo + 32]:
             lines += [{"index": index}, q["body"]]
-        pages += [page_of(r) for r in client.msearch(lines)["responses"]]
+        pages += [served_page(r)
+                  for r in client.msearch(lines)["responses"]]
         if first_s is None:
             first_s = time.time() - t0
-    singles = [page_of(client.search(index, queries[i]["body"]))
+    singles = [served_page(client.search(index, queries[i]["body"]))
                for i in SINGLES]
     return pages, singles, first_s
 
@@ -492,8 +411,8 @@ def _hold_set(label: str, queries: list, sent: tuple, refs: list) -> None:
     for i, page in list(enumerate(pages)) + list(zip(SINGLES, singles)):
         for name, ref in refs[i]:
             try:
-                compare_page(f"{label}/{queries[i]['kind']}[{i}] vs {name}",
-                             page, ref)
+                hold_page(f"{label}/{queries[i]['kind']}[{i}] vs {name}",
+                          page, ref)
             except SmokeFailure as e:
                 failed.append(str(e))
     if failed:
@@ -532,20 +451,20 @@ def _device_memory() -> dict:
 def phase_b(seed: int, ndocs: int, meter: CompileMeter) -> dict:
     import jax
 
-    import bench
     from opensearch_tpu import native
     from opensearch_tpu.rest.client import RestClient
     from opensearch_tpu.search import fastpath
 
     rng = np.random.default_rng(seed + 1)
     c0, t0 = counters(), time.time()
-    starts, doc_ids, tfs, dl, df = bench.build_corpus(ndocs, seed=seed)
+    starts, doc_ids, tfs, dl, df = corpus.build_corpus(ndocs, VOCAB, AVG_DL,
+                                                       seed)
     status = rng.integers(0, 3, ndocs).astype(np.int32)
     price = rng.integers(0, 1000, ndocs).astype(np.int64)
-    vocab = _vocab(len(df))
+    vocab = corpus.vocab_strings(len(df))
     client = RestClient()
-    seg = bench.make_index(client, (starts, doc_ids, tfs, vocab), dl, None,
-                           status, price)
+    seg = corpus.plant_index(client, INDEX, (starts, doc_ids, tfs), vocab,
+                             dl, status, price, {"number_of_replicas": 0})
     build_s = time.time() - t0
     note(f"B: {ndocs}-doc segment built on the host")
 
@@ -560,16 +479,17 @@ def phase_b(seed: int, ndocs: int, meter: CompileMeter) -> dict:
 
     queries = _queries(df, rng, vocab)
     m0, t0 = meter.mark(), time.time()
-    pages, singles, first_s = _send_set(client, "bench", queries)
+    pages, singles, first_s = _send_set(client, INDEX, queries)
     cold_s, cold = time.time() - t0, meter.since(m0)
     note("B: cold set answered")
     m0, t0 = meter.mark(), time.time()
-    warm = _send_set(client, "bench", queries)
+    warm = _send_set(client, INDEX, queries)
     warm_s, warm_compiles = time.time() - t0, meter.since(m0)
     note("B: warm set answered; scoring the references")
 
-    # references: numpy dense always; native MaxScore where it built
-    csr = (starts, doc_ids, tfs, dl)
+    # references: the benchmark's numpy dense always; native MaxScore
+    # where it built
+    ref = reference.Reference((starts, doc_ids, tfs), dl)
     have_native = native.available()
     if have_native:
         kdoc = (K1 * (1.0 - B + B * dl.astype(np.float32)
@@ -580,8 +500,8 @@ def phase_b(seed: int, ndocs: int, meter: CompileMeter) -> dict:
     refs = []
     for q in queries:
         mask = None if q["status"] is None else status == q["status"]
-        refs.append([("numpy dense", dense_bm25(csr, q["terms"], q["msm"],
-                                                mask))])
+        refs.append([("numpy dense", reference_page(ref, q["terms"],
+                                                    q["msm"], mask))])
         if have_native:
             d, s, total = native.maxscore_topk(
                 starts, doc_ids, tfs, kdoc, idf, ub,
@@ -616,8 +536,38 @@ def phase_b(seed: int, ndocs: int, meter: CompileMeter) -> dict:
 # --chips 4 — the mesh path against the host shard loop
 # ---------------------------------------------------------------------
 
+class _IdsFrom:
+    """A shard's doc-id strings, counted from its first id, made on demand."""
+
+    def __init__(self, base: int, n: int):
+        self.base, self.n = base, n
+
+    def __len__(self):
+        return self.n
+
+    def __getitem__(self, i):
+        if isinstance(i, slice):
+            return [str(self.base + j) for j in range(*i.indices(self.n))]
+        return str(self.base + i)
+
+
+def _plant_shard(client, shard: int, id_base: int, csr, vocab, dl, status,
+                 price) -> None:
+    """`corpus.plant_index` makes an index of one segment in shard 0; the
+    mesh phase wants that segment in shard `shard` of INDEX, its ids
+    counted from `id_base`: planted under a scratch name, then moved."""
+    scratch = f"plant{shard}"
+    seg = corpus.plant_index(client, scratch, csr, vocab, dl, status, price,
+                             {"number_of_replicas": 0})
+    client.node.indices[scratch].shards[0].segments = []
+    client.indices.delete(scratch)
+    seg.name, seg.ids = f"{INDEX}{shard}", _IdsFrom(id_base, len(dl))
+    svc = client.node.indices[INDEX]
+    svc.shards[shard].segments = [seg]
+    svc.generation += 1
+
+
 def phase_mesh(seed: int, ndocs: int, meter: CompileMeter) -> dict:
-    import bench
     from opensearch_tpu.cluster.node import Node
     from opensearch_tpu.rest.client import RestClient
 
@@ -631,7 +581,7 @@ def phase_mesh(seed: int, ndocs: int, meter: CompileMeter) -> dict:
                            "MeshSearchService with more than one device")
     c0, t0 = counters(), time.time()
     for c in (mesh_client, host_client):
-        c.indices.create("bench", {
+        c.indices.create(INDEX, {
             "settings": {"number_of_shards": shards,
                          "number_of_replicas": 0},
             "mappings": {"properties": {
@@ -640,28 +590,27 @@ def phase_mesh(seed: int, ndocs: int, meter: CompileMeter) -> dict:
     df, vocab = 0, None
     for s in range(shards):
         rng = np.random.default_rng(seed + 1 + s)
-        starts, doc_ids, tfs, dl, df_s = bench.build_corpus(per,
-                                                            seed=seed + s)
+        starts, doc_ids, tfs, dl, df_s = corpus.build_corpus(
+            per, VOCAB, AVG_DL, seed + s)
         df = df + df_s
-        vocab = vocab or _vocab(len(df_s))
+        vocab = vocab or corpus.vocab_strings(len(df_s))
         status = rng.integers(0, 3, per).astype(np.int32)
         price = rng.integers(0, 1000, per).astype(np.int64)
         for c in (mesh_client, host_client):
-            bench.make_index(c, (starts, doc_ids, tfs, vocab), dl, None,
-                             status, price, create=False, shard=s,
-                             id_base=s * per)
+            _plant_shard(c, s, s * per, (starts, doc_ids, tfs), vocab, dl,
+                         status, price)
     build_s = time.time() - t0
     note(f"mesh: {shards} x {per}-doc segments built, twice")
 
     queries = _queries(df, np.random.default_rng(seed + 1), vocab)
     m0, t0 = meter.mark(), time.time()
-    pages, singles, first_s = _send_set(mesh_client, "bench", queries)
+    pages, singles, first_s = _send_set(mesh_client, INDEX, queries)
     cold_s, cold = time.time() - t0, meter.since(m0)
     memory = _device_memory()       # before the host loop adds its own
     note(f"mesh: cold set answered; per-device bytes in use "
          f"{memory['per_device_bytes_in_use']}")
     t0 = time.time()
-    warm = _send_set(mesh_client, "bench", queries)
+    warm = _send_set(mesh_client, INDEX, queries)
     warm_s = time.time() - t0
     note("mesh: warm set answered; asking the host shard loop")
     dispatched, declined = svc.dispatched, svc.fallbacks
@@ -669,7 +618,7 @@ def phase_mesh(seed: int, ndocs: int, meter: CompileMeter) -> dict:
         raise SmokeFailure("mesh: no search was dispatched to the mesh "
                            f"(declined {declined}: {svc.stats()})")
 
-    ref_pages, _, _ = _send_set(host_client, "bench", queries)
+    ref_pages, _, _ = _send_set(host_client, INDEX, queries)
     refs = [[("host loop", ref)] for ref in ref_pages]
     out = {"phase": "mesh", "ndocs": per * shards, "shards": shards,
            "queries": len(queries), "singles": len(SINGLES),
@@ -740,10 +689,7 @@ def main(argv=None) -> None:
         readouts.append(phase_mesh(args.seed, args.ndocs, meter))
     verdict(readouts, args.chips)
     emit({"phase": "total", "wall_s": round(time.time() - t_start, 1),
-          "programs": meter.programs,
-          "compile_s": round(meter.seconds, 3),
-          "persistent_cache_hits": meter.cache_hits,
-          "counters": counters()})
+          "compiled": meter.mark(), "counters": counters()})
     print(json.dumps({"ok": True, "device": device}), flush=True)
 
 

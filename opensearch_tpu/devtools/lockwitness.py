@@ -12,8 +12,7 @@ one-in-a-million scheduling that turns the inversion into a deadlock.
 Activation:
     OPENSEARCH_TPU_LOCKWITNESS=1         wrap + record (report only)
     OPENSEARCH_TPU_LOCKWITNESS_STRICT=1  also raise LockOrderInversion
-or programmatically `lockwitness.install(strict=...)` (tests, the
-measure_concurrency overhead gate).
+or programmatically `lockwitness.install(strict=...)` (tests).
 
 Mechanics: `install()` patches the `threading.Lock` / `threading.RLock`
 factories. The replacement walks the creating stack frame (skipping
@@ -28,9 +27,8 @@ lock — the witness never changes behavior outside the package.
 Hot-path cost: per acquire, one thread-local list append plus one plain
 dict membership probe per held lock (GIL-safe reads); the slow path
 (first sighting of an edge — stack capture under an internal raw lock)
-runs once per (held, acquired) pair per process. The
-measure_concurrency.py `lockwitness_overhead_32t` stamp gates the
-wrapped/unwrapped qps ratio at >= 0.98x.
+runs once per (held, acquired) pair per process. What that costs a
+request on the chip is not measured.
 
 Known modeling edges (shared with the static pass, see
 docs/STATIC_ANALYSIS.md "Concurrency suite"): `Condition.wait()`

@@ -1,7 +1,7 @@
 """oslint — AST-based host/device discipline linter for opensearch_tpu.
 
 Four checkers tailored to this repo's failure modes (see
-docs/STATIC_ANALYSIS.md for rationale and ADVICE.md lineage):
+docs/STATIC_ANALYSIS.md for rationale and the round-5 review's lineage):
 
 - OSL101/OSL102 dtype-discipline (`dtype_rules`): float domain mixing in
   score comparisons; float-rounded count planes.

@@ -407,8 +407,7 @@ class Engine:
     def codec_mix(self) -> Dict[int, int]:
         """Live segments per codec version — the serving tier can carry a
         mixed v1/v2 set indefinitely (v1 loads untouched; refresh/merge
-        emit the process default). Surfaced in bench `extra.impacts` and
-        scripts/hbm_report.py."""
+        emit the process default). Surfaced by scripts/hbm_report.py."""
         mix: Dict[int, int] = {}
         for s in self.segments:
             v = int(getattr(s, "codec_version", 1))

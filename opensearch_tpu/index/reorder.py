@@ -4,8 +4,9 @@ Block-max pruning (search/impactpath.py, ops/pallas_bm25 impact kernel)
 prices every 128-posting block at `w_t · scale · block_max` and skips the
 cheap ones. On a corpus indexed in arrival order the per-block maxima are
 near-uniform — every block of a queried term contains SOME high-impact
-posting — so only skewed/single-term query shapes ever skip (0.58 skip
-rate on the BENCH_r06 synthetic; equal-idf multi-term mixes skip ~0).
+posting — so only skewed/single-term query shapes ever skip (equal-idf
+multi-term mixes skip next to nothing on the synthetic corpus of
+docs/BENCH_CORPUS.md).
 Reordering doc ids so documents with similar high-impact terms are
 ADJACENT concentrates each term's impact mass into few blocks, which is
 the classic block-max force multiplier (recursive graph bisection /
@@ -51,8 +52,8 @@ planes are built, and has three stages:
 Skipped when: the segment is below REORDER_MIN_DOCS (block pruning can't
 win anything under a few hundred blocks), no codec-v2 impact plane
 exists (v1 segments), the signature band is empty, or
-OPENSEARCH_TPU_REORDER=0 pins the pass off (rollback / ablation knob —
-the bench A/B runs both arms through it).
+OPENSEARCH_TPU_REORDER=0 pins the pass off (rollback / ablation knob:
+tests/test_reorder.py runs both arms through it).
 """
 
 from __future__ import annotations

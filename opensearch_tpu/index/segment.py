@@ -652,7 +652,7 @@ class Segment:
         (model-assigned weights quantized directly, kind="feature") so
         `neural_sparse` serves through the impact ladder.
         Idempotent; used by build_segment/merge and by direct CSR corpus
-        wrappers (bench.py, scripts/hbm_report.py)."""
+        wrappers (benchmark/corpus.py, scripts/hbm_report.py)."""
         feature_fields = set(feature_fields)
         for f, pb in self.postings.items():
             if pb.impact is not None:

@@ -200,9 +200,10 @@ def maxscore_topk(starts: np.ndarray, doc_ids: np.ndarray, tfs: np.ndarray,
                   qterms: np.ndarray, msm: int, k: int,
                   filt: Optional[np.ndarray] = None):
     """Skipping (MaxScore/conjunction) BM25 top-k over one CSR field — the
-    Lucene-BulkScorer-class CPU baseline used by bench.py, also a parity
-    oracle for tests. qterms: i32[nt] term rows (-1 pad). msm: minimum
-    matching terms (nt = conjunction). filt: optional u8[ndocs] 0/1 mask.
+    Lucene-BulkScorer-class CPU scorer chip_smoke.py holds its pages to,
+    also a parity oracle for tests. qterms: i32[nt] term rows (-1 pad).
+    msm: minimum matching terms (nt = conjunction). filt: optional
+    u8[ndocs] 0/1 mask.
     -> (docs i32[k] (-1 pad), scores f32[k], total int — exact for the
     conjunction path, -1 when the MaxScore path early-terminated)."""
     lib = _load()

@@ -34,9 +34,9 @@ Design:
 - **Release exactness.** `release()` is idempotent per allocation; an
   `owner` object ties release to a `weakref.finalize`, so a tenant GC'd
   without an explicit release still credits the breaker exactly once.
-- **Peak tracking.** Total and per-kind peaks survive releases — the
-  `extra.hbm` bench stamp is the committed footprint baseline future PRs
-  must beat.
+- **Peak tracking.** Total and per-kind peaks survive releases (the
+  `_nodes/stats` "hbm" block; the allocator's own peak is the
+  benchmark's `hbm_peak_gib`).
 - **Silicon cross-check.** On a real device backend `check_device()`
   compares the ledger total against `device.memory_stats()["bytes_in_use"]`
   and triggers a flight-recorder anomaly dump (`hbm_drift`) past the
@@ -393,8 +393,8 @@ class HBMLedger:
             return self._total
 
     def snapshot(self) -> dict:
-        """Rollup for `_nodes/stats` "hbm" and the bench `extra.hbm`
-        stamp: totals, peaks, and per-tenant-kind bytes/peaks/counts."""
+        """Rollup for `_nodes/stats` "hbm" and scripts/hbm_report.py:
+        totals, peaks, and per-tenant-kind bytes/peaks/counts."""
         with self._lock:
             counts: Dict[str, int] = {}
             charged = 0
@@ -418,18 +418,6 @@ class HBMLedger:
                     "breaker_trips": self.breaker_trips,
                     "pressure_evictions": self.pressure_evictions,
                     "tenants": tenants}
-
-    def peak_stamp(self) -> dict:
-        """The BENCH-json `extra.hbm` stamp (bench.py and
-        scripts/measure_concurrency.py both emit it): current + peak
-        totals and peak bytes by tenant kind — the committed footprint
-        baseline ROADMAP item 1 must beat."""
-        snap = self.snapshot()
-        return {"total_bytes": snap["total_bytes"],
-                "peak_bytes": snap["peak_bytes"],
-                "peak_by_kind": {k: t["peak_bytes"]
-                                 for k, t in snap["tenants"].items()
-                                 if t["peak_bytes"]}}
 
     def top_tenants(self, limit: int = 10) -> List[dict]:
         """Largest live allocations, for `scripts/hbm_report.py`."""

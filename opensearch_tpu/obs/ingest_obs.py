@@ -7,8 +7,7 @@ attribution, segment merge + BP reorder, translog, replica write-through
 the `indexing.` prefix. This module owns the pieces they share:
 
 - the enable flag (`enabled()` / `set_enabled()`, env
-  `OPENSEARCH_TPU_INGEST_OBS`) — the measure_concurrency overhead pair
-  toggles it to pin the instrumentation cost;
+  `OPENSEARCH_TPU_INGEST_OBS`);
 - the build-stage collector (`stage_scope()` / `note_stage()`): a
   thread-local dict the segment builders and the merge drop wall-time
   attributions into (pack / spill / chunk_merge / quantize /

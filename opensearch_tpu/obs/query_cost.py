@@ -36,9 +36,8 @@ finish it records DDSketch histograms (`cost.bytes_per_query`,
 by `_nodes/stats` and `/_metrics`, and the snapshot surfaces as the
 `cost` block of a `profile` response and the `explain=device_plan` view.
 
-`OPENSEARCH_TPU_COST=0` disables accounting entirely (the
-`measure_concurrency.py` gate pins cost-on qps >= 0.98x cost-off with
-byte-identical responses).
+`OPENSEARCH_TPU_COST=0` disables accounting entirely (a `profile`
+response then carries no `cost` block).
 """
 
 from __future__ import annotations
@@ -175,12 +174,11 @@ def finish(token, record: bool = True) -> None:
 
 
 def bytes_per_query_stamp() -> dict:
-    """The BENCH-json `extra.bytes_per_query` stamp: count/p50/p95 of the
-    predicted and actual bytes-gathered histograms plus the
-    reconciliation percentiles. One definition for bench.py,
-    scripts/measure_concurrency.py and scripts/hbm_report.py — the
-    DDSketch snapshot's `*_ms` keys carry raw BYTE values for these
-    series (the registry's log bins are unit-agnostic)."""
+    """count/p50/p95 of the predicted and actual bytes-gathered
+    histograms plus the reconciliation percentiles, for
+    scripts/hbm_report.py. The DDSketch snapshot's `*_ms` keys carry raw
+    BYTE values for these series (the registry's log bins are
+    unit-agnostic)."""
     hists = METRICS.snapshot()["histograms"]
 
     def _pct(name: str) -> dict:

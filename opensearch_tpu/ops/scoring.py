@@ -139,8 +139,8 @@ def dequant_impact(q: jnp.ndarray, scale) -> jnp.ndarray:
 
 
 def dequant_impact_np(q, scale):
-    """Host mirror of `dequant_impact` (planning bounds, head selection,
-    bench stamps)."""
+    """Host mirror of `dequant_impact` (planning bounds, head
+    selection)."""
     return np.asarray(q).astype(np.float32) * np.float32(scale)
 
 

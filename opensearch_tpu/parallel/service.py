@@ -195,8 +195,8 @@ class MeshSearchService:
         self.terms_agg_dispatched = 0  # of dispatched: with a terms agg
         self.phrase_dispatched = 0     # of dispatched: match_phrase
         # WHY each declined search host-looped, by decline site — surfaced
-        # in _nodes/stats so a dispatch-share measurement (MESH_SHARE)
-        # can't silently flatter: a flat `fallbacks` total hides whether
+        # in _nodes/stats so a dispatch share can't silently flatter: a
+        # flat `fallbacks` total hides whether
         # the misses are benign (single-shard index) or a served shape
         # regressing (e.g. agg columns failing to stack)
         self.fallback_shapes: Dict[str, int] = {}
@@ -1224,9 +1224,9 @@ class MeshSearchService:
                  if filtered else None)
         fn = self._program_for(mesh, bucket, stacked.ndocs_pad, K, k1,
                                b_eff, filtered)
-        # one scoring-program invocation serves the whole query group —
-        # THE denominator for the serving scheduler's coalescing win
-        # (scripts/measure_concurrency.py: invocations per query)
+        # one scoring-program invocation serves the whole query group:
+        # queries per launch is the serving scheduler's coalescing ratio
+        # (`_nodes/stats` mesh block, `/_metrics`)
         self.launches += 1
         METRICS.counter("mesh.launches").inc()
         gdocs_b, gvals_b, totals_b = fn(stacked.tree(), rows, boosts, msm,

@@ -80,9 +80,9 @@ STATS = CounterGroup(METRICS, "fastpath", {
     "shard_view_served": 0, "impact_frontier": 0,
     "reorder_tie_fallback": 0})
 
-# phase-2 rescore instrumentation (surfaced in _nodes/stats and read by
-# scripts/measure_escalation.py): where the candidate-union rescore ran
-# and what it cost. wall_ms includes the device_get sync, so device
+# phase-2 rescore instrumentation (surfaced in _nodes/stats, read by the
+# benchmark's `bm25_match` counters and chip_smoke.py): where the
+# candidate-union rescore ran and what it cost. wall_ms includes the device_get sync, so device
 # numbers are honest end-to-end, not launch-and-forget.
 RESCORE_STATS = CounterGroup(METRICS, "fastpath.rescore", {
     "host_calls": 0, "host_wall_ms": 0.0,
@@ -923,8 +923,8 @@ def _launch_pure_groups_async(seg: Segment,
         avg = np.array([[v.avgdl] for v in gvqs], np.float32)
         dlo = np.array([[v.dlo] for v in gvqs], np.int32)
         dhi = np.array([[v.dhi] for v in gvqs], np.int32)
-        # per-launch attribution (scripts/measure_concurrency.py divides
-        # served queries by launches to report the coalescing ratio)
+        # per-launch attribution: served queries over launches is the
+        # coalescing ratio (`/_metrics`)
         METRICS.counter("fastpath.launches").inc()
         cost = _qc.current()
         if impact:

@@ -116,7 +116,7 @@ def stats() -> dict:
 
 def block_skip_rate() -> float:
     """Fraction of sidecar blocks the device never gathered (planned
-    queries only) — the bench `extra.impacts.block_skip_rate` stamp."""
+    queries only): `block_skip_rate` of `_nodes/stats` "impactpath"."""
     total = STATS["blocks_total"]
     return (STATS["blocks_skipped"] / total) if total else 0.0
 

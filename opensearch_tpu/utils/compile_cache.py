@@ -5,7 +5,7 @@ The cache directory is part of the cache key, so a directory that moves
 places it (`JAX_COMPILATION_CACHE_DIR`, which JAX reads itself — nothing
 is set in code then); otherwise it is `<checkout>/.jax_cache`, a fixed
 path that `.gitignore` already lists. Used by the entry points that
-compile many programs per run (`chip_smoke.py`, `bench.py`)."""
+compile many programs per run (`chip_smoke.py`, `benchmark/run.py`)."""
 
 from __future__ import annotations
 

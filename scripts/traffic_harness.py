@@ -46,8 +46,9 @@ Scenarios:
                  recover it, green within the window, the pin releases.
 
 Per-scenario emissions (time-to-green, shed fraction, green-under-load
-booleans) land in BENCH_out.json under `extra.traffic`, where
-`scripts/bench_diff.py` gates them like any BENCH round.
+booleans) are printed, and written whole with `--json`. Every time here
+is the host's wall clock on XLA's CPU backend: the harness judges the
+loop's verdicts, it measures no speed.
 
 Run:  python scripts/traffic_harness.py [--mini] [--json out.json]
 Mini: 2 nodes / 2k docs / baseline + one burn-and-recover scenario —
@@ -90,6 +91,10 @@ TICK_S = 0.05
 # good samples ages out of the window.
 FAST_W = 1.2
 SLOW_W = 4.0
+# the windows, holds and caps of a scenario are sized for latency budgets
+# up to this; a host whose warm phase asks for more stretches them all by
+# budget / NOMINAL_BUDGET_MS (`run_scenario`)
+NOMINAL_BUDGET_MS = 400.0
 
 # ---------------------------------------------------------------------
 # the shape catalog: insight-distinct bodies with small value pools so
@@ -220,7 +225,8 @@ def build_fleet(n_nodes=3, ndocs=6000, n_shards=6):
     return nodes
 
 
-def make_slos(lat_budget_ms):
+def make_slos(lat_budget_ms, stretch=1.0):
+    fast_w, slow_w = FAST_W * stretch, SLOW_W * stretch
     reqs = ["search.lane.interactive.requests",
             "search.lane.batch.requests"]
     # min_events keeps near-empty windows honest (a handful of
@@ -232,11 +238,11 @@ def make_slos(lat_budget_ms):
     # high event floor
     return [
         SLO("interactive-latency", "latency", target=0.90,
-            fast_window_s=FAST_W, slow_window_s=SLOW_W,
+            fast_window_s=fast_w, slow_window_s=slow_w,
             lane="interactive", latency_budget_ms=lat_budget_ms,
             burn_threshold=2.0, min_events=8),
         SLO("batch-latency", "latency", target=0.90,
-            fast_window_s=FAST_W, slow_window_s=SLOW_W, lane="batch",
+            fast_window_s=fast_w, slow_window_s=slow_w, lane="batch",
             latency_budget_ms=lat_budget_ms * 2.0,
             burn_threshold=2.0, min_events=8),
         # tight error budget: a hard-killed member produces a handful
@@ -244,7 +250,7 @@ def make_slos(lat_budget_ms):
         # at harness request rates those must still burn the budget —
         # while a clean run (zero failures) burns exactly nothing
         SLO("transport-health", "counter_ratio", target=0.999,
-            fast_window_s=FAST_W, slow_window_s=SLOW_W,
+            fast_window_s=fast_w, slow_window_s=slow_w,
             bad_metrics=["dist.rpc.failed"], total_metrics=reqs,
             burn_threshold=1.0, min_events=8),
     ]
@@ -513,8 +519,12 @@ def run_scenario(kind, fleet, cal, seed=7, recovery_window_s=6.0,
     UNARMED concurrent warm phase first (the first seconds of real
     concurrency pay one-time costs — compile stragglers, allocator
     warmup — that must not read as a burn), derive the latency budget
-    from the warm phase's own concurrent p95, then arm SLOs + the
-    actuator and run the detect -> attribute -> act -> verify ladder."""
+    from the warm phase's own concurrent p95 and, from the budget, the
+    stretch of every window, hold and cap that follows (the time
+    arguments are in nominal seconds: a host that is twice as busy gets
+    twice the budget AND twice the clock, so the verdict does not follow
+    the host's load), then arm SLOs + the actuator and run the
+    detect -> attribute -> act -> verify ladder."""
     coord, victim_node = fleet[0], fleet[-1]
     victim = victim_node.name
     SAMPLER.reset()
@@ -528,13 +538,7 @@ def run_scenario(kind, fleet, cal, seed=7, recovery_window_s=6.0,
     SAMPLER.track_histogram("search.lane.interactive.latency_ms",
                             "search.lane.batch.latency_ms")
     engine = SLOEngine(sampler=SAMPLER, registry=METRICS)
-    rem = Remediator(RemediationConfig(
-        ttl_s=max(recovery_window_s * 2, 8.0), green_hold_s=0.6,
-        engage_cooldown_s=0.5, max_shed_shapes=8,
-        # headroom above one alert's worth of sheds: re-attribution
-        # must be able to ADD the true offender once it becomes
-        # visible, not bounce off a cap filled by first-edge bystanders
-        max_actions=16))
+    rem = Remediator()          # its clocks are set with the stretch
     olds = [(n, n.remediation_engine, n.node.remediation)
             for n in fleet]
     for n in fleet:
@@ -549,21 +553,42 @@ def run_scenario(kind, fleet, cal, seed=7, recovery_window_s=6.0,
         load.start()
         _wait(lambda: False, warm_s)          # unarmed concurrent warm
         warm = load.snapshot()
-        # clamped: a noisy warm window must not inflate the budget past
-        # usefulness (the objective exists to catch real degradation).
         # The floor keeps baseline jitter out of the p90 objective —
         # 150ms, raised on a box whose SEQUENTIAL calibration p95 is
-        # already slow — and the injected pressure scales WITH the
-        # budget, so detection is preserved at any clamp.
+        # already slow. No ceiling: the injected pressure scales WITH
+        # the budget, so detection is preserved at any budget, and a
+        # ceiling in milliseconds is what a busy host's clean mix
+        # crosses (five users on one GIL read p95 170-350ms on an idle
+        # sandbox). What a larger budget would starve is the windows
+        # (a pause of 1.5x the budget leaves fewer completions in a
+        # window of fixed seconds than min_events asks), so the clock
+        # stretches with it.
         floor_ms = max(150.0, 3.0 * float(cal.get("clean_p95_ms", 0.0)))
-        budget_ms = min(max(3.0 * warm["lat_ms_p95"], floor_ms), 400.0)
-        row["latency_budget_ms"] = round(budget_ms, 2)
-        engine.arm(make_slos(budget_ms))
+        budget_ms = max(3.0 * warm["lat_ms_p95"], floor_ms)
+        stretch = max(1.0, budget_ms / NOMINAL_BUDGET_MS)
+        ttl_s = max(recovery_window_s * 2, 8.0)
+        row.update(latency_budget_ms=round(budget_ms, 2),
+                   time_stretch=round(stretch, 3),
+                   recovery_window_s=round(recovery_window_s * stretch, 3))
+
+        def wait(cond, nominal_s):
+            return _wait(cond, nominal_s * stretch, TICK_S * stretch)
+
+        rem.config = RemediationConfig(
+            ttl_s=ttl_s * stretch, green_hold_s=0.6 * stretch,
+            engage_cooldown_s=0.5 * stretch,
+            max_shed_shapes=8,
+            # headroom above one alert's worth of sheds: re-attribution
+            # must be able to ADD the true offender once it becomes
+            # visible, not bounce off a cap filled by first-edge
+            # bystanders
+            max_actions=16)
+        engine.arm(make_slos(budget_ms, stretch))
         rem.arm(slo_engine=engine, sampler=SAMPLER,
                 member_fd=coord.member_fd)
         _tick()
         if kind == "baseline":
-            _wait(lambda: False, warm_s + 1.2)
+            wait(lambda: False, warm_s + 1.2)
             row["time_to_green_s"] = 0.0
         else:
             if kind == "overload":
@@ -574,7 +599,7 @@ def run_scenario(kind, fleet, cal, seed=7, recovery_window_s=6.0,
                 # GC-pause/overloaded-peer shape) slows queries down
                 row["victim"] = victim
                 load.flood.set()
-                _wait(lambda: False, 1.5)
+                wait(lambda: False, 1.5)
                 faults.install(faults.ChaosSchedule(seed=11).pause_node(
                     victim, 1.5 * budget_ms / 1000.0))
             else:                             # churn: hard-kill
@@ -582,7 +607,7 @@ def run_scenario(kind, fleet, cal, seed=7, recovery_window_s=6.0,
                 faults.install(
                     faults.ChaosSchedule(seed=12).kill_node(victim))
             t_pressure = time.monotonic()
-            fired, t_detect = _wait(
+            fired, t_detect = wait(
                 lambda: engine.alerts_fired > 0, pressure_cap_s)
             row["alert_fired"] = fired
             row["time_to_detect_s"] = round(t_detect, 3)
@@ -592,8 +617,8 @@ def run_scenario(kind, fleet, cal, seed=7, recovery_window_s=6.0,
             # slow query and re-attempts; re-alerts widen the shed set
             # as the window re-attributes under pressure) — then clear
             if kind == "overload":
-                _wait(lambda: load.hostile["shed"] > 0, 8.0)
-            _wait(lambda: False, shed_window_s)
+                wait(lambda: load.hostile["shed"] > 0, 8.0)
+            wait(lambda: False, shed_window_s)
             faults.uninstall()
             load.flood.clear()
             t_clear = time.monotonic()
@@ -605,12 +630,12 @@ def run_scenario(kind, fleet, cal, seed=7, recovery_window_s=6.0,
                 if kind == "churn":
                     coord.member_fd.tick(coord.members)
                 return not _firing(engine)
-            ok_green, waited = _wait(green, recovery_window_s)
+            ok_green, waited = wait(green, recovery_window_s)
             row["green_within_window"] = ok_green
             row["time_to_green_s"] = round(waited, 3)
             # auto-release: green hold first, TTL as the hard backstop
-            ok_rel, _ = _wait(lambda: not rem.status()["active"],
-                              max(rem.config.ttl_s, 4.0) + 2.0)
+            ok_rel, _ = wait(lambda: not rem.status()["active"],
+                             ttl_s + 2.0)
             row["released_all"] = ok_rel
             row["pressure_held_s"] = round(t_clear - t_pressure, 3)
     finally:
@@ -770,21 +795,6 @@ def main():
     if args.json:
         with open(args.json, "w") as fh:
             json.dump(out, fh, indent=2)
-    # merge into the standing BENCH emission (extra.traffic), the
-    # measure_faults pattern: the closed-loop run is part of the repo's
-    # bench record and bench_diff gates its trajectory
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    out_path = os.path.join(repo, "BENCH_out.json")
-    try:
-        with open(out_path) as fh:
-            bench_doc = json.load(fh)
-    except (OSError, ValueError):
-        bench_doc = {"metric": "bm25_rest_qps_per_chip", "value": None,
-                     "unit": "queries/sec", "vs_baseline": None,
-                     "extra": {"status": "traffic_only"}}
-    bench_doc.setdefault("extra", {})["traffic"] = out
-    with open(out_path, "w") as fh:
-        json.dump(bench_doc, fh, indent=2)
     return 0 if out["gate_ok"] else 1
 
 

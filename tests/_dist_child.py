@@ -1,5 +1,5 @@
-"""Child process for multi-process cluster harnesses (tests/test_distnode.py,
-bench.py's legs A/B cell): brings up a full DistClusterNode under the given
+"""Child process for multi-process cluster harnesses
+(tests/test_distnode.py): brings up a full DistClusterNode under the given
 name, joins the seed, serves until killed."""
 
 import sys

@@ -637,8 +637,7 @@ class TestFleetFederation:
 
 class TestChaosDetection:
     def test_burn_alert_fires_under_seeded_chaos(self):
-        """The acceptance loop in miniature (scripts/measure_faults.py
-        runs the full 3-node ladder): seeded chaos kills a member's RPC
+        """The acceptance loop in miniature: seeded chaos kills a member's RPC
         plane, replica failover keeps pages identical — and the SLO
         engine now DETECTS the event within the fast window, dumping
         the offending window's series."""
