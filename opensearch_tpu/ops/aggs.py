@@ -6,9 +6,22 @@ reduction (bincount / segment reduce / scatter-max) over the whole segment.
 All kernels take `match` — the query's dense f32 0/1 match vector (already
 live-masked) — so aggregations run in the same jitted program as scoring and
 XLA fuses the mask with the reduction.
+
+Two forms count documents per bucket. `bucket_counts` is a scatter-add of
+one update a row and serves ids in any order (`terms_counts`, `hist`,
+`geo_grid`, `composite`, `multi_terms`, `ord_counts`, a date histogram
+over a segment whose timestamps are out of order). `run_counts` serves a
+plane whose ids are non-decreasing in row order (a date histogram over an
+append-only log segment): each bucket is one run of rows, so a count is a
+difference of two prefix sums of the weights, read at the runs' boundaries.
+`search/compiler._date_bucket_plane` observes the order once a plane and
+`prepare_agg` selects the form.
 """
 
 from __future__ import annotations
+
+import math
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -27,9 +40,64 @@ def bucket_counts(bucket_ids: jnp.ndarray, w: jnp.ndarray,
     """Documents per bucket, i32[nbuckets]: `w` is a 0/1 weight per row and
     ids outside [0, nbuckets) are dropped. Counts accumulate in int32: a
     float32 count stops at 2^24 = 16,777,216, and one bucket of a large
-    segment can hold more."""
+    segment can hold more. The form for ids in any order: one scatter
+    update a row, which the TPU runs one after another (8.7 ns each);
+    ids that are sorted by row take `run_counts`."""
     return jnp.zeros(nbuckets, jnp.int32).at[bucket_ids].add(
         (w > 0).astype(jnp.int32), mode="drop")
+
+
+# rows a block of `run_counts` (the probe on the chip read 512 to 4,096
+# alike at 67,108,864 rows, 8,192 slower, and 128 at 24 s of compile:
+# PERF.md, PR 31); a boundary reads one block, so a block is also capped at
+# the rows a boundary has on average, and the boundaries together read at
+# most the plane once
+_RUN_BLOCK = 2048
+
+
+def run_blocks(n: int, nbounds: int) -> Optional[Tuple[int, int]]:
+    """(R, C): `run_counts`'s cut of `n` rows into R blocks of C, the
+    largest power of two that divides `n` and is at most `_RUN_BLOCK` and
+    `n // nbounds`; None where that leaves under 8 rows a block (an odd
+    `n`, more boundaries than rows): the scatter-add serves those."""
+    c = min(_RUN_BLOCK, n // nbounds)
+    if c < 8:
+        return None
+    c = math.gcd(1 << (c.bit_length() - 1), n)
+    return (n // c, c) if c >= 8 else None
+
+
+def run_counts(w: jnp.ndarray, starts: jnp.ndarray) -> jnp.ndarray:
+    """Documents per bucket, i32[nbuckets], where each bucket is one run of
+    rows: `w` i32[n] is a 0/1 weight per row, `starts` i32[nbuckets + 1]
+    the non-decreasing row at which each bucket's run begins (`starts[b]`
+    <= `n`; rows before `starts[0]` and from `starts[nbuckets]` on belong
+    to no bucket). Equal to `bucket_counts` over the ids the runs spell,
+    exact in int32: block sums in one pass over `w`, their running total,
+    and for each boundary the weights before it inside its own block."""
+    n, nb = w.shape[0], starts.shape[0] - 1
+    cut = run_blocks(n, nb + 1)
+    if cut is None:
+        ids = jnp.searchsorted(starts, jnp.arange(n, dtype=jnp.int32),
+                               side="right").astype(jnp.int32) - 1
+        return bucket_counts(jnp.where(ids < 0, nb, ids), w, nb)
+    r, c = cut
+    # rows of 128: a 1-D plane's own tiling on the TPU, so this view is no
+    # copy ([n] -> [R, C] is a relayout of the whole plane: PERF.md, PR 29)
+    lane = min(c, 128)
+    g = c // lane
+    tiles = w.reshape(n // lane, lane)
+    sums = jnp.sum(jnp.sum(tiles, axis=1).reshape(r, g), axis=1)
+    before = jnp.concatenate([jnp.zeros(1, jnp.int32), jnp.cumsum(sums)])
+    blk, off = starts // c, starts % c
+    idx = ((jnp.minimum(blk, r - 1) * g)[:, None]
+           + jnp.arange(g, dtype=jnp.int32)[None, :])
+    rows = tiles[idx].reshape(nb + 1, c)
+    inside = jnp.sum(jnp.where(
+        jnp.arange(c, dtype=jnp.int32)[None, :] < off[:, None], rows, 0),
+        axis=1)
+    prefix = before[blk] + inside
+    return prefix[1:] - prefix[:-1]
 
 
 def terms_counts(kw: dict, match: jnp.ndarray, nvocab_pad: int) -> jnp.ndarray:

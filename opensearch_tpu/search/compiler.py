@@ -70,9 +70,14 @@ _JIT_FAMILIES = ("executor", "mask", "gather", "agg", "rescore", "join")
 # and the per-segment planes that stay on the device so that it is handed
 # none of `ndocs_pad` elements: a date_histogram's bucket ids and a field
 # sort's ranks (builds / hits of the per-segment caches, bytes built);
-# `topk_keys_sorted`: the keys a launch's top-k hands to `lax.top_k`
+# `topk_keys_sorted`: the keys a launch's top-k hands to `lax.top_k`;
+# `agg_bucket_launches`: the date-histogram bucket counts the launches
+# carried, `agg_run_counted`: those of them whose plane is in row order and
+# took `ops.aggs.run_counts` (the others scatter-add)
 EXECUTOR_STATS = CounterGroup(METRICS, "executor", {"params_h2d_bytes": 0,
-                                                    "topk_keys_sorted": 0})
+                                                    "topk_keys_sorted": 0,
+                                                    "agg_bucket_launches": 0,
+                                                    "agg_run_counted": 0})
 BUCKET_PLANE_STATS = CounterGroup(METRICS, "aggs.bucket_plane",
                                   {"builds": 0, "hits": 0, "bytes": 0})
 RANK_PLANE_STATS = CounterGroup(METRICS, "sort.rank_plane",
@@ -3475,7 +3480,8 @@ def _segment_plane(seg: Segment, cache_name: str, key, kind: str, stats,
                    build: Callable[[], tuple]) -> tuple:
     """One i32[ndocs_pad] plane of per-document ids (-1 = none) kept on the
     device for the segment's lifetime, with whatever `build` returns after
-    its host ids: -> (device plane, *rest). Cached under
+    its host ids (a numpy array among it goes to the device and is charged
+    with the plane): -> (device plane, *rest). Cached under
     `seg.<cache_name>[key]` (a tuple that starts with the field) and attributed in the HBM ledger as `kind`;
     `derived._purge_query_caches` drops a rematerialized field's planes
     and the segment's GC the rest. `stats` counts builds, hits and bytes.
@@ -3500,11 +3506,15 @@ def _segment_plane(seg: Segment, cache_name: str, key, kind: str, stats,
         pad = np.full(seg.ndocs_pad, -1, dtype=np.int32)
         pad[: len(ids)] = ids
         plane = jnp.asarray(pad)
-        alloc = LEDGER.register(kind, pad.nbytes, owner=seg, segment=seg,
+        nbytes = pad.nbytes + sum(x.nbytes for x in rest
+                                  if isinstance(x, np.ndarray))
+        rest = [jnp.asarray(x) if isinstance(x, np.ndarray) else x
+                for x in rest]
+        alloc = LEDGER.register(kind, nbytes, owner=seg, segment=seg,
                                 label=f"{kind}[{seg.name}][{key}]")
         seg.__dict__.setdefault("_plane_allocs", {})[cache_name, key] = alloc
         stats.inc("builds")
-        stats.inc("bytes", pad.nbytes)
+        stats.inc("bytes", nbytes)
         cache[key] = (plane, *rest)
         return cache[key]
 
@@ -3525,12 +3535,14 @@ def _date_bucket_plane(seg: Segment, field: str, interval_ms: int,
                        offset_ms: int, calendar: Optional[str]):
     """Exact date bucketing on host i64, once per (segment, field, interval,
     offset, calendar), then resident: -> (bucket ids i32[ndocs_pad] on the
-    device, -1 = no value, min_bucket, nbuckets). Calendar intervals follow
-    real calendars (reference Rounding.Builder)."""
+    device, -1 = no value, min_bucket, nbuckets, starts). Calendar intervals
+    follow real calendars (reference Rounding.Builder). `starts` is
+    `_run_starts` of the ids, on the device too, where the segment's values
+    are in row order (an append-only log), else None."""
     def build():
         col = seg.numeric_cols.get(field)
         if col is None or not col.present.any():
-            return np.full(seg.ndocs, -1, np.int32), 0, 1
+            return np.full(seg.ndocs, -1, np.int32), 0, 1, None
         vals = col.values.astype(np.int64)
         if calendar is None:
             b = np.floor_divide(vals - offset_ms, interval_ms)
@@ -3539,10 +3551,30 @@ def _date_bucket_plane(seg: Segment, field: str, interval_ms: int,
         bp = b[col.present]
         mn, mx = int(bp.min()), int(bp.max())
         ids = np.where(col.present, b - mn, -1).astype(np.int32)
-        return ids, mn, int(mx - mn + 1)
+        nb = int(mx - mn + 1)
+        return ids, mn, nb, _run_starts(ids, nb, seg.ndocs_pad)
     return _segment_plane(seg, "_date_bucket_cache",
                           (field, interval_ms, offset_ms, calendar),
                           "agg_bucket_plane", BUCKET_PLANE_STATS, build)
+
+
+def _run_starts(ids: np.ndarray, nbuckets: int,
+                ndocs_pad: int) -> Optional[np.ndarray]:
+    """i32[nbuckets + 1] for `ops.aggs.run_counts`: `starts[b]` is the first
+    row whose id, or the id of the nearest row before it that has one, is
+    at least b, so `starts[nbuckets]` = `len(ids)`. None where the ids of
+    the rows that have a value (id >= 0; the others weigh nothing) are not
+    non-decreasing in row order, or `run_blocks` has no cut for the sizes:
+    such a plane is counted by scatter-add."""
+    if agg_ops.run_blocks(ndocs_pad, nbuckets + 1) is None:
+        return None
+    # the running maximum forward-fills the rows without a value (-1), and
+    # a row in order is one that is its own running maximum
+    filled = np.maximum.accumulate(ids)
+    if ((ids >= 0) & (ids < filled)).any():
+        return None
+    return np.searchsorted(filled, np.arange(nbuckets + 1),
+                           side="left").astype(np.int32)
 
 
 _DAY_MS = 86400000
@@ -3886,6 +3918,24 @@ def range_agg_spec(ranges: List[dict]) -> tuple:
     return lows, highs, keys, metas
 
 
+def _bind_date_buckets(params: dict, prefix: str, seg: Segment, field: str,
+                       interval_ms: int, offset_ms: int,
+                       calendar: Optional[str]) -> Tuple[int, int, str]:
+    """Hand a date histogram's resident planes to the launch: the bucket
+    ids as `<prefix>_dbuckets` and, where the segment's values are in row
+    order, the runs' boundaries as `<prefix>_dstarts`. -> (min_bucket,
+    nbuckets, form): "runs" or "scatter", the static member of the spec
+    that `_date_bucket_counts` builds the program from and `_count_launch`
+    counts."""
+    plane, min_b, nb, starts = _date_bucket_plane(
+        seg, field, interval_ms, offset_ms, calendar)
+    params[f"{prefix}_dbuckets"] = plane
+    if starts is None:
+        return min_b, nb, "scatter"
+    params[f"{prefix}_dstarts"] = starts
+    return min_b, nb, "runs"
+
+
 def prepare_agg(node: AggNode, seg: Segment, ctx: ShardContext, params: dict,
                 prefix: str, nest_stack: Tuple = ()):  # noqa: C901
     """-> hashable agg spec; params filled per segment. `prefix` keys params.
@@ -3931,13 +3981,14 @@ def prepare_agg(node: AggNode, seg: Segment, ctx: ShardContext, params: dict,
         offset_ms = (parse_interval_ms(body.get("offset", 0),
                                        allow_negative=True)
                      if body.get("offset") else 0)
-        params[f"{prefix}_dbuckets"], min_b, nb = _date_bucket_plane(
-            seg, field, max(interval_ms, 1), offset_ms, calendar)
+        min_b, nb, form = _bind_date_buckets(
+            params, prefix, seg, field, max(interval_ms, 1), offset_ms,
+            calendar)
         subs = tuple(prepare_agg(s, seg, ctx, params, f"{prefix}_{i}",
                                  nest_stack)
                      for i, s in enumerate(node.subs))
         return ("date_hist", prefix, field, interval_ms, offset_ms, calendar,
-                min_b, nb, subs)
+                min_b, nb, subs, form)
 
     if kind in ("range", "date_range"):
         field = _resolve_agg_field(node, ctx)
@@ -4267,13 +4318,13 @@ def prepare_agg(node: AggNode, seg: Segment, ctx: ShardContext, params: dict,
         target = max(int(body.get("buckets", 10)), 1)
         col = seg.numeric_cols.get(field)
         interval_ms = _auto_interval(col, target)
-        params[f"{prefix}_dbuckets"], min_b, nb = _date_bucket_plane(
-            seg, field, interval_ms, 0, None)
+        min_b, nb, form = _bind_date_buckets(params, prefix, seg, field,
+                                             interval_ms, 0, None)
         subs = tuple(prepare_agg(s, seg, ctx, params, f"{prefix}_{i}",
                                  nest_stack)
                      for i, s in enumerate(node.subs))
         return ("auto_date_hist", prefix, field, interval_ms, target,
-                min_b, nb, subs)
+                min_b, nb, subs, form)
 
     if kind == "scripted_metric":
         return ("scripted", prefix)
@@ -4401,8 +4452,9 @@ def _prepare_composite(node: AggNode, seg: Segment, ctx: ShardContext,
             interval_ms = (0 if calendar else
                            parse_interval_ms(scfg.get("fixed_interval",
                                                       scfg.get("interval", "1d"))))
-            params[f"{prefix}_s{si}"], min_b, nb = _date_bucket_plane(
-                seg, field, max(interval_ms, 1), 0, calendar)
+            params[f"{prefix}_s{si}"], min_b, nb, _starts = \
+                _date_bucket_plane(seg, field, max(interval_ms, 1), 0,
+                                   calendar)
             if nb <= 0:
                 return ("terms_missing", prefix)
             infos.append(("date", field, nb, min_b,
@@ -4638,11 +4690,10 @@ def emit_agg(spec, seg_arrays: dict, params: dict, match, scores=None):  # noqa:
         return out
 
     if kind == "date_hist":
-        _, prefix, field, interval_ms, offset_ms, calendar, min_b, nb, subs = spec
-        b_all = params[f"{prefix}_dbuckets"][:ndocs_pad]
-        w = match * jnp.where(b_all >= 0, 1.0, 0.0)
-        b = jnp.where((b_all >= 0) & (w > 0), b_all, nb)
-        out = {"counts": agg_ops.bucket_counts(b, w, nb)}
+        (_, prefix, field, interval_ms, offset_ms, calendar, min_b, nb, subs,
+         form) = spec
+        counts, b = _date_bucket_counts(jnp, params, prefix, match, nb, form)
+        out = {"counts": counts}
         for i, sub in enumerate(subs):
             out.update(_emit_bucketed_sub(jnp, sub, i, b, nb, seg_arrays, match))
         return out
@@ -4935,11 +4986,9 @@ def emit_agg(spec, seg_arrays: dict, params: dict, match, scores=None):  # noqa:
         return out
 
     if kind == "auto_date_hist":
-        _, prefix, field, interval_ms, target, min_b, nb, subs = spec
-        bucket_ids = params[f"{prefix}_dbuckets"][:ndocs_pad]
-        w = match * (bucket_ids >= 0).astype(jnp.float32)
-        b = jnp.where(w > 0, bucket_ids, nb)
-        out = {"counts": agg_ops.bucket_counts(b, w, nb)}
+        _, prefix, field, interval_ms, target, min_b, nb, subs, form = spec
+        counts, b = _date_bucket_counts(jnp, params, prefix, match, nb, form)
+        out = {"counts": counts}
         for i, sub in enumerate(subs):
             out.update(_emit_bucketed_sub(jnp, sub, i, b, nb, seg_arrays,
                                           match))
@@ -4951,6 +5000,22 @@ def emit_agg(spec, seg_arrays: dict, params: dict, match, scores=None):  # noqa:
                                                    else jnp.zeros_like(match))}
 
     raise ValueError(f"cannot emit aggregation spec [{kind}]")
+
+
+def _date_bucket_counts(jnp, params: dict, prefix: str, match, nb: int,
+                        form: str):
+    """A date histogram's counts over its resident bucket plane: ->
+    (counts i32[nb], per-row bucket ids with `nb` where the row does not
+    count, for the sub-aggregations' scatters). A row counts where it
+    matches and has a value; `form` "runs" reads the counts at the runs'
+    boundaries (`ops.aggs.run_counts`), "scatter" adds a row at a time."""
+    ids = params[f"{prefix}_dbuckets"][:match.shape[0]]
+    held = (match > 0) & (ids >= 0)
+    b = jnp.where(held, ids, nb)
+    if form == "runs":
+        return agg_ops.run_counts(held.astype(jnp.int32),
+                                  params[f"{prefix}_dstarts"]), b
+    return agg_ops.bucket_counts(b, held, nb), b
 
 
 def _emit_bucketed_sub(jnp, sub, i: int, bucket_ids, nb: int, seg_arrays, match):
@@ -5380,14 +5445,32 @@ def _count_launch(full_spec, seg_arrays: dict, cparams: dict) -> None:
     the bytes of every host numpy array or scalar it is handed (each is
     copied to the device by the call; planes that live there are not
     counted). `executor.topk_keys_sorted`: the keys its `ops.topk_docs`
-    hands to `lax.top_k` (a collapse launch takes `collapse_topk`: none)."""
+    hands to `lax.top_k` (a collapse launch takes `collapse_topk`: none).
+    `executor.agg_bucket_launches` / `agg_run_counted`: its date-histogram
+    bucket counts, and those whose spec says "runs"."""
     EXECUTOR_STATS.inc("params_h2d_bytes", sum(
         v.nbytes for v in cparams.values()
         if isinstance(v, (np.ndarray, np.generic))))
-    _query, _sort, _aggs, k_pad, _named, _after, collapse_spec = full_spec
+    _query, _sort, aggs, k_pad, _named, _after, collapse_spec = full_spec
     if collapse_spec is None:
         EXECUTOR_STATS.inc("topk_keys_sorted", ops.topk_keys_sorted(
             seg_arrays["live"].shape[0], k_pad))
+    forms = list(_date_count_forms(aggs))
+    if forms:
+        EXECUTOR_STATS.inc("agg_bucket_launches", len(forms))
+        EXECUTOR_STATS.inc("agg_run_counted", forms.count("runs"))
+
+
+def _date_count_forms(spec):
+    """The `form` of every `date_hist` / `auto_date_hist` spec in a tree of
+    aggregation specs (a pair that only carries such a name, an aggregation
+    a user called so, ends in no form)."""
+    if isinstance(spec, tuple):
+        if (spec and spec[0] in ("date_hist", "auto_date_hist")
+                and spec[-1] in ("runs", "scatter")):
+            yield spec[-1]
+        for x in spec:
+            yield from _date_count_forms(x)
 
 
 def canon_query(query_spec, sort_spec, k_pad: int, params: dict):

@@ -2230,7 +2230,8 @@ def _device_agg_to_partial(node: AggNode, aspec, device_out: Optional[dict],
         return _hist_partial(node, device_out, min_b, interval, offset)
 
     if kind == "date_hist":
-        _, prefix, f, interval_ms, offset_ms, calendar, min_b, nb, subs = aspec
+        (_, prefix, f, interval_ms, offset_ms, calendar, min_b, nb, subs,
+         _form) = aspec
         if calendar is not None:
             # convert calendar bucket ids to epoch-ms keys host-side
             counts = np.asarray(device_out["counts"])
@@ -2486,7 +2487,7 @@ def _device_agg_to_partial(node: AggNode, aspec, device_out: Optional[dict],
         return {"buckets": buckets}
 
     if kind == "auto_date_hist":
-        _, prefix, f, interval_ms, target, min_b, nb, sub_specs = aspec
+        _, prefix, f, interval_ms, target, min_b, nb, sub_specs, _form = aspec
         part = _hist_partial(node, device_out, min_b, float(interval_ms), 0.0)
         # re-key to absolute epoch ms (merge coarsens across intervals)
         part["buckets"] = {int(b * interval_ms): rec

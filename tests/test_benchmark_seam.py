@@ -91,3 +91,21 @@ def test_topk_sorted_kelems_reads_the_counter_or_nothing(keys, want):
         ctx["window"]["counters"]["executor.topk_keys_sorted"] = keys
     assert harness.read_layer_metric("topk_sorted_kelems_per_query",
                                      ctx) == want
+
+
+@pytest.mark.parametrize("counters,want", [
+    ({}, None),                                   # the parent: no counters
+    ({"executor.agg_bucket_launches": 0,
+      "executor.agg_run_counted": 0}, None),      # no aggregation launched
+    ({"executor.agg_bucket_launches": 11,
+      "executor.agg_run_counted": 11}, 100.0),
+    ({"executor.agg_bucket_launches": 8,
+      "executor.agg_run_counted": 2}, 25.0),
+    ({"executor.agg_bucket_launches": 8}, None),  # half a pair is no pair
+])
+def test_agg_run_counted_share_reads_the_counters_or_nothing(counters, want):
+    """100 x `executor.agg_run_counted` / `executor.agg_bucket_launches`;
+    a program without the counters, or a window without a bucket
+    aggregation, reports nothing."""
+    ctx = {"window": {"queries": 88, "counters": counters}}
+    assert harness.read_layer_metric("agg_run_counted_share", ctx) == want

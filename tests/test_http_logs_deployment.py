@@ -162,9 +162,11 @@ def test_an_unknown_calendar_interval_is_an_error():
 BIG = 17_000_000            # over 2^24 = 16,777,216, where float32 stops
 
 
-def _bare(kind: str):
+def _bare(kind: str, form: str = "scatter"):
     """`emit_agg` of one agg kind over bare arrays in which every one of
-    BIG rows matches and falls into bucket 0. -> the counts it returns."""
+    BIG rows matches and falls into bucket 0. -> the counts it returns.
+    `form`: how a date histogram counts ("runs" hands it the boundaries of
+    the four buckets' runs: all of the rows, then three empty ones)."""
     import jax
     import jax.numpy as jnp
     live = jnp.ones(BIG, jnp.float32)
@@ -176,11 +178,13 @@ def _bare(kind: str):
                                     "doc_of_value": jnp.arange(
                                         BIG, dtype=jnp.int32)}}}
     params = {"a0_dbuckets": zeros_i,
+              "a0_dstarts": jnp.asarray([0, BIG, BIG, BIG, BIG], jnp.int32),
               "a0_lows": np.asarray([-1.0, 5.0], np.float32),
               "a0_highs": np.asarray([5.0, 9.0], np.float32)}
-    spec = {"date_hist": ("date_hist", "a0", "f", 3600000, 0, None, 0, 4, ()),
+    spec = {"date_hist": ("date_hist", "a0", "f", 3600000, 0, None, 0, 4, (),
+                          form),
             "auto_date_hist": ("auto_date_hist", "a0", "f", 3600000, 10, 0,
-                               4, ()),
+                               4, (), form),
             "hist": ("hist", "a0", "f", 10.0, 0.0, 0, 4, ()),
             "terms": ("terms", "a0", "f", 16, ()),
             "range": ("range", "a0", "f", ("lo", "hi"), True, (),
@@ -190,10 +194,14 @@ def _bare(kind: str):
     return np.asarray(out["counts"])
 
 
-@pytest.mark.parametrize("kind", ["date_hist", "auto_date_hist", "hist",
-                                  "terms", "range"])
-def test_a_bucket_past_2_to_the_24_counts_exactly(kind):
-    counts = _bare(kind)
+@pytest.mark.parametrize("kind,form", [
+    ("date_hist", "scatter"), ("date_hist", "runs"),
+    ("auto_date_hist", "scatter"), ("auto_date_hist", "runs"),
+    ("hist", "scatter"), ("terms", "scatter"), ("range", "scatter")])
+def test_a_bucket_past_2_to_the_24_counts_exactly(kind, form):
+    # 2^6 divides BIG, so "runs" is the run form proper, not its fall-back
+    assert agg_ops.run_blocks(BIG, 5) == (BIG // 64, 64)
+    counts = _bare(kind, form)
     assert counts.dtype == np.int32
     assert int(counts[0]) == BIG and int(counts[1:].sum()) == 0
 
