@@ -19,6 +19,7 @@ from dataclasses import dataclass, field as dc_field
 from typing import Any, Dict, List, Optional, Tuple
 
 from ..analysis import AnalysisRegistry, Analyzer
+from .date_formats import DateFormatError, parse_date, validate_format
 
 TEXT_TYPES = {"text", "match_only_text", "search_as_you_type",
               "annotated_text"}
@@ -134,33 +135,22 @@ def parse_annotated_text(raw: str):
     return "".join(plain_parts), spans
 
 
-def _parse_date(value: Any, fmt: Optional[str]) -> int:
-    """Parse a date into epoch millis (reference DateFieldMapper; default
-    format `strict_date_optional_time||epoch_millis`)."""
-    if isinstance(value, bool):
-        raise ValueError(f"cannot parse date from boolean [{value}]")
-    if isinstance(value, numbers.Number):
-        return int(value)
-    s = str(value).strip()
-    if fmt == "epoch_second":
-        return int(float(s) * 1000)
-    if s.isdigit() or (s[:1] == "-" and s[1:].isdigit()):
-        return int(s)
-    iso = s.replace("Z", "+00:00")
-    try:
-        dt = _dt.datetime.fromisoformat(iso)
-    except ValueError:
-        for f in ("%Y/%m/%d", "%Y/%m/%d %H:%M:%S", "%d-%m-%Y", "%m/%d/%Y"):
-            try:
-                dt = _dt.datetime.strptime(s, f)
-                break
-            except ValueError:
-                continue
-        else:
-            raise ValueError(f"failed to parse date field [{s}]")
-    if dt.tzinfo is None:
-        dt = dt.replace(tzinfo=_dt.timezone.utc)
-    return int(dt.timestamp() * 1000)
+# epoch millis of a date value under a format (reference DateFieldMapper;
+# default `strict_date_optional_time||epoch_millis`): the patterns, and the
+# rounding of the parts a text leaves out, are `date_formats.parse_date`'s
+_parse_date = parse_date
+
+
+def _checked_date_format(path: str, ftype: str, cfg: dict) -> Optional[str]:
+    """A mapping's `format`, refused where this engine cannot read one of
+    its patterns (a date field would otherwise index under another)."""
+    fmt = cfg.get("format")
+    if fmt is not None and ftype in ("date", "date_nanos", "date_range"):
+        try:
+            validate_format(fmt)
+        except DateFormatError as e:
+            raise DateFormatError(f"field [{path}]: {e}")
+    return fmt
 
 
 def _ip_to_int(value: str) -> int:
@@ -357,7 +347,7 @@ class Mappings:
             ignore_above=cfg.get("ignore_above"),
             copy_to=list(cfg.get("copy_to", []) if isinstance(cfg.get("copy_to", []), list)
                          else [cfg["copy_to"]]),
-            date_format=cfg.get("format"),
+            date_format=_checked_date_format(path, ftype, cfg),
             term_vector=cfg.get("term_vector", "no"),
             boost=cfg.get("boost", 1.0),
             norms=cfg.get("norms", True),
@@ -445,6 +435,12 @@ class Mappings:
                 d["normalizer"] = ft.normalizer
             if not ft.index:
                 d["index"] = False
+            # what decides how a value is read and stored round-trips
+            # (`get_mapping`, and the persisted metadata a restart reads)
+            if ft.date_format is not None:
+                d["format"] = ft.date_format
+            if ft.scaling_factor is not None:
+                d["scaling_factor"] = ft.scaling_factor
             if ft.subfields:
                 d["fields"] = {s: {"type": sf.type} for s, sf in ft.subfields.items()}
             node[parts[-1]] = d

@@ -100,6 +100,153 @@ def run_counts(w: jnp.ndarray, starts: jnp.ndarray) -> jnp.ndarray:
     return prefix[1:] - prefix[:-1]
 
 
+# ---------------------------------------------------------------------
+# sums whose error does not grow with the bucket
+# ---------------------------------------------------------------------
+# A float32 accumulator past 2^24 times its addends' size rounds every
+# addend to its own spacing (a bucket of 8M values near 9.35 sums to 7.5e7,
+# spacing 8: the sum is wrong in the second digit), whatever the order. So
+# a sum is taken in fixed point: each value, scaled by a power of two so
+# that the column's largest magnitude lies under 1, is cut into limbs of L
+# bits (48 bits or more in all: every float32 within 2^24 of the column's
+# largest magnitude is held exactly, a smaller one to 2^-48 of that
+# magnitude), the limbs are added in int32 over blocks of 2^(31 - L) rows
+# (a block's sum cannot overflow), and each block sum is handed on as its
+# high and low 16 bits summed over the blocks (int32 again: at most 2^15
+# blocks). The host finishes in float64 (`limb_sums_to_f64`). Absolute error
+# of a sum of `count` values: at most count x 2^-48 x the column's largest
+# magnitude (rounded up to a power of two), whatever `count` is.
+SUB_METRIC_SCOPE = "aggs.bucketed_sub"
+# limb bits by number of limbs: the host reads L off the output's shape
+_LIMB_BITS = {3: 16, 4: 12, 6: 8, 8: 6, 10: 5}
+_SUM_ACC_MAX = 1 << 22      # int32 elements of one limb's accumulators
+
+
+def sum_limb_plan(n: int, nbuckets: int) -> Tuple[int, int, int]:
+    """(limbs, L, rows a block) for `n` rows into `nbuckets` buckets: the
+    widest limbs whose per-block accumulators ((n / rows) x nbuckets int32
+    a limb) stay under `_SUM_ACC_MAX`."""
+    for limbs, bits in _LIMB_BITS.items():
+        rows = 1 << (31 - bits)
+        if -(-n // rows) * nbuckets <= _SUM_ACC_MAX:
+            return limbs, bits, min(rows, max(n, 1))
+    limbs, bits = next(reversed(_LIMB_BITS.items()))
+    return limbs, bits, min(1 << (31 - bits), max(n, 1))
+
+
+def sum_scale_inv(max_abs: float) -> np.float32:
+    """2^-e with 2^e > `max_abs` (the column's largest magnitude): the
+    factor that brings every value under 1, exact in float32."""
+    top = float(np.float32(abs(max_abs)))       # as the device holds it
+    e = int(np.clip(math.frexp(top)[1], -100, 120)) if np.isfinite(top) \
+        else 120
+    return np.float32(2.0 ** -e)
+
+
+def _limbs(v: jnp.ndarray, w: jnp.ndarray, inv, limbs: int, bits: int):
+    """The signed int32 limbs of `v * inv` (|v * inv| < 1), most
+    significant first; rows with `w` 0 give zeros. Every step is exact in
+    float32 but the last limb's rounding."""
+    x = jnp.abs(v) * inv
+    sign = jnp.where(w > 0, jnp.where(v < 0, -1, 1), 0).astype(jnp.int32)
+    out = []
+    for i in range(limbs):
+        x = x * np.float32(1 << bits)
+        # (the last limb rounds, and stays a limb: a block of 2^(31 - L)
+        # rows of it cannot pass int32)
+        l = (jnp.minimum(jnp.round(x), np.float32((1 << bits) - 1))
+             if i == limbs - 1 else jnp.floor(x))
+        x = x - l
+        out.append(l.astype(jnp.int32) * sign)
+    return out
+
+
+def _fold_blocks(acc: jnp.ndarray) -> list:
+    """i32[blocks, nb] block sums -> their high and low 16 bits, each summed
+    over the blocks (acc = hi * 65536 + lo, lo in [0, 65536))."""
+    return [jnp.sum(acc >> 16, axis=0), jnp.sum(acc & 0xFFFF, axis=0)]
+
+
+def bucket_sums_exact(bucket_ids: jnp.ndarray, v: jnp.ndarray,
+                      w: jnp.ndarray, nbuckets: int, inv) -> jnp.ndarray:
+    """Per-bucket sums of `v` over the rows with `w` > 0 and an id in
+    [0, nbuckets), as i32[2 x limbs, nbuckets] for `limb_sums_to_f64`:
+    one int32 scatter-add a limb, keyed by (block of rows, bucket)."""
+    n = bucket_ids.shape[0]
+    limbs, bits, rows = sum_limb_plan(n, nbuckets)
+    nblk = -(-n // rows)
+    blk = jnp.arange(n, dtype=jnp.int32) // rows
+    ok = (w > 0) & (bucket_ids >= 0) & (bucket_ids < nbuckets)
+    ids = jnp.where(ok, blk * nbuckets + bucket_ids, nblk * nbuckets)
+    out = []
+    for limb in _limbs(v, w, inv, limbs, bits):
+        acc = jnp.zeros(nblk * nbuckets, jnp.int32).at[ids].add(
+            limb, mode="drop")
+        out += _fold_blocks(acc.reshape(nblk, nbuckets))
+    return jnp.stack(out)
+
+
+def sums_exact(v: jnp.ndarray, w: jnp.ndarray, inv) -> jnp.ndarray:
+    """The sum of `v` over the rows with `w` > 0, as i32[2 x limbs, 1]:
+    `bucket_sums_exact` with one bucket and no scatter."""
+    n = v.shape[0]
+    limbs, bits, rows = sum_limb_plan(n, 1)
+    nblk = -(-n // rows)
+    out = []
+    for limb in _limbs(v, w, inv, limbs, bits):
+        if nblk * rows != n:
+            limb = jnp.pad(limb, (0, nblk * rows - n))
+        out += _fold_blocks(jnp.sum(limb.reshape(nblk, rows),
+                                    axis=1)[:, None])
+    return jnp.stack(out)
+
+
+def limb_sums_to_f64(parts: np.ndarray, inv) -> np.ndarray:
+    """Host: i32[2 x limbs, nb] of `bucket_sums_exact` / `sums_exact` and
+    the scale they were cut with -> f64[nb] sums."""
+    parts = np.asarray(parts).astype(np.int64)
+    limbs = parts.shape[0] // 2
+    bits = _LIMB_BITS[limbs]
+    total = np.zeros(parts.shape[1], np.float64)
+    for i in reversed(range(limbs)):
+        whole = parts[2 * i] * 65536 + parts[2 * i + 1]
+        total += whole.astype(np.float64) * 2.0 ** (-bits * (i + 1))
+    return total / float(inv)
+
+
+def sub_metric_scatters(n: int, nbuckets: int, sumsq: bool) -> int:
+    """Scatters `bucketed_sub_metric` issues for `n` rows: the count, the
+    minimum, the maximum and a limb each of the sum (and of the squares)."""
+    limbs = sum_limb_plan(n, nbuckets)[0]
+    return 3 + limbs * (2 if sumsq else 1)
+
+
+def bucketed_sub_metric(bucket_ids: jnp.ndarray, v: jnp.ndarray,
+                        w: jnp.ndarray, nbuckets: int, inv,
+                        sumsq: bool) -> dict:
+    """count / min / max / sum (and the sum of squares where `sumsq`) of
+    `v` per bucket over the rows with `w` > 0: counts in int32, extremes as
+    they are stored, sums in limbs (`bucket_sums_exact`). `inv` is
+    `sum_scale_inv` of the column, handed back as `scale` for the host."""
+    # the scope names these ops in the device trace (an op's provenance:
+    # the benchmark's `agg_bucketed_sub_share` sums their time)
+    with jax.named_scope(SUB_METRIC_SCOPE):
+        b = jnp.where(w > 0, bucket_ids, nbuckets)
+        out = {"count": bucket_counts(b, w, nbuckets),
+               "min": jnp.full(nbuckets, F32_MAX).at[b].min(
+                   jnp.where(w > 0, v, F32_MAX), mode="drop"),
+               "max": jnp.full(nbuckets, -F32_MAX).at[b].max(
+                   jnp.where(w > 0, v, -F32_MAX), mode="drop"),
+               "sum": bucket_sums_exact(b, v, w, nbuckets, inv),
+               "scale": inv}
+        if sumsq:
+            # a square is rounded once to float32 (2^-24 of itself, the
+            # same for a bucket of any size) and then summed exactly
+            out["sumsq"] = bucket_sums_exact(b, v * v, w, nbuckets,
+                                             inv * inv)
+    return out
+
+
 def terms_counts(kw: dict, match: jnp.ndarray, nvocab_pad: int) -> jnp.ndarray:
     """Keyword terms agg: per-ordinal doc counts (reference
     GlobalOrdinalsStringTermsAggregator). Returns i32[nvocab_pad]."""
@@ -108,22 +255,16 @@ def terms_counts(kw: dict, match: jnp.ndarray, nvocab_pad: int) -> jnp.ndarray:
 
 
 def terms_sub_metric(kw: dict, match: jnp.ndarray, values_f32: jnp.ndarray,
-                     present: jnp.ndarray, nvocab_pad: int):
-    """Per-ordinal (sum, count, min, max) of a numeric column — powers metric
-    sub-aggregations under a terms bucket in a single fused pass."""
+                     present: jnp.ndarray, nvocab_pad: int, inv,
+                     sumsq: bool) -> dict:
+    """Per-ordinal count / min / max / sum of a numeric column: the metric
+    sub-aggregations under a terms bucket (`bucketed_sub_metric` over the
+    flat values' ordinals)."""
     docs = kw["doc_of_value"]
     safe = jnp.minimum(docs, values_f32.shape[0] - 1)
     w = _gather_match(match, docs) * jnp.where(present[safe], 1.0, 0.0)
-    v = values_f32[safe]
-    ords = kw["ords"]
-    sums = jnp.zeros(nvocab_pad, jnp.float32).at[ords].add(w * v, mode="drop")
-    cnts = jnp.zeros(nvocab_pad, jnp.float32).at[ords].add(w, mode="drop")
-    mins = jnp.full(nvocab_pad, F32_MAX).at[ords].min(
-        jnp.where(w > 0, v, F32_MAX), mode="drop")
-    maxs = jnp.full(nvocab_pad, -F32_MAX).at[ords].max(
-        jnp.where(w > 0, v, -F32_MAX), mode="drop")
-    sumsq = jnp.zeros(nvocab_pad, jnp.float32).at[ords].add(w * v * v, mode="drop")
-    return sums, cnts, mins, maxs, sumsq
+    return bucketed_sub_metric(kw["ords"], values_f32[safe], w, nvocab_pad,
+                               inv, sumsq)
 
 
 def histogram_counts(values_f32: jnp.ndarray, present: jnp.ndarray, match: jnp.ndarray,
@@ -147,17 +288,21 @@ def range_counts(values_f32: jnp.ndarray, present: jnp.ndarray, match: jnp.ndarr
     return jnp.sum((in_range & ok).astype(jnp.int32), axis=1)
 
 
-def stats_agg(values_f32: jnp.ndarray, present: jnp.ndarray, match: jnp.ndarray):
-    """count/sum/min/max/sumsq in one pass (reference StatsAggregator /
-    ExtendedStatsAggregator)."""
+def stats_agg(values_f32: jnp.ndarray, present: jnp.ndarray,
+              match: jnp.ndarray, inv, sumsq: bool) -> dict:
+    """count / sum / min / max (and the sum of squares) in one pass
+    (reference StatsAggregator / ExtendedStatsAggregator): the count in
+    int32 (a float32 count stops at 2^24), the sums in limbs
+    (`sums_exact`), `inv` the column's `sum_scale_inv`."""
     w = match * jnp.where(present, 1.0, 0.0)
     v = values_f32
-    count = jnp.sum(w)
-    s = jnp.sum(w * v)
-    ssq = jnp.sum(w * v * v)
-    mn = jnp.min(jnp.where(w > 0, v, F32_MAX))
-    mx = jnp.max(jnp.where(w > 0, v, -F32_MAX))
-    return count, s, mn, mx, ssq
+    out = {"count": jnp.sum((w > 0).astype(jnp.int32)),
+           "sum": sums_exact(v, w, inv), "scale": inv,
+           "min": jnp.min(jnp.where(w > 0, v, F32_MAX)),
+           "max": jnp.max(jnp.where(w > 0, v, -F32_MAX))}
+    if sumsq:
+        out["sumsq"] = sums_exact(v * v, w, inv * inv)
+    return out
 
 
 def value_count_keyword(kw: dict, match: jnp.ndarray) -> jnp.ndarray:

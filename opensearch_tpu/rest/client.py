@@ -1739,7 +1739,7 @@ class IndicesClient:
         self.c = client
 
     def create(self, index: str, body: Optional[dict] = None) -> dict:
-        return self.c.node.create_index(index, body)
+        return _map_date_format_errors(self.c.node.create_index, index, body)
 
     def delete(self, index: str) -> dict:
         return _map_ds_errors(self.c.node.delete_index, index)
@@ -1772,7 +1772,7 @@ class IndicesClient:
             svc = self.c.node.indices[n]
             # mapping merge mutates structures in-flight doc parses read
             with svc.write_lock:
-                svc.mappings.merge(body)
+                _map_date_format_errors(svc.mappings.merge, body)
                 self.c.node._persist_meta(n)
         return {"acknowledged": True}
 
@@ -2003,6 +2003,15 @@ class SnapshotClient:
                 if snapshot in ("_all", "*") or name == snapshot:
                     snaps.append({"snapshot": name, "state": "SUCCESS"})
         return {"snapshots": snaps}
+
+
+def _map_date_format_errors(fn, *args):
+    """A mapping's date `format` this engine cannot read -> 400."""
+    from ..index.date_formats import DateFormatError
+    try:
+        return fn(*args)
+    except DateFormatError as e:
+        raise ApiError(400, "mapper_parsing_exception", str(e))
 
 
 def _map_admin_errors(fn, *args):

@@ -20,7 +20,7 @@ from __future__ import annotations
 
 import math
 from dataclasses import dataclass, field as dc_field
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
 
@@ -101,7 +101,8 @@ def merge_partials(node: AggNode, partials: List[dict]) -> dict:
         for b, slot in acc.items():
             slot["subs"] = _merge_sub_metrics(node.subs, slot["subs"])
         return {"buckets": acc, "interval": parts[0]["interval"],
-                "offset": parts[0].get("offset", 0.0), "keyed_fmt": parts[0].get("keyed_fmt")}
+                "offset": parts[0].get("offset", 0.0), "keyed_fmt": parts[0].get("keyed_fmt"),
+                "calendar": parts[0].get("calendar")}
     if kind in ("range", "date_range", "geo_distance", "filters", "ip_range",
                 "adjacency_matrix"):
         acc = {}
@@ -152,20 +153,13 @@ def merge_partials(node: AggNode, partials: List[dict]) -> dict:
     if kind == "scripted_metric":
         return {"states": [s for p in parts for s in p["states"]]}
     if kind == "auto_date_histogram":
-        # shards may have rounded at different intervals: coarsen everything
-        # to the widest before accumulating (reference
+        # shards (and segments) may have rounded at different units: bring
+        # every bucket to the coarsest before accumulating (reference
         # InternalAutoDateHistogram#reduce)
-        interval = max(p["interval_ms"] for p in parts)
-        acc: Dict[Any, dict] = {}
-        for p in parts:
-            for key, rec in p["buckets"].items():
-                ck = (int(key) // interval) * interval
-                slot = acc.setdefault(ck, {"doc_count": 0, "subs": []})
-                slot["doc_count"] += rec["doc_count"]
-                slot["subs"].append(rec.get("subs"))
-        for slot in acc.values():
-            slot["subs"] = _merge_sub_metrics(node.subs, slot["subs"])
-        return {"buckets": acc, "interval_ms": interval}
+        unit = max(p["unit"] for p in parts)
+        return {"buckets": _auto_accumulate(
+            node, [(p["buckets"], p["unit"]) for p in parts], unit),
+            "unit": unit}
     if kind == "composite":
         return {"buckets": _acc_buckets(node, parts)}
     if kind == "matrix_stats":
@@ -200,6 +194,53 @@ def merge_partials(node: AggNode, partials: List[dict]) -> dict:
         rows.sort(key=lambda r: -r["_score"] if r["_score"] is not None else 0)
         return {"hits": rows[: parts[0]["size"]], "total": sum(p["total"] for p in parts)}
     raise ValueError(f"cannot merge aggregation kind [{kind}]")
+
+
+# empty buckets a histogram's response is filled with at most (the
+# reference's `search.max_buckets` default is 65,535)
+_MAX_FILLED_BUCKETS = 65_535
+
+
+def _with_empty_buckets(held: dict, calendar: Optional[str]) -> dict:
+    """A histogram's merged buckets with the empty ones between the least
+    and the greatest key: keys are bucket numbers, or, under a calendar
+    interval, the buckets' starts in epoch ms. Left as it is where that
+    would pass `_MAX_FILLED_BUCKETS`."""
+    empty = {"doc_count": 0, "subs": {}}
+    if calendar is None:
+        if max(held) - min(held) >= _MAX_FILLED_BUCKETS:
+            return held
+        return {b: held.get(b, empty)
+                for b in range(min(held), max(held) + 1)}
+    from .compiler import _calendar_bucket_ids, calendar_bucket_start_ms
+    lo, hi = (int(x) for x in _calendar_bucket_ids(
+        [min(held), max(held)], calendar))
+    if hi - lo >= _MAX_FILLED_BUCKETS:
+        return held
+    out = dict(held)
+    for b in range(lo, hi + 1):
+        out.setdefault(calendar_bucket_start_ms(b, calendar), empty)
+    return out
+
+
+def _auto_accumulate(node: AggNode, parts: List[Tuple[dict, int]],
+                     unit: int) -> Dict[Any, dict]:
+    """auto_date_histogram buckets (keyed by their start in epoch ms under
+    each part's own rounding) brought to rounding `unit` and accumulated:
+    `parts` is [(buckets, their unit)]."""
+    from . import compiler as C
+    acc: Dict[Any, dict] = {}
+    for buckets, from_unit in parts:
+        for key, rec in buckets.items():
+            if from_unit != unit:
+                key = C.auto_unit_start_ms(
+                    int(C.auto_unit_ids(key, unit)), unit)
+            slot = acc.setdefault(key, {"doc_count": 0, "subs": []})
+            slot["doc_count"] += rec["doc_count"]
+            slot["subs"].append(rec.get("subs"))
+    for slot in acc.values():
+        slot["subs"] = _merge_sub_metrics(node.subs, slot["subs"])
+    return acc
 
 
 def _acc_buckets(node: AggNode, parts: List[dict]) -> Dict[Any, dict]:
@@ -275,9 +316,16 @@ def finalize(node: AggNode, merged: dict, pipelines: bool = True) -> dict:
         return result
     if kind in ("histogram", "date_histogram"):
         buckets = []
-        for b in sorted(merged["buckets"]):
-            rec = merged["buckets"][b]
-            if rec["doc_count"] <= 0 and int(node.body.get("min_doc_count", 0)) > 0:
+        held = merged["buckets"]
+        min_doc_count = int(node.body.get("min_doc_count", 0))
+        if min_doc_count == 0 and held:
+            # `min_doc_count` 0 (the default): the empty buckets between
+            # the least and the greatest key are part of the answer
+            # (reference InternalHistogram#addEmptyBuckets)
+            held = _with_empty_buckets(held, merged.get("calendar"))
+        for b in sorted(held):
+            rec = held[b]
+            if rec["doc_count"] <= 0 and min_doc_count > 0:
                 continue
             key = b * merged["interval"] + merged.get("offset", 0.0)
             entry = {"key": key, "doc_count": int(rec["doc_count"])}
@@ -469,38 +517,43 @@ def finalize(node: AggNode, merged: dict, pipelines: bool = True) -> dict:
         _apply_bucket_pipelines(node, result, "all" if pipelines else "early")
         return result
     if kind == "auto_date_histogram":
+        # the coordinator's final rounding (reference
+        # InternalAutoDateHistogram#reduce, recalled): the finest (unit,
+        # inner interval) under which the buckets from the least to the
+        # greatest non-empty one number at most `buckets`; buckets of an
+        # inner interval over 1 are merged from the least one on, empty
+        # buckets between are part of the answer
+        from . import compiler as C
         target = max(int(node.body.get("buckets", 10)), 1)
-        interval = merged.get("interval_ms", 1000)
-        buckets = dict(merged.get("buckets", {}))
-        # coarsen until the bucket count fits the target (coordinator-side
-        # final rounding step of the reference)
-        from .compiler import _AUTO_LADDER, auto_interval_name
-        ladder = [ms for ms, _ in _AUTO_LADDER]
-        li = next((i for i, ms in enumerate(ladder) if ms >= interval), 0)
-        while buckets and len(buckets) > target and li + 1 < len(ladder):
-            li += 1
-            interval = ladder[li]
-            acc: Dict[Any, dict] = {}
-            for key, rec in buckets.items():
-                ck = (int(key) // interval) * interval
-                slot = acc.setdefault(ck, {"doc_count": 0, "subs": []})
-                slot["doc_count"] += rec["doc_count"]
-                slot["subs"].append(rec.get("subs"))
-            for slot in acc.values():
-                slot["subs"] = _merge_sub_metrics(node.subs, slot["subs"])
-            buckets = acc
-        out_buckets = []
-        for key in sorted(buckets):
-            rec = buckets[key]
-            entry = {"key": int(key),
-                     "key_as_string": _format_epoch_ms(int(key)),
-                     "doc_count": int(rec["doc_count"])}
-            for sub in node.subs:
-                entry[sub.name] = finalize(sub, rec["subs"].get(sub.name, {}),
-                                           pipelines)
-            out_buckets.append(entry)
-        result = {"buckets": out_buckets,
-                  "interval": auto_interval_name(interval)}
+        unit = merged.get("unit", 0)
+        buckets = {k: v for k, v in merged.get("buckets", {}).items()
+                   if v["doc_count"] > 0}
+        out_buckets, inner = [], 1
+        while buckets:
+            ids = {int(C.auto_unit_ids(k, unit)): k for k in buckets}
+            lo, hi = min(ids), max(ids)
+            inner = C.auto_inner_for(hi - lo + 1, unit, target)
+            if inner is not None:
+                break
+            unit += 1
+            buckets = _auto_accumulate(node, [(buckets, unit - 1)], unit)
+        if buckets:
+            groups: Dict[int, list] = {}
+            for i, k in ids.items():
+                groups.setdefault((i - lo) // inner, []).append(buckets[k])
+            for g in range((hi - lo) // inner + 1):
+                recs = groups.get(g, [])
+                key = C.auto_unit_start_ms(lo + g * inner, unit)
+                entry = {"key": key, "key_as_string": _format_epoch_ms(key),
+                         "doc_count": int(sum(r["doc_count"] for r in recs))}
+                subs = _merge_sub_metrics(node.subs,
+                                          [r.get("subs") for r in recs])
+                for sub in node.subs:
+                    entry[sub.name] = finalize(sub, subs.get(sub.name, {}),
+                                               pipelines)
+                out_buckets.append(entry)
+        interval = f"{inner}{C.AUTO_ROUNDINGS[unit][0]}"
+        result = {"buckets": out_buckets, "interval": interval}
         _apply_bucket_pipelines(node, result, "all" if pipelines else "early")
         return result
     if kind == "significant_text":

@@ -36,6 +36,7 @@ from ..index.mappings import (FLOAT_TYPES, INT_TYPES, KEYWORD_TYPES,
 from ..index.segment import (CODEC_V1, CODEC_V2, Segment, next_pow2,
                              split_i64)
 from ..models.similarity import Similarity, resolve_similarity
+from ..index.date_formats import parse_date
 from ..ops import aggs as agg_ops
 from ..ops import scoring as ops
 from ..script import painless_lite as pl
@@ -77,7 +78,24 @@ _JIT_FAMILIES = ("executor", "mask", "gather", "agg", "rescore", "join")
 EXECUTOR_STATS = CounterGroup(METRICS, "executor", {"params_h2d_bytes": 0,
                                                     "topk_keys_sorted": 0,
                                                     "agg_bucket_launches": 0,
-                                                    "agg_run_counted": 0})
+                                                    "agg_run_counted": 0,
+                                                    "launches": 0})
+# what the aggregations of the launches cost, counted at each launch from
+# the static spec (`_count_launch`): `scatter.updates` the rows handed to
+# every scatter (a bucket count by `ops.aggs.bucket_counts`, and each
+# scatter of a bucketed sub-metric: count, minimum, maximum and a limb a
+# sum); `blocked.rows` the rows a form that replaces a scatter reads
+# (`ops.aggs.run_counts`); `bucketed_sub.launches` / `.buckets` the
+# launches that carry a metric under a bucket aggregation, and their
+# buckets; `auto_date.requests` the top-level auto_date_histograms a
+# segment was asked, `auto_date.refine_launches` the launches taken first
+# to learn their matched range (`auto_date_range`)
+AGG_STATS = CounterGroup(METRICS, "aggs", {"scatter.updates": 0,
+                                           "blocked.rows": 0,
+                                           "bucketed_sub.launches": 0,
+                                           "bucketed_sub.buckets": 0,
+                                           "auto_date.requests": 0,
+                                           "auto_date.refine_launches": 0})
 BUCKET_PLANE_STATS = CounterGroup(METRICS, "aggs.bucket_plane",
                                   {"builds": 0, "hits": 0, "bytes": 0})
 RANK_PLANE_STATS = CounterGroup(METRICS, "sort.rank_plane",
@@ -1107,14 +1125,31 @@ def _rewrite(q: dsl.Query, ctx: ShardContext, scoring: bool) -> LNode:  # noqa: 
         kind = "float" if ft.type in FLOAT_TYPES else "int"
         lo = hi = None
         inc_lo = inc_hi = True
+        if ft.type == "date":
+            # the request's `format` replaces the mapping's for its bounds;
+            # the parts a bound leaves out round up for lte / gt and down
+            # for gte / lt (reference DateMathParser's roundUpProperty)
+            fmt = q.date_format or ft.date_format
+
+            def bound(v, round_up):
+                # an unknown pattern and a text outside its format alike
+                # are the request's fault: a 400 that names it
+                try:
+                    return parse_date(v, fmt, round_up)
+                except ValueError as e:
+                    raise dsl.QueryParseError(
+                        f"[range] query on [{q.field}]: {e}")
+        else:
+            def bound(v, _round_up):
+                return coerce_value(ft, v)
         if q.gte is not None:
-            lo, inc_lo = coerce_value(ft, q.gte), True
+            lo, inc_lo = bound(q.gte, False), True
         if q.gt is not None:
-            lo, inc_lo = coerce_value(ft, q.gt), False
+            lo, inc_lo = bound(q.gt, True), False
         if q.lte is not None:
-            hi, inc_hi = coerce_value(ft, q.lte), True
+            hi, inc_hi = bound(q.lte, True), True
         if q.lt is not None:
-            hi, inc_hi = coerce_value(ft, q.lt), False
+            hi, inc_hi = bound(q.lt, False), False
         return LRange(field=ft.name, kind=kind, lo=lo, hi=hi,
                       include_lo=inc_lo, include_hi=inc_hi, boost=q.boost)
 
@@ -3605,6 +3640,25 @@ def _calendar_bucket_ids(ms: np.ndarray, calendar: str) -> np.ndarray:
     raise ValueError(f"unknown calendar_interval [{calendar}]")
 
 
+def calendar_bucket_start_ms(b: int, calendar: str) -> int:
+    """Epoch ms (UTC) at which calendar bucket `b` starts: the inverse of
+    `_calendar_bucket_ids`."""
+    if calendar in ("minute", "1m"):
+        return b * 60000
+    if calendar in ("hour", "1h"):
+        return b * 3600000
+    if calendar in ("day", "1d"):
+        return b * _DAY_MS
+    if calendar in ("week", "1w"):
+        return (b * 7 - 3) * _DAY_MS
+    months = {"month": 1, "1M": 1, "quarter": 3, "1q": 3, "year": 12,
+              "1y": 12}.get(calendar)
+    if months is None:
+        raise ValueError(f"unknown calendar_interval [{calendar}]")
+    return int(np.datetime64(b * months, "M").astype(
+        "datetime64[ms]").astype(np.int64))
+
+
 _CAL_MS = {"month": None, "1M": None, "year": None, "1y": None, "quarter": None,
            "1q": None, "week": None, "1w": None}
 
@@ -3716,36 +3770,70 @@ def _geo_grid_cache(seg: Segment, field: str, kind: str, precision: int):
     return cache[key]
 
 
-# auto_date_histogram rounding ladder (fixed-interval approximation of the
-# reference's calendar ladder — months/years as 30/365 days)
-_AUTO_LADDER = [
-    (1_000, "1s"), (5_000, "5s"), (10_000, "10s"), (30_000, "30s"),
-    (60_000, "1m"), (300_000, "5m"), (600_000, "10m"), (1_800_000, "30m"),
-    (3_600_000, "1h"), (10_800_000, "3h"), (43_200_000, "12h"),
-    (86_400_000, "1d"), (604_800_000, "7d"), (2_592_000_000, "1M"),
-    (7_776_000_000, "3M"), (31_536_000_000, "1y"), (157_680_000_000, "5y"),
-    (315_360_000_000, "10y"), (3_153_600_000_000, "100y"),
-]
+# auto_date_histogram's roundings (reference AutoDateHistogramAggregation-
+# Builder.buildRoundings, recalled): a unit and the multiples of it a bucket
+# may span. (abbreviation, `_date_bucket_plane` calendar, inner intervals)
+AUTO_ROUNDINGS = (
+    ("s", None, (1, 5, 10, 30)),
+    ("m", "minute", (1, 5, 10, 30)),
+    ("h", "hour", (1, 3, 12)),
+    ("d", "day", (1, 7)),
+    ("M", "month", (1, 3)),
+    ("y", "year", (1, 5, 10, 20, 50, 100)),
+)
 
 
-def _auto_interval(col, target: int) -> int:
-    """Smallest ladder interval giving <= target buckets over the column's
-    span (reference AutoDateHistogramAggregator rounding prepare)."""
-    if col is None or not col.present.any():
-        return _AUTO_LADDER[0][0]
-    mn, mx = col.min_max
-    span = max(mx - mn, 1.0)
-    for ms, _name in _AUTO_LADDER:
-        if span / ms <= target:
-            return ms
-    return _AUTO_LADDER[-1][0]
+def auto_unit_ids(ms, unit: int) -> np.ndarray:
+    """Bucket ids of epoch-millisecond values under rounding `unit` (UTC):
+    whole seconds, minutes, hours and days since the epoch, calendar months
+    since 1970-01, years since 1970."""
+    cal = AUTO_ROUNDINGS[unit][1]
+    ms = np.asarray(ms, dtype=np.int64)
+    return ms // 1000 if cal is None else _calendar_bucket_ids(ms, cal)
 
 
-def auto_interval_name(interval_ms: int) -> str:
-    for ms, name in _AUTO_LADDER:
-        if ms == interval_ms:
-            return name
-    return f"{interval_ms}ms"
+def auto_unit_start_ms(bucket_id: int, unit: int) -> int:
+    """Epoch ms at which bucket `bucket_id` of rounding `unit` starts."""
+    cal = AUTO_ROUNDINGS[unit][1]
+    return (int(bucket_id) * 1000 if cal is None
+            else calendar_bucket_start_ms(int(bucket_id), cal))
+
+
+def auto_unit_for(lo_ms: int, hi_ms: int, target: int) -> int:
+    """The finest rounding under which the buckets from `lo_ms`'s to
+    `hi_ms`'s, merged by the rounding's widest inner interval, number at
+    most `target` (the coarsest where none does)."""
+    for unit, (_abbr, _cal, inners) in enumerate(AUTO_ROUNDINGS):
+        lo, hi = auto_unit_ids([lo_ms, hi_ms], unit)
+        if -(-(int(hi) - int(lo) + 1) // inners[-1]) <= target:
+            return unit
+    return len(AUTO_ROUNDINGS) - 1
+
+
+def auto_window(unit: int, target: int) -> int:
+    """Buckets of rounding `unit` a launch counts: what `auto_unit_for`
+    admits, as a power of two (a static size of the program)."""
+    return next_pow2(target * AUTO_ROUNDINGS[unit][2][-1])
+
+
+def auto_inner_for(nbuckets: int, unit: int, target: int) -> Optional[int]:
+    """The least inner interval of rounding `unit` that merges `nbuckets`
+    consecutive buckets into at most `target`; None where none does and a
+    coarser rounding is left to try (the coarsest takes its widest)."""
+    inners = AUTO_ROUNDINGS[unit][2]
+    for inner in inners:
+        if -(-nbuckets // inner) <= target:
+            return inner
+    return inners[-1] if unit + 1 == len(AUTO_ROUNDINGS) else None
+
+
+def auto_bucket_end_ms(key_ms: int, interval: str) -> int:
+    """Epoch ms at which the bucket that starts at `key_ms` ends, `interval`
+    as the response names it (`7d`, `3M`)."""
+    unit = next(u for u, r in enumerate(AUTO_ROUNDINGS)
+                if r[0] == interval[-1])
+    first = int(auto_unit_ids(key_ms, unit))
+    return auto_unit_start_ms(first + int(interval[:-1]), unit)
 
 
 def _multi_terms_cache(seg: Segment, ctx: ShardContext, node, fields: Tuple[str, ...]):
@@ -3937,10 +4025,13 @@ def _bind_date_buckets(params: dict, prefix: str, seg: Segment, field: str,
 
 
 def prepare_agg(node: AggNode, seg: Segment, ctx: ShardContext, params: dict,
-                prefix: str, nest_stack: Tuple = ()):  # noqa: C901
+                prefix: str, nest_stack: Tuple = (),
+                auto_range: Optional[Tuple[int, int]] = None):  # noqa: C901
     """-> hashable agg spec; params filled per segment. `prefix` keys params.
     `nest_stack` is the nesting path down to `seg`: ((path, segment), ...)
-    root-first, empty at root — reverse_nested climbs it."""
+    root-first, empty at root — reverse_nested climbs it. `auto_range` is
+    the least and greatest value of a top-level `auto_date_histogram`'s
+    field among this segment's matched documents (`auto_date_range`)."""
     kind = node.kind
     body = node.body
 
@@ -4082,7 +4173,14 @@ def prepare_agg(node: AggNode, seg: Segment, ctx: ShardContext, params: dict,
         field = _resolve_agg_field(node, ctx)
         if kind == "value_count" and field in seg.keyword_cols:
             return ("vc_keyword", prefix, field)
-        return ("stats", prefix, field, field in seg.numeric_cols)
+        col = seg.numeric_cols.get(field)
+        if col is not None:
+            # the power of two that brings the column under 1, for the
+            # sums' fixed point (`ops.aggs.bucket_sums_exact`)
+            _p(params, f"{prefix}_sinv",
+               agg_ops.sum_scale_inv(max(abs(x) for x in col.min_max)))
+        return ("stats", prefix, field, col is not None,
+                kind == "extended_stats")
 
     if kind == "cardinality":
         field = _resolve_agg_field(node, ctx)
@@ -4317,14 +4415,27 @@ def prepare_agg(node: AggNode, seg: Segment, ctx: ShardContext, params: dict,
         field = _resolve_agg_field(node, ctx)
         target = max(int(body.get("buckets", 10)), 1)
         col = seg.numeric_cols.get(field)
-        interval_ms = _auto_interval(col, target)
-        min_b, nb, form = _bind_date_buckets(params, prefix, seg, field,
-                                             interval_ms, 0, None)
+        if col is None or not col.present.any():
+            return ("hist_missing", prefix, 0.0, 0.0)
+        # the rounding follows the matched documents' least and greatest
+        # value where the executor learned them for this node (a top-level
+        # aggregation: `auto_range`), the column's span elsewhere (a
+        # superset, so the window below still holds every matched bucket)
+        lo_ms, hi_ms = auto_range or tuple(int(x) for x in col.min_max)
+        unit = auto_unit_for(lo_ms, hi_ms, target)
+        abbr, calendar, _inners = AUTO_ROUNDINGS[unit]
+        min_b, nb, form = _bind_date_buckets(
+            params, prefix, seg, field, 1000 if calendar is None else 1, 0,
+            calendar)
+        window = auto_window(unit, target)
+        first = int(auto_unit_ids(lo_ms, unit))
+        params[f"{prefix}_dfirst"] = np.int32(
+            np.clip(first - min_b, -(1 << 30), 1 << 30))
         subs = tuple(prepare_agg(s, seg, ctx, params, f"{prefix}_{i}",
                                  nest_stack)
                      for i, s in enumerate(node.subs))
-        return ("auto_date_hist", prefix, field, interval_ms, target,
-                min_b, nb, subs, form)
+        return ("auto_date_hist", prefix, field, unit, target, min_b, nb,
+                window, subs, form)
 
     if kind == "scripted_metric":
         return ("scripted", prefix)
@@ -4499,11 +4610,12 @@ def emit_agg(spec, seg_arrays: dict, params: dict, match, scores=None):  # noqa:
                "fg_total": jnp.sum(match)}
         for i, sub in enumerate(subs):
             if sub and sub[0] == "stats":
-                _, sprefix, sfield, col_exists = sub
+                _, sprefix, sfield, col_exists, sumsq = sub
                 if col_exists:
                     col = seg_arrays["numeric"][sfield]
                     out[f"sub{i}"] = agg_ops.terms_sub_metric(
-                        kw, match, col["f32"], col["present"], nvocab_pad)
+                        kw, match, col["f32"], col["present"], nvocab_pad,
+                        params[f"{sprefix}_sinv"], sumsq)
         return out
 
     if kind == "sampler":
@@ -4540,7 +4652,8 @@ def emit_agg(spec, seg_arrays: dict, params: dict, match, scores=None):  # noqa:
         b = jnp.where(w > 0, ords, nb)
         out = {"counts": agg_ops.bucket_counts(b, w, nb)}
         for i, sub in enumerate(subs):
-            out.update(_emit_bucketed_sub(jnp, sub, i, b, nb, seg_arrays, match))
+            out.update(_emit_bucketed_sub(jnp, sub, i, b, nb, seg_arrays,
+                                          match, params))
         return out
 
     if kind == "nested_agg":
@@ -4613,11 +4726,12 @@ def emit_agg(spec, seg_arrays: dict, params: dict, match, scores=None):  # noqa:
         out = {"counts": agg_ops.terms_counts(kw, match, nb)}
         for i, sub in enumerate(subs):
             if sub and sub[0] == "stats":
-                _, sprefix, sfield, col_exists = sub
+                _, sprefix, sfield, col_exists, sumsq = sub
                 if col_exists:
                     col = seg_arrays["numeric"][sfield]
                     out[f"sub{i}"] = agg_ops.terms_sub_metric(
-                        kw, match, col["f32"], col["present"], nb)
+                        kw, match, col["f32"], col["present"], nb,
+                        params[f"{sprefix}_sinv"], sumsq)
         return out
 
     if kind == "composite":
@@ -4640,7 +4754,7 @@ def emit_agg(spec, seg_arrays: dict, params: dict, match, scores=None):  # noqa:
         out = {"counts": agg_ops.bucket_counts(b, w, total)}
         for i, sub in enumerate(subs):
             out.update(_emit_bucketed_sub(jnp, sub, i, b, total, seg_arrays,
-                                          match * w))
+                                          match * w, params))
         return out
 
     if kind == "matrix_stats":
@@ -4671,11 +4785,12 @@ def emit_agg(spec, seg_arrays: dict, params: dict, match, scores=None):  # noqa:
         out = {"counts": agg_ops.terms_counts(kw, match, nvocab_pad)}
         for i, sub in enumerate(subs):
             if sub and sub[0] == "stats":
-                _, sprefix, sfield, col_exists = sub
+                _, sprefix, sfield, col_exists, sumsq = sub
                 if col_exists:
                     col = seg_arrays["numeric"][sfield]
                     out[f"sub{i}"] = agg_ops.terms_sub_metric(
-                        kw, match, col["f32"], col["present"], nvocab_pad)
+                        kw, match, col["f32"], col["present"], nvocab_pad,
+                        params[f"{sprefix}_sinv"], sumsq)
         return out
 
     if kind == "hist":
@@ -4686,7 +4801,8 @@ def emit_agg(spec, seg_arrays: dict, params: dict, match, scores=None):  # noqa:
         b = jnp.where((b >= 0) & (b < nb) & (w > 0), b, nb)
         out = {"counts": agg_ops.bucket_counts(b, w, nb)}
         for i, sub in enumerate(subs):
-            out.update(_emit_bucketed_sub(jnp, sub, i, b, nb, seg_arrays, match))
+            out.update(_emit_bucketed_sub(jnp, sub, i, b, nb, seg_arrays,
+                                          match, params))
         return out
 
     if kind == "date_hist":
@@ -4695,7 +4811,8 @@ def emit_agg(spec, seg_arrays: dict, params: dict, match, scores=None):  # noqa:
         counts, b = _date_bucket_counts(jnp, params, prefix, match, nb, form)
         out = {"counts": counts}
         for i, sub in enumerate(subs):
-            out.update(_emit_bucketed_sub(jnp, sub, i, b, nb, seg_arrays, match))
+            out.update(_emit_bucketed_sub(jnp, sub, i, b, nb, seg_arrays,
+                                          match, params))
         return out
 
     if kind == "range":
@@ -4791,12 +4908,12 @@ def emit_agg(spec, seg_arrays: dict, params: dict, match, scores=None):  # noqa:
         return out
 
     if kind == "stats":
-        _, prefix, field, col_exists = spec
+        _, prefix, field, col_exists, sumsq = spec
         if not col_exists:
             return {"empty": jnp.float32(0)}
         col = seg_arrays["numeric"][field]
-        count, s, mn, mx, ssq = agg_ops.stats_agg(col["f32"], col["present"], match)
-        return {"count": count, "sum": s, "min": mn, "max": mx, "sumsq": ssq}
+        return agg_ops.stats_agg(col["f32"], col["present"], match,
+                                 params[f"{prefix}_sinv"], sumsq)
 
     if kind == "vc_keyword":
         _, prefix, field = spec
@@ -4955,7 +5072,7 @@ def emit_agg(spec, seg_arrays: dict, params: dict, match, scores=None):  # noqa:
         b = jnp.where(ords >= 0, ords, nord_pad)
         for i, sub in enumerate(subs):
             out.update(_emit_bucketed_sub(jnp, sub, i, b, nord_pad,
-                                          seg_arrays, match))
+                                          seg_arrays, match, params))
         return out
 
     if kind == "adjacency":
@@ -4986,12 +5103,15 @@ def emit_agg(spec, seg_arrays: dict, params: dict, match, scores=None):  # noqa:
         return out
 
     if kind == "auto_date_hist":
-        _, prefix, field, interval_ms, target, min_b, nb, subs, form = spec
-        counts, b = _date_bucket_counts(jnp, params, prefix, match, nb, form)
-        out = {"counts": counts}
+        (_, prefix, field, unit, target, min_b, nb, window, subs,
+         form) = spec
+        first = params[f"{prefix}_dfirst"]
+        counts, b = _date_bucket_counts(jnp, params, prefix, match, nb, form,
+                                        first, window)
+        out = {"counts": counts, "first": first}
         for i, sub in enumerate(subs):
-            out.update(_emit_bucketed_sub(jnp, sub, i, b, nb, seg_arrays,
-                                          match))
+            out.update(_emit_bucketed_sub(jnp, sub, i, b, window, seg_arrays,
+                                          match, params))
         return out
 
     if kind in ("scripted", "sig_text"):
@@ -5003,41 +5123,45 @@ def emit_agg(spec, seg_arrays: dict, params: dict, match, scores=None):  # noqa:
 
 
 def _date_bucket_counts(jnp, params: dict, prefix: str, match, nb: int,
-                        form: str):
+                        form: str, first=None, window: Optional[int] = None):
     """A date histogram's counts over its resident bucket plane: ->
     (counts i32[nb], per-row bucket ids with `nb` where the row does not
     count, for the sub-aggregations' scatters). A row counts where it
     matches and has a value; `form` "runs" reads the counts at the runs'
-    boundaries (`ops.aggs.run_counts`), "scatter" adds a row at a time."""
+    boundaries (`ops.aggs.run_counts`), "scatter" adds a row at a time.
+    With `first` (a traced scalar) and `window` the counts are those of
+    the plane's buckets [first, first + window) alone, i32[window]
+    (`auto_date_histogram`: the plane spans the column, the response a few
+    buckets of it)."""
     ids = params[f"{prefix}_dbuckets"][:match.shape[0]]
     held = (match > 0) & (ids >= 0)
+    starts = params.get(f"{prefix}_dstarts")
+    if window is not None:
+        ids = ids - first
+        held = held & (ids >= 0) & (ids < window)
+        if form == "runs":
+            at = first + jnp.arange(window + 1, dtype=jnp.int32)
+            starts = starts[jnp.clip(at, 0, nb)]
+        nb = window
     b = jnp.where(held, ids, nb)
     if form == "runs":
-        return agg_ops.run_counts(held.astype(jnp.int32),
-                                  params[f"{prefix}_dstarts"]), b
+        return agg_ops.run_counts(held.astype(jnp.int32), starts), b
     return agg_ops.bucket_counts(b, held, nb), b
 
 
-def _emit_bucketed_sub(jnp, sub, i: int, bucket_ids, nb: int, seg_arrays, match):
-    """Metric sub-agg under an ordinal bucket agg: scatter into per-bucket
-    accumulators."""
+def _emit_bucketed_sub(jnp, sub, i: int, bucket_ids, nb: int, seg_arrays, match,
+                       params: dict):
+    """Metric sub-agg under an ordinal bucket agg: per-bucket accumulators
+    (`ops.aggs.bucketed_sub_metric`: int32 counts, sums in limbs)."""
     if not sub or sub[0] != "stats":
         return {}
-    _, sprefix, sfield, col_exists = sub
+    _, sprefix, sfield, col_exists, sumsq = sub
     if not col_exists:
         return {}
     col = seg_arrays["numeric"][sfield]
     w = match * jnp.where(col["present"], 1.0, 0.0)
-    v = col["f32"]
-    b = jnp.where(w > 0, bucket_ids, nb)
-    sums = jnp.zeros(nb, jnp.float32).at[b].add(w * v, mode="drop")
-    cnts = jnp.zeros(nb, jnp.float32).at[b].add(w, mode="drop")
-    mins = jnp.full(nb, 3.4e38, jnp.float32).at[b].min(
-        jnp.where(w > 0, v, 3.4e38), mode="drop")
-    maxs = jnp.full(nb, -3.4e38, jnp.float32).at[b].max(
-        jnp.where(w > 0, v, -3.4e38), mode="drop")
-    sumsq = jnp.zeros(nb, jnp.float32).at[b].add(w * v * v, mode="drop")
-    return {f"sub{i}": (sums, cnts, mins, maxs, sumsq)}
+    return {f"sub{i}": agg_ops.bucketed_sub_metric(
+        bucket_ids, col["f32"], w, nb, params[f"{sprefix}_sinv"], sumsq)}
 
 
 # =====================================================================
@@ -5455,10 +5579,71 @@ def _count_launch(full_spec, seg_arrays: dict, cparams: dict) -> None:
     if collapse_spec is None:
         EXECUTOR_STATS.inc("topk_keys_sorted", ops.topk_keys_sorted(
             seg_arrays["live"].shape[0], k_pad))
+    EXECUTOR_STATS.inc("launches")
     forms = list(_date_count_forms(aggs))
     if forms:
         EXECUTOR_STATS.inc("agg_bucket_launches", len(forms))
         EXECUTOR_STATS.inc("agg_run_counted", forms.count("runs"))
+    if aggs:
+        cost = {"scatter": 0, "blocked": 0, "sub_buckets": 0}
+        for _name, aspec in aggs:
+            _agg_cost(aspec, seg_arrays, cost)
+        if cost["scatter"]:
+            AGG_STATS.inc("scatter.updates", cost["scatter"])
+        if cost["blocked"]:
+            AGG_STATS.inc("blocked.rows", cost["blocked"])
+        if cost["sub_buckets"]:
+            AGG_STATS.inc("bucketed_sub.launches")
+            AGG_STATS.inc("bucketed_sub.buckets", cost["sub_buckets"])
+
+
+# where the sub-aggregation specs sit in the containers that hand their
+# children this segment's own rows (the nested and join kinds hand them
+# another segment's: not walked)
+_AGG_CONTAINER_SUBS = {"filter": 3, "filters": 3, "global": 2, "missing": 4,
+                       "range": 5, "geo_range": 5, "sampler": 4,
+                       "adjacency": 4}
+
+
+def _agg_cost(spec, seg_arrays: dict, cost: dict) -> None:
+    """What `emit_agg` builds for `spec`, reckoned from the spec alone (the
+    walk mirrors it): rows handed to scatters, rows read by `run_counts`,
+    buckets that carry a metric sub-aggregation. Kinds that scatter
+    nothing per row of the segment add nothing."""
+    if not isinstance(spec, tuple) or not spec:
+        return
+    kind = spec[0]
+    n = seg_arrays["live"].shape[0]
+    rows = nb = None                    # of this node's own bucket count
+    if kind == "hist":
+        rows, nb, subs = n, spec[6], spec[7]
+    elif kind == "date_hist":
+        rows, nb, subs = n, spec[7], spec[8]
+    elif kind == "auto_date_hist":
+        rows, nb, subs = n, spec[7], spec[8]
+    elif kind in ("terms", "sig_terms", "composite_mv"):
+        rows = seg_arrays["keyword"][spec[2]]["ords"].shape[0]
+        nb, subs = spec[3], spec[4]
+    elif kind == "geo_grid":
+        rows, nb, subs = n, spec[5], spec[6]
+    elif kind == "composite":
+        rows, nb, subs = n, spec[3], spec[4]
+    elif kind == "multi_terms":
+        rows, nb, subs = n, spec[2], spec[4]
+    if rows is None:
+        at = _AGG_CONTAINER_SUBS.get(kind)
+        for sub in (spec[at] if at is not None else ()):
+            _agg_cost(sub, seg_arrays, cost)
+        return
+    if spec[-1] == "runs":
+        cost["blocked"] += rows
+    else:
+        cost["scatter"] += rows
+    for sub in subs:
+        if sub and sub[0] == "stats" and sub[3]:
+            cost["scatter"] += rows * agg_ops.sub_metric_scatters(
+                rows, nb, sub[4])
+            cost["sub_buckets"] += nb
 
 
 def _date_count_forms(spec):
@@ -5547,3 +5732,57 @@ def run_agg_only(query_spec, agg_spec, seg_arrays: dict, params: dict):
     canon = _canon_spec((query_spec, agg_spec), mapping)
     cparams = {_canon_param_key(k, mapping): v for k, v in params.items()}
     return _build_agg_executor(canon)(seg_arrays, cparams)
+
+
+@_instrumented_program_cache("agg", maxsize=128)
+def _build_auto_range_executor(key):
+    """The launch an `auto_date_histogram` takes first: the least and the
+    greatest value of each of `fields` among the matched live documents,
+    exact in the (hi, lo) words of the int64 planes, with their count."""
+    import jax
+
+    query_spec, fields = key
+    big = np.int32((1 << 31) - 1)
+
+    def auto_range_program(seg_arrays, params):
+        import jax.numpy as jnp
+
+        sm = emit(query_spec, seg_arrays, params)
+        ok0 = (sm.matched > 0) & (seg_arrays["live"] > 0)
+        out = {}
+        for f in fields:
+            col = seg_arrays["numeric"][f]
+            ok, hi, lo = ok0 & col["present"], col["hi"], col["lo"]
+            min_hi = jnp.min(jnp.where(ok, hi, big))
+            max_hi = jnp.max(jnp.where(ok, hi, -big - 1))
+            out[f] = (min_hi,
+                      jnp.min(jnp.where(ok & (hi == min_hi), lo, big)),
+                      max_hi,
+                      jnp.max(jnp.where(ok & (hi == max_hi), lo, -big - 1)),
+                      jnp.sum(ok.astype(jnp.int32)))
+        return out
+
+    return jax.jit(auto_range_program)
+
+
+def auto_date_range(query_spec, fields: Tuple[str, ...], seg_arrays: dict,
+                    params: dict) -> Dict[str, Optional[Tuple[int, int]]]:
+    """field -> (least, greatest) epoch ms among the documents `query_spec`
+    matches in this segment, None where none has a value: one launch and
+    one read, counted as `executor.launches` and
+    `aggs.auto_date.refine_launches`."""
+    import jax
+
+    mapping: Dict[int, int] = {}
+    canon = _canon_spec((query_spec, tuple(fields)), mapping)
+    cparams = {_canon_param_key(k, mapping): v for k, v in params.items()}
+    EXECUTOR_STATS.inc("launches")
+    AGG_STATS.inc("auto_date.refine_launches")
+    out = _build_auto_range_executor(canon)(seg_arrays, cparams)
+    with TRACER.span("device.wait", program="executor_auto_range"):
+        got = jax.device_get(out)
+
+    def i64(hi, lo):
+        return (int(hi) << 32) + int(lo) + (1 << 31)
+    return {f: (i64(v[0], v[1]), i64(v[2], v[3])) if int(v[4]) else None
+            for f, v in got.items()}

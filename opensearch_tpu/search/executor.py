@@ -441,12 +441,15 @@ class ShardSearcher:
                 sspec = C.prepare_sort(sort_specs, seg, params)
                 agg_specs = []
                 if agg_nodes:
+                    auto_ranges = _auto_date_ranges(
+                        agg_nodes, qspec, seg, ctx, params, self.device)
                     with TRACER.span("search.aggs.prepare"):
                         for i, an in enumerate(agg_nodes):
                             if an.kind == "top_hits":
                                 continue  # from this segment's top-k below
                             agg_specs.append((an.name, C.prepare_agg(
-                                an, seg, ctx, params, f"a{i}")))
+                                an, seg, ctx, params, f"a{i}",
+                                auto_range=auto_ranges.get(an.name))))
                 named_specs = []
                 for nm, nnode in named_nodes:
                     nparams: Dict[str, Any] = {}
@@ -1727,8 +1730,8 @@ def _bucket_filter(node: AggNode, bucket: dict) -> Optional[dict]:
         key = int(bucket["key"])
         # the chosen interval is in the finalized result, threaded onto the
         # bucket by _refine via the parent result's "interval"
-        interval_ms = bucket.get("_interval_ms", 1000)
-        return {"range": {field: {"gte": key, "lt": key + interval_ms}}}
+        return {"range": {field: {"gte": key, "lt": C.auto_bucket_end_ms(
+            key, bucket.get("_interval", "1s"))}}}
     if kind == "histogram":
         interval = float(body["interval"])
         return {"range": {field: {"gte": bucket["key"],
@@ -1788,10 +1791,8 @@ def _refine_complex_subs(searchers: List[ShardSearcher], body: dict,
             return
         if kind == "auto_date_histogram":
             # thread the coordinator-chosen interval to the bucket filters
-            name_to_ms = {n: ms for ms, n in C._AUTO_LADDER}
-            iv = name_to_ms.get(result.get("interval"), 1000)
             for b in buckets:
-                b["_interval_ms"] = iv
+                b["_interval"] = result.get("interval", "1s")
         for b in buckets:
             bf = _bucket_filter(node, b)
             if bf is None:
@@ -1804,7 +1805,7 @@ def _refine_complex_subs(searchers: List[ShardSearcher], body: dict,
             for s in complex_subs:
                 b[s.name] = resp["aggregations"][s.name]
         for b in buckets:
-            b.pop("_interval_ms", None)
+            b.pop("_interval", None)
         return
     if kind == "filter":
         for s in node.subs:
@@ -2179,22 +2180,38 @@ def _ordinal_buckets(node: AggNode, device_out: dict, vocab) -> dict:
     """Shared ordinal-bucket partial extraction (terms / significant_terms /
     geo grids): nonzero counts keyed by vocab + per-bucket stats tuples."""
     counts = np.asarray(device_out["counts"])
+    subs = _sub_metric_columns(node, device_out)
     buckets: dict = {}
     for o in np.nonzero(counts[: len(vocab)] > 0)[0]:
         rec: dict = {"doc_count": int(round(float(counts[o])))}
-        sub_partials = {}
-        for i, sub_node in enumerate(node.subs):
-            t = device_out.get(f"sub{i}")
-            if t is not None:
-                sums, cnts, mins, maxs, sumsq = (np.asarray(x) for x in t)
-                sub_partials[sub_node.name] = {
-                    "count": float(cnts[o]), "sum": float(sums[o]),
-                    "min": float(mins[o]), "max": float(maxs[o]),
-                    "sumsq": float(sumsq[o])}
+        sub_partials = _bucket_subs(subs, int(o))
         if sub_partials:
             rec["subs"] = sub_partials
         buckets[vocab[o]] = rec
     return buckets
+
+
+def _auto_date_ranges(agg_nodes, qspec, seg: Segment, ctx, params: dict,
+                      device) -> dict:
+    """aggregation name -> (least, greatest) epoch ms of its field among
+    the documents the query matches in `seg`, for every top-level
+    `auto_date_histogram` (its rounding follows the matched range, which
+    only the device knows): one launch before the request's own, in a
+    `search.aggs.refine` span. Empty where the request has none."""
+    fields = {}
+    for an in agg_nodes:
+        if an.kind == "auto_date_histogram":
+            f = C._resolve_agg_field(an, ctx)
+            col = seg.numeric_cols.get(f)
+            if col is not None and col.kind == "int":
+                fields[an.name] = f
+    if not fields:
+        return {}
+    C.AGG_STATS.inc("auto_date.requests", len(fields))
+    with TRACER.span("search.aggs.refine"):
+        got = C.auto_date_range(qspec, tuple(sorted(set(fields.values()))),
+                                seg.device_arrays(device), params)
+    return {name: got[f] for name, f in fields.items() if got[f]}
 
 
 def _fetch_agg_outputs(device_out):
@@ -2235,13 +2252,15 @@ def _device_agg_to_partial(node: AggNode, aspec, device_out: Optional[dict],
         if calendar is not None:
             # convert calendar bucket ids to epoch-ms keys host-side
             counts = np.asarray(device_out["counts"])
+            sub_cols = _sub_metric_columns(node, device_out)
             buckets = {}
             for j in np.nonzero(counts > 0)[0]:
-                epoch = _calendar_bucket_to_epoch_ms(min_b + int(j), calendar)
+                epoch = C.calendar_bucket_start_ms(min_b + int(j), calendar)
                 rec = {"doc_count": int(round(float(counts[j])))}
-                rec["subs"] = _bucket_subs(node, device_out, int(j))
+                rec["subs"] = _bucket_subs(sub_cols, int(j))
                 buckets[epoch] = rec
-            return {"buckets": buckets, "interval": 1, "offset": 0.0}
+            return {"buckets": buckets, "interval": 1, "offset": 0.0,
+                    "calendar": calendar}
         return _hist_partial(node, device_out, min_b, float(interval_ms),
                              float(offset_ms))
 
@@ -2372,6 +2391,7 @@ def _device_agg_to_partial(node: AggNode, aspec, device_out: Optional[dict],
         _, prefix, infos, total, subs = aspec
         counts = np.asarray(device_out["counts"])
         nz = np.nonzero(counts[:total] > 0)[0]
+        sub_cols = _sub_metric_columns(node, device_out)
         buckets = {}
         for comb in nz:
             vals = []
@@ -2384,20 +2404,12 @@ def _device_agg_to_partial(node: AggNode, aspec, device_out: Optional[dict],
                 elif stype == "hist":
                     vals.append((min_b + o) * interval)
                 elif cal:
-                    vals.append(_calendar_bucket_to_epoch_ms(min_b + o, cal))
+                    vals.append(C.calendar_bucket_start_ms(min_b + o, cal))
                 else:
                     vals.append(int((min_b + o) * interval))
             key = tuple(reversed(vals))
             rec = {"doc_count": int(round(float(counts[comb])))}
-            sub_partials = {}
-            for i, sub_node in enumerate(node.subs):
-                t = device_out.get(f"sub{i}")
-                if t is not None:
-                    sums, cnts, mins, maxs, sumsq = (np.asarray(x) for x in t)
-                    sub_partials[sub_node.name] = {
-                        "count": float(cnts[comb]), "sum": float(sums[comb]),
-                        "min": float(mins[comb]), "max": float(maxs[comb]),
-                        "sumsq": float(sumsq[comb])}
+            sub_partials = _bucket_subs(sub_cols, int(comb))
             if sub_partials:
                 rec["subs"] = sub_partials
             buckets[key] = rec
@@ -2407,11 +2419,9 @@ def _device_agg_to_partial(node: AggNode, aspec, device_out: Optional[dict],
         if "empty" in device_out:
             return {"count": 0, "sum": 0.0, "min": float("inf"),
                     "max": float("-inf"), "sumsq": 0.0}
-        return {"count": float(np.asarray(device_out["count"])),
-                "sum": float(np.asarray(device_out["sum"])),
-                "min": float(np.asarray(device_out["min"])),
-                "max": float(np.asarray(device_out["max"])),
-                "sumsq": float(np.asarray(device_out["sumsq"]))}
+        cols = _sub_metric_arrays(device_out)
+        return {k: float(np.asarray(v).reshape(-1)[0])
+                for k, v in cols.items()}
 
     if kind == "vc_keyword":
         return {"count": float(np.asarray(device_out["count"])), "sum": 0.0,
@@ -2487,13 +2497,15 @@ def _device_agg_to_partial(node: AggNode, aspec, device_out: Optional[dict],
         return {"buckets": buckets}
 
     if kind == "auto_date_hist":
-        _, prefix, f, interval_ms, target, min_b, nb, sub_specs, _form = aspec
-        part = _hist_partial(node, device_out, min_b, float(interval_ms), 0.0)
-        # re-key to absolute epoch ms (merge coarsens across intervals)
-        part["buckets"] = {int(b * interval_ms): rec
-                           for b, rec in part["buckets"].items()}
-        part["interval_ms"] = int(interval_ms)
-        return part
+        (_, prefix, f, unit, target, min_b, nb, window, sub_specs,
+         _form) = aspec
+        first = min_b + int(np.asarray(device_out["first"]))
+        part = _hist_partial(node, device_out, first, 1.0, 0.0)
+        # keyed by the unit bucket's start in epoch ms (the merge coarsens
+        # across shards' units)
+        return {"buckets": {C.auto_unit_start_ms(b, unit): rec
+                            for b, rec in part["buckets"].items()},
+                "unit": unit}
 
     if kind == "scripted":
         return _scripted_metric_partial(node, device_out, seg)
@@ -2596,49 +2608,42 @@ def _find_sub_spec(aspec, i):
     return None
 
 
-def _bucket_subs(node: AggNode, device_out: dict, j: int) -> dict:
-    subs = {}
-    for i, sub_node in enumerate(node.subs):
-        t = device_out.get(f"sub{i}")
-        if t is not None:
-            sums, cnts, mins, maxs, sumsq = (np.asarray(x) for x in t)
-            subs[sub_node.name] = {"count": float(cnts[j]), "sum": float(sums[j]),
-                                   "min": float(mins[j]), "max": float(maxs[j]),
-                                   "sumsq": float(sumsq[j])}
-    return subs
+def _sub_metric_arrays(out: dict) -> dict:
+    """A metric's device output (`ops.aggs.bucketed_sub_metric` /
+    `stats_agg`, read to the host) -> {"count", "sum", "min", "max",
+    "sumsq"} as numpy: the limb sums finished in float64."""
+    inv = float(np.asarray(out["scale"]))
+    cols = {"count": np.asarray(out["count"]),
+            "sum": C.agg_ops.limb_sums_to_f64(out["sum"], inv),
+            "min": np.asarray(out["min"]), "max": np.asarray(out["max"])}
+    cols["sumsq"] = (C.agg_ops.limb_sums_to_f64(out["sumsq"], inv * inv)
+                     if "sumsq" in out else np.zeros_like(cols["sum"]))
+    return cols
+
+
+def _sub_metric_columns(node: AggNode, device_out: dict) -> dict:
+    """sub-aggregation name -> `_sub_metric_arrays` of a bucket
+    aggregation's `sub{i}` outputs (once a partial, not once a bucket)."""
+    return {sub_node.name: _sub_metric_arrays(device_out[f"sub{i}"])
+            for i, sub_node in enumerate(node.subs)
+            if device_out.get(f"sub{i}") is not None}
+
+
+def _bucket_subs(sub_cols: dict, j: int) -> dict:
+    return {name: {k: float(v[j]) for k, v in cols.items()}
+            for name, cols in sub_cols.items()}
 
 
 def _hist_partial(node: AggNode, device_out: dict, min_b: int, interval: float,
                   offset: float) -> dict:
     counts = np.asarray(device_out["counts"])
+    sub_cols = _sub_metric_columns(node, device_out)
     buckets = {}
     for j in np.nonzero(counts > 0)[0]:
         rec = {"doc_count": int(round(float(counts[j])))}
-        rec["subs"] = _bucket_subs(node, device_out, int(j))
+        rec["subs"] = _bucket_subs(sub_cols, int(j))
         buckets[min_b + int(j)] = rec
     return {"buckets": buckets, "interval": interval, "offset": offset}
-
-
-def _calendar_bucket_to_epoch_ms(b: int, calendar: str) -> int:
-    import datetime as dt
-
-    if calendar in ("month", "1M"):
-        y, m = 1970 + b // 12, b % 12 + 1
-        return int(dt.datetime(y, m, 1, tzinfo=dt.timezone.utc).timestamp() * 1000)
-    if calendar in ("year", "1y"):
-        return int(dt.datetime(1970 + b, 1, 1, tzinfo=dt.timezone.utc).timestamp() * 1000)
-    if calendar in ("quarter", "1q"):
-        y, q = 1970 + b // 4, b % 4
-        return int(dt.datetime(y, q * 3 + 1, 1, tzinfo=dt.timezone.utc).timestamp() * 1000)
-    if calendar in ("week", "1w"):
-        return (b * 7 - 3) * 86400000
-    if calendar in ("day", "1d"):
-        return b * 86400000
-    if calendar in ("hour", "1h"):
-        return b * 3600000
-    if calendar in ("minute", "1m"):
-        return b * 60000
-    raise ValueError(calendar)
 
 
 # =====================================================================

@@ -177,14 +177,14 @@ def _bare(kind: str, form: str = "scatter"):
                   "keyword": {"f": {"ords": zeros_i,
                                     "doc_of_value": jnp.arange(
                                         BIG, dtype=jnp.int32)}}}
-    params = {"a0_dbuckets": zeros_i,
+    params = {"a0_dbuckets": zeros_i, "a0_dfirst": np.int32(0),
               "a0_dstarts": jnp.asarray([0, BIG, BIG, BIG, BIG], jnp.int32),
               "a0_lows": np.asarray([-1.0, 5.0], np.float32),
               "a0_highs": np.asarray([5.0, 9.0], np.float32)}
     spec = {"date_hist": ("date_hist", "a0", "f", 3600000, 0, None, 0, 4, (),
                           form),
-            "auto_date_hist": ("auto_date_hist", "a0", "f", 3600000, 10, 0,
-                               4, (), form),
+            "auto_date_hist": ("auto_date_hist", "a0", "f", 2, 10, 0,
+                               4, 4, (), form),
             "hist": ("hist", "a0", "f", 10.0, 0.0, 0, 4, ()),
             "terms": ("terms", "a0", "f", 16, ()),
             "range": ("range", "a0", "f", ("lo", "hi"), True, (),
