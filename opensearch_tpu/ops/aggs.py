@@ -7,15 +7,22 @@ All kernels take `match` — the query's dense f32 0/1 match vector (already
 live-masked) — so aggregations run in the same jitted program as scoring and
 XLA fuses the mask with the reduction.
 
-Two forms count documents per bucket. `bucket_counts` is a scatter-add of
-one update a row and serves ids in any order (`terms_counts`, `hist`,
-`geo_grid`, `composite`, `multi_terms`, `ord_counts`, a date histogram
-over a segment whose timestamps are out of order). `run_counts` serves a
-plane whose ids are non-decreasing in row order (a date histogram over an
-append-only log segment): each bucket is one run of rows, so a count is a
-difference of two prefix sums of the weights, read at the runs' boundaries.
-`search/compiler._date_bucket_plane` observes the order once a plane and
-`prepare_agg` selects the form.
+Three forms reduce rows per bucket, and the sizes choose among them.
+Ids in any order (`terms_counts`, `hist`, `geo_grid`, `composite`,
+`multi_terms`, `ord_counts`, a date histogram over a segment whose
+timestamps are out of order) take one of two: where the buckets are few
+(`dense_buckets(nbuckets)`, a static shape) the dense form compares each
+block of rows against every bucket id while the block is on the chip and
+adds, or takes the minimum / maximum, into that block's partial
+accumulators (rows x buckets lane operations and no update a row); at or
+over that many buckets a scatter issues one update a row, which the TPU
+runs one after another (8.7 ns each). Both serve `bucket_counts`,
+`bucket_sums_exact` and `bucketed_sub_metric` and give equal arrays.
+`run_counts` serves a plane whose ids are non-decreasing in row order (a
+date histogram over an append-only log segment): each bucket is one run of
+rows, so a count is a difference of two prefix sums of the weights, read at
+the runs' boundaries. `search/compiler._date_bucket_plane` observes the
+order once a plane and `prepare_agg` selects that form.
 """
 
 from __future__ import annotations
@@ -35,16 +42,99 @@ def _gather_match(match: jnp.ndarray, docs: jnp.ndarray) -> jnp.ndarray:
     return jnp.where(docs < match.shape[0], match[safe], 0.0)
 
 
+# buckets under which a per-bucket reduction takes the dense form. Its cost
+# is rows x buckets where the scatter's is rows x 8.7 ns, so the crossover
+# is a property of the chip. On a v5e at 33,554,432 rows (PERF.md, PR 33)
+# a count reads 4.4 / 11.3 / 24.2 / 49.7 / 141.9 ms at 101 / 366 / 1,023 /
+# 2,048 / 4,096 buckets where the scatter reads 295 (225 from 2,048 on),
+# and a metric's six accumulators 17 / 60 / 134 / 262 / 773 where six
+# scatters read 1,340-1,770: the forms cross near 6,000 buckets, and the
+# constant stands where the dense form still wins four times over
+_DENSE_BUCKETS = 2048
+
+
+def dense_buckets(nbuckets: int) -> bool:
+    """Whether a reduction into `nbuckets` buckets takes the dense form
+    (compare a block of rows against every bucket) and not a scatter: the
+    one predicate `bucket_counts`, `bucket_sums_exact` and
+    `bucketed_sub_metric` choose by, and `compiler._agg_cost` counts by."""
+    return nbuckets < _DENSE_BUCKETS
+
+
+def _held_ids(bucket_ids: jnp.ndarray, w: jnp.ndarray,
+              nbuckets: int) -> jnp.ndarray:
+    """Bucket ids with `nbuckets` where the row does not count: `w` is 0 or
+    the id lies outside [0, nbuckets)."""
+    ok = (w > 0) & (bucket_ids >= 0) & (bucket_ids < nbuckets)
+    return jnp.where(ok, bucket_ids, nbuckets)
+
+
+# rows a block of the dense form where nothing else cuts the rows (the sums
+# are cut by `sum_limb_plan`): what the probe ran, and 128 KiB a plane of
+# the chip's on-chip memory
+_DENSE_BLOCK = 1 << 15
+
+
+def _dense_reduce(held: jnp.ndarray, nbuckets: int, rows: int,
+                  v: Optional[jnp.ndarray] = None, parts=None,
+                  extremes: bool = False) -> tuple:
+    """The dense form: for every block of `rows` rows and every bucket, the
+    count of the rows of `held` (`_held_ids`) in it; the sum over them of
+    each int32 plane that `parts(block of v, block of rows that count)`
+    makes of the block's values (a sum's limbs: made a block at a time,
+    they never exist as whole planes); with `extremes` the least and the
+    greatest of `v` among them -> i32[blocks, nbuckets] each, the extremes
+    f32 (an empty bucket reads 0, `F32_MAX`, `-F32_MAX`). A loop over the
+    blocks: each is read from HBM once, laid along the lanes and compared
+    against all bucket ids at once, and every accumulator is a reduction
+    over that one comparison, so the [buckets, rows] one-hot exists a
+    block at a time, inside a fusion. The planes enter as
+    [blocks, R, 128], the 1-D plane's own tiling on the TPU: a view,
+    where [blocks, rows] is a relayout of each plane (PERF.md, PR 29,
+    PR 31 and PR 33). More than one block needs `rows` in whole tiles;
+    the tail is padded with rows that count nowhere."""
+    n = held.shape[0]
+    nblk = max(-(-n // rows), 1)
+    per = -(-rows // 128) * 128
+    assert nblk == 1 or per == rows, (n, rows)
+
+    def blocks(x, fill):
+        if nblk * per != n:
+            x = jnp.pad(x, (0, nblk * per - n), constant_values=fill)
+        return x.reshape(nblk, per // 128, 128)
+
+    ids = jnp.arange(nbuckets, dtype=jnp.int32)[:, None]
+
+    def one(block):
+        t, vals = block
+        hot = t.reshape(1, per) == ids
+        out = [jnp.sum(hot.astype(jnp.int32), axis=1)]
+        if parts is not None:
+            out += [jnp.sum(jnp.where(hot, a.reshape(1, per), 0), axis=1)
+                    for a in parts(vals, t < nbuckets)]
+        if extremes:
+            vals = vals.reshape(1, per)
+            out += [jnp.min(jnp.where(hot, vals, F32_MAX), axis=1),
+                    jnp.max(jnp.where(hot, vals, -F32_MAX), axis=1)]
+        return tuple(out)
+
+    return jax.lax.map(one, (blocks(held, nbuckets),
+                             None if v is None else blocks(v, 0.0)))
+
+
 def bucket_counts(bucket_ids: jnp.ndarray, w: jnp.ndarray,
                   nbuckets: int) -> jnp.ndarray:
     """Documents per bucket, i32[nbuckets]: `w` is a 0/1 weight per row and
     ids outside [0, nbuckets) are dropped. Counts accumulate in int32: a
     float32 count stops at 2^24 = 16,777,216, and one bucket of a large
-    segment can hold more. The form for ids in any order: one scatter
-    update a row, which the TPU runs one after another (8.7 ns each);
-    ids that are sorted by row take `run_counts`."""
-    return jnp.zeros(nbuckets, jnp.int32).at[bucket_ids].add(
-        (w > 0).astype(jnp.int32), mode="drop")
+    segment can hold more. The forms for ids in any order (the module's
+    docstring): dense under `_DENSE_BUCKETS` buckets, else one scatter
+    update a row; ids that are sorted by row take `run_counts`."""
+    held = _held_ids(bucket_ids, w, nbuckets)
+    if dense_buckets(nbuckets):
+        rows = max(min(held.shape[0], _DENSE_BLOCK), 1)
+        return jnp.sum(_dense_reduce(held, nbuckets, rows)[0], axis=0)
+    return jnp.zeros(nbuckets, jnp.int32).at[held].add(1, mode="drop")
 
 
 # rows a block of `run_counts` (the probe on the chip read 512 to 4,096
@@ -145,8 +235,8 @@ def sum_scale_inv(max_abs: float) -> np.float32:
 
 def _limbs(v: jnp.ndarray, w: jnp.ndarray, inv, limbs: int, bits: int):
     """The signed int32 limbs of `v * inv` (|v * inv| < 1), most
-    significant first; rows with `w` 0 give zeros. Every step is exact in
-    float32 but the last limb's rounding."""
+    significant first; rows with `w` 0 (or false) give zeros. Every step
+    is exact in float32 but the last limb's rounding."""
     x = jnp.abs(v) * inv
     sign = jnp.where(w > 0, jnp.where(v < 0, -1, 1), 0).astype(jnp.int32)
     out = []
@@ -167,23 +257,38 @@ def _fold_blocks(acc: jnp.ndarray) -> list:
     return [jnp.sum(acc >> 16, axis=0), jnp.sum(acc & 0xFFFF, axis=0)]
 
 
+def _scatter_block_sums(held: jnp.ndarray, planes: list, nbuckets: int,
+                        rows: int) -> list:
+    """The scatter form of `_dense_reduce`'s sums: each int32 plane added
+    into i32[blocks, nbuckets] by one scatter keyed by (block of `rows`
+    rows, bucket)."""
+    n = held.shape[0]
+    nblk = -(-n // rows)
+    blk = jnp.arange(n, dtype=jnp.int32) // rows
+    ids = jnp.where(held < nbuckets, blk * nbuckets + held, nblk * nbuckets)
+    return [jnp.zeros(nblk * nbuckets, jnp.int32).at[ids].add(
+        plane, mode="drop").reshape(nblk, nbuckets) for plane in planes]
+
+
+def _folded(accs: list) -> jnp.ndarray:
+    """A sum's limbs as block sums -> i32[2 x limbs, nbuckets]."""
+    return jnp.stack([half for acc in accs for half in _fold_blocks(acc)])
+
+
 def bucket_sums_exact(bucket_ids: jnp.ndarray, v: jnp.ndarray,
                       w: jnp.ndarray, nbuckets: int, inv) -> jnp.ndarray:
     """Per-bucket sums of `v` over the rows with `w` > 0 and an id in
     [0, nbuckets), as i32[2 x limbs, nbuckets] for `limb_sums_to_f64`:
-    one int32 scatter-add a limb, keyed by (block of rows, bucket)."""
-    n = bucket_ids.shape[0]
-    limbs, bits, rows = sum_limb_plan(n, nbuckets)
-    nblk = -(-n // rows)
-    blk = jnp.arange(n, dtype=jnp.int32) // rows
-    ok = (w > 0) & (bucket_ids >= 0) & (bucket_ids < nbuckets)
-    ids = jnp.where(ok, blk * nbuckets + bucket_ids, nblk * nbuckets)
-    out = []
-    for limb in _limbs(v, w, inv, limbs, bits):
-        acc = jnp.zeros(nblk * nbuckets, jnp.int32).at[ids].add(
-            limb, mode="drop")
-        out += _fold_blocks(acc.reshape(nblk, nbuckets))
-    return jnp.stack(out)
+    each limb summed in int32 by (block of rows, bucket), densely or by
+    one scatter-add a limb (`dense_buckets`)."""
+    limbs, bits, rows = sum_limb_plan(bucket_ids.shape[0], nbuckets)
+    held = _held_ids(bucket_ids, w, nbuckets)
+    if dense_buckets(nbuckets):
+        return _folded(_dense_reduce(
+            held, nbuckets, rows, v,
+            lambda vb, ok: _limbs(vb, ok, inv, limbs, bits))[1:])
+    return _folded(_scatter_block_sums(
+        held, _limbs(v, w, inv, limbs, bits), nbuckets, rows))
 
 
 def sums_exact(v: jnp.ndarray, w: jnp.ndarray, inv) -> jnp.ndarray:
@@ -215,8 +320,9 @@ def limb_sums_to_f64(parts: np.ndarray, inv) -> np.ndarray:
 
 
 def sub_metric_scatters(n: int, nbuckets: int, sumsq: bool) -> int:
-    """Scatters `bucketed_sub_metric` issues for `n` rows: the count, the
-    minimum, the maximum and a limb each of the sum (and of the squares)."""
+    """Scatters `bucketed_sub_metric` issues for `n` rows where it
+    scatters (`dense_buckets` false): the count, the minimum, the maximum
+    and a limb each of the sum (and of the squares)."""
     limbs = sum_limb_plan(n, nbuckets)[0]
     return 3 + limbs * (2 if sumsq else 1)
 
@@ -227,23 +333,38 @@ def bucketed_sub_metric(bucket_ids: jnp.ndarray, v: jnp.ndarray,
     """count / min / max / sum (and the sum of squares where `sumsq`) of
     `v` per bucket over the rows with `w` > 0: counts in int32, extremes as
     they are stored, sums in limbs (`bucket_sums_exact`). `inv` is
-    `sum_scale_inv` of the column, handed back as `scale` for the host."""
+    `sum_scale_inv` of the column, handed back as `scale` for the host.
+    Under `_DENSE_BUCKETS` buckets every accumulator is a reduction over
+    one comparison of the rows with the bucket ids; else each is a
+    scatter."""
     # the scope names these ops in the device trace (an op's provenance:
     # the benchmark's `agg_bucketed_sub_share` sums their time)
     with jax.named_scope(SUB_METRIC_SCOPE):
-        b = jnp.where(w > 0, bucket_ids, nbuckets)
-        out = {"count": bucket_counts(b, w, nbuckets),
-               "min": jnp.full(nbuckets, F32_MAX).at[b].min(
-                   jnp.where(w > 0, v, F32_MAX), mode="drop"),
-               "max": jnp.full(nbuckets, -F32_MAX).at[b].max(
-                   jnp.where(w > 0, v, -F32_MAX), mode="drop"),
-               "sum": bucket_sums_exact(b, v, w, nbuckets, inv),
-               "scale": inv}
+        b = _held_ids(bucket_ids, w, nbuckets)
+        limbs, bits, rows = sum_limb_plan(b.shape[0], nbuckets)
+
+        def parts(v, w):
+            out = _limbs(v, w, inv, limbs, bits)
+            if sumsq:
+                # a square is rounded once to float32 (2^-24 of itself,
+                # the same for a bucket of any size), then summed exactly
+                out += _limbs(v * v, w, inv * inv, limbs, bits)
+            return out
+
+        if dense_buckets(nbuckets):     # one pass for every accumulator
+            count, *accs, lo, hi = _dense_reduce(b, nbuckets, rows, v,
+                                                 parts, extremes=True)
+            count = jnp.sum(count, axis=0)
+            lo, hi = jnp.min(lo, axis=0), jnp.max(hi, axis=0)
+        else:
+            count = bucket_counts(b, w, nbuckets)
+            lo = jnp.full(nbuckets, F32_MAX).at[b].min(v, mode="drop")
+            hi = jnp.full(nbuckets, -F32_MAX).at[b].max(v, mode="drop")
+            accs = _scatter_block_sums(b, parts(v, w), nbuckets, rows)
+        out = {"count": count, "min": lo, "max": hi,
+               "sum": _folded(accs[:limbs]), "scale": inv}
         if sumsq:
-            # a square is rounded once to float32 (2^-24 of itself, the
-            # same for a bucket of any size) and then summed exactly
-            out["sumsq"] = bucket_sums_exact(b, v * v, w, nbuckets,
-                                             inv * inv)
+            out["sumsq"] = _folded(accs[limbs:])
     return out
 
 

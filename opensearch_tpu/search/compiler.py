@@ -74,7 +74,7 @@ _JIT_FAMILIES = ("executor", "mask", "gather", "agg", "rescore", "join")
 # `topk_keys_sorted`: the keys a launch's top-k hands to `lax.top_k`;
 # `agg_bucket_launches`: the date-histogram bucket counts the launches
 # carried, `agg_run_counted`: those of them whose plane is in row order and
-# took `ops.aggs.run_counts` (the others scatter-add)
+# took `ops.aggs.run_counts` (the others take `ops.aggs.bucket_counts`)
 EXECUTOR_STATS = CounterGroup(METRICS, "executor", {"params_h2d_bytes": 0,
                                                     "topk_keys_sorted": 0,
                                                     "agg_bucket_launches": 0,
@@ -84,8 +84,11 @@ EXECUTOR_STATS = CounterGroup(METRICS, "executor", {"params_h2d_bytes": 0,
 # the static spec (`_count_launch`): `scatter.updates` the rows handed to
 # every scatter (a bucket count by `ops.aggs.bucket_counts`, and each
 # scatter of a bucketed sub-metric: count, minimum, maximum and a limb a
-# sum); `blocked.rows` the rows a form that replaces a scatter reads
-# (`ops.aggs.run_counts`); `bucketed_sub.launches` / `.buckets` the
+# sum), which is what a reduction into `ops.aggs.dense_buckets` buckets
+# or more takes; `blocked.rows` the rows a form that replaces a scatter
+# reads, rows x passes over them (`ops.aggs.run_counts`, and under that
+# many buckets the dense form: one pass a bucket count, one for all of a
+# sub-metric's accumulators); `bucketed_sub.launches` / `.buckets` the
 # launches that carry a metric under a bucket aggregation, and their
 # buckets; `auto_date.requests` the top-level auto_date_histograms a
 # segment was asked, `auto_date.refine_launches` the launches taken first
@@ -5126,9 +5129,10 @@ def _date_bucket_counts(jnp, params: dict, prefix: str, match, nb: int,
                         form: str, first=None, window: Optional[int] = None):
     """A date histogram's counts over its resident bucket plane: ->
     (counts i32[nb], per-row bucket ids with `nb` where the row does not
-    count, for the sub-aggregations' scatters). A row counts where it
-    matches and has a value; `form` "runs" reads the counts at the runs'
-    boundaries (`ops.aggs.run_counts`), "scatter" adds a row at a time.
+    count, for the sub-aggregations). A row counts where it matches and
+    has a value; `form` "runs" reads the counts at the runs' boundaries
+    (`ops.aggs.run_counts`), "scatter" takes `ops.aggs.bucket_counts`,
+    whose bucket count chooses between its dense form and a scatter-add.
     With `first` (a traced scalar) and `window` the counts are those of
     the plane's buckets [first, first + window) alone, i32[window]
     (`auto_date_histogram`: the plane spans the column, the response a few
@@ -5607,9 +5611,10 @@ _AGG_CONTAINER_SUBS = {"filter": 3, "filters": 3, "global": 2, "missing": 4,
 
 def _agg_cost(spec, seg_arrays: dict, cost: dict) -> None:
     """What `emit_agg` builds for `spec`, reckoned from the spec alone (the
-    walk mirrors it): rows handed to scatters, rows read by `run_counts`,
-    buckets that carry a metric sub-aggregation. Kinds that scatter
-    nothing per row of the segment add nothing."""
+    walk mirrors it): rows handed to scatters, rows read by `run_counts`
+    and by the dense form (`ops.aggs.dense_buckets`, the predicate the
+    emit chooses by), buckets that carry a metric sub-aggregation. Kinds
+    that reduce nothing per row of the segment add nothing."""
     if not isinstance(spec, tuple) or not spec:
         return
     kind = spec[0]
@@ -5635,14 +5640,18 @@ def _agg_cost(spec, seg_arrays: dict, cost: dict) -> None:
         for sub in (spec[at] if at is not None else ()):
             _agg_cost(sub, seg_arrays, cost)
         return
-    if spec[-1] == "runs":
+    dense = agg_ops.dense_buckets(nb)
+    if spec[-1] == "runs" or dense:
         cost["blocked"] += rows
     else:
         cost["scatter"] += rows
     for sub in subs:
         if sub and sub[0] == "stats" and sub[3]:
-            cost["scatter"] += rows * agg_ops.sub_metric_scatters(
-                rows, nb, sub[4])
+            if dense:
+                cost["blocked"] += rows
+            else:
+                cost["scatter"] += rows * agg_ops.sub_metric_scatters(
+                    rows, nb, sub[4])
             cost["sub_buckets"] += nb
 
 

@@ -1,0 +1,273 @@
+"""The two forms of a per-bucket reduction over ids in any order
+(`ops.aggs`: dense under `_DENSE_BUCKETS` buckets, else a scatter) give
+equal arrays, dtypes included, for `bucket_counts`, `bucket_sums_exact` and
+`bucketed_sub_metric`; the constant alone chooses; and `compiler._agg_cost`
+counts `aggs.blocked.rows` / `aggs.scatter.updates` by the predicate the
+emit chooses by. A test steers the form by moving the constant (the program
+has no option for it)."""
+
+import numpy as np
+import pytest
+
+import jax
+
+from opensearch_tpu.ops import aggs as agg_ops
+from opensearch_tpu.search import compiler as C
+
+DENSE, SCATTER = 1 << 30, 0
+
+
+def _both(monkeypatch, fn, *args):
+    """`fn(*args)` jitted under each form -> (dense, scatter) as numpy."""
+    out = []
+    for constant in (DENSE, SCATTER):
+        monkeypatch.setattr(agg_ops, "_DENSE_BUCKETS", constant)
+        got = jax.jit(fn)(*args)            # a fresh jit: traced anew
+        out.append(jax.tree_util.tree_map(np.asarray, got))
+    return out
+
+
+def _same(a, b):
+    la, lb = jax.tree_util.tree_leaves(a), jax.tree_util.tree_leaves(b)
+    assert len(la) == len(lb)
+    for x, y in zip(la, lb):
+        assert x.dtype == y.dtype and x.shape == y.shape, (x.dtype, y.dtype)
+        assert np.array_equal(x, y), (x, y)
+
+
+def _rows(n, nb, seed, out_of_range=True, w_zero=0.2):
+    """ids with some equal to `nb`, beyond it and below 0; values of both
+    signs in hundredths; weights with zeros."""
+    rng = np.random.default_rng(seed)
+    lo, hi = (-2, nb + 3) if out_of_range else (0, nb)
+    b = rng.integers(lo, hi, n).astype(np.int32)
+    v = np.round(rng.normal(3.0, 40.0, n), 2).astype(np.float32)
+    w = (rng.random(n) >= w_zero).astype(np.float32)
+    inv = agg_ops.sum_scale_inv(float(np.abs(v).max()) if n else 1.0)
+    return b, v, w, inv
+
+
+# rows that a block divides and rows that it does not, one row, fewer rows
+# than a tile of 128, more than one block of the dense count's 32,768
+SIZES = [(1, 1), (16, 3), (1000, 7), (4096, 101), (4097, 64), (70001, 366),
+         (131072, 256)]
+
+
+@pytest.mark.parametrize("n,nb", SIZES)
+def test_bucket_counts_forms_agree(monkeypatch, n, nb):
+    b, _v, w, _inv = _rows(n, nb, n + nb)
+    dense, scatter = _both(
+        monkeypatch, lambda b, w: agg_ops.bucket_counts(b, w, nb), b, w)
+    _same(dense, scatter)
+    ok = (w > 0) & (b >= 0) & (b < nb)
+    assert dense.dtype == np.int32 and dense.shape == (nb,)
+    assert np.array_equal(dense, np.bincount(b[ok], minlength=nb))
+
+
+@pytest.mark.parametrize("n,nb", SIZES)
+def test_bucket_sums_exact_forms_agree(monkeypatch, n, nb):
+    b, v, w, inv = _rows(n, nb, 3 * n + nb)
+    dense, scatter = _both(
+        monkeypatch,
+        lambda b, v, w: agg_ops.bucket_sums_exact(b, v, w, nb, inv), b, v, w)
+    _same(dense, scatter)
+    limbs = agg_ops.sum_limb_plan(n, nb)[0]
+    assert dense.dtype == np.int32 and dense.shape == (2 * limbs, nb)
+    ok = (w > 0) & (b >= 0) & (b < nb)
+    want = np.bincount(b[ok], weights=v[ok].astype(np.float64), minlength=nb)
+    got = agg_ops.limb_sums_to_f64(dense, inv)
+    assert np.allclose(got, want, rtol=0, atol=1e-9 * max(n, 1))
+
+
+@pytest.mark.parametrize("sumsq", [False, True])
+@pytest.mark.parametrize("n,nb", SIZES)
+def test_bucketed_sub_metric_forms_agree(monkeypatch, n, nb, sumsq):
+    b, v, w, inv = _rows(n, nb, 5 * n + nb)
+    dense, scatter = _both(
+        monkeypatch,
+        lambda b, v, w: agg_ops.bucketed_sub_metric(b, v, w, nb, inv, sumsq),
+        b, v, w)
+    assert set(dense) == {"count", "min", "max", "sum", "scale"} | (
+        {"sumsq"} if sumsq else set())
+    _same(dense, scatter)
+    assert dense["count"].dtype == np.int32
+    assert dense["min"].dtype == dense["max"].dtype == np.float32
+    ok = (w > 0) & (b >= 0) & (b < nb)
+    for k in range(nb):
+        vals = v[ok & (b == k)]
+        assert dense["count"][k] == vals.size
+        if vals.size:
+            assert dense["min"][k] == vals.min()
+            assert dense["max"][k] == vals.max()
+
+
+def test_buckets_left_empty_read_the_identities(monkeypatch):
+    """Every row in bucket 2 of 5, or nowhere: the others read count 0,
+    `F32_MAX` / `-F32_MAX` and a zero sum in both forms."""
+    n, nb = 3000, 5
+    _b, v, w, inv = _rows(n, nb, 11)
+    b = np.where(np.arange(n) % 3 == 0, nb, 2).astype(np.int32)
+    dense, scatter = _both(
+        monkeypatch,
+        lambda b, v, w: agg_ops.bucketed_sub_metric(b, v, w, nb, inv, True),
+        b, v, w)
+    _same(dense, scatter)
+    empty = np.arange(nb) != 2
+    assert (dense["count"][empty] == 0).all() and dense["count"][2] > 0
+    assert (dense["min"][empty] == agg_ops.F32_MAX).all()
+    assert (dense["max"][empty] == -agg_ops.F32_MAX).all()
+    assert (dense["sum"][:, empty] == 0).all()
+    assert (dense["sumsq"][:, empty] == 0).all()
+
+
+def test_no_row_counts(monkeypatch):
+    n, nb = 500, 9
+    b, v, _w, inv = _rows(n, nb, 12)
+    w = np.zeros(n, np.float32)
+    dense, scatter = _both(
+        monkeypatch,
+        lambda b, v, w: agg_ops.bucketed_sub_metric(b, v, w, nb, inv, False),
+        b, v, w)
+    _same(dense, scatter)
+    assert not dense["count"].any() and not dense["sum"].any()
+
+
+@pytest.mark.parametrize("sign", [1.0, -1.0])
+def test_limb_partials_near_their_bound(monkeypatch, sign):
+    """One bucket holds every row and every value is the column's largest
+    magnitude just under a power of two, so each 16-bit limb is 0xFFFF and a
+    block's partial sum (rows x 65,535) stands at its int32 bound in
+    miniature: more than one block (the plan is moved to blocks of 1,024
+    rows), a tail block that is part empty, either sign. Equal in both
+    forms and exact against float64."""
+    monkeypatch.setattr(agg_ops, "_LIMB_BITS", {3: 16})
+    monkeypatch.setattr(
+        agg_ops, "sum_limb_plan", lambda n, nb: (3, 16, min(1024, max(n, 1))))
+    n, nb = 5 * 1024 + 300, 4
+    top = np.float32(sign * (2.0 - 2.0 ** -23))     # 24 ones: 0xFFFF, 0xFF00
+    v = np.full(n, top, np.float32)
+    b = np.full(n, 1, np.int32)
+    w = np.ones(n, np.float32)
+    inv = agg_ops.sum_scale_inv(float(abs(top)))
+    dense, scatter = _both(
+        monkeypatch,
+        lambda b, v, w: agg_ops.bucket_sums_exact(b, v, w, nb, inv), b, v, w)
+    _same(dense, scatter)
+    got = agg_ops.limb_sums_to_f64(dense, inv)
+    assert got[1] == float(top) * n and not got[[0, 2, 3]].any()
+    # the first limb's block sums really are rows x 0xFFFF
+    whole = dense[0].astype(np.int64) * 65536 + dense[1]
+    assert abs(whole[1]) == n * 0xFFFF
+
+
+def test_a_bucket_of_more_than_2_to_24_rows_worth_of_weight(monkeypatch):
+    """A count past float32's 2^24 in miniature: the dense count sums 0/1
+    in int32 over blocks, so 40,000 rows of one bucket read 40,000 whatever
+    the block, as the scatter reads them (a float32 accumulator is what PR 32
+    removed; here the check is that no block's partial is narrowed)."""
+    n, nb = 40_000, 3
+    b = np.zeros(n, np.int32)
+    w = np.ones(n, np.float32)
+    monkeypatch.setattr(agg_ops, "_DENSE_BLOCK", 1 << 10)
+    dense, scatter = _both(
+        monkeypatch, lambda b, w: agg_ops.bucket_counts(b, w, nb), b, w)
+    _same(dense, scatter)
+    assert dense.tolist() == [n, 0, 0]
+
+
+@pytest.mark.parametrize("nb", [1, 101, 366])
+def test_under_the_constant_no_scatter_is_built(nb):
+    """The form is chosen by `nbuckets` against the one constant: under it
+    the jaxpr of all three entries holds no scatter, at it they all do."""
+    assert agg_ops.dense_buckets(nb)
+    assert agg_ops.dense_buckets(agg_ops._DENSE_BUCKETS - 1)
+    assert not agg_ops.dense_buckets(agg_ops._DENSE_BUCKETS)
+    b, v, w, inv = _rows(2048, nb, 1)
+
+    def all_three(nb):
+        def fn(b, v, w):
+            return (agg_ops.bucket_counts(b, w, nb),
+                    agg_ops.bucket_sums_exact(b, v, w, nb, inv),
+                    agg_ops.bucketed_sub_metric(b, v, w, nb, inv, True))
+        return str(jax.make_jaxpr(fn)(b, v, w))
+    assert "scatter" not in all_three(nb)
+    assert all_three(agg_ops._DENSE_BUCKETS).count("scatter") >= 3
+
+
+def test_the_dense_ops_carry_the_sub_metric_scope():
+    b, v, w, inv = _rows(2048, 16, 2)
+    text = jax.jit(
+        lambda b, v, w: agg_ops.bucketed_sub_metric(b, v, w, 16, inv, False)
+    ).lower(b, v, w).as_text(debug_info=True)
+    # the loop over the blocks and what runs inside it are under the scope
+    assert agg_ops.SUB_METRIC_SCOPE + "/while/body" in text
+    assert "stablehlo.scatter" not in text
+
+
+# ---------------------------------------------------------------------
+# `_agg_cost` counts by the predicate the emit chooses by
+# ---------------------------------------------------------------------
+N = 4096
+SEG = {"live": np.zeros(N, np.float32),
+       "keyword": {"k": {"ords": np.zeros(3 * N, np.int32)}}}
+STATS = ("stats", "s0", "f", True, False)
+EXT = ("stats", "s0", "f", True, True)
+GONE = ("stats", "s0", "f", False, False)       # the column does not exist
+
+
+def _spec(kind, nb, subs, form=None):
+    if kind == "hist":
+        return ("hist", "p", "f", 1.0, 0.0, 0, nb, subs)
+    if kind == "date_hist":
+        return ("date_hist", "p", "f", 1, 0, None, 0, nb, subs, form)
+    if kind == "auto_date_hist":
+        return ("auto_date_hist", "p", "f", "d", 20, 0, 4 * nb, nb, subs,
+                form)
+    if kind == "terms":
+        return ("terms", "p", "k", nb, subs)
+    if kind == "geo_grid":
+        return ("geo_grid", "p", "geohash", "f", 5, nb, subs)
+    raise AssertionError(kind)
+
+
+def _cost(spec):
+    cost = {"scatter": 0, "blocked": 0, "sub_buckets": 0}
+    C._agg_cost(spec, SEG, cost)
+    return cost
+
+
+@pytest.mark.parametrize("kind,form,rows", [
+    ("hist", None, N), ("date_hist", "scatter", N),
+    ("auto_date_hist", "scatter", N), ("terms", None, 3 * N),
+    ("geo_grid", None, N)])
+@pytest.mark.parametrize("subs", [(), (STATS,), (EXT,), (STATS, GONE, EXT)])
+def test_agg_cost_follows_the_predicate(kind, form, rows, subs):
+    few, many = agg_ops._DENSE_BUCKETS - 1, agg_ops._DENSE_BUCKETS
+    counted = sum(1 for s in subs if s[3])
+    # under the constant: a pass for the count and one a sub-metric
+    got = _cost(_spec(kind, few, subs, form))
+    assert got == {"scatter": 0, "blocked": rows * (1 + counted),
+                   "sub_buckets": few * counted}
+    # at it: one scatter for the count and `sub_metric_scatters` a metric
+    got = _cost(_spec(kind, many, subs, form))
+    scatters = 1 + sum(agg_ops.sub_metric_scatters(rows, many, s[4])
+                       for s in subs if s[3])
+    assert got == {"scatter": rows * scatters, "blocked": 0,
+                   "sub_buckets": many * counted}
+
+
+@pytest.mark.parametrize("nb", [64, 5000])
+def test_agg_cost_of_a_run_counted_plane(nb):
+    """The count of a plane in row order is `run_counts`' one pass whatever
+    the buckets; the metric under it follows the predicate."""
+    got = _cost(_spec("date_hist", nb, (STATS,), "runs"))
+    if agg_ops.dense_buckets(nb):
+        assert got == {"scatter": 0, "blocked": 2 * N, "sub_buckets": nb}
+    else:
+        assert got == {"scatter": 6 * N, "blocked": N, "sub_buckets": nb}
+
+
+def test_agg_cost_walks_containers():
+    inner = _spec("hist", 50, (STATS,))
+    got = _cost(("filter", "p", "q", (inner, ("avg", "x"))))
+    assert got == {"scatter": 0, "blocked": 2 * N, "sub_buckets": 50}

@@ -4,7 +4,8 @@ Two families, both in this ONE file (only one process at a time may load
 the TPU's library; a second file could land on another xdist worker and
 skip in silence):
 
-* the three live Pallas kernels and `exact_rescore_batch` COMPILED for a
+* the three live Pallas kernels, `exact_rescore_batch` and the dense form
+  of `ops.aggs`' per-bucket reductions COMPILED for a
   described (not attached) `v5e:2x2` device at real buckets — what the
   compiler refuses here costs no chip time. The topology, the sharding
   and the shapes are built inside module-scoped fixtures that skip when
@@ -32,6 +33,7 @@ from opensearch_tpu.ops.pallas_bm25 import (DL_BITS, HBM_ALIGN, INT_SENTINEL,
                                             fused_bm25_bool_topk,
                                             fused_bm25_topk_impact,
                                             fused_bm25_topk_tfdl)
+from opensearch_tpu.ops import aggs as agg_ops
 from opensearch_tpu.ops.rescore import exact_rescore_batch, plane_in_vmem
 from tests.test_pruned import (sim_fused_bm25_topk_impact,
                                sim_fused_bm25_topk_tfdl)
@@ -147,6 +149,44 @@ def test_exact_rescore_compiles_for_v5e(shape_on_chip, P, QB, T, C):
         assert len(re.findall(r"\bwhile\(", text)) == T
     # the [QB, T, C] probe intermediates fit the chip beside the planes
     assert compiled.memory_analysis().temp_size_in_bytes < 4 << 30
+
+
+N_TRIPS = 1 << 25       # the trip-analytics cell's padded rows
+# a plane relaid as [blocks, rows] (a copy of it) and not viewed in tiles
+RELAID = f"[{N_TRIPS >> 15},{1 << 15}]"
+
+
+@pytest.mark.parametrize("nb,sumsq", [(101, False), (366, True),
+                                      (agg_ops._DENSE_BUCKETS - 1, False)])
+def test_dense_sub_metric_compiles_for_v5e(shape_on_chip, nb, sumsq):
+    """`bucketed_sub_metric` under `_DENSE_BUCKETS` buckets at the cell's
+    size: one loop over the blocks, no scatter, the planes viewed and not
+    relaid, and no [rows, buckets] one-hot written (33.5M x 128 x 4 bytes
+    would be 17 GB), nor the limbs' planes: the one temporary of the rows'
+    size is the held ids."""
+    S = shape_on_chip
+    rows = (N_TRIPS,)
+    compiled = jax.jit(
+        lambda b, v, w, inv: agg_ops.bucketed_sub_metric(b, v, w, nb, inv,
+                                                         sumsq)
+    ).lower(S(rows, jnp.int32), S(rows, jnp.float32), S(rows, jnp.float32),
+            S((), jnp.float32)).compile()
+    text = compiled.as_text()
+    assert len(re.findall(r"\bwhile\(", text)) == 1
+    assert "scatter(" not in text and RELAID not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 160 << 20
+
+
+@pytest.mark.parametrize("nb", [101, 256, 366])
+def test_dense_bucket_counts_compile_for_v5e(shape_on_chip, nb):
+    S = shape_on_chip
+    compiled = jax.jit(lambda b, w: agg_ops.bucket_counts(b, w, nb)).lower(
+        S((N_TRIPS,), jnp.int32), S((N_TRIPS,), jnp.float32)).compile()
+    text = compiled.as_text()
+    assert len(re.findall(r"\bwhile\(", text)) == 1
+    assert "scatter(" not in text and RELAID not in text
+    # the held ids, once: nothing of rows x buckets
+    assert compiled.memory_analysis().temp_size_in_bytes < 160 << 20
 
 
 # ---------------------------------------------------------------------
