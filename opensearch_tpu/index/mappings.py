@@ -45,9 +45,19 @@ U64_BIAS = 1 << 63
 GEO_TYPES = {"geo_point"}
 SHAPE_TYPES = {"geo_shape"}
 VECTOR_TYPES = {"dense_vector", "knn_vector"}
+# a vector field's space, by the k-NN plugin's names and this repo's, as
+# the one name the scorer knows (`compiler.emit`'s `_sim_score`)
+VECTOR_SPACES = {"l2": "l2_norm", "innerproduct": "dot_product",
+                 "cosinesimil": "cosine", "l2_norm": "l2_norm",
+                 "dot_product": "dot_product", "cosine": "cosine"}
 # feature-weight CSR fields (reference mapper-extras RankFeaturesFieldMapper;
 # sparse_vector is the same storage with learned-sparse token weights)
 FEATURE_TYPES = {"rank_features", "sparse_vector"}
+
+
+class VectorMappingError(ValueError):
+    """A vector field's space or method this engine does not have (a
+    client error: 400)."""
 
 
 @dataclass
@@ -352,19 +362,28 @@ class Mappings:
             boost=cfg.get("boost", 1.0),
             norms=cfg.get("norms", True),
             dims=int(cfg.get("dims", cfg.get("dimension", 0))),
-            vector_similarity=cfg.get("similarity",
-                                      cfg.get("space_type", "cosine")),
         )
         if ftype in VECTOR_TYPES:
             method = cfg.get("method") or cfg.get("index_options")
+            # OpenSearch 2.x carries `space_type` inside `method`; the
+            # field's own key (1.x, and this repo's `similarity`) wins
+            space = cfg.get("similarity", cfg.get(
+                "space_type", (method or {}).get("space_type", "cosine")))
+            if space not in VECTOR_SPACES:
+                raise VectorMappingError(
+                    f"unknown space_type [{space}] for field [{path}] "
+                    f"(supported: {', '.join(VECTOR_SPACES)})")
+            ft.vector_similarity = VECTOR_SPACES[space]
             if method:
+                # `engine` and HNSW's `parameters` (m, ef_construction,
+                # ef_search) ride along unread: IVF's are nlist / nprobe
                 name = method.get("name", method.get("type", "ivf"))
                 if name not in ("ivf", "flat", "exact"):
-                    raise ValueError(
+                    raise VectorMappingError(
                         f"unknown ANN method [{name}] for field [{path}] "
                         f"(supported: ivf, flat)")
                 if name == "ivf":
-                    p = method.get("parameters", method)
+                    p = method.get("parameters") or method
                     ft.vector_method = {
                         "name": "ivf",
                         "nlist": (int(p["nlist"]) if p.get("nlist") else None),

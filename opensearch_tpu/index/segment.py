@@ -515,13 +515,19 @@ class VectorColumn:
             self._normed = (self.values / np.maximum(n, 1e-12)).astype(np.float32)
         return self._normed
 
-    def ivf(self):
+    def ivf(self, mat=None):
         """Lazily built balanced-IVF index (deterministic: same data ->
-        same index, so persistence only records the method, not arrays)."""
+        same index, so persistence only records the method, not arrays).
+        `mat`: the column's resident device matrix (`device_arrays`' "mat":
+        the scored rows, padded), which the build then reads where it
+        lies; without it the host rows are put on the device for the
+        build and dropped after."""
         if self._ivf is None and self.method and self.method.get("name") == "ivf":
             from ..ops.ann import build_ivf
-            src = self.normed() if self.similarity == "cosine" else self.values
-            self._ivf = build_ivf(src, self.present,
+            if mat is None:
+                mat = self.normed() if self.similarity == "cosine" \
+                    else self.values
+            self._ivf = build_ivf(mat, self.present,
                                   nlist=self.method.get("nlist"),
                                   nprobe=self.method.get("nprobe"))
         return self._ivf
@@ -775,20 +781,24 @@ class Segment:
         for f, col in self.vector_cols.items():
             dims = col.values.shape[1]
             dpad128 = ((dims + 127) // 128) * 128  # MXU lane alignment
+            # the one padded host copy; dropped once it is on the device
             mat = np.zeros((dpad, dpad128), np.float32)
-            src = col.normed() if col.similarity == "cosine" else col.values
-            mat[: self.ndocs, :dims] = src
+            mat[: self.ndocs, :dims] = (col.normed() if col.similarity
+                                        == "cosine" else col.values)
             vcols[f] = {
                 "mat": jnp.asarray(mat),
                 "present": jnp.asarray(_pad_to(col.present, dpad, False)),
             }
-            ivf = col.ivf()
+            del mat
+            # built from the resident matrix: the vectors are on the
+            # device once, under the build as under the queries
+            ivf = col.ivf(vcols[f]["mat"])
             if ivf is not None:
                 # nlist padded pow2; padding rows are invalid (cvalid
                 # False -> -inf centroid score, lists slots -1)
                 lpad = next_pow2(ivf.nlist)
                 cent = np.zeros((lpad, dpad128), np.float32)
-                cent[: ivf.nlist, :dims] = ivf.centroids
+                cent[: ivf.nlist, : ivf.centroids.shape[1]] = ivf.centroids
                 lists = np.full((lpad, ivf.cap), -1, np.int32)
                 lists[: ivf.nlist] = ivf.lists
                 cvalid = np.zeros(lpad, bool)

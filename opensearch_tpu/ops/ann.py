@@ -23,11 +23,24 @@ in exactly one list), which the tests assert.
 
 from __future__ import annotations
 
+import time
 from dataclasses import dataclass
 from functools import partial
 from typing import Optional
 
 import numpy as np
+
+from ..utils.metrics import METRICS, CounterGroup
+
+# what the builds of the process cost and made (`build_ivf` adds at its
+# end): `build_s` wall seconds, k-means, assignment and the balanced fill;
+# `rows` the present rows filed; `spilled_rows` those that did not fit
+# their nearest list and went to the second-best (or, rarely, to any list
+# with room): a probe that would have found them in their own list has to
+# reach the other one; `nlist` / `cap` of the last build
+IVF_STATS = CounterGroup(METRICS, "ivf", {"build_s": 0.0, "rows": 0,
+                                          "spilled_rows": 0, "nlist": 0,
+                                          "cap": 0})
 
 
 @dataclass
@@ -46,14 +59,21 @@ def _next_pow2(n: int) -> int:
 _BLOCK = 8192
 
 
-def _kmeans_device(vals_b, pres_b, init, iters: int):
-    """Lloyd iterations over blocked data. vals_b: f32[nb, B, D],
-    pres_b: f32[nb, B], init: f32[nlist, D]. Returns f32[nlist, D]."""
-    import jax
+def _blocked(a):
+    """[N, ...] -> [nb, B, ...] with B the scan's block (N is a multiple of
+    it: `build_ivf` sees to that). Inside a jit this reshape moves nothing,
+    so a build reads the caller's matrix where it lies."""
+    return a.reshape(-1, min(_BLOCK, a.shape[0]), *a.shape[1:])
+
+
+def _kmeans_device(vals, pres, init, iters: int):
+    """Lloyd iterations over the rows in blocks. vals: f32[N, D],
+    pres: f32[N], init: f32[nlist, D]. Returns f32[nlist, D]."""
     import jax.numpy as jnp
     from jax import lax
 
     nlist = init.shape[0]
+    vals_b, pres_b = _blocked(vals), _blocked(pres)
 
     def one_iter(cents, _):
         csq = jnp.sum(cents * cents, axis=1)  # [nlist]
@@ -80,13 +100,14 @@ def _kmeans_device(vals_b, pres_b, init, iters: int):
     return cents
 
 
-def _assign_top2_device(vals_b, cents):
+def _assign_top2_device(vals, cents):
     """Per row: (best cluster, 2nd-best cluster, best distance).
-    vals_b: f32[nb, B, D] -> (i32[nb,B], i32[nb,B], f32[nb,B])."""
+    vals: f32[N, D] -> (i32[N], i32[N], f32[N])."""
     import jax.numpy as jnp
     from jax import lax
 
     csq = jnp.sum(cents * cents, axis=1)
+    vals_b = _blocked(vals)
 
     def block(_, v):
         d2 = csq - 2.0 * jnp.dot(v, cents.T,
@@ -98,21 +119,25 @@ def _assign_top2_device(vals_b, cents):
         return None, (a1.astype(jnp.int32), a2.astype(jnp.int32), d1)
 
     _, (a1, a2, d1) = lax.scan(block, None, vals_b)
-    return a1, a2, d1
+    return a1.reshape(-1), a2.reshape(-1), d1.reshape(-1)
 
 
-def build_ivf(values: np.ndarray, present: np.ndarray,
+def build_ivf(values, present: np.ndarray,
               nlist: Optional[int] = None, nprobe: Optional[int] = None,
               iters: int = 8, seed: int = 0, slack: float = 1.5
               ) -> Optional[IvfIndex]:
     """values: f32[N, D] — pass the SAME matrix the scorer uses (unit-normed
-    for cosine) so centroid geometry matches search geometry."""
+    for cosine) so centroid geometry matches search geometry. A host array
+    is padded and put on the device for the build; a device array (the
+    segment's resident matrix, whose row count is a power of two; rows
+    past `present` are padding) is read where it lies: the build then
+    holds no copy of the vectors of its own. `present`: bool[n], host."""
     import jax
     import jax.numpy as jnp
 
-    values = np.asarray(values, np.float32)
+    t0 = time.perf_counter()
     present = np.asarray(present, bool)
-    n = values.shape[0]
+    n = min(values.shape[0], len(present))
     pres_idx = np.nonzero(present[:n])[0]
     npres = len(pres_idx)
     if npres == 0:
@@ -122,24 +147,33 @@ def build_ivf(values: np.ndarray, present: np.ndarray,
     default_nprobe = int(min(nprobe or max(1, nlist // 8), nlist))
 
     rng = np.random.default_rng(seed)
-    init = values[rng.choice(pres_idx, nlist, replace=False)].copy()
-
-    # block + pad for the scan (padded rows carry weight 0)
-    npad = ((n + _BLOCK - 1) // _BLOCK) * _BLOCK
-    vb = np.zeros((npad, values.shape[1]), np.float32)
-    vb[:n] = values
+    chosen = rng.choice(pres_idx, nlist, replace=False)
+    if isinstance(values, np.ndarray):
+        # block + pad for the scan (padded rows carry weight 0)
+        values = np.asarray(values, np.float32)
+        npad = ((n + _BLOCK - 1) // _BLOCK) * _BLOCK if n > _BLOCK else n
+        vb = np.zeros((npad, values.shape[1]), np.float32)
+        vb[:n] = values[:n]
+        vals = jnp.asarray(vb)
+        del vb
+    else:
+        vals = values
+        npad = vals.shape[0]
+        if npad % min(_BLOCK, npad):
+            raise ValueError(f"a device matrix of {npad} rows does not "
+                             f"split into blocks of {_BLOCK}")
     pb = np.zeros(npad, np.float32)
-    pb[:n] = present[:n].astype(np.float32)
-    vb = vb.reshape(-1, _BLOCK, values.shape[1])
-    pbb = pb.reshape(-1, _BLOCK)
+    pb[:n] = present[:n]
+    init = vals[jnp.asarray(chosen)]
 
     kmeans = jax.jit(partial(_kmeans_device, iters=iters))
-    cents = kmeans(jnp.asarray(vb), jnp.asarray(pbb), jnp.asarray(init))
-    a1, a2, d1 = jax.jit(_assign_top2_device)(jnp.asarray(vb), cents)
+    cents = kmeans(vals, jnp.asarray(pb), init)
+    a1, a2, d1 = jax.jit(_assign_top2_device)(vals, cents)
+    del vals
     cents = np.asarray(cents)
-    a1 = np.asarray(a1).reshape(-1)[:n]
-    a2 = np.asarray(a2).reshape(-1)[:n]
-    d1 = np.asarray(d1).reshape(-1)[:n]
+    a1 = np.asarray(a1)[:n]
+    a2 = np.asarray(a2)[:n]
+    d1 = np.asarray(d1)[:n]
 
     # ---- balanced fill (vectorized host pass) ----
     # round 1: rows claim their primary cluster, closest-first
@@ -172,5 +206,9 @@ def build_ivf(values: np.ndarray, present: np.ndarray,
             open_slots = np.nonzero(lists.reshape(-1) == -1)[0]
             take = open_slots[: len(left)]
             lists.reshape(-1)[take] = left
+    IVF_STATS.inc("build_s", time.perf_counter() - t0)
+    IVF_STATS.inc("rows", npres)
+    IVF_STATS.inc("spilled_rows", int(len(spill)))
+    IVF_STATS["nlist"], IVF_STATS["cap"] = nlist, cap
     return IvfIndex(centroids=cents, lists=lists, nlist=nlist, cap=cap,
                     default_nprobe=default_nprobe)

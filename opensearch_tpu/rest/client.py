@@ -1739,7 +1739,7 @@ class IndicesClient:
         self.c = client
 
     def create(self, index: str, body: Optional[dict] = None) -> dict:
-        return _map_date_format_errors(self.c.node.create_index, index, body)
+        return _map_mapping_errors(self.c.node.create_index, index, body)
 
     def delete(self, index: str) -> dict:
         return _map_ds_errors(self.c.node.delete_index, index)
@@ -1772,7 +1772,7 @@ class IndicesClient:
             svc = self.c.node.indices[n]
             # mapping merge mutates structures in-flight doc parses read
             with svc.write_lock:
-                _map_date_format_errors(svc.mappings.merge, body)
+                _map_mapping_errors(svc.mappings.merge, body)
                 self.c.node._persist_meta(n)
         return {"acknowledged": True}
 
@@ -2005,12 +2005,14 @@ class SnapshotClient:
         return {"snapshots": snaps}
 
 
-def _map_date_format_errors(fn, *args):
-    """A mapping's date `format` this engine cannot read -> 400."""
+def _map_mapping_errors(fn, *args):
+    """A mapping's date `format` this engine cannot read, or a vector
+    field's space or method it does not have -> 400."""
     from ..index.date_formats import DateFormatError
+    from ..index.mappings import VectorMappingError
     try:
         return fn(*args)
-    except DateFormatError as e:
+    except (DateFormatError, VectorMappingError) as e:
         raise ApiError(400, "mapper_parsing_exception", str(e))
 
 

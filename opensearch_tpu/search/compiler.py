@@ -99,6 +99,24 @@ AGG_STATS = CounterGroup(METRICS, "aggs", {"scatter.updates": 0,
                                            "bucketed_sub.buckets": 0,
                                            "auto_date.requests": 0,
                                            "auto_date.refine_launches": 0})
+# what the `knn` nodes of the launches cost, counted at each launch from
+# the static spec (`_count_launch`): `queries` the nodes over a segment that
+# holds the field, `ann_queries` / `exact_queries` those that probe the
+# column's IVF lists / scan the whole matrix, `lists_probed` the lists a
+# probe reads (`nprobe`), `candidate_slots` the rows it gathers, scores and
+# scatters back (`nprobe * cap`, padding slots included), and
+# `query_vector_bytes` the padded query vector a node is handed
+KNN_STATS = CounterGroup(METRICS, "knn", {"queries": 0, "ann_queries": 0,
+                                          "exact_queries": 0,
+                                          "lists_probed": 0,
+                                          "candidate_slots": 0,
+                                          "query_vector_bytes": 0})
+# the precision a `knn` node's scoring product names. Unnamed, a batch of
+# queries a launch (the vmapped `msearch` twin) is ONE bfloat16 pass of the
+# chip's matrix unit, scores 4e-4 relative off where the 100th neighbour
+# stands closer than that to the 101st; one query a launch, and a CPU, are
+# float32 either way: tests_tpu/test_knn_tpu.py moves this to see both
+_KNN_SCORE_PRECISION = "highest"
 BUCKET_PLANE_STATS = CounterGroup(METRICS, "aggs.bucket_plane",
                                   {"builds": 0, "hits": 0, "bytes": 0})
 RANK_PLANE_STATS = CounterGroup(METRICS, "sort.rank_plane",
@@ -1861,6 +1879,33 @@ def _pad_to_sentinel(arr: np.ndarray, size: int) -> np.ndarray:
     return out
 
 
+def _prepare_knn(node, seg: Segment, ctx, params: dict):
+    """A `knn` node's params (the padded query vector, |q|^2, the boost),
+    its filter's spec, and the static probe width where the column has an
+    IVF index and the query did not force the exact scan."""
+    nid = node.nid
+    col_exists = node.field in seg.vector_cols
+    if col_exists:
+        dims = seg.vector_cols[node.field].values.shape[1]
+        v = np.zeros(((dims + 127) // 128) * 128, np.float32)  # lane pad
+        v[:dims] = node.vector[:dims]
+        _p(params, f"q{nid}_vec", v)
+        _scalar_f32(params, f"q{nid}_qsq", float(np.dot(node.vector, node.vector)))
+    _scalar_f32(params, f"q{nid}_boost", node.boost)
+    fspec = prepare(node.filter, seg, ctx, params) if node.filter else None
+    # ANN route: mapping opted into IVF and the query didn't force
+    # exact -> static nprobe (jit-key) clamped to this segment's nlist.
+    # Building here (host, once, cached on the column) keeps emit pure.
+    ann_nprobe = None
+    if col_exists and not node.exact:
+        ivf = seg.vector_cols[node.field].ivf()
+        if ivf is not None:
+            ann_nprobe = int(min(node.nprobe or ivf.default_nprobe,
+                                 ivf.nlist))
+    return ("knn", nid, node.field, col_exists, node.similarity, fspec,
+            ann_nprobe)
+
+
 def prepare(node: LNode, seg: Segment, ctx: ShardContext, params: dict):  # noqa: C901
     """-> hashable spec tree; fills `params` with this segment's arrays."""
     nid = node.nid
@@ -2217,27 +2262,8 @@ def prepare(node: LNode, seg: Segment, ctx: ShardContext, params: dict):  # noqa
         return ("scriptscore", nid, child_spec, node.ast, field_srcs, pkeys)
 
     if isinstance(node, LKnn):
-        col_exists = node.field in seg.vector_cols
-        if col_exists:
-            dims = seg.vector_cols[node.field].values.shape[1]
-            dpad = ((dims + 127) // 128) * 128
-            v = np.zeros(dpad, np.float32)
-            v[:dims] = node.vector[:dims]
-            _p(params, f"q{nid}_vec", v)
-            _scalar_f32(params, f"q{nid}_qsq", float(np.dot(node.vector, node.vector)))
-        _scalar_f32(params, f"q{nid}_boost", node.boost)
-        fspec = prepare(node.filter, seg, ctx, params) if node.filter else None
-        # ANN route: mapping opted into IVF and the query didn't force
-        # exact -> static nprobe (jit-key) clamped to this segment's nlist.
-        # Building here (host, once, cached on the column) keeps emit pure.
-        ann_nprobe = None
-        if col_exists and not node.exact:
-            ivf = seg.vector_cols[node.field].ivf()
-            if ivf is not None:
-                ann_nprobe = int(min(node.nprobe or ivf.default_nprobe,
-                                     ivf.nlist))
-        return ("knn", nid, node.field, col_exists, node.similarity, fspec,
-                ann_nprobe)
+        with TRACER.span("knn.prepare", field=node.field):
+            return _prepare_knn(node, seg, ctx, params)
 
     if isinstance(node, LTermsSet):
         child_spec = prepare(node.child, seg, ctx, params)
@@ -3134,6 +3160,7 @@ def emit(spec, seg_arrays: dict, params: dict) -> ops.ScoredMask:  # noqa: C901
                               matched.astype(jnp.float32))
 
     if kind == "knn":
+        import jax
         from jax import lax as _lax
         _, _, field, col_exists, simkind, fspec, ann_nprobe = spec
         if not col_exists:
@@ -3146,8 +3173,13 @@ def emit(spec, seg_arrays: dict, params: dict) -> ops.ScoredMask:  # noqa: C901
                 return (1.0 + raw) / 2.0
             if simkind in ("dot_product", "innerproduct"):
                 return jnp.where(raw > 0, raw + 1.0, 1.0 / (1.0 - raw))
-            d2 = jnp.maximum(vecs_sq + params[f"q{nid}_qsq"] - 2.0 * raw, 0.0)
+            d2 = jnp.maximum(vecs_sq() + params[f"q{nid}_qsq"] - 2.0 * raw,
+                             0.0)
             return 1.0 / (1.0 + d2)
+
+        def _product(vecs):
+            return jnp.dot(vecs, qvec, preferred_element_type=jnp.float32,
+                           precision=_KNN_SCORE_PRECISION)
 
         if ann_nprobe is not None and "ivf_centroids" in vc:
             # balanced-IVF probe (ops/ann.py): centroid matvec -> static
@@ -3155,31 +3187,40 @@ def emit(spec, seg_arrays: dict, params: dict) -> ops.ScoredMask:  # noqa: C901
             # matvec -> scatter back into doc space. Everything static-shape;
             # candidate count = nprobe*cap regardless of data.
             cents, lists = vc["ivf_centroids"], vc["ivf_lists"]
-            cdot = jnp.dot(cents, qvec, preferred_element_type=jnp.float32)
-            if simkind in ("cosine", "dot_product", "innerproduct"):
-                caff = cdot
-            else:  # l2: nearest centroid = max of 2c.q - ||c||^2
-                caff = 2.0 * cdot - jnp.sum(cents * cents, axis=1)
-            caff = jnp.where(vc["ivf_cvalid"], caff, -jnp.inf)
-            _, pids = _lax.top_k(caff, ann_nprobe)
-            cand = lists[pids].reshape(-1)            # i32[nprobe*cap]
-            valid = cand >= 0
-            cidx = jnp.where(valid, cand, ndocs_pad)  # OOB -> dropped scatter
-            vecs = vc["mat"][jnp.where(valid, cand, 0)]
-            raw = jnp.dot(vecs, qvec, preferred_element_type=jnp.float32)
-            s = _sim_score(raw, jnp.sum(vecs * vecs, axis=1))
-            s = jnp.where(valid, s, 0.0)
-            # each doc lives in exactly one list -> max==set, but max is
-            # insensitive to the padding sentinel collisions
-            score = zeros.at[cidx].max(s, mode="drop")
-            cmask = zeros.at[cidx].max(valid.astype(jnp.float32), mode="drop")
+            with jax.named_scope("knn.centroids"):
+                # default precision: this product only chooses lists
+                cdot = jnp.dot(cents, qvec,
+                               preferred_element_type=jnp.float32)
+                if simkind in ("cosine", "dot_product", "innerproduct"):
+                    caff = cdot
+                else:  # l2: nearest centroid = max of 2c.q - ||c||^2
+                    caff = 2.0 * cdot - jnp.sum(cents * cents, axis=1)
+                caff = jnp.where(vc["ivf_cvalid"], caff, -jnp.inf)
+                _, pids = _lax.top_k(caff, ann_nprobe)
+            with jax.named_scope("knn.gather"):
+                cand = lists[pids].reshape(-1)            # i32[nprobe*cap]
+                valid = cand >= 0
+                vecs = vc["mat"][jnp.where(valid, cand, 0)]
+            with jax.named_scope("knn.score"):
+                s = _sim_score(_product(vecs),
+                               lambda: jnp.sum(vecs * vecs, axis=1))
+                s = jnp.where(valid, s, 0.0)
+            with jax.named_scope("knn.scatter"):
+                cidx = jnp.where(valid, cand, ndocs_pad)  # OOB -> dropped
+                # each doc lives in exactly one list -> max==set, but max is
+                # insensitive to the padding sentinel collisions
+                score = zeros.at[cidx].max(s, mode="drop")
+                cmask = zeros.at[cidx].max(valid.astype(jnp.float32),
+                                           mode="drop")
             matched = (cmask > 0) & vc["present"] & (live > 0)
         else:
             # one MXU matvec per segment: exact brute-force kNN (the
             # reference k-NN plugin approximates with HNSW; at HBM bandwidth
             # the dense scan is the TPU-native answer for exact)
-            raw = jnp.dot(vc["mat"], qvec, preferred_element_type=jnp.float32)
-            score = _sim_score(raw, jnp.sum(vc["mat"] * vc["mat"], axis=1))
+            with jax.named_scope("knn.scan"):
+                mat = vc["mat"]
+                score = _sim_score(_product(mat),
+                                   lambda: jnp.sum(mat * mat, axis=1))
             matched = vc["present"] & (live > 0)
         if fspec is not None:
             matched = matched & emit(fspec, seg_arrays, params).matched
@@ -5584,6 +5625,8 @@ def _count_launch(full_spec, seg_arrays: dict, cparams: dict) -> None:
         EXECUTOR_STATS.inc("topk_keys_sorted", ops.topk_keys_sorted(
             seg_arrays["live"].shape[0], k_pad))
     EXECUTOR_STATS.inc("launches")
+    for node in _knn_nodes(_query):
+        _count_knn(node, seg_arrays, cparams)
     forms = list(_date_count_forms(aggs))
     if forms:
         EXECUTOR_STATS.inc("agg_bucket_launches", len(forms))
@@ -5599,6 +5642,33 @@ def _count_launch(full_spec, seg_arrays: dict, cparams: dict) -> None:
         if cost["sub_buckets"]:
             AGG_STATS.inc("bucketed_sub.launches")
             AGG_STATS.inc("bucketed_sub.buckets", cost["sub_buckets"])
+
+
+def _knn_nodes(spec):
+    """The `knn` nodes of a query spec, its filters' included."""
+    if isinstance(spec, (tuple, list)):
+        if spec and spec[0] == "knn":
+            yield spec
+        for part in spec:
+            yield from _knn_nodes(part)
+
+
+def _count_knn(node, seg_arrays: dict, cparams: dict) -> None:
+    """One `knn` node of a launch into `KNN_STATS`, by the predicate
+    `emit` itself routes by."""
+    _, nid, field, col_exists, _sim, _fspec, ann_nprobe = node
+    if not col_exists:
+        return
+    vc = seg_arrays["vector"][field]
+    KNN_STATS.inc("queries")
+    KNN_STATS.inc("query_vector_bytes", cparams[f"q{nid}_vec"].nbytes)
+    if ann_nprobe is not None and "ivf_centroids" in vc:
+        KNN_STATS.inc("ann_queries")
+        KNN_STATS.inc("lists_probed", ann_nprobe)
+        KNN_STATS.inc("candidate_slots",
+                      ann_nprobe * vc["ivf_lists"].shape[1])
+    else:
+        KNN_STATS.inc("exact_queries")
 
 
 # where the sub-aggregation specs sit in the containers that hand their

@@ -2150,6 +2150,11 @@ def _docvalue_fields(seg: Segment, doc: int, specs: List) -> dict:
     out = {}
     for spec in specs:
         f = spec if isinstance(spec, str) else spec.get("field")
+        if f == "_id":
+            # OpenSearch Benchmark's vector search reads a hit's id here,
+            # with `stored_fields: _none_`
+            out[f] = [seg.ids[doc]]
+            continue
         col = seg.numeric_cols.get(f)
         if col is not None and col.present[doc]:
             out[f] = [_render_numeric(col, doc)]
