@@ -35,9 +35,15 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from ..obs.ingest_obs import note_stage
+from ..utils.metrics import METRICS, CounterGroup
 from .mappings import FLOAT_TYPES, GEO_TYPES, FieldType, Mappings
 
 INT32_SENTINEL = np.int32(2**31 - 1)  # padded doc_id -> dropped by scatter
+
+# `live_recounts`: full passes over a segment's live mask to count it, one a
+# whole-mask assignment (`Segment.live`'s setter). A delete and a read of
+# `live_count` make none, so a served window reads 0 here.
+SEGMENT_STATS = CounterGroup(METRICS, "segment", {"live_recounts": 0})
 
 # ---------------------------------------------------------------------
 # segment codec versions (docs/INDEX_FORMAT.md)
@@ -557,9 +563,37 @@ class NestedBlock:
         return a, b
 
 
+def _read_only(a: np.ndarray) -> np.ndarray:
+    v = a.view()
+    v.flags.writeable = False
+    return v
+
+
 class Segment:
     """One immutable searchable unit (analog of a Lucene segment + its
-    SegmentReader, reference `index/engine/Engine.java#acquireSearcher`)."""
+    SegmentReader, reference `index/engine/Engine.java#acquireSearcher`).
+
+    **Who writes `live`, the one part that is not immutable.** Two forms,
+    both on the write side (the engine's write lock, or before the segment
+    is published to a searcher), so `live_count` is only ever written where
+    the mask is and a reader never writes either:
+
+    - `delete_doc(local_doc)` clears one row. `live_count` steps down by one
+      where the row was live and stays where it was not (a second delete of
+      one row counts once); `live_gen` steps up and every device copy of the
+      mask is marked dirty, as before the count was kept.
+    - `seg.live = mask` replaces the whole mask (`load`, `reorder`, merge's
+      temporary swap on nested children, builders that wrap ready-made
+      arrays). The segment takes the array over: the caller keeps no
+      reference it writes through (an array that is not writeable, or not
+      bool, is copied). The assignment counts the mask once
+      (`segment.live_recounts` in `METRICS` steps by one) and touches
+      neither `live_gen` nor the device plane's dirty flags.
+
+    `seg.live` reads as a numpy bool array that is NOT writeable: an element
+    written from outside would leave `live_count` stale, so it raises. A
+    reader that needs a writeable mask takes a copy. `live_count` is that
+    kept integer: exact at every instant, O(1), never a sum over the mask."""
 
     _seq = 0
 
@@ -598,7 +632,11 @@ class Segment:
         self.ids = ids
         self.sources = sources
         self.seq_nos = seq_nos if seq_nos is not None else np.zeros(ndocs, dtype=np.int64)
-        self.live = np.ones(ndocs, dtype=bool)
+        # the mask, the view of it that `live` hands out and the number of
+        # set rows go together: here, in `live`'s setter and in `delete_doc`
+        self._live = np.ones(ndocs, dtype=bool)
+        self._live_ro = _read_only(self._live)
+        self._live_count = ndocs
         self.live_gen = 0
         self.id2doc: Dict[str, int] = {d: i for i, d in enumerate(ids)}
         # per-device host->HBM residency: key None = process default device;
@@ -690,15 +728,31 @@ class Segment:
 
     # ---------------- live docs / deletes ----------------
 
+    @property
+    def live(self) -> np.ndarray:
+        return self._live_ro
+
+    @live.setter
+    def live(self, mask: np.ndarray) -> None:
+        mask = np.asarray(mask, dtype=bool)
+        if not mask.flags.writeable:
+            mask = mask.copy()
+        SEGMENT_STATS.inc("live_recounts")
+        self._live = mask
+        self._live_ro = _read_only(mask)
+        self._live_count = int(np.count_nonzero(mask))
+
     def delete_doc(self, local_doc: int) -> None:
-        self.live[local_doc] = False
+        if self._live[local_doc]:
+            self._live[local_doc] = False
+            self._live_count -= 1
         for k in self._device_live_dirty:
             self._device_live_dirty[k] = True
         self.live_gen += 1  # invalidates live-dependent host caches
 
     @property
     def live_count(self) -> int:
-        return int(self.live.sum())
+        return self._live_count
 
     # ---------------- device residency ----------------
 
