@@ -192,7 +192,7 @@ class HBMLedger:
         pressure event also evicts the segment's other-device planes
         (and `bytes` below records only the chosen group's share)."""
         groups: Dict[tuple, list] = {}
-        for a in self._allocs.values():
+        for a in list(self._allocs.values()):       # a snapshot, as there
             if a.evictor is None or a.breaker is not breaker:
                 continue
             if a.seg_uid is None or a.seg_uid == exclude_uid:
@@ -398,7 +398,7 @@ class HBMLedger:
         with self._lock:
             counts: Dict[str, int] = {}
             charged = 0
-            for a in self._allocs.values():
+            for a in list(self._allocs.values()):   # as below: a snapshot
                 counts[a.kind] = counts.get(a.kind, 0) + 1
                 if a.charged:
                     charged += a.nbytes
@@ -434,7 +434,10 @@ class HBMLedger:
         name — the `GET /_cat/segments` columns."""
         out: Dict[Any, dict] = {}
         with self._lock:
-            for a in self._allocs.values():
+            # a snapshot: a weakref finalizer of an owner the collector
+            # frees inside this loop releases its allocation on this very
+            # thread (the lock is re-entrant) and would resize the dict
+            for a in list(self._allocs.values()):
                 if not a.segment and a.seg_uid is None:
                     continue
                 key = a.seg_uid if a.seg_uid is not None else a.segment

@@ -51,6 +51,12 @@ def _gather_match(match: jnp.ndarray, docs: jnp.ndarray) -> jnp.ndarray:
 # scatters read 1,340-1,770: the forms cross near 6,000 buckets, and the
 # constant stands where the dense form still wins four times over
 _DENSE_BUCKETS = 2048
+# the three forms name their ops in the device trace (`jax.named_scope`:
+# metadata of an op, read by `benchmark/launch_reduce.py`), one scope a
+# form, inside whatever scope the caller stands in
+DENSE_SCOPE = "aggs.dense"
+SCATTER_SCOPE = "aggs.scatter"
+RUN_COUNTS_SCOPE = "aggs.run_counts"
 
 
 def dense_buckets(nbuckets: int) -> bool:
@@ -118,8 +124,9 @@ def _dense_reduce(held: jnp.ndarray, nbuckets: int, rows: int,
                     jnp.max(jnp.where(hot, vals, -F32_MAX), axis=1)]
         return tuple(out)
 
-    return jax.lax.map(one, (blocks(held, nbuckets),
-                             None if v is None else blocks(v, 0.0)))
+    with jax.named_scope(DENSE_SCOPE):
+        return jax.lax.map(one, (blocks(held, nbuckets),
+                                 None if v is None else blocks(v, 0.0)))
 
 
 def bucket_counts(bucket_ids: jnp.ndarray, w: jnp.ndarray,
@@ -134,7 +141,8 @@ def bucket_counts(bucket_ids: jnp.ndarray, w: jnp.ndarray,
     if dense_buckets(nbuckets):
         rows = max(min(held.shape[0], _DENSE_BLOCK), 1)
         return jnp.sum(_dense_reduce(held, nbuckets, rows)[0], axis=0)
-    return jnp.zeros(nbuckets, jnp.int32).at[held].add(1, mode="drop")
+    with jax.named_scope(SCATTER_SCOPE):
+        return jnp.zeros(nbuckets, jnp.int32).at[held].add(1, mode="drop")
 
 
 # rows a block of `run_counts` (the probe on the chip read 512 to 4,096
@@ -176,18 +184,20 @@ def run_counts(w: jnp.ndarray, starts: jnp.ndarray) -> jnp.ndarray:
     # copy ([n] -> [R, C] is a relayout of the whole plane: PERF.md, PR 29)
     lane = min(c, 128)
     g = c // lane
-    tiles = w.reshape(n // lane, lane)
-    sums = jnp.sum(jnp.sum(tiles, axis=1).reshape(r, g), axis=1)
-    before = jnp.concatenate([jnp.zeros(1, jnp.int32), jnp.cumsum(sums)])
-    blk, off = starts // c, starts % c
-    idx = ((jnp.minimum(blk, r - 1) * g)[:, None]
-           + jnp.arange(g, dtype=jnp.int32)[None, :])
-    rows = tiles[idx].reshape(nb + 1, c)
-    inside = jnp.sum(jnp.where(
-        jnp.arange(c, dtype=jnp.int32)[None, :] < off[:, None], rows, 0),
-        axis=1)
-    prefix = before[blk] + inside
-    return prefix[1:] - prefix[:-1]
+    with jax.named_scope(RUN_COUNTS_SCOPE):
+        tiles = w.reshape(n // lane, lane)
+        sums = jnp.sum(jnp.sum(tiles, axis=1).reshape(r, g), axis=1)
+        before = jnp.concatenate([jnp.zeros(1, jnp.int32),
+                                  jnp.cumsum(sums)])
+        blk, off = starts // c, starts % c
+        idx = ((jnp.minimum(blk, r - 1) * g)[:, None]
+               + jnp.arange(g, dtype=jnp.int32)[None, :])
+        rows = tiles[idx].reshape(nb + 1, c)
+        inside = jnp.sum(jnp.where(
+            jnp.arange(c, dtype=jnp.int32)[None, :] < off[:, None], rows,
+            0), axis=1)
+        prefix = before[blk] + inside
+        return prefix[1:] - prefix[:-1]
 
 
 # ---------------------------------------------------------------------
@@ -266,8 +276,9 @@ def _scatter_block_sums(held: jnp.ndarray, planes: list, nbuckets: int,
     nblk = -(-n // rows)
     blk = jnp.arange(n, dtype=jnp.int32) // rows
     ids = jnp.where(held < nbuckets, blk * nbuckets + held, nblk * nbuckets)
-    return [jnp.zeros(nblk * nbuckets, jnp.int32).at[ids].add(
-        plane, mode="drop").reshape(nblk, nbuckets) for plane in planes]
+    with jax.named_scope(SCATTER_SCOPE):
+        return [jnp.zeros(nblk * nbuckets, jnp.int32).at[ids].add(
+            plane, mode="drop").reshape(nblk, nbuckets) for plane in planes]
 
 
 def _folded(accs: list) -> jnp.ndarray:
@@ -358,8 +369,9 @@ def bucketed_sub_metric(bucket_ids: jnp.ndarray, v: jnp.ndarray,
             lo, hi = jnp.min(lo, axis=0), jnp.max(hi, axis=0)
         else:
             count = bucket_counts(b, w, nbuckets)
-            lo = jnp.full(nbuckets, F32_MAX).at[b].min(v, mode="drop")
-            hi = jnp.full(nbuckets, -F32_MAX).at[b].max(v, mode="drop")
+            with jax.named_scope(SCATTER_SCOPE):
+                lo = jnp.full(nbuckets, F32_MAX).at[b].min(v, mode="drop")
+                hi = jnp.full(nbuckets, -F32_MAX).at[b].max(v, mode="drop")
             accs = _scatter_block_sums(b, parts(v, w), nbuckets, rows)
         out = {"count": count, "min": lo, "max": hi,
                "sum": _folded(accs[:limbs]), "scale": inv}
@@ -508,7 +520,9 @@ def hll_registers(hashes_u32: jnp.ndarray, valid: jnp.ndarray, log2m: int = 14) 
     rank = (nbits + 1) - jnp.ceil(jnp.log2(rest.astype(jnp.float32) + 1.0)).astype(jnp.int32)
     rank = jnp.clip(rank, 1, nbits + 1)
     reg = jnp.where(valid, reg, m)  # invalid -> dropped
-    return jnp.zeros(m, jnp.int32).at[reg].max(jnp.where(valid, rank, 0), mode="drop")
+    with jax.named_scope(SCATTER_SCOPE):
+        return jnp.zeros(m, jnp.int32).at[reg].max(
+            jnp.where(valid, rank, 0), mode="drop")
 
 
 def cardinality_numeric_registers(values_f32: jnp.ndarray, present: jnp.ndarray,
@@ -547,7 +561,8 @@ def ddsketch_hist(values_f32: jnp.ndarray, present: jnp.ndarray,
     b = jnp.where(values_f32 > 0, DD_HALF + 1 + idx,
                   jnp.where(values_f32 < 0, DD_HALF - 1 - idx, DD_HALF))
     b = jnp.where(w > 0, b, DD_NBINS)  # dropped
-    return jnp.zeros(DD_NBINS, jnp.float32).at[b].add(w, mode="drop")
+    with jax.named_scope(SCATTER_SCOPE):
+        return jnp.zeros(DD_NBINS, jnp.float32).at[b].add(w, mode="drop")
 
 
 def ddsketch_bin(v: float) -> int:

@@ -99,56 +99,60 @@ def exact_rescore_batch(docs_hbm: jnp.ndarray, tfdl_hbm: jnp.ndarray,
 
     # lower_bound over [start, start+len): branchless bisection. A window of
     # `len` is empty after exactly `len.bit_length()` halvings, and further
-    # ones do not move `pos_c` below.
-    if plane_in_vmem(P):
-        # every gather in the entry computation, so XLA prefetches the plane
-        # into VMEM once: the plane's own depth over [QB, T, C], unrolled
-        lo = jnp.broadcast_to(starts[:, :, None], starts.shape + (C,))
-        hi = lo + lens[:, :, None]
-        for _ in range(int(P).bit_length()):
-            lo, hi = halve(lo, hi, c)
-    else:
-        # one `while` per term slot over that slot's [QB, C] bounds,
-        # `rounds[t]` trips deep
-        los = []
+    # ones do not move `pos_c` below. Two stages by `jax.named_scope`:
+    # `rescore.probe` the searches, `rescore.score` all that follows
+    with jax.named_scope("rescore.probe"):
+        if plane_in_vmem(P):
+            # every gather in the entry computation, so XLA prefetches the
+            # plane into VMEM once: the plane's own depth over [QB, T, C],
+            # unrolled
+            lo = jnp.broadcast_to(starts[:, :, None], starts.shape + (C,))
+            hi = lo + lens[:, :, None]
+            for _ in range(int(P).bit_length()):
+                lo, hi = halve(lo, hi, c)
+        else:
+            # one `while` per term slot over that slot's [QB, C] bounds,
+            # `rounds[t]` trips deep
+            los = []
+            for t in range(T):
+                lo_t = jnp.broadcast_to(starts[:, t, None], cand.shape)
+                los.append(jax.lax.fori_loop(
+                    0, rounds[t], lambda _, lo_hi: halve(*lo_hi, cand),
+                    (lo_t, lo_t + lens[:, t, None]))[0])
+            lo = jnp.stack(los, axis=1)
+    with jax.named_scope("rescore.score"):
+        end = (starts + lens)[:, :, None]
+        # mirror the host's clamped probe: pos_c = min(pos, row_end - 1)
+        pos_c = jnp.clip(jnp.minimum(lo, end - 1), 0, P - 1)
+        found = ((docs_hbm[pos_c] == c) & (lens[:, :, None] > 0)
+                 & (c < INT_SENTINEL))
+        tfdl = tfdl_hbm[pos_c]
+        tf = jnp.where(found, ((tfdl >> DL_BITS) & TF_MAX), 0
+                       ).astype(jnp.float32)
+        # the candidate's doc length, recovered from any matched posting (all
+        # postings of one doc in one field carry the same dl; candidates are
+        # head members, so a real candidate matches >= 1 full row). Padding /
+        # no-match candidates get dl 0 — their contribution is masked to 0
+        # anyway, matching the host oracle's zero output for them.
+        dl_c = jnp.max(jnp.where(found, (tfdl & DL_MASK), 0),
+                       axis=1).astype(jnp.float32)
+        # EXACTLY `fastpath._exact_rescore`'s expression and evaluation order:
+        # (1.0 - b) folds at trace time in f64 then rounds to f32 on the add,
+        # the same NEP50 weak-scalar rounding the numpy pass performs
+        avg = jnp.maximum(avgdl, jnp.float32(1e-9))           # [QB, 1]
+        kfac = k1 * ((1.0 - b) + b * dl_c / avg)              # [QB, C] f32
+        exact = jnp.zeros(kfac.shape, jnp.float32)
+        counts = jnp.zeros(kfac.shape, jnp.int32)
+        # term-order f32 accumulation: adding a masked 0.0f is an exact
+        # identity on the non-negative partial sums, so skipped/absent slots
+        # leave the running sum bit-identical to the host loop's
         for t in range(T):
-            lo_t = jnp.broadcast_to(starts[:, t, None], cand.shape)
-            los.append(jax.lax.fori_loop(
-                0, rounds[t], lambda _, lo_hi: halve(*lo_hi, cand),
-                (lo_t, lo_t + lens[:, t, None]))[0])
-        lo = jnp.stack(los, axis=1)
-    end = (starts + lens)[:, :, None]
-    # mirror the host's clamped probe: pos_c = min(pos, row_end - 1)
-    pos_c = jnp.clip(jnp.minimum(lo, end - 1), 0, P - 1)
-    found = ((docs_hbm[pos_c] == c) & (lens[:, :, None] > 0)
-             & (c < INT_SENTINEL))
-    tfdl = tfdl_hbm[pos_c]
-    tf = jnp.where(found, ((tfdl >> DL_BITS) & TF_MAX), 0
-                   ).astype(jnp.float32)
-    # the candidate's doc length, recovered from any matched posting (all
-    # postings of one doc in one field carry the same dl; candidates are
-    # head members, so a real candidate matches >= 1 full row). Padding /
-    # no-match candidates get dl 0 — their contribution is masked to 0
-    # anyway, matching the host oracle's zero output for them.
-    dl_c = jnp.max(jnp.where(found, (tfdl & DL_MASK), 0),
-                   axis=1).astype(jnp.float32)
-    # EXACTLY `fastpath._exact_rescore`'s expression and evaluation order:
-    # (1.0 - b) folds at trace time in f64 then rounds to f32 on the add,
-    # the same NEP50 weak-scalar rounding the numpy pass performs
-    avg = jnp.maximum(avgdl, jnp.float32(1e-9))           # [QB, 1]
-    kfac = k1 * ((1.0 - b) + b * dl_c / avg)              # [QB, C] f32
-    exact = jnp.zeros(kfac.shape, jnp.float32)
-    counts = jnp.zeros(kfac.shape, jnp.int32)
-    # term-order f32 accumulation: adding a masked 0.0f is an exact
-    # identity on the non-negative partial sums, so skipped/absent slots
-    # leave the running sum bit-identical to the host loop's
-    for t in range(T):
-        tft = tf[:, t, :]
-        foundt = found[:, t, :]
-        contrib = jnp.where(foundt,
-                            weights[:, t:t + 1] * tft / (tft + kfac), 0.0)
-        exact = exact + contrib.astype(jnp.float32)
-        counts = counts + foundt.astype(jnp.int32)
+            tft = tf[:, t, :]
+            foundt = found[:, t, :]
+            contrib = jnp.where(foundt,
+                                weights[:, t:t + 1] * tft / (tft + kfac), 0.0)
+            exact = exact + contrib.astype(jnp.float32)
+            counts = counts + foundt.astype(jnp.int32)
     return exact, counts
 
 

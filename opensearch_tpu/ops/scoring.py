@@ -179,16 +179,18 @@ def impact_score_blocks(doc_ids: jnp.ndarray, impacts: jnp.ndarray,
     saturation was evaluated at index time (BM25S eager scoring). Counts
     are exact for the gathered blocks: postings partition (term, doc)
     pairs, so counting postings counts matching terms."""
-    docs, iq, b_idx, valid = gather_impact_blocks(doc_ids, impacts,
-                                                  bstart, blen, bucket)
-    contrib = jnp.where(valid, dequant_impact(iq, bweight[b_idx]), 0.0)
-    scores = jnp.zeros(ndocs_pad, jnp.float32).at[docs].add(contrib,
-                                                            mode="drop")
-    counts = jnp.zeros(ndocs_pad, jnp.float32).at[docs].add(
-        jnp.where(valid, 1.0, 0.0), mode="drop")
-    live_ok = live > 0
-    return ScoredMask(jnp.where(live_ok, scores, 0.0),
-                      jnp.where(live_ok, counts, 0.0))
+    with jax.named_scope("impact.gather"):
+        docs, iq, b_idx, valid = gather_impact_blocks(doc_ids, impacts,
+                                                      bstart, blen, bucket)
+        contrib = jnp.where(valid, dequant_impact(iq, bweight[b_idx]), 0.0)
+    with jax.named_scope("impact.accumulate"):
+        scores = jnp.zeros(ndocs_pad, jnp.float32).at[docs].add(
+            contrib, mode="drop")
+        counts = jnp.zeros(ndocs_pad, jnp.float32).at[docs].add(
+            jnp.where(valid, 1.0, 0.0), mode="drop")
+        live_ok = live > 0
+        return ScoredMask(jnp.where(live_ok, scores, 0.0),
+                          jnp.where(live_ok, counts, 0.0))
 
 
 def gather_docs_only(starts: jnp.ndarray, doc_ids: jnp.ndarray,

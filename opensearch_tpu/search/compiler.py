@@ -54,7 +54,9 @@ HLL_LOG2M = 14
 #
 # Every jitted program builder in this module is lru_cache'd per
 # canonical spec; the instrumented wrapper mirrors cache traffic into the
-# registry and times the programs themselves. Attribution model: a
+# registry and times the programs themselves, by the `device.dispatch`
+# span round each call (attributes `program`, the family, and
+# `first_call`). Attribution model: a
 # program's FIRST python-side invocation runs trace + lower + XLA compile
 # inline, so its wall lands in `search.jit.<family>.compile_ms`;
 # steady-state calls land in `.execute_ms` (dispatch wall — XLA execution
@@ -133,18 +135,31 @@ class _TimedProgram:
         self._compiled = False
 
     def __call__(self, *a, **kw):
-        from ..utils.metrics import METRICS
+        # one `device.dispatch` span a launch, round the call alone:
+        # flattening the argument tree, the host arrays' copy up and the
+        # enqueue (on a first call also trace, lower and compile). Its
+        # `start_ns` / `end_ns` are the launch's one clock: the
+        # `perf_counter` pair below is the path of a disabled tracer
+        first = not self._compiled
+        if TRACER.enabled:
+            with TRACER.span("device.dispatch", program=self._family,
+                             first_call=first) as span:
+                out = self._fn(*a, **kw)
+            dt = (span.end_ns - span.start_ns) / 1e6
+        elif METRICS.enabled:
+            t0 = _time_mod.perf_counter()
+            out = self._fn(*a, **kw)
+            dt = (_time_mod.perf_counter() - t0) * 1e3
+        else:
+            out = self._fn(*a, **kw)
+        self._compiled = True
         if not METRICS.enabled:
-            return self._fn(*a, **kw)
-        t0 = _time_mod.perf_counter()
-        out = self._fn(*a, **kw)
-        dt = (_time_mod.perf_counter() - t0) * 1e3
+            return out
         base = f"search.jit.{self._family}"
-        if not self._compiled:
+        if first:
             # benign race: two threads can both attribute their first
             # call as a compile — the histogram stays honest enough and
             # a lock here would tax every launch
-            self._compiled = True
             METRICS.histogram(f"{base}.compile_ms").record(dt)
             if self._shape:
                 METRICS.histogram(
@@ -5245,7 +5260,8 @@ def _build_mask_executor(spec):
     import jax
 
     def mask_program(seg_arrays, params):
-        return emit(spec, seg_arrays, params).matched
+        with jax.named_scope("executor.match"):
+            return emit(spec, seg_arrays, params).matched
 
     return jax.jit(mask_program)
 
@@ -5312,11 +5328,12 @@ def build_impact_program(B: int, bucket: int, C: int, bits: int):
         ndocs_pad = live.shape[0]
         sm = ops.impact_score_blocks(d_docs, d_impacts, live, bstart,
                                      blen, bweight, bucket, ndocs_pad)
-        ok = (sm.count >= msm) & (live > 0)
-        masked = jnp.where(ok, sm.scores, ops.NEG_INF)
-        total = jnp.sum(ok.astype(jnp.int32))
-        kk = min(C, ndocs_pad)
-        vals, idx = jax.lax.top_k(masked, kk)
+        with jax.named_scope("impact.topk"):
+            ok = (sm.count >= msm) & (live > 0)
+            masked = jnp.where(ok, sm.scores, ops.NEG_INF)
+            total = jnp.sum(ok.astype(jnp.int32))
+            kk = min(C, ndocs_pad)
+            vals, idx = jax.lax.top_k(masked, kk)
         return vals, idx, total
 
     return jax.jit(impact_program)
@@ -5472,8 +5489,11 @@ def _mask_for_key(key, spec, local: dict, mapping: Dict[int, int],
         exe = _build_mask_executor(canon)
         arrays = (seg.pruned_arrays(dev_key, needs) if needs is not None
                   else seg.device_arrays(dev_key))
+        import jax
+        launched = exe(arrays, canon_local)
         # host-resident bools: safe to feed executors on ANY device
-        mask = np.asarray(exe(arrays, canon_local))
+        with TRACER.span("device.wait", program="mask"):
+            mask = jax.device_get(launched)
         with _FILTER_MASK_LOCK:
             # two threads can race the same miss: keep the winner's entry so
             # the byte counter never double-counts one key
@@ -5528,49 +5548,66 @@ def _executor_run_fn(full_spec):
     `_build_executor` — the ONE program both the direct path and the
     coalesced knn batch (`launch_segment_batch`) invoke, which is what
     makes a batched page byte-identical to its direct sibling."""
+    import jax
+
     (query_spec, sort_spec, agg_specs, k_pad, named_specs, has_after,
      collapse_spec) = full_spec
 
     def executor_program(seg_arrays, params):
         import jax.numpy as jnp
 
-        sm = emit(query_spec, seg_arrays, params)
+        # the stages carry `jax.named_scope`s (metadata of the ops, read
+        # from a trace by `benchmark/launch_reduce.py`): `executor.match`,
+        # `.sort_key`, `.topk`, `.total`, `.aggs` (the forms of
+        # `ops/aggs.py` name themselves inside it), `.named`
+        with jax.named_scope("executor.match"):
+            sm = emit(query_spec, seg_arrays, params)
         live = seg_arrays["live"]
-        key = emit_sort_key(sort_spec, seg_arrays, params, sm.scores)
-        matched = sm.matched
-        if has_after:
-            # search_after: strictly below the cursor in ranking order
-            matched = matched & (key < params["after_key"])
-        sm = ops.ScoredMask(sm.scores, matched.astype(jnp.float32))
-        if collapse_spec is not None:
-            _, cfield, n_ord_pad, use_kw = collapse_spec
-            if use_kw:
-                ords = seg_arrays["keyword"][cfield]["min_ord"]
+        with jax.named_scope("executor.sort_key"):
+            key = emit_sort_key(sort_spec, seg_arrays, params, sm.scores)
+            matched = sm.matched
+            if has_after:
+                # search_after: strictly below the cursor in ranking order
+                matched = matched & (key < params["after_key"])
+            sm = ops.ScoredMask(sm.scores, matched.astype(jnp.float32))
+        with jax.named_scope("executor.topk"):
+            if collapse_spec is not None:
+                _, cfield, n_ord_pad, use_kw = collapse_spec
+                if use_kw:
+                    ords = seg_arrays["keyword"][cfield]["min_ord"]
+                else:
+                    ords = params["collapse_ords"]
+                vals, idx = ops.collapse_topk(key, sm.matched, live, ords,
+                                              n_ord_pad, k_pad)
             else:
-                ords = params["collapse_ords"]
-            vals, idx = ops.collapse_topk(key, sm.matched, live, ords,
-                                          n_ord_pad, k_pad)
-        else:
-            vals, idx = ops.topk_docs(key, sm.matched, live, k_pad)
+                vals, idx = ops.topk_docs(key, sm.matched, live, k_pad)
+            topk_scores = sm.scores[idx]
+        with jax.named_scope("executor.total"):
+            total = ops.total_hits(sm.matched, live)
+            max_score = jnp.max(jnp.where(sm.matched & (live > 0),
+                                          sm.scores, -jnp.inf))
         out = {
             "topk_key": vals,
             "topk_idx": idx,
-            "topk_scores": sm.scores[idx],
-            "total": ops.total_hits(sm.matched, live),
-            "max_score": jnp.max(jnp.where(sm.matched & (live > 0), sm.scores, -jnp.inf)),
+            "topk_scores": topk_scores,
+            "total": total,
+            "max_score": max_score,
         }
-        match_f = sm.matched.astype(jnp.float32) * jnp.where(live > 0, 1.0, 0.0)
         aggs = {}
-        for name, aspec in agg_specs:
-            res = emit_agg(aspec, seg_arrays, params, match_f, sm.scores)
-            if res:  # oslint: disable=OSL201 -- host dict truthiness, trace-static
-                aggs[name] = res
+        with jax.named_scope("executor.aggs"):
+            match_f = (sm.matched.astype(jnp.float32)
+                       * jnp.where(live > 0, 1.0, 0.0))
+            for name, aspec in agg_specs:
+                res = emit_agg(aspec, seg_arrays, params, match_f, sm.scores)
+                if res:  # oslint: disable=OSL201 -- host dict truthiness, trace-static
+                    aggs[name] = res
         if aggs:  # oslint: disable=OSL201 -- host dict truthiness, trace-static
             out["aggs"] = aggs
         named = {}
-        for nm, nspec in named_specs:
-            nsm = emit(nspec, seg_arrays, params)
-            named[nm] = nsm.matched[idx]
+        with jax.named_scope("executor.named"):
+            for nm, nspec in named_specs:
+                nsm = emit(nspec, seg_arrays, params)
+                named[nm] = nsm.matched[idx]
         if named:  # oslint: disable=OSL201 -- host dict truthiness, trace-static
             out["named"] = named
         return out
@@ -5798,10 +5835,14 @@ def _build_agg_executor(key):
     def agg_program(seg_arrays, params):
         import jax.numpy as jnp
 
-        sm = emit(query_spec, seg_arrays, params)
+        with jax.named_scope("executor.match"):
+            sm = emit(query_spec, seg_arrays, params)
         live = seg_arrays["live"]
-        match_f = sm.matched.astype(jnp.float32) * jnp.where(live > 0, 1.0, 0.0)
-        return emit_agg(agg_spec, seg_arrays, params, match_f, sm.scores)
+        with jax.named_scope("executor.aggs"):
+            match_f = (sm.matched.astype(jnp.float32)
+                       * jnp.where(live > 0, 1.0, 0.0))
+            return emit_agg(agg_spec, seg_arrays, params, match_f,
+                            sm.scores)
 
     return jax.jit(agg_program)
 
@@ -5826,19 +5867,22 @@ def _build_auto_range_executor(key):
     def auto_range_program(seg_arrays, params):
         import jax.numpy as jnp
 
-        sm = emit(query_spec, seg_arrays, params)
-        ok0 = (sm.matched > 0) & (seg_arrays["live"] > 0)
+        with jax.named_scope("executor.match"):
+            sm = emit(query_spec, seg_arrays, params)
         out = {}
-        for f in fields:
-            col = seg_arrays["numeric"][f]
-            ok, hi, lo = ok0 & col["present"], col["hi"], col["lo"]
-            min_hi = jnp.min(jnp.where(ok, hi, big))
-            max_hi = jnp.max(jnp.where(ok, hi, -big - 1))
-            out[f] = (min_hi,
-                      jnp.min(jnp.where(ok & (hi == min_hi), lo, big)),
-                      max_hi,
-                      jnp.max(jnp.where(ok & (hi == max_hi), lo, -big - 1)),
-                      jnp.sum(ok.astype(jnp.int32)))
+        with jax.named_scope("aggs.auto_range"):
+            ok0 = (sm.matched > 0) & (seg_arrays["live"] > 0)
+            for f in fields:
+                col = seg_arrays["numeric"][f]
+                ok, hi, lo = ok0 & col["present"], col["hi"], col["lo"]
+                min_hi = jnp.min(jnp.where(ok, hi, big))
+                max_hi = jnp.max(jnp.where(ok, hi, -big - 1))
+                out[f] = (
+                    min_hi,
+                    jnp.min(jnp.where(ok & (hi == min_hi), lo, big)),
+                    max_hi,
+                    jnp.max(jnp.where(ok & (hi == max_hi), lo, -big - 1)),
+                    jnp.sum(ok.astype(jnp.int32)))
         return out
 
     return jax.jit(auto_range_program)
@@ -5858,7 +5902,7 @@ def auto_date_range(query_spec, fields: Tuple[str, ...], seg_arrays: dict,
     EXECUTOR_STATS.inc("launches")
     AGG_STATS.inc("auto_date.refine_launches")
     out = _build_auto_range_executor(canon)(seg_arrays, cparams)
-    with TRACER.span("device.wait", program="executor_auto_range"):
+    with TRACER.span("device.wait", program="agg", outputs="auto_range"):
         got = jax.device_get(out)
 
     def i64(hi, lo):

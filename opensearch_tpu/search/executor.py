@@ -2226,7 +2226,7 @@ def _fetch_agg_outputs(device_out):
     if not device_out:
         return device_out
     import jax
-    with TRACER.span("device.wait", program="executor_aggs"):
+    with TRACER.span("device.wait", program="executor", outputs="aggs"):
         return jax.device_get(device_out)
 
 

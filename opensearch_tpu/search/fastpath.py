@@ -868,6 +868,20 @@ def _prepare_vqueries(seg: Segment, ctx, lts: Sequence, avgdl_cache: dict,
     return out
 
 
+# the (kernel, plane and batch shapes, statics) a kernel was launched with:
+# a launch whose key is new traces, lowers and compiles, and its
+# `device.dispatch` span says so (`first_call`), as `_TimedProgram`'s does
+# for the XLA programs; one entry a compiled kernel program
+_LAUNCHED_SHAPES: set = set()
+
+
+def _first_launch(*key) -> bool:
+    if key in _LAUNCHED_SHAPES:
+        return False
+    _LAUNCHED_SHAPES.add(key)
+    return True
+
+
 def _launch_pure_groups_async(seg: Segment,
                               vq_lists: List[Optional[List[_VQuery]]],
                               K: int) -> list:
@@ -945,9 +959,15 @@ def _launch_pure_groups_async(seg: Segment,
                                  path="fused_bm25_topk_impact",
                                  segment=seg)
             STATS.inc("impact_frontier", len(gvqs))
-            pending.append((gvqs, K_launch, fused_bm25_topk_impact(
-                al.d_docs, al.d_imp, rowstarts, nrows, lens, skips,
-                w_fold, msm, dlo, dhi, T=T_pad, L=L, K=K_launch)))
+            with TRACER.span("device.dispatch", program="frontier",
+                             kernel="fused_bm25_topk_impact",
+                             first_call=_first_launch(
+                                 "impact", al.d_docs.shape, len(gvqs),
+                                 T_pad, L, K_launch)):
+                launched = fused_bm25_topk_impact(
+                    al.d_docs, al.d_imp, rowstarts, nrows, lens, skips,
+                    w_fold, msm, dlo, dhi, T=T_pad, L=L, K=K_launch)
+            pending.append((gvqs, K_launch, launched))
             continue
         if cost is not None:
             # actual bytes moved = the kernel's DMA windows: per term,
@@ -957,9 +977,16 @@ def _launch_pure_groups_async(seg: Segment,
             cost.note_actual(int(nrows.sum()) * LANES * 8,
                              int(lens.sum()), K_launch * len(gvqs),
                              path="kernel")
-        pending.append((gvqs, K_launch, fused_bm25_topk_tfdl(
-            al.d_docs, al.d_tfdl, rowstarts, nrows, lens, skips, weights,
-            msm, avg, dlo, dhi, T=T_pad, L=L, K=K_launch, k1=k1, b=b_eff)))
+        with TRACER.span("device.dispatch", program="frontier",
+                         kernel="fused_bm25_topk_tfdl",
+                         first_call=_first_launch(
+                             "tfdl", al.d_docs.shape, len(gvqs), T_pad, L,
+                             K_launch, k1, b_eff)):
+            launched = fused_bm25_topk_tfdl(
+                al.d_docs, al.d_tfdl, rowstarts, nrows, lens, skips,
+                weights, msm, avg, dlo, dhi, T=T_pad, L=L, K=K_launch,
+                k1=k1, b=b_eff)
+        pending.append((gvqs, K_launch, launched))
     return pending
 
 
@@ -2350,10 +2377,16 @@ def _launch_bool(seg: Segment, ctx, specs: Sequence[FastSpec], K: int
             cost.note_actual(int(nrows.sum()) * LANES * 8,
                              int(lens.sum()), K_extract * len(gvqs),
                              path="kernel_bool")
-        pending.append((gvqs, fused_bm25_bool_topk(
-            d_docs, d_tfdl, filt, rowstarts, nrows, lens, skips, weights,
-            cw, thresh, avg, dlo, dhi, TS=TS, L=L, K=K_extract, k1=k1,
-            b=b_eff, filtered=filtered)))
+        with TRACER.span("device.dispatch", program="frontier_bool",
+                         kernel="fused_bm25_bool_topk",
+                         first_call=_first_launch(
+                             "bool", d_docs.shape, filt.shape, len(gvqs),
+                             TS, L, K_extract, k1, b_eff, filtered)):
+            launched = fused_bm25_bool_topk(
+                d_docs, d_tfdl, filt, rowstarts, nrows, lens, skips,
+                weights, cw, thresh, avg, dlo, dhi, TS=TS, L=L,
+                K=K_extract, k1=k1, b=b_eff, filtered=filtered)
+        pending.append((gvqs, launched))
     return (vq_lists, pending)
 
 

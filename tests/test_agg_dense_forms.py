@@ -199,8 +199,10 @@ def test_the_dense_ops_carry_the_sub_metric_scope():
     text = jax.jit(
         lambda b, v, w: agg_ops.bucketed_sub_metric(b, v, w, 16, inv, False)
     ).lower(b, v, w).as_text(debug_info=True)
-    # the loop over the blocks and what runs inside it are under the scope
-    assert agg_ops.SUB_METRIC_SCOPE + "/while/body" in text
+    # the loop over the blocks and what runs inside it are under the scope,
+    # and under the dense form's own inside it (PR 38)
+    assert (f"{agg_ops.SUB_METRIC_SCOPE}/{agg_ops.DENSE_SCOPE}/while/body"
+            in text)
     assert "stablehlo.scatter" not in text
 
 

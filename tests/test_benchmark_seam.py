@@ -3,6 +3,8 @@ benchmark's own suite (`benchmark/tests`) runs by hand, so a PR that breaks
 the seam between the harness and what it finds by name would otherwise
 show only on the chip."""
 
+import glob
+import gzip
 import json
 import os
 import sys
@@ -66,13 +68,23 @@ def test_a_per_layer_metric_has_its_reader(metric):
     assert callable(harness._load_module(path, "reader_" + metric).read)
 
 
-def test_the_new_readers_read_nothing_from_a_program_without_their_source():
+# the readers of a launch's spans and of the device's named stages (PR 38)
+LAUNCH_READERS = ("dispatch_ms_per_query", "launch_latency_ms_per_query",
+                  "readback_ms_per_query", "device_scoped_share",
+                  "impact_accumulate_ms_per_query",
+                  "rescore_probe_ms_per_query", "executor_topk_ms_per_query")
+
+
+def test_the_new_readers_read_nothing_from_a_program_without_their_source(
+        tmp_path, monkeypatch):
     """A per-layer reader returns None where the program has no such
-    counter, span or module (the parent of the PR that added it)."""
+    counter, span, scope or module (the parent of the PR that added it)."""
+    import span_reduce
+    monkeypatch.setattr(span_reduce, "OUT_DIR", str(tmp_path))  # no trace
     ctx = {"window": {"queries": 10, "counters": {}},
            "trace": {"queries": 10, "requests": 10, "module_s": {}}}
     for name in ("params_h2d_mib_per_query",
-                 "executor_program_ms_per_query"):
+                 "executor_program_ms_per_query") + LAUNCH_READERS:
         assert harness.read_layer_metric(name, ctx) is None
     ctx["window"]["counters"]["executor.params_h2d_bytes"] = 10 << 20
     ctx["trace"]["module_s"]["jit_executor_program"] = 0.5
@@ -109,3 +121,47 @@ def test_agg_run_counted_share_reads_the_counters_or_nothing(counters, want):
     aggregation, reports nothing."""
     ctx = {"window": {"queries": 88, "counters": counters}}
     assert harness.read_layer_metric("agg_run_counted_share", ctx) == want
+
+
+# the recorded 5-request traces of `benchmark/tests/data`: two of programs
+# that wrote no `device.dispatch` and named no stage (PR 24 / PR 25), two
+# recorded with them (PR 38, `launches/`)
+_DATA = os.path.join(harness.HERE, "tests", "data")
+_RECORDED = sorted(os.path.relpath(p, _DATA) for p in glob.glob(
+    os.path.join(_DATA, "**", "*.xplane.pb.gz"), recursive=True))
+
+
+@pytest.mark.parametrize("packed", _RECORDED)
+def test_the_launch_readers_on_a_recorded_trace(packed, tmp_path,
+                                                monkeypatch):
+    """On a trace of a program without the spans every reader of PR 38
+    reads nothing; on one with them the three parts of a wait's region add
+    up to the device's idle time there, within 2%."""
+    import launch_reduce
+    import span_reduce
+    import trace_reduce
+    path = str(tmp_path / "trace" / "recorded.xplane.pb")
+    os.makedirs(os.path.dirname(path))
+    with gzip.open(os.path.join(_DATA, packed)) as src, \
+            open(path, "wb") as dst:
+        dst.write(src.read())
+    monkeypatch.setattr(span_reduce, "OUT_DIR", str(tmp_path))
+    monkeypatch.setattr(launch_reduce, "_memo", {})
+    reduced = trace_reduce.reduce_file(path)
+    ctx = {"trace": dict(reduced, queries=reduced["requests"])}
+    values = {m: harness.read_layer_metric(m, ctx) for m in LAUNCH_READERS}
+    if not packed.startswith("launches"):
+        assert values == dict.fromkeys(LAUNCH_READERS)
+        return
+    seam = launch_reduce.seam_for_ctx(ctx)
+    assert seam["dispatches"] == seam["modules"] == seam["launches"] > 0
+    assert seam["identity_error"] < 0.02
+    assert all(values[m] > 0 for m in LAUNCH_READERS[:3])
+    assert 99 < values["device_scoped_share"] <= 100
+    staged = {m: v for m, v in values.items()
+              if m in LAUNCH_READERS[4:] and v is not None}
+    assert set(staged) == ({"executor_topk_ms_per_query"}
+                           if "httplogs" in packed
+                           else {"rescore_probe_ms_per_query"})
+    assert 0 < sum(staged.values()) <= 1e3 * reduced["busy_s"] / 5
+    assert span_reduce.for_ctx(ctx)["unknown"] == ["device.dispatch"]
