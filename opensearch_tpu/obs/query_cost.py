@@ -21,8 +21,10 @@ Model (documented in docs/OBSERVABILITY.md):
   shapes: the XLA path flattens a term group into a pow2 `bucket`
   (`ops.pick_bucket`), so it moves `bucket × 8` bytes and scatter-adds
   `bucket` slots; the codec-v2 impact pass (search/impactpath.py, path
-  "impact") moves `bucket × (4 + bits/8)` bytes over its block-pruned
-  windows; the fastpath kernel DMAs per-term lane-aligned windows
+  "impact") moves `2 × B_pad × 128 × (4 + bits/8)` bytes over its
+  block-pruned windows (one row of 128 slots a kept block slot, read as
+  the two 128-posting plane rows its window lies in); the fastpath
+  kernel DMAs per-term lane-aligned windows
   (`nrows × LANES` slots of 8 bytes) and extracts `K` top-k lanes per
   kernel row. The predicted/actual gap is therefore exactly the padding +
   alignment tax.

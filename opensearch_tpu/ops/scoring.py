@@ -11,7 +11,18 @@ The shape of the computation, per (segment, query term group):
 All shapes are static: the flat gather width `bucket` is a power-of-two chosen
 on the host from the *host* row pointers (no device sync), and segment arrays
 are pow2-padded (see segment.py), so XLA compiles a handful of kernels that
-get reused across queries and segments.
+get reused across queries and segments. The `searchsorted` there is over the
+`T` <= 16 row lengths of one term group (`gather_postings`,
+`gather_docs_only`). The codec-v2 impact pass searches nothing and gathers no
+element alone: its unit is the fixed-width posting block, so
+`gather_impact_blocks` reads one row of IMPACT_BLOCK slots a kept block (a
+slot's block is its row index), each as the two rows of the planes'
+[P / 128, 128] view that its window lies in:
+
+    kept blocks (bstart, blen, bweight)[B_pad] ──two row reads a block,
+    a select a lane──▶ (doc_id, quantized impact)[B_pad, 128]
+    ──one dequant multiply──▶ contrib ──row-major scatter-add──▶
+    dense scores[ndocs_pad] ──▶ masked top-C
 
 Scatter-adds here are the analog of Lucene accumulating scores doc-at-a-time;
 on TPU they run at HBM bandwidth over the whole posting block at once.
@@ -146,43 +157,65 @@ def dequant_impact_np(q, scale):
 
 def gather_impact_blocks(doc_ids: jnp.ndarray, impacts: jnp.ndarray,
                          bstart: jnp.ndarray, blen: jnp.ndarray,
-                         bucket: int):
-    """Flatten explicit posting-block windows [bstart_i, bstart_i+blen_i)
-    into static width `bucket` — the block-granular analog of
-    `gather_postings` for the codec-v2 impact path, where the host's
-    block-max prune selects WHICH blocks are gathered at all (skipped
-    blocks never move bytes). Returns (docs i32[B], iq uint[B],
-    block_idx i32[B], valid bool[B]); iq stays in the quantized integer
-    domain — callers dequantize via `dequant_impact`."""
-    nblk = bstart.shape[0]
-    cum = jnp.cumsum(blen)
-    total = cum[-1]
-    i = jnp.arange(bucket, dtype=jnp.int32)
-    b_idx = jnp.searchsorted(cum, i, side="right").astype(jnp.int32)
-    b_idx = jnp.minimum(b_idx, nblk - 1)
-    prev = jnp.where(b_idx > 0, cum[jnp.maximum(b_idx - 1, 0)], 0)
-    src = bstart[b_idx] + (i - prev)
-    valid = i < total
-    src = jnp.clip(src, 0, doc_ids.shape[0] - 1)
-    docs = jnp.where(valid, doc_ids[src], jnp.int32(2**31 - 1))
-    iq = jnp.where(valid, impacts[src], 0)
-    return docs, iq, b_idx, valid
+                         block: int):
+    """Read explicit posting-block windows [bstart_b, bstart_b+blen_b),
+    blen_b <= `block`, as one row of `block` slots each — the
+    block-granular analog of `gather_postings` for the codec-v2 impact
+    path, where the host's block-max prune selects WHICH blocks are
+    gathered at all (skipped blocks never move bytes). A slot's block is
+    its row, so nothing is searched, and no element is gathered alone:
+    the planes are viewed as [P / block, block] (the 1-D tile itself),
+    a window starting at lane `off` of plane row `r` lies in rows `r` and
+    `r + 1`, and slot (b, l) takes lane `l` of whichever of the two holds
+    a posting of the window there (row `r` where `l >= off`). The window
+    arrives rotated by `off`: slot (b, l) is posting `(l - off) mod block`
+    of block b, a posting where that is under `blen[b]`. Blocks keep the
+    plan's order; inside a block the rotation is free, because a block's
+    postings are one term's and so distinct documents: every document
+    still meets its contributions block after block.
+    Returns (docs i32[B, block], iq uint[B, block], valid bool[B, block]);
+    a slot that is no posting reads docs 2**31-1, iq 0. iq stays in the
+    quantized integer domain — callers dequantize via `dequant_impact`."""
+    nrows = max(2, -(-doc_ids.shape[0] // block))
+    pad = nrows * block - doc_ids.shape[0]
+    if pad:     # a plane of under two rows (segment planes are pow2-padded)
+        doc_ids, impacts = jnp.pad(doc_ids, (0, pad)), jnp.pad(impacts,
+                                                               (0, pad))
+    r = bstart // block
+    off = (bstart - r * block)[:, None]
+    r_next = jnp.minimum(r + 1, nrows - 1)
+    lane = jnp.arange(block, dtype=jnp.int32)[None, :]
+    in_first = lane >= off
+    nth = jnp.where(in_first, lane - off, lane - off + block)
+    valid = nth < blen[:, None]
+
+    def window(plane):
+        rows = plane.reshape(nrows, block)
+        return jnp.where(in_first, rows[r], rows[r_next])
+    docs = jnp.where(valid, window(doc_ids), jnp.int32(2**31 - 1))
+    iq = jnp.where(valid, window(impacts), 0)
+    return docs, iq, valid
 
 
 def impact_score_blocks(doc_ids: jnp.ndarray, impacts: jnp.ndarray,
                         live: jnp.ndarray, bstart: jnp.ndarray,
                         blen: jnp.ndarray, bweight: jnp.ndarray,
-                        bucket: int, ndocs_pad: int) -> ScoredMask:
+                        block: int, ndocs_pad: int) -> ScoredMask:
     """The codec-v2 eager hot loop: gather quantized impacts over the
-    kept blocks, one dequant multiply (weight·scale pre-folded per block
-    on the host), scatter-add. NO per-posting tf/doclen math — the BM25
-    saturation was evaluated at index time (BM25S eager scoring). Counts
-    are exact for the gathered blocks: postings partition (term, doc)
-    pairs, so counting postings counts matching terms."""
+    kept blocks (one row of `block` slots a block), one dequant multiply
+    (weight·scale pre-folded per block on the host, broadcast along the
+    row), scatter-add in row-major order. NO per-posting tf/doclen math —
+    the BM25 saturation was evaluated at index time (BM25S eager
+    scoring). Counts are exact for the gathered blocks: postings
+    partition (term, doc) pairs, so counting postings counts matching
+    terms."""
     with jax.named_scope("impact.gather"):
-        docs, iq, b_idx, valid = gather_impact_blocks(doc_ids, impacts,
-                                                      bstart, blen, bucket)
-        contrib = jnp.where(valid, dequant_impact(iq, bweight[b_idx]), 0.0)
+        docs, iq, valid = gather_impact_blocks(doc_ids, impacts,
+                                               bstart, blen, block)
+        contrib = jnp.where(valid, dequant_impact(iq, bweight[:, None]),
+                            0.0)
+        docs, contrib, valid = (docs.reshape(-1), contrib.reshape(-1),
+                                valid.reshape(-1))
     with jax.named_scope("impact.accumulate"):
         scores = jnp.zeros(ndocs_pad, jnp.float32).at[docs].add(
             contrib, mode="drop")

@@ -33,8 +33,8 @@ import numpy as np
 from ..index.mappings import (FLOAT_TYPES, INT_TYPES, KEYWORD_TYPES,
                               RANGE_MEMBER, RANGE_TYPES, TEXT_TYPES,
                               Mappings, coerce_value, _parse_range_value)
-from ..index.segment import (CODEC_V1, CODEC_V2, Segment, next_pow2,
-                             split_i64)
+from ..index.segment import (CODEC_V1, CODEC_V2, IMPACT_BLOCK, Segment,
+                             next_pow2, split_i64)
 from ..models.similarity import Similarity, resolve_similarity
 from ..index.date_formats import parse_date
 from ..ops import aggs as agg_ops
@@ -5311,7 +5311,8 @@ def build_rescore_program(T: int, C: int, k1: float, b: float):
 # ---------------------------------------------------------------------
 #
 # Program variants are KEYED BY CODEC layout: (impact bit width, block
-# slot bucket, gather bucket, candidate window). The program is the
+# slot bucket, candidate window); the gather is IMPACT_BLOCK slots a
+# block slot, so the block bucket fixes its width. The program is the
 # whole eager hot loop — integer impact gather over the host-pruned
 # block windows, one dequant multiply, scatter-add, masked top-C — with
 # no tf/doclen math anywhere in the trace.
@@ -5319,15 +5320,15 @@ def build_rescore_program(T: int, C: int, k1: float, b: float):
 
 @_instrumented_program_cache(
     "impact", maxsize=128,
-    shape_of=lambda B, bucket, C, bits: f"B{B}xG{bucket}xC{C}u{bits}")
-def build_impact_program(B: int, bucket: int, C: int, bits: int):
+    shape_of=lambda B, C, bits: f"B{B}xC{C}u{bits}")
+def build_impact_program(B: int, C: int, bits: int):
     import jax
 
     def impact_program(d_docs, d_impacts, live, bstart, blen, bweight, msm):
         import jax.numpy as jnp
         ndocs_pad = live.shape[0]
         sm = ops.impact_score_blocks(d_docs, d_impacts, live, bstart,
-                                     blen, bweight, bucket, ndocs_pad)
+                                     blen, bweight, IMPACT_BLOCK, ndocs_pad)
         with jax.named_scope("impact.topk"):
             ok = (sm.count >= msm) & (live > 0)
             masked = jnp.where(ok, sm.scores, ops.NEG_INF)

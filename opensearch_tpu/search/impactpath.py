@@ -13,9 +13,17 @@ codec-v2 segments (index/segment.py `ImpactPlane`). Per query:
    The pruned remainder is summarized as one sound scalar `B_rem =
    Σ_t w_t·scale·max(pruned block_max of t)`.
 2. **First pass (device).** ONE jit program (compiler.build_impact_program,
-   keyed by the codec layout): integer impact gather over the kept block
-   windows, a single dequant multiply through `ops.scoring.dequant_impact`
-   (weights pre-folded per block), scatter-add, masked top-C. No
+   keyed by the codec layout: impact bits, block-slot bucket `B_pad`,
+   candidate window): integer impact gather over the kept block windows,
+   one row of IMPACT_BLOCK slots a block slot (a slot's block is its
+   row: nothing is searched; a window is read as the two rows of the
+   planes' [P / IMPACT_BLOCK, IMPACT_BLOCK] view it lies in, a select a
+   lane, so no element is gathered alone; the `B_pad * IMPACT_BLOCK`
+   slots in row-major order keep the plan's block order, each window
+   rotated by its first lane, which reorders no document's additions:
+   a block's postings are distinct documents), a single dequant
+   multiply through `ops.scoring.dequant_impact` (weights pre-folded per
+   block, broadcast along the row), scatter-add, masked top-C. No
    per-posting tf/doclen math anywhere — the BM25 saturation was
    evaluated at index time (BM25S eager scoring, arxiv 2407.03618).
 3. **Certify (host).** Candidates are exact-rescored against the full
@@ -61,7 +69,6 @@ from ..index.segment import (CODEC_V1, CODEC_V2, IMPACT_BLOCK, Segment,
 from ..obs import flight_recorder as _fr
 from ..obs import insights as _ins
 from ..obs import query_cost as _qc
-from ..ops import scoring as ops
 from ..ops.scoring import dequant_impact_np
 from ..utils.metrics import METRICS, CounterGroup
 from ..utils.trace import TRACER
@@ -626,19 +633,22 @@ def segment_search(seg: Segment, ctx, spec: ImpactSpec, k: int
     bstart[: len(offs)] = offs.astype(np.int32)
     blen[: len(offs)] = lens
     bweight[: len(offs)] = bw
-    bucket = ops.pick_bucket(kept_post)
+    # the gather reads IMPACT_BLOCK slots a block slot (a slot's block is
+    # its row), so the program is keyed by B_pad alone
+    slots = B_pad * IMPACT_BLOCK
 
     arrs = seg.device_arrays()
     post = arrs["postings"][lt.field]
     cost = _qc.current()
     if cost is not None:
         # actual moved bytes of the eager pass: doc i32 + u8/u16 impact
-        # per gathered slot — the codec-v2 byte-volume claim, measured
-        cost.note_actual(bucket * (4 + plane.bits // 8), kept_post,
+        # per gathered slot — the codec-v2 byte-volume claim, measured.
+        # A block slot's window is read as the two plane rows it lies in
+        cost.note_actual(2 * slots * (4 + plane.bits // 8), kept_post,
                          Ccand, path="impact", segment=seg)
     with TRACER.span("impactpath.gather", blocks=int(len(offs)),
-                     bucket=bucket), METRICS.timer("impactpath.gather"):
-        prog = C.build_impact_program(B_pad, bucket, Ccand, plane.bits)
+                     slots=slots), METRICS.timer("impactpath.gather"):
+        prog = C.build_impact_program(B_pad, Ccand, plane.bits)
         launched = prog(
             post["doc_ids"], post["impacts"], arrs["live"], bstart, blen,
             bweight, np.float32(1.0 if pruned else msm))

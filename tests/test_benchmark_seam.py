@@ -68,10 +68,12 @@ def test_a_per_layer_metric_has_its_reader(metric):
     assert callable(harness._load_module(path, "reader_" + metric).read)
 
 
-# the readers of a launch's spans and of the device's named stages (PR 38)
+# the readers of a launch's spans and of the device's named stages (PR 38;
+# `impact_gather_ms_per_query` PR 39)
 LAUNCH_READERS = ("dispatch_ms_per_query", "launch_latency_ms_per_query",
                   "readback_ms_per_query", "device_scoped_share",
                   "impact_accumulate_ms_per_query",
+                  "impact_gather_ms_per_query",
                   "rescore_probe_ms_per_query", "executor_topk_ms_per_query")
 
 

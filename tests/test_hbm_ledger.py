@@ -301,14 +301,16 @@ class TestQueryCost:
         assert cost["predicted_bytes_gathered"] == 8 * 6
         assert cost["predicted_scatter_adds"] == 8
         # actual, from the launched program shape: the eager impact pass
-        # (search/impactpath.py) flattens the kept blocks into
-        # pick_bucket(8) = 256 slots (pow2 floor 256) of 6 bytes; the
-        # scatter count is the TRUE kept posting count
-        assert cost["actual_bytes_gathered"] == 256 * 6
+        # (search/impactpath.py) reads one row of IMPACT_BLOCK = 128 slots
+        # a kept block slot, each as the two 128-posting plane rows its
+        # window lies in: 3 kept blocks in the floor of 8 block slots =
+        # 2 x 1,024 slots of 6 bytes; the scatter count is the TRUE kept
+        # posting count
+        assert cost["actual_bytes_gathered"] == 2 * 8 * 128 * 6
         assert cost["actual_scatter_adds"] == 8
         assert cost["launches"] == 1
         assert cost["predicted_vs_actual_pct"] == pytest.approx(
-            100.0 * 48 / 1536, abs=0.01)
+            100.0 * 48 / 12288, abs=0.01)
 
     def test_profile_cost_v1_oracle(self, monkeypatch):
         """The legacy codec keeps the 8-byte slot model and the XLA

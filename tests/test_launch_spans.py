@@ -254,7 +254,7 @@ def _rescore_args():
 
 
 STAGED = {
-    "impact": (lambda: C.build_impact_program(8, 1024, 64, 8)._fn.__wrapped__,
+    "impact": (lambda: C.build_impact_program(8, 64, 8)._fn.__wrapped__,
                _impact_args, {},
                {"impact.gather", "impact.accumulate", "impact.topk"}),
     "rescore": (lambda: exact_rescore_batch.__wrapped__, _rescore_args,

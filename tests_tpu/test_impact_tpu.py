@@ -115,3 +115,76 @@ def test_block_compacted_windows_exclude_skipped_postings():
     assert int(totals[0][0]) == keep
     assert int(out_docs[0].max()) < 2 * keep         # no skipped docs
     assert np.all(scores[0][:128] == np.float32(200.0))
+
+
+# ---------------------------------------------------------------------
+# the XLA first pass (`compiler.build_impact_program`) at the size the
+# benchmark's `treccovid.search1.long` launches it
+# ---------------------------------------------------------------------
+
+def _flat_search_program(bucket, C):
+    """The first pass as it stood before a slot's block was its row: one
+    flat bucket of slots, each binary-searching the cumulative block
+    lengths for its block. Kept here as the reference and nowhere else."""
+    import jax.numpy as jnp
+
+    def program(d_docs, d_impacts, live, bstart, blen, bweight, msm):
+        cum = jnp.cumsum(blen)
+        i = jnp.arange(bucket, dtype=jnp.int32)
+        b_idx = jnp.minimum(
+            jnp.searchsorted(cum, i, side="right").astype(jnp.int32),
+            bstart.shape[0] - 1)
+        prev = jnp.where(b_idx > 0, cum[jnp.maximum(b_idx - 1, 0)], 0)
+        valid = i < cum[-1]
+        src = jnp.clip(bstart[b_idx] + (i - prev), 0, d_docs.shape[0] - 1)
+        docs = jnp.where(valid, d_docs[src], jnp.int32(2**31 - 1))
+        contrib = jnp.where(
+            valid, d_impacts[src].astype(jnp.float32) * bweight[b_idx], 0.0)
+        n = live.shape[0]
+        scores = jnp.zeros(n, jnp.float32).at[docs].add(contrib, mode="drop")
+        counts = jnp.zeros(n, jnp.float32).at[docs].add(
+            jnp.where(valid, 1.0, 0.0), mode="drop")
+        ok = (counts >= msm) & (live > 0)
+        vals, idx = jax.lax.top_k(jnp.where(ok, scores, -jnp.inf), C)
+        return vals, idx, jnp.sum(ok.astype(jnp.int32))
+    return jax.jit(program)
+
+
+def cell_sized_plan(seed, nblocks=4000, B_pad=4096, nterms=11,
+                    ndocs=171_332, P=18_500_000):
+    """A plan of the cell's shape: `nterms` rows, each a run of whole
+    128-posting blocks and one partial block at its end, `nblocks` in all,
+    over planes of the cell's lengths (2^25 postings, 2^18 documents)."""
+    rng = np.random.default_rng(seed)
+    d_docs = np.full(1 << 25, 2**31 - 1, np.int32)
+    d_docs[:P] = rng.integers(0, ndocs, P, dtype=np.int32)
+    d_imp = np.zeros(1 << 25, np.uint16)
+    d_imp[:P] = rng.integers(1, 65536, P).astype(np.uint16)
+    live = np.zeros(1 << 18, np.float32)
+    live[:ndocs] = 1.0
+    per = np.diff(np.linspace(0, nblocks, nterms + 1).astype(int))
+    row0 = np.sort(rng.choice(P // 128 - nblocks, nterms, replace=False)
+                   ) * 128 + rng.integers(0, 128, nterms)
+    bstart = np.zeros(B_pad, np.int32)
+    blen = np.zeros(B_pad, np.int32)
+    bweight = np.zeros(B_pad, np.float32)
+    at = 0
+    for t, n in enumerate(per):
+        bstart[at: at + n] = row0[t] + 128 * np.arange(n)
+        blen[at: at + n] = 128
+        blen[at + n - 1] = rng.integers(1, 128)
+        bweight[at: at + n] = rng.uniform(0.5, 6.0) * 1.7e-5
+        at += n
+    return d_docs, d_imp, live, bstart, blen, bweight
+
+
+def test_first_pass_equals_the_flat_search_form_at_the_cells_size():
+    from opensearch_tpu.search import compiler as C
+    d_docs, d_imp, live, bstart, blen, bweight = cell_sized_plan(39)
+    planes = [jax.device_put(a) for a in (d_docs, d_imp, live)]
+    args = (*planes, bstart, blen, bweight, np.float32(1.0))
+    want = jax.device_get(_flat_search_program(4096 * 128, 32)(*args))
+    got = jax.device_get(C.build_impact_program(4096, 32, 16)(*args))
+    assert int(got[2]) == int(want[2]) > 0
+    np.testing.assert_array_equal(got[1], want[1])
+    np.testing.assert_array_equal(got[0], want[0])     # the same f32 sums
