@@ -849,17 +849,24 @@ class Segment:
             ivf = col.ivf(vcols[f]["mat"])
             if ivf is not None:
                 # nlist padded pow2; padding rows are invalid (cvalid
-                # False -> -inf centroid score, lists slots -1)
+                # False -> -inf centroid score, fill 0)
                 lpad = next_pow2(ivf.nlist)
                 cent = np.zeros((lpad, dpad128), np.float32)
                 cent[: ivf.nlist, : ivf.centroids.shape[1]] = ivf.centroids
-                lists = np.full((lpad, ivf.cap), -1, np.int32)
-                lists[: ivf.nlist] = ivf.lists
                 cvalid = np.zeros(lpad, bool)
                 cvalid[: ivf.nlist] = True
                 vcols[f]["ivf_centroids"] = jnp.asarray(cent)
-                vcols[f]["ivf_lists"] = jnp.asarray(lists)
                 vcols[f]["ivf_cvalid"] = jnp.asarray(cvalid)
+                # the rows once more, in list order (ops/ann.py): a probe
+                # reads a list as one dense window of this matrix
+                from ..ops.ann import list_rows
+                vcols[f]["ivf_ids"] = jnp.asarray(ivf.order)
+                vcols[f]["ivf_rows"] = list_rows(vcols[f]["mat"],
+                                                 vcols[f]["ivf_ids"])
+                vcols[f]["ivf_offset"] = jnp.asarray(
+                    _pad_to(ivf.offset, lpad, np.int32(0)))
+                vcols[f]["ivf_fill"] = jnp.asarray(
+                    _pad_to(ivf.fill, lpad, np.int32(0)))
         gcols = {f: _geo_field_arrays(col, dpad, jnp)
                  for f, col in self.geo_cols.items()}
         dls = {f: jnp.asarray(_pad_to(dl.astype(np.float32), dpad, np.float32(0)))
@@ -898,11 +905,11 @@ class Segment:
         # dense-vector residency is its own tenant pair (ISSUE 15: kNN
         # as a first-class serving citizen needs its HBM bytes visible):
         # the doc matrices under `vector_columns`, the balanced-IVF
-        # probe structures (centroids + dense lists + validity) under
-        # `ann_ivf` — both still charged, just attributed
-        ivf_bytes = sum(int(v[k2].nbytes) for v in vcols.values()
-                        for k2 in ("ivf_centroids", "ivf_lists",
-                                   "ivf_cvalid") if k2 in v)
+        # probe structures (centroids + validity, the rows in list order
+        # with their ids, offsets and fills) under `ann_ivf` — both
+        # still charged, just attributed
+        ivf_bytes = sum(int(a.nbytes) for v in vcols.values()
+                        for k2, a in v.items() if k2.startswith("ivf_"))
         vec_bytes = _tree_nbytes(vcols) - ivf_bytes
         nbytes = sum(_tree_nbytes(self._device_cache[key][g])
                      for g in ("postings", "numeric", "keyword",

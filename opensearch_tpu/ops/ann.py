@@ -9,13 +9,28 @@ design is inverted-file with BALANCED clusters instead:
   then a vectorized host pass that caps every cluster at `cap` rows,
   spilling overflow to the row's second-best cluster (the ScaNN-style
   trade: bounded list length buys static shapes and dense DMA).
-- Layout: `lists` is a DENSE i32[nlist, cap] matrix (-1 padded). A probe
-  is `lists[top_nprobe]` — one gather of a [nprobe, cap] tile, no CSR
-  walk, no dynamic shapes anywhere.
+- Layout: the build's product is `lists`, a DENSE i32[nlist, cap] matrix of
+  doc ids (-1 padded), which stays on the host. On the device a list's
+  ROWS lie next to one another (`list_rows`): one compact matrix
+  f32[rows + cap, D], list 0's rows, then list 1's, ..., and a tail of
+  `cap` zero rows so that a window of `cap` rows from the last list's
+  start stays in bounds; beside it each slot's doc id (i32[rows + cap],
+  -1 in the tail) and each list's first slot and fill (`order`, `offset`,
+  `fill` below). A list starts wherever the one before it ends: on the
+  chip a window that starts off a multiple of 8 rows (the float32 tile's
+  sublanes) reads no slower than one on it (tests_tpu/test_knn_tpu.py).
+  Compact, not [nlist, cap, D]: at slack 1.5 the padded form is half again
+  the size of the vectors. The copy costs what the vectors cost (3.07 GB a
+  million rows of 768 floats), beside the doc-ordered matrix the exact
+  scan and every other reader keep.
 - Search (in search/compiler.py emit "knn"): centroid matvec -> static
-  top-nprobe -> gather candidate rows -> MXU matvec -> scatter scores
-  back into the dense per-doc score space, so ANN kNN composes with every
-  other plan node (bool, filters, aggs) exactly like the exact path.
+  top-nprobe -> for each probed list the window of `cap` rows at its
+  offset, read in place by the scoring product (rows past the list's fill
+  belong to the next list and are masked) -> scatter scores back into the
+  dense per-doc score space, so ANN kNN composes with every other plan
+  node (bool, filters, aggs) exactly like the exact path. No dynamic
+  shapes anywhere: a probe is `nprobe` dense slices, never a fetch by doc
+  id.
 
 Setting nprobe = nlist provably recovers the exact search (every row is
 in exactly one list), which the tests assert.
@@ -37,10 +52,12 @@ from ..utils.metrics import METRICS, CounterGroup
 # `rows` the present rows filed; `spilled_rows` those that did not fit
 # their nearest list and went to the second-best (or, rarely, to any list
 # with room): a probe that would have found them in their own list has to
-# reach the other one; `nlist` / `cap` of the last build
+# reach the other one; `nlist` / `cap` of the last build. `list_rows` adds
+# its seconds (the device gather's compile and launch) to `build_s` and
+# sets `list_rows_bytes`, the resident bytes of the last list-ordered copy
 IVF_STATS = CounterGroup(METRICS, "ivf", {"build_s": 0.0, "rows": 0,
                                           "spilled_rows": 0, "nlist": 0,
-                                          "cap": 0})
+                                          "cap": 0, "list_rows_bytes": 0})
 
 
 @dataclass
@@ -50,6 +67,10 @@ class IvfIndex:
     nlist: int
     cap: int
     default_nprobe: int
+    # the lists end to end, as the device keeps their rows (`list_rows`)
+    order: np.ndarray       # i32[rows + cap]: a slot's doc id, -1 = no row
+    offset: np.ndarray      # i32[nlist]: a list's first slot
+    fill: np.ndarray        # i32[nlist]: its rows (slots offset .. offset+fill)
 
 
 def _next_pow2(n: int) -> int:
@@ -210,5 +231,41 @@ def build_ivf(values, present: np.ndarray,
     IVF_STATS.inc("rows", npres)
     IVF_STATS.inc("spilled_rows", int(len(spill)))
     IVF_STATS["nlist"], IVF_STATS["cap"] = nlist, cap
+    order, offset, fill = _list_order(lists)
     return IvfIndex(centroids=cents, lists=lists, nlist=nlist, cap=cap,
-                    default_nprobe=default_nprobe)
+                    default_nprobe=default_nprobe, order=order,
+                    offset=offset, fill=fill)
+
+
+def _list_order(lists: np.ndarray):
+    """(order, offset, fill) of `IvfIndex`: list l's filled slots (a prefix
+    of its row: every round of the fill appends) go to slots
+    offset[l] .. offset[l] + fill[l]."""
+    filled = lists >= 0
+    fill = filled.sum(axis=1)
+    order = np.concatenate([lists[filled],
+                            np.full(lists.shape[1], -1, np.int32)])
+    return (order, (np.cumsum(fill) - fill).astype(np.int32),
+            fill.astype(np.int32))
+
+
+def _rows_in_order(mat, order):
+    import jax.numpy as jnp
+
+    return jnp.where((order >= 0)[:, None], mat[jnp.maximum(order, 0)], 0.0)
+
+
+def list_rows(mat, order):
+    """The rows in list order, made on the device: `mat[order]`, zero where
+    a slot holds no row. mat: f32[N, D] (the resident doc-ordered matrix),
+    order: i32[S] on the same device -> f32[S, D]. The vectors never come
+    back to the host; the gather's seconds count as build (its compile and
+    launch: the caller holds the segment's build lock, so nothing waits
+    here for the device)."""
+    import jax
+
+    t0 = time.perf_counter()
+    rows = jax.jit(_rows_in_order)(mat, order)
+    IVF_STATS.inc("build_s", time.perf_counter() - t0)
+    IVF_STATS["list_rows_bytes"] = int(rows.nbytes)
+    return rows

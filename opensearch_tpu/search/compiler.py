@@ -105,13 +105,17 @@ AGG_STATS = CounterGroup(METRICS, "aggs", {"scatter.updates": 0,
 # the static spec (`_count_launch`): `queries` the nodes over a segment that
 # holds the field, `ann_queries` / `exact_queries` those that probe the
 # column's IVF lists / scan the whole matrix, `lists_probed` the lists a
-# probe reads (`nprobe`), `candidate_slots` the rows it gathers, scores and
-# scatters back (`nprobe * cap`, padding slots included), and
-# `query_vector_bytes` the padded query vector a node is handed
+# probe reads (`nprobe`), `candidate_slots` the slots it reads, scores and
+# scatters back (`nprobe * cap`: a window of `cap` rows a list, whatever
+# the list holds), `rows_by_id` the candidate rows a launch fetches one doc
+# id at a time (none: a probe reads its lists where they lie, as dense
+# windows of the list-ordered rows), and `query_vector_bytes` the padded
+# query vector a node is handed
 KNN_STATS = CounterGroup(METRICS, "knn", {"queries": 0, "ann_queries": 0,
                                           "exact_queries": 0,
                                           "lists_probed": 0,
                                           "candidate_slots": 0,
+                                          "rows_by_id": 0,
                                           "query_vector_bytes": 0})
 # the precision a `knn` node's scoring product names. Unnamed, a batch of
 # queries a launch (the vmapped `msearch` twin) is ONE bfloat16 pass of the
@@ -1896,7 +1900,8 @@ def _pad_to_sentinel(arr: np.ndarray, size: int) -> np.ndarray:
 
 def _prepare_knn(node, seg: Segment, ctx, params: dict):
     """A `knn` node's params (the padded query vector, |q|^2, the boost),
-    its filter's spec, and the static probe width where the column has an
+    its filter's spec, and the static probe (`(nprobe, cap)`: the lists a
+    probe reads and the rows of a list's window) where the column has an
     IVF index and the query did not force the exact scan."""
     nid = node.nid
     col_exists = node.field in seg.vector_cols
@@ -1909,16 +1914,17 @@ def _prepare_knn(node, seg: Segment, ctx, params: dict):
     _scalar_f32(params, f"q{nid}_boost", node.boost)
     fspec = prepare(node.filter, seg, ctx, params) if node.filter else None
     # ANN route: mapping opted into IVF and the query didn't force
-    # exact -> static nprobe (jit-key) clamped to this segment's nlist.
-    # Building here (host, once, cached on the column) keeps emit pure.
-    ann_nprobe = None
+    # exact -> static nprobe (jit-key) clamped to this segment's nlist,
+    # with the index's cap. Building here (host, once, cached on the
+    # column) keeps emit pure.
+    probe = None
     if col_exists and not node.exact:
         ivf = seg.vector_cols[node.field].ivf()
         if ivf is not None:
-            ann_nprobe = int(min(node.nprobe or ivf.default_nprobe,
-                                 ivf.nlist))
+            probe = (int(min(node.nprobe or ivf.default_nprobe, ivf.nlist)),
+                     ivf.cap)
     return ("knn", nid, node.field, col_exists, node.similarity, fspec,
-            ann_nprobe)
+            probe)
 
 
 def prepare(node: LNode, seg: Segment, ctx: ShardContext, params: dict):  # noqa: C901
@@ -3177,7 +3183,7 @@ def emit(spec, seg_arrays: dict, params: dict) -> ops.ScoredMask:  # noqa: C901
     if kind == "knn":
         import jax
         from jax import lax as _lax
-        _, _, field, col_exists, simkind, fspec, ann_nprobe = spec
+        _, _, field, col_exists, simkind, fspec, probe = spec
         if not col_exists:
             return ops.ScoredMask(zeros, zeros)
         vc = seg_arrays["vector"][field]
@@ -3196,12 +3202,15 @@ def emit(spec, seg_arrays: dict, params: dict) -> ops.ScoredMask:  # noqa: C901
             return jnp.dot(vecs, qvec, preferred_element_type=jnp.float32,
                            precision=_KNN_SCORE_PRECISION)
 
-        if ann_nprobe is not None and "ivf_centroids" in vc:
+        if probe is not None and "ivf_centroids" in vc:
             # balanced-IVF probe (ops/ann.py): centroid matvec -> static
-            # top-nprobe -> dense [nprobe, cap] list gather -> candidate
-            # matvec -> scatter back into doc space. Everything static-shape;
-            # candidate count = nprobe*cap regardless of data.
-            cents, lists = vc["ivf_centroids"], vc["ivf_lists"]
+            # top-nprobe -> each probed list's window of `cap` rows, read
+            # where it lies by the product -> scatter back into doc space.
+            # Everything static-shape; candidate count = nprobe*cap
+            # regardless of data.
+            nprobe, cap = probe
+            cents = vc["ivf_centroids"]
+            rows, ids = vc["ivf_rows"], vc["ivf_ids"]
             with jax.named_scope("knn.centroids"):
                 # default precision: this product only chooses lists
                 cdot = jnp.dot(cents, qvec,
@@ -3211,15 +3220,30 @@ def emit(spec, seg_arrays: dict, params: dict) -> ops.ScoredMask:  # noqa: C901
                 else:  # l2: nearest centroid = max of 2c.q - ||c||^2
                     caff = 2.0 * cdot - jnp.sum(cents * cents, axis=1)
                 caff = jnp.where(vc["ivf_cvalid"], caff, -jnp.inf)
-                _, pids = _lax.top_k(caff, ann_nprobe)
+                _, pids = _lax.top_k(caff, nprobe)
+
+            def one_list(_, start):
+                # the window runs past a short list's fill into the next
+                # list's rows (the last list's into the zero tail): those
+                # slots are masked below, by the fill
+                with jax.named_scope("knn.gather"):
+                    win = _lax.dynamic_slice(rows, (start, 0),
+                                             (cap, rows.shape[1]))
+                    cand = _lax.dynamic_slice(ids, (start,), (cap,))
+                with jax.named_scope("knn.score"):
+                    s = _sim_score(_product(win),
+                                   lambda: jnp.sum(win * win, axis=1))
+                return None, (s, cand)
+
             with jax.named_scope("knn.gather"):
-                cand = lists[pids].reshape(-1)            # i32[nprobe*cap]
-                valid = cand >= 0
-                vecs = vc["mat"][jnp.where(valid, cand, 0)]
+                starts, fills = vc["ivf_offset"][pids], vc["ivf_fill"][pids]
+            # two lists a step: what a step costs beside its product is
+            # the loop's own time (PERF.md section 6, PR 42: the forms)
+            _, (s, cand) = _lax.scan(one_list, None, starts, unroll=2)
             with jax.named_scope("knn.score"):
-                s = _sim_score(_product(vecs),
-                               lambda: jnp.sum(vecs * vecs, axis=1))
-                s = jnp.where(valid, s, 0.0)
+                valid = (jnp.arange(cap) < fills[:, None]).reshape(-1)
+                cand = cand.reshape(-1)                   # i32[nprobe*cap]
+                s = jnp.where(valid, s.reshape(-1), 0.0)
             with jax.named_scope("knn.scatter"):
                 cidx = jnp.where(valid, cand, ndocs_pad)  # OOB -> dropped
                 # each doc lives in exactly one list -> max==set, but max is
@@ -5694,17 +5718,17 @@ def _knn_nodes(spec):
 def _count_knn(node, seg_arrays: dict, cparams: dict) -> None:
     """One `knn` node of a launch into `KNN_STATS`, by the predicate
     `emit` itself routes by."""
-    _, nid, field, col_exists, _sim, _fspec, ann_nprobe = node
+    _, nid, field, col_exists, _sim, _fspec, probe = node
     if not col_exists:
         return
     vc = seg_arrays["vector"][field]
     KNN_STATS.inc("queries")
     KNN_STATS.inc("query_vector_bytes", cparams[f"q{nid}_vec"].nbytes)
-    if ann_nprobe is not None and "ivf_centroids" in vc:
+    if probe is not None and "ivf_centroids" in vc:
+        nprobe, cap = probe
         KNN_STATS.inc("ann_queries")
-        KNN_STATS.inc("lists_probed", ann_nprobe)
-        KNN_STATS.inc("candidate_slots",
-                      ann_nprobe * vc["ivf_lists"].shape[1])
+        KNN_STATS.inc("lists_probed", nprobe)
+        KNN_STATS.inc("candidate_slots", nprobe * cap)
     else:
         KNN_STATS.inc("exact_queries")
 

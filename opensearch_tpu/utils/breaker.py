@@ -40,12 +40,15 @@ class CircuitBreaker:
 
 
 class BreakerService:
-    def __init__(self, device_limit_bytes: int = 12 << 30):
-        # v5e has 16 GiB HBM; leave headroom for scratch + compiled programs.
-        # fielddata covers the fastpath's device-resident layouts (aligned
-        # postings + filter-specialized copies), the dominant HBM tenant —
-        # give it most of the budget (reference fielddata default is 40% of
-        # a JVM heap; HBM residency is this engine's whole design)
+    def __init__(self, device_limit_bytes: int = 16 << 30):
+        # v5e has 16 GiB HBM. fielddata covers every device-resident
+        # layout (the fastpath's aligned postings + filter-specialized
+        # copies, the segments' columns, the vector matrices and the IVF
+        # rows in list order), the dominant HBM tenant — give it three
+        # quarters (12 GiB: a shard of 2M vectors of 768 floats with its
+        # list-ordered copy is 12.65 GB), the rest is scratch + compiled
+        # programs (reference fielddata default is 40% of a JVM heap; HBM
+        # residency is this engine's whole design)
         self.breakers = {
             "fielddata": CircuitBreaker("fielddata",
                                         device_limit_bytes * 3 // 4),

@@ -17,10 +17,10 @@ DIMS = 32
 NDOCS = 400
 
 
-def _clustered(n, d, ncenters=12, spread=0.4):
-    centers = RNG.normal(size=(ncenters, d)).astype(np.float32) * 2.5
-    v = centers[RNG.integers(0, ncenters, n)] + \
-        RNG.normal(size=(n, d)).astype(np.float32) * spread
+def _clustered(n, d, ncenters=12, spread=0.4, rng=RNG):
+    centers = rng.normal(size=(ncenters, d)).astype(np.float32) * 2.5
+    v = centers[rng.integers(0, ncenters, n)] + \
+        rng.normal(size=(n, d)).astype(np.float32) * spread
     return v.astype(np.float32)
 
 
@@ -43,6 +43,89 @@ class TestBuildIvf:
         v = _clustered(10, 8)
         ivf = build_ivf(v, np.ones(10, bool), nlist=64)
         assert ivf.nlist <= 10
+
+
+def _vector_segment(n, dims, absent_every=0, deleted=(), sim="dot_product"):
+    """One planted segment holding one IVF-mapped vector column."""
+    from opensearch_tpu.index.segment import Segment, VectorColumn
+    # (a generator of its own: the cases below draw from the module's)
+    vecs = _clustered(n, dims, rng=np.random.default_rng(n))
+    pres = np.ones(n, bool)
+    if absent_every:
+        pres[::absent_every] = False
+    col = VectorColumn("emb", vecs, pres, sim, method={"name": "ivf"})
+    seg = Segment(name="v0", ndocs=n, postings={}, numeric_cols={},
+                  keyword_cols={}, geo_cols={}, doc_lens={}, text_stats={},
+                  ids=[str(i) for i in range(n)], sources=[None] * n,
+                  vector_cols={"emb": col})
+    for d in deleted:
+        seg.delete_doc(d)
+    return seg, vecs, pres
+
+
+@pytest.mark.parametrize("n,absent_every,deleted", [
+    (300, 0, ()), (300, 7, ()), (5000, 0, ()), (5000, 13, ()),
+    (5000, 0, (3, 1700, 4999)), (20000, 0, ()), (20000, 11, ())])
+class TestListOrderedResidency:
+    """The device keeps a list's rows next to one another (`ann.list_rows`):
+    the partition `IvfIndex.lists` describes, row for row, and a probe of
+    every list through it is the exact scan."""
+
+    def test_the_rows_in_list_order_are_the_partition(self, n, absent_every,
+                                                      deleted):
+        seg, _vecs, pres = _vector_segment(n, DIMS, absent_every, deleted)
+        ivf = seg.vector_cols["emb"].ivf()
+        vc = seg.device_arrays()["vector"]["emb"]
+        assert "ivf_lists" not in vc
+        mat, rows_l, ids = (np.asarray(vc[k])
+                            for k in ("mat", "ivf_rows", "ivf_ids"))
+        offset, fill = np.asarray(vc["ivf_offset"]), np.asarray(vc["ivf_fill"])
+        assert (ids == ivf.order).all() and len(rows_l) == len(ids)
+        assert (offset[: ivf.nlist] == ivf.offset).all()
+        assert (fill[: ivf.nlist] == ivf.fill).all()
+        assert not fill[ivf.nlist:].any()       # a padded centroid: no rows
+        # every present row once (a deleted one too: `live` masks it)
+        assert sorted(ids[ids >= 0].tolist()) == np.nonzero(pres)[0].tolist()
+        assert (fill[: ivf.nlist] == (ivf.lists >= 0).sum(axis=1)).all()
+        for li in range(ivf.nlist):
+            o, f = offset[li], fill[li]
+            assert (ids[o: o + f] == ivf.lists[li, :f]).all()
+            assert (ivf.lists[li, f:] == -1).all()
+            assert np.array_equal(rows_l[o: o + f], mat[ivf.lists[li, :f]])
+        # no row where no id is; the tail a whole window of them
+        assert not rows_l[ids < 0].any()
+        assert (ids[-ivf.cap:] == -1).all()
+        assert offset[ivf.nlist - 1] + ivf.cap <= len(ids)
+
+    def test_probing_every_list_is_the_exact_scan(self, n, absent_every,
+                                                  deleted):
+        import jax
+        from opensearch_tpu.search import compiler as C
+        seg, vecs, _pres = _vector_segment(n, DIMS, absent_every, deleted)
+        ivf = seg.vector_cols["emb"].ivf()
+        arrays = seg.device_arrays()
+        q = np.zeros(128, np.float32)
+        q[:DIMS] = vecs[5] + 0.05
+        params = {"q1_vec": q, "q1_qsq": np.float32(q @ q),
+                  "q1_boost": np.float32(1.0)}
+
+        def plane(probe):
+            node = ("knn", 1, "emb", True, "dot_product", None, probe)
+            sm = jax.jit(lambda a, p: C.emit(node, a, p))(arrays, params)
+            return np.asarray(sm.scores), np.asarray(sm.count)
+        (ann_s, ann_m), (exact_s, exact_m) = \
+            plane((ivf.nlist, ivf.cap)), plane(None)
+        assert (ann_m == exact_m).all()
+        assert ann_m.sum() == seg.live_count - (~_pres & seg.live).sum()
+        page = np.argsort(-exact_s, kind="stable")[:50]
+        assert (np.argsort(-ann_s, kind="stable")[:50] == page).all()
+        assert ann_s[page] == pytest.approx(exact_s[page], rel=1e-6)
+        # a narrow probe reaches whole lists, and only present live rows
+        part_s, part_m = plane((max(1, ivf.nlist // 8), ivf.cap))
+        assert 0 < part_m.sum() < exact_m.sum()
+        assert not (part_m > exact_m).any()
+        assert part_s[part_m > 0] == pytest.approx(exact_s[part_m > 0],
+                                                   rel=1e-6)
 
 
 @pytest.fixture(scope="module", params=["cosine", "l2_norm", "dot_product"])

@@ -222,7 +222,7 @@ def test_the_ivf_build_reads_the_resident_matrix_and_counts(deployments):
     """`device_arrays` hands `build_ivf` the matrix it has just put on the
     device: the same index as a build from the host rows, no second copy
     of the vectors, and the `ivf.*` counters say what it made."""
-    _client, built, _stream, _config = deployments("innerproduct", "ivf")
+    client, built, _stream, _config = deployments("innerproduct", "ivf")
     vecs = built["corpus"]["vectors"]
     before = dict(ann.IVF_STATS.items())
     host = ann.build_ivf(vecs, np.ones(len(vecs), bool))
@@ -247,15 +247,26 @@ def test_the_ivf_build_reads_the_resident_matrix_and_counts(deployments):
     spilled = (after["spilled_rows"] - before["spilled_rows"]) // 2
     assert 0 < spilled < NDOCS // 2             # uneven topics do spill
     ro = built["readout"]["ivf"]
-    assert (ro["nlist"], ro["cap"], ro["rows"]) == (64, 96, NDOCS)
+    # (`rows` counts every build of the process, another file's too)
+    assert (ro["nlist"], ro["cap"]) == (64, 96) and ro["rows"] >= NDOCS
     assert set(ann.IVF_STATS) == {"build_s", "rows", "spilled_rows",
-                                  "nlist", "cap"}
+                                  "nlist", "cap", "list_rows_bytes"}
+    # the rows once more in list order, charged where the centroids are
+    vc = client.node.indices[harness.INDEX].shards[0].segments[0] \
+        .device_arrays()["vector"][FIELD]
+    assert "ivf_lists" not in vc
+    assert vc["ivf_rows"].shape == (len(host.order), 128)
+    assert ro["list_rows_bytes"] == vc["ivf_rows"].nbytes \
+        == len(host.order) * 128 * 4
+    from opensearch_tpu.obs.hbm_ledger import LEDGER
+    assert LEDGER.snapshot()["tenants"]["ann_ivf"]["bytes"] \
+        >= ro["list_rows_bytes"]
 
 
 def test_knn_stats_count_a_launch_by_its_route(deployments):
     assert set(C.KNN_STATS) == {"queries", "ann_queries", "exact_queries",
                                 "lists_probed", "candidate_slots",
-                                "query_vector_bytes"}
+                                "rows_by_id", "query_vector_bytes"}
     client, built, _stream, _config = deployments("innerproduct", "ivf")
     flat_client = deployments("innerproduct", "flat")[0]
     # vectors no other test has sent: the request cache answers a body it
@@ -269,7 +280,7 @@ def test_knn_stats_count_a_launch_by_its_route(deployments):
     assert {k: c1[k] - c0[k] for k in c1} == {
         "queries": 3, "ann_queries": 3, "exact_queries": 0,
         "lists_probed": 3 * (nlist // 8),
-        "candidate_slots": 3 * (nlist // 8) * cap,
+        "candidate_slots": 3 * (nlist // 8) * cap, "rows_by_id": 0,
         "query_vector_bytes": 3 * 128 * 4}      # 64 floats pad to 128
     _held(flat_client, stream.take(2))
     _held(client, stream.take(1), exact=True)
@@ -294,7 +305,7 @@ def test_the_program_names_its_knn_scopes(deployments, method, want):
     params = {"q1_vec": qvec, "q1_qsq": np.float32(1.0),
               "q1_boost": np.float32(1.0)}
     node = ("knn", 1, FIELD, True, "dot_product", None,
-            4 if method == "ivf" else None)
+            (4, built["readout"]["ivf"]["cap"]) if method == "ivf" else None)
     text = jax.jit(lambda a, p: C.emit(node, a, p).scores).lower(
         seg.device_arrays(), params).as_text(debug_info=True)
     scopes = {s for s in ("knn.centroids", "knn.gather", "knn.score",
