@@ -489,14 +489,6 @@ def ord_counts(ords: jnp.ndarray, match: jnp.ndarray, nord_pad: int
     return bucket_counts(o, match, nord_pad)
 
 
-def cardinality_keyword(kw: dict, match: jnp.ndarray, nvocab_pad: int) -> jnp.ndarray:
-    """Exact distinct count via ordinals (the reference uses global ords +
-    HLL; segment-local ords are exact on-device, merged across segments on
-    the host via vocab union)."""
-    counts = terms_counts(kw, match, nvocab_pad)
-    return jnp.sum(jnp.where(counts > 0, 1, 0))
-
-
 def _hash_f32(v: jnp.ndarray) -> jnp.ndarray:
     """Cheap 32-bit integer mix (fmix32 from MurmurHash3) of float bit patterns."""
     h = jax.lax.bitcast_convert_type(v, jnp.int32).astype(jnp.uint32)
@@ -531,11 +523,14 @@ def cardinality_numeric_registers(values_f32: jnp.ndarray, present: jnp.ndarray,
 
 
 def cardinality_keyword_registers(kw: dict, match: jnp.ndarray, nvocab_pad: int,
-                                  ord_hashes_u32: jnp.ndarray, log2m: int = 14) -> jnp.ndarray:
+                                  ord_hashes_u32: jnp.ndarray, log2m: int = 14):
     """Keyword cardinality: HLL over per-ordinal string hashes (host-computed
-    once per segment), activated by matched ordinals."""
-    counts = terms_counts(kw, match, nvocab_pad)
-    return hll_registers(ord_hashes_u32, counts > 0, log2m)
+    once per segment), activated by matched ordinals -> (registers, the
+    number of matched ordinals: the segment's exact distinct count, which
+    is the answer where one segment gives it and nothing is merged)."""
+    held = terms_counts(kw, match, nvocab_pad) > 0
+    return (hll_registers(ord_hashes_u32, held, log2m),
+            jnp.sum(held.astype(jnp.int32)))
 
 
 # DDSketch-style log-binned quantile sketch: bins are GLOBAL constants

@@ -9,9 +9,20 @@ OpenSearch-shaped response JSON.
 
 Design notes vs the reference:
 - terms aggs are exact per shard (full ordinal bincount on device — no
-  shard_size truncation error; doc_count_error_upper_bound is honestly 0).
+  shard_size truncation error; doc_count_error_upper_bound is honestly 0,
+  sum_other_doc_count the exact rest). A `terms` / `multi_terms` partial
+  carries its counts as ARRAYS by ordinal (`OrdinalBuckets`: the segment's
+  vocabulary, or the combinations that occur in it, with the sub-metrics'
+  columns beside them); segments merge by vocabulary, array to array, and
+  a bucket becomes a Python record only when `finalize` knows the `size`
+  buckets the response returns. A `composite` partial is records already,
+  but of one page: the first `size` non-empty combinations after `after`
+  in key order, which is all a merged page can draw from a segment.
 - cardinality is device-side HyperLogLog (log2m=14) over value hashes —
-  mergeable across segments and shards like the reference's HLL++.
+  mergeable across segments and shards like the reference's HLL++. A
+  keyword cardinality that ONE segment answers is its exact count of
+  matched ordinals (`distinct`); merged with anything it is the sketch's
+  estimate (standard error 1.04 / sqrt(2^14) = 0.81%).
 - percentiles use a mergeable 4096-bin histogram sketch between index-wide
   column bounds instead of TDigest.
 """
@@ -56,6 +67,79 @@ class AggNode:
     deferred: bool = False
 
 
+_STAT_ADD = ("count", "sum", "sumsq")
+
+
+class OrdinalBuckets:
+    """A `terms` or `multi_terms` partial's buckets as arrays: `counts`
+    int[n] by ordinal, `keys` the sequence that names an ordinal's key
+    (a segment's sorted vocabulary, a `compiler.ComboSpace`: ordinal order
+    is key order), `subs` {sub-aggregation name: {"count", "sum", "min",
+    "max", "sumsq": float[n]}} for the metric sub-aggregations the launch
+    carried. `items()` spells the non-empty buckets as the records the
+    other bucket kinds' partials hold, for a consumer that wants them all
+    (counted: `aggs.terms.records`)."""
+
+    __slots__ = ("keys", "counts", "subs")
+
+    def __init__(self, keys, counts: np.ndarray, subs: Optional[dict] = None):
+        self.keys, self.counts, self.subs = keys, counts, subs or {}
+
+    def sub_partials(self, j: int) -> dict:
+        return {name: {k: float(v[j]) for k, v in cols.items()}
+                for name, cols in self.subs.items()}
+
+    def items(self):
+        from .compiler import AGG_STATS
+        held = np.flatnonzero(self.counts > 0)
+        AGG_STATS.inc("terms.records", len(held))
+        out = []
+        for j in held.tolist():
+            rec = {"doc_count": int(self.counts[j])}
+            subs = self.sub_partials(j)
+            if subs:
+                rec["subs"] = subs
+            out.append((self.keys[j], rec))
+        return out
+
+    @staticmethod
+    def merged(parts: List["OrdinalBuckets"]) -> "OrdinalBuckets":
+        """Segments merged by vocabulary: the union of the keys that hold
+        a document somewhere, in key order, their counts and sub-metric
+        columns added (minimum and maximum taken) array to array."""
+        if len(parts) == 1:
+            return parts[0]
+        slot: Dict[Any, int] = {}
+        at = []
+        for p in parts:
+            held = np.flatnonzero(p.counts > 0)
+            at.append((held, np.fromiter(
+                (slot.setdefault(p.keys[j], len(slot))
+                 for j in held.tolist()), np.int64, len(held))))
+        keys = sorted(slot)
+        rank = np.empty(len(keys), np.int64)
+        rank[[slot[k] for k in keys]] = np.arange(len(keys))
+        counts = np.zeros(len(keys), np.int64)
+        names = sorted({n for p in parts for n in p.subs})
+        subs = {n: {"count": np.zeros(len(keys)), "sum": np.zeros(len(keys)),
+                    "sumsq": np.zeros(len(keys)),
+                    "min": np.full(len(keys), np.inf),
+                    "max": np.full(len(keys), -np.inf)} for n in names}
+        for p, (held, to) in zip(parts, at):
+            to = rank[to]           # a key comes once a part: plain stores
+            counts[to] += np.asarray(p.counts)[held].astype(np.int64)
+            for n, cols in p.subs.items():
+                has = np.asarray(cols["count"])[held] > 0
+                for k in _STAT_ADD:
+                    subs[n][k][to] += np.asarray(cols[k])[held]
+                t = to[has]
+                subs[n]["min"][t] = np.minimum(
+                    subs[n]["min"][t], np.asarray(cols["min"])[held][has])
+                subs[n]["max"][t] = np.maximum(
+                    subs[n]["max"][t], np.asarray(cols["max"])[held][has])
+        return OrdinalBuckets(keys, counts, subs)
+
+
 def parse_aggs(aggs: Optional[dict]) -> List[AggNode]:
     out: List[AggNode] = []
     if not aggs:
@@ -90,6 +174,9 @@ def merge_partials(node: AggNode, partials: List[dict]) -> dict:
     kind = node.kind
     if kind in ("terms", "geohash_grid", "geotile_grid", "rare_terms",
                 "multi_terms"):
+        if all(isinstance(p["buckets"], OrdinalBuckets) for p in parts):
+            return {"buckets": OrdinalBuckets.merged(
+                [p["buckets"] for p in parts])}
         return {"buckets": _acc_buckets(node, parts)}
     if kind in ("histogram", "date_histogram"):
         acc = {}
@@ -176,6 +263,8 @@ def merge_partials(node: AggNode, partials: List[dict]) -> dict:
     if kind in ("min", "max", "sum", "avg", "stats", "extended_stats", "value_count"):
         return _merge_stats(parts)
     if kind == "cardinality":
+        if len(parts) == 1:     # one segment's `distinct`, where it has one
+            return parts[0]
         regs = parts[0]["registers"]
         for p in parts[1:]:
             regs = np.maximum(regs, p["registers"])
@@ -257,6 +346,52 @@ def _acc_buckets(node: AggNode, parts: List[dict]) -> Dict[Any, dict]:
     return acc
 
 
+def _finalize_ordinal(node: AggNode, held: OrdinalBuckets,
+                      pipelines: bool) -> dict:
+    """`terms` / `multi_terms` from counts by ordinal: the `size` buckets
+    the response returns are chosen over the arrays (ordinal order is key
+    order, so a tie in the count breaks by key as the reference's does),
+    and only they become records; `sum_other_doc_count` is the exact
+    rest."""
+    from .compiler import AGG_STATS
+    terms = node.kind == "terms"
+    size = int(node.body.get("size", 10))
+    okey, odir = "_count", "desc"
+    if terms:
+        order = node.body.get("order", {"_count": "desc"})
+        if isinstance(order, dict):
+            (okey, odir), = order.items()
+    counts = np.asarray(held.counts).astype(np.int64)
+    least = max(int(node.body.get("min_doc_count", 1)), 1) if terms else 1
+    kept = np.flatnonzero(counts >= least)
+    if okey == "_key":
+        kept = kept[::-1] if odir == "desc" else kept
+    else:
+        c = counts[kept]
+        kept = kept[np.lexsort((kept, -c if odir == "desc" else c))]
+    page = kept[:size].tolist()
+    AGG_STATS.inc("terms.records", len(page))
+    buckets = []
+    for j in page:
+        key = held.keys[j]
+        b = ({"key": key} if terms else
+             {"key": list(key), "key_as_string": "|".join(str(x)
+                                                          for x in key)})
+        b["doc_count"] = int(counts[j])
+        subs = held.sub_partials(j)
+        for sub in node.subs:
+            part = subs.get(sub.name)
+            b[sub.name] = finalize(
+                sub, merge_partials(sub, [part]) if part else {}, pipelines)
+        buckets.append(b)
+    rest = int(counts[kept].sum() - sum(b["doc_count"] for b in buckets))
+    result = ({"doc_count_error_upper_bound": 0, "sum_other_doc_count": rest,
+               "buckets": buckets} if terms else
+              {"buckets": buckets, "sum_other_doc_count": rest})
+    _apply_bucket_pipelines(node, result, "all" if pipelines else "early")
+    return result
+
+
 def _merge_stats(parts: List[dict]) -> dict:
     count = sum(p["count"] for p in parts)
     s = sum(p["sum"] for p in parts)
@@ -288,6 +423,9 @@ def finalize(node: AggNode, merged: dict, pipelines: bool = True) -> dict:
     kind = node.kind
     if not merged:
         return _empty_result(node)
+    if kind in ("terms", "multi_terms") and isinstance(
+            merged["buckets"], OrdinalBuckets):
+        return _finalize_ordinal(node, merged["buckets"], pipelines)
     if kind == "terms":
         size = int(node.body.get("size", 10))
         order = node.body.get("order", {"_count": "desc"})
@@ -413,6 +551,8 @@ def finalize(node: AggNode, merged: dict, pipelines: bool = True) -> dict:
                 "sum_of_squares": merged["sumsq"], "variance": var,
                 "std_deviation": math.sqrt(var)}
     if kind == "cardinality":
+        if merged.get("distinct") is not None:
+            return {"value": int(merged["distinct"])}
         return {"value": int(round(_hll_estimate(merged["registers"])))}
     if kind == "percentiles":
         return {"values": _hist_percentiles(merged)}

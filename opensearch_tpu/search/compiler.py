@@ -94,13 +94,23 @@ EXECUTOR_STATS = CounterGroup(METRICS, "executor", {"params_h2d_bytes": 0,
 # launches that carry a metric under a bucket aggregation, and their
 # buckets; `auto_date.requests` the top-level auto_date_histograms a
 # segment was asked, `auto_date.refine_launches` the launches taken first
-# to learn their matched range (`auto_date_range`)
+# to learn their matched range (`auto_date_range`); `terms.ordinals` the
+# vocabulary or combination slots the launches' `terms`, `multi_terms` and
+# `composite` group-bys counted into, `composite.combinations` those of the
+# composites alone (the combinations that occur in the segment, not the
+# product of the sources' value spaces), and `terms.records` the bucket
+# records the host then built from such counts (`executor` for a partial
+# that is records, `aggregations.finalize` for one that stays arrays: the
+# buckets a response returns, not the vocabulary)
 AGG_STATS = CounterGroup(METRICS, "aggs", {"scatter.updates": 0,
                                            "blocked.rows": 0,
                                            "bucketed_sub.launches": 0,
                                            "bucketed_sub.buckets": 0,
                                            "auto_date.requests": 0,
-                                           "auto_date.refine_launches": 0})
+                                           "auto_date.refine_launches": 0,
+                                           "terms.ordinals": 0,
+                                           "terms.records": 0,
+                                           "composite.combinations": 0})
 # what the `knn` nodes of the launches cost, counted at each launch from
 # the static spec (`_count_launch`): `queries` the nodes over a segment that
 # holds the field, `ann_queries` / `exact_queries` those that probe the
@@ -3600,7 +3610,9 @@ def _segment_plane(seg: Segment, cache_name: str, key, kind: str, stats,
     device for the segment's lifetime, with whatever `build` returns after
     its host ids (a numpy array among it goes to the device and is charged
     with the plane): -> (device plane, *rest). Cached under
-    `seg.<cache_name>[key]` (a tuple that starts with the field) and attributed in the HBM ledger as `kind`;
+    `seg.<cache_name>[key]` (a tuple that starts with the field, or with the
+    tuple of the fields of a plane over several) and attributed in the HBM
+    ledger as `kind`;
     `derived._purge_query_caches` drops a rematerialized field's planes
     and the segment's GC the rest. `stats` counts builds, hits and bytes.
     The per-segment lock keeps two first requests from building (and
@@ -3638,15 +3650,24 @@ def _segment_plane(seg: Segment, cache_name: str, key, kind: str, stats,
 
 
 def drop_segment_planes(seg: Segment, field: str) -> None:
-    """Drop `field`'s rank and bucket planes and release their ledger
-    bytes (a rematerialized derived field: `derived._purge_query_caches`)."""
+    """Drop `field`'s rank, bucket and combination planes (a combination
+    plane is every one of its fields') and release their ledger bytes (a
+    rematerialized derived field: `derived._purge_query_caches`)."""
     from ..obs.hbm_ledger import LEDGER
     allocs = seg.__dict__.get("_plane_allocs", {})
-    for cache_name in ("_sort_dev_cache", "_date_bucket_cache"):
+    for cache_name in ("_sort_dev_cache", "_date_bucket_cache",
+                       "_combo_plane_cache"):
         cache = seg.__dict__.get(cache_name, {})
-        for key in [k for k in cache if k[0] == field]:
+        for key in [k for k in cache if field == k[0] or (
+                isinstance(k[0], tuple) and field in k[0])]:
             del cache[key]
             LEDGER.release(allocs.pop((cache_name, key), None))
+    # the host-side state that names the field: the mesh path's copies of
+    # a `multi_terms` space, the multi-valued flag
+    mesh = seg.__dict__.get("_multi_terms_cache", {})
+    for fields in [k for k in mesh if field in k]:
+        del mesh[fields]
+    seg.__dict__.get("_kw_multi_cache", {}).pop(field, None)
 
 
 def _date_bucket_plane(seg: Segment, field: str, interval_ms: int,
@@ -3658,22 +3679,30 @@ def _date_bucket_plane(seg: Segment, field: str, interval_ms: int,
     `_run_starts` of the ids, on the device too, where the segment's values
     are in row order (an append-only log), else None."""
     def build():
-        col = seg.numeric_cols.get(field)
-        if col is None or not col.present.any():
-            return np.full(seg.ndocs, -1, np.int32), 0, 1, None
-        vals = col.values.astype(np.int64)
-        if calendar is None:
-            b = np.floor_divide(vals - offset_ms, interval_ms)
-        else:
-            b = _calendar_bucket_ids(vals, calendar)
-        bp = b[col.present]
-        mn, mx = int(bp.min()), int(bp.max())
-        ids = np.where(col.present, b - mn, -1).astype(np.int32)
-        nb = int(mx - mn + 1)
+        ids, mn, nb = _date_bucket_ids(seg, field, interval_ms, offset_ms,
+                                       calendar)
         return ids, mn, nb, _run_starts(ids, nb, seg.ndocs_pad)
     return _segment_plane(seg, "_date_bucket_cache",
                           (field, interval_ms, offset_ms, calendar),
                           "agg_bucket_plane", BUCKET_PLANE_STATS, build)
+
+
+def _date_bucket_ids(seg: Segment, field: str, interval_ms: int,
+                     offset_ms: int, calendar: Optional[str]):
+    """(bucket ids i32[ndocs] from the least bucket, -1 = no value, the
+    least bucket, the number of buckets) of a date column, on host i64."""
+    col = seg.numeric_cols.get(field)
+    if col is None or not col.present.any():
+        return np.full(seg.ndocs, -1, np.int32), 0, 1
+    vals = col.values.astype(np.int64)
+    if calendar is None:
+        b = np.floor_divide(vals - offset_ms, interval_ms)
+    else:
+        b = _calendar_bucket_ids(vals, calendar)
+    bp = b[col.present]
+    mn, mx = int(bp.min()), int(bp.max())
+    ids = np.where(col.present, b - mn, -1).astype(np.int32)
+    return ids, mn, int(mx - mn + 1)
 
 
 def _run_starts(ids: np.ndarray, nbuckets: int,
@@ -3919,57 +3948,193 @@ def auto_bucket_end_ms(key_ms: int, interval: str) -> int:
     return auto_unit_start_ms(first + int(interval[:-1]), unit)
 
 
-def _multi_terms_cache(seg: Segment, ctx: ShardContext, node, fields: Tuple[str, ...]):
-    """(vocab of key tuples, combined doc-major ordinal i32[ndocs_pad]) for a
-    multi_terms source list; docs missing ANY source are excluded (-1),
-    matching reference MultiTermsAggregator."""
-    cache = getattr(seg, "_multi_terms_cache", None)
-    if cache is None:
-        cache = seg._multi_terms_cache = {}
-    if fields in cache:
-        return cache[fields]
-    per_field = []
+# a combination space is enumerated through a table over the product of its
+# sources' value spaces up to this many slots (a byte and an int32 each for
+# the build's moment), beyond that by a sort of the rows' codes
+_COMBO_TABLE_MAX = 1 << 26
+
+
+class ComboSpace:
+    """The combinations of source values that occur among a segment's
+    documents, numbered in key order under each source's `order`: what a
+    `multi_terms` or a `composite` over several sources counts into, one
+    slot a combination that occurs (the product of the sources' value
+    spaces, most of it empty, is laid out nowhere). `codes` i64[n]
+    ascending: a combination's code is its sources' positions in mixed
+    radix, first source first, a position being the value's ordinal under
+    `asc` and `radix - 1 - ordinal` under `desc`. `sources` says how a
+    source's ordinal decodes: ("terms", sorted values), ("hist", least
+    bucket, interval) or ("date", least bucket, interval ms, calendar).
+    A sequence of the key tuples besides (`len`, `[j]`, iteration), each
+    decoded when asked for: a response names a page of them."""
+
+    __slots__ = ("codes", "radix", "desc", "sources")
+
+    def __init__(self, codes, radix, desc, sources):
+        self.codes, self.radix = codes, tuple(radix)
+        self.desc, self.sources = tuple(desc), tuple(sources)
+
+    def __len__(self) -> int:
+        return len(self.codes)
+
+    def __iter__(self):
+        return (self[j] for j in range(len(self.codes)))
+
+    def __getitem__(self, j) -> tuple:
+        rem, ords = int(self.codes[j]), []
+        for n, desc in zip(reversed(self.radix), reversed(self.desc)):
+            rem, t = divmod(rem, n)
+            ords.append(n - 1 - t if desc else t)
+        return tuple(self._value(src, o)
+                     for src, o in zip(self.sources, reversed(ords)))
+
+    @staticmethod
+    def _value(src: tuple, o: int):
+        if src[0] == "terms":
+            return src[1][o]
+        if src[0] == "hist":
+            return (src[1] + o) * src[2]
+        _, min_b, interval_ms, calendar = src
+        if calendar:
+            return calendar_bucket_start_ms(min_b + o, calendar)
+        return int((min_b + o) * interval_ms)
+
+    @staticmethod
+    def _position(src: tuple, n: int, v) -> Tuple[int, bool]:
+        """(how many of the source's `n` values lie under `v`, whether `v`
+        is one of them)."""
+        if src[0] == "terms":
+            at = bisect_left(src[1], v)
+            return at, at < n and src[1][at] == v
+        if src[0] == "date" and src[3]:
+            b = int(_calendar_bucket_ids(np.asarray([int(v)]), src[3])[0])
+            held = calendar_bucket_start_ms(b, src[3]) == int(v)
+            return min(max(b - src[1] + (not held), 0), n), \
+                held and 0 <= b - src[1] < n
+        q = float(v) / src[2] - src[1]
+        near = int(np.floor(q + 0.5))    # the bucket a key would name
+        if 0 <= near < n and abs(ComboSpace._value(src, near) - v) \
+                <= 1e-9 * max(1.0, abs(float(v))):
+            return near, True
+        return min(max(int(np.ceil(q)), 0), n), False
+
+    def first_after(self, after: tuple) -> int:
+        """The number of the first combination whose key comes after the
+        key tuple `after` in the sources' orders (`len(self)`: none)."""
+        code, mult = 0, [1]
+        for n in reversed(self.radix[1:]):
+            mult.insert(0, mult[0] * n)
+        for src, n, desc, m, v in zip(self.sources, self.radix, self.desc,
+                                      mult, after):
+            under, held = self._position(src, n, v)
+            # the first position whose value is `v` or comes after it
+            at = (n - 1 - under if held else n - under) if desc else under
+            code += at * m
+            if not held:
+                return int(np.searchsorted(self.codes, code, side="left"))
+        return int(np.searchsorted(self.codes, code, side="right"))
+
+
+def _combo_ids(per_source: list, desc: tuple, ndocs: int):
+    """(combination numbers i32[ndocs], -1 = a document that lacks a
+    source; codes i64[n] ascending) from each source's (ordinals i32[ndocs]
+    with -1 = none, number of values)."""
+    valid = np.ones(ndocs, bool)
+    code = np.zeros(ndocs, np.int64)
+    product = 1
+    for (ords, n), d in zip(per_source, desc):
+        n = max(int(n), 1)
+        valid &= ords >= 0
+        code *= n
+        code += np.maximum((n - 1 - ords) if d else ords, 0)
+        product *= n
+    if product >= 1 << 62:
+        raise dsl.QueryParseError(
+            f"the sources' value spaces multiply to {product}: too many")
+    held = code[valid]
+    if product <= _COMBO_TABLE_MAX:
+        seen = np.zeros(product, bool)
+        seen[held] = True
+        codes = np.flatnonzero(seen)
+        number = (np.cumsum(seen, dtype=np.int32) - 1)[held]
+    else:
+        codes, number = np.unique(held, return_inverse=True)
+    ids = np.full(ndocs, -1, np.int32)
+    ids[valid] = number
+    return ids, codes.astype(np.int64)
+
+
+def _multi_terms_sources(seg: Segment, ctx: ShardContext,
+                         fields: Tuple[str, ...]):
+    """[(ordinals i32[ndocs], number of values)] and the `ComboSpace`
+    sources of a `multi_terms` source list: a keyword's least ordinal, a
+    numeric column's rank among its distinct values; a field the segment
+    lacks excludes every document."""
+    per_source, sources = [], []
     for f in fields:
         f = ctx.mappings.aliases.get(f, f)
         kcol = seg.keyword_cols.get(f)
-        if kcol is not None:
-            per_field.append(("kw", kcol.min_ord[: seg.ndocs], kcol.vocab))
-            continue
         ncol = seg.numeric_cols.get(f)
-        if ncol is not None:
+        if kcol is not None:
+            ords, values = kcol.min_ord[: seg.ndocs], kcol.vocab
+        elif ncol is not None:
             ords = ncol.sort_ords()[: seg.ndocs]
-            vals = sorted({(float(v) if ncol.kind == "float" else int(v))
-                           for v in ncol.values[ncol.present]})
-            per_field.append(("num", ords, vals))
-            continue
-        per_field.append(("none", np.full(seg.ndocs, -1, np.int32), []))
-    combined = np.zeros(seg.ndocs, np.int64)
-    valid = np.ones(seg.ndocs, bool)
-    mult = 1
-    for kind_, ords, vocab in reversed(per_field):
-        valid &= ords >= 0
-        combined += np.maximum(ords, 0).astype(np.int64) * mult
-        mult *= max(len(vocab), 1)
-    uniq, inv = np.unique(combined[valid], return_inverse=True)
-    ords_out = np.full(next_pow2(seg.ndocs), -1, np.int32)
-    ords_out[: seg.ndocs][valid] = inv.astype(np.int32)
-    # decode each unique combined ordinal back to its key tuple
-    mults = []
-    m = 1
-    for _kind, _o, vocab in reversed(per_field):
-        mults.append(m)
-        m *= max(len(vocab), 1)
-    mults.reverse()
-    vocab_out = []
-    for code in uniq:
-        key = []
-        rem = int(code)
-        for (_kind, _o, vocab), mm in zip(per_field, mults):
-            idx = rem // mm
-            rem = rem % mm
-            key.append(vocab[idx] if idx < len(vocab) else None)
-        vocab_out.append(tuple(key))
-    cache[fields] = (vocab_out, ords_out)
+            values = np.unique(ncol.values[ncol.present]).tolist()
+        else:
+            ords, values = np.full(seg.ndocs, -1, np.int32), []
+        per_source.append((ords, len(values)))
+        sources.append(("terms", values))
+    return per_source, sources
+
+
+def _combo_space(per_source: list, sources: list, desc: tuple, ndocs: int):
+    """(combination numbers i32[ndocs], `ComboSpace`) of per-source
+    (ordinals, number of values) pairs and their decoders."""
+    ids, codes = _combo_ids(per_source, desc, ndocs)
+    return ids, ComboSpace(codes, [max(n, 1) for _o, n in per_source], desc,
+                           sources)
+
+
+def _combo_plane(seg: Segment, key: tuple, build: Callable[[], tuple]):
+    """(combination numbers of the documents as a resident plane,
+    `ComboSpace`) under `key` (the fields' tuple first): `build`
+    (`_combo_space`) runs once a segment on the host, the plane then lives
+    on the device for the segment's lifetime, in the HBM ledger with the
+    bucket planes (`_segment_plane`)."""
+    return _segment_plane(seg, "_combo_plane_cache", key,
+                          "agg_bucket_plane", BUCKET_PLANE_STATS, build)
+
+
+def _multi_terms_space(seg: Segment, ctx: ShardContext,
+                       fields: Tuple[str, ...]):
+    """`_combo_space` of a `multi_terms` source list; documents missing ANY
+    source are excluded (-1), matching reference MultiTermsAggregator."""
+    per_source, sources = _multi_terms_sources(seg, ctx, fields)
+    return _combo_space(per_source, sources, (False,) * len(fields),
+                        seg.ndocs)
+
+
+def multi_terms_plane(seg: Segment, ctx: ShardContext,
+                      fields: Tuple[str, ...]):
+    """(plane, `ComboSpace`) of a `multi_terms` source list."""
+    return _combo_plane(seg, (tuple(fields), "multi_terms"),
+                        lambda: _multi_terms_space(seg, ctx, fields))
+
+
+def _multi_terms_cache(seg: Segment, ctx: ShardContext, node, fields: Tuple[str, ...]):
+    """(`ComboSpace` as the vocabulary of key tuples, combined doc-major
+    ordinal i32[ndocs_pad] on the HOST) for the mesh path, which restacks
+    the segments' ordinals into one index-wide space
+    (`parallel/service.py`); the executor's launches read
+    `multi_terms_plane`."""
+    cache = getattr(seg, "_multi_terms_cache", None)
+    if cache is None:
+        cache = seg._multi_terms_cache = {}
+    if fields not in cache:
+        ids, space = _multi_terms_space(seg, ctx, fields)
+        ords_out = np.full(next_pow2(seg.ndocs), -1, np.int32)
+        ords_out[: seg.ndocs] = ids
+        cache[fields] = (space, ords_out)
     return cache[fields]
 
 
@@ -4473,14 +4638,13 @@ def prepare_agg(node: AggNode, seg: Segment, ctx: ShardContext, params: dict,
         if len(sources) < 2:
             raise dsl.QueryParseError(
                 "[multi_terms] requires at least two [terms] sources")
-        vocab, ords = _multi_terms_cache(seg, ctx, node, tuple(
-            s["field"] for s in sources))
-        params[f"{prefix}_mords"] = ords
+        params[f"{prefix}_mords"], space = multi_terms_plane(
+            seg, ctx, tuple(s["field"] for s in sources))
         subs = tuple(prepare_agg(s, seg, ctx, params, f"{prefix}_{i}",
                                  nest_stack)
                      for i, s in enumerate(node.subs))
-        return ("multi_terms", prefix, next_pow2(max(len(vocab), 1)),
-                len(vocab), subs)
+        return ("multi_terms", prefix, next_pow2(max(len(space), 1)),
+                len(space), subs)
 
     if kind == "adjacency_matrix":
         raw = body.get("filters", {})
@@ -4597,28 +4761,37 @@ def _prepare_join_agg(node: AggNode, seg: Segment, ctx: ShardContext,
     return ("parent_agg", prefix, pf, subs)
 
 
-def _prepare_composite(node: AggNode, seg: Segment, ctx: ShardContext,
-                       params: dict, prefix: str, nest_stack):
-    """Composite agg: each doc maps to one combined ordinal over the product
-    of per-source value spaces; one device bincount yields every composite
-    bucket of the segment, the coordinator pages with after_key (reference
-    CompositeAggregator builds the same slot machinery per leaf)."""
+def _kw_multi_valued(seg: Segment, field: str) -> bool:
+    """Whether some document holds two values of keyword `field` or more:
+    a pass over the column's row pointers, once a segment."""
+    cache = seg.__dict__.setdefault("_kw_multi_cache", {})
+    if field not in cache:
+        starts = seg.keyword_cols[field].starts
+        cache[field] = bool(len(starts) > 1
+                            and int(np.max(starts[1:] - starts[:-1])) > 1)
+    return cache[field]
+
+
+def _composite_sources(node: AggNode, seg: Segment, ctx: ShardContext):
+    """The sources of a composite over `seg`, resolved -> ([(source type,
+    field, number of values, least bucket, interval, calendar, desc)] or
+    None where the segment lacks a source's column (no bucket), the field
+    of a single-source composite's multi-valued `terms` source or
+    None)."""
     from .aggregations import composite_sources
 
     sources = composite_sources(node)
     infos = []
-    total = 1
-    for si, (nm, stype, scfg, order) in enumerate(sources):
+    for nm, stype, scfg, order in sources:
         field = scfg.get("field", "")
         ft = ctx.mappings.resolve_field(field)
         field = ft.name if ft else field
+        desc = order == "desc"
         if stype == "terms":
             col = seg.keyword_cols.get(field)
             if col is None:
-                return ("terms_missing", prefix)
-            multi = (len(col.ords) > 0 and
-                     int(np.max(col.starts[1:] - col.starts[:-1])) > 1)
-            if multi:
+                return None, None
+            if _kw_multi_valued(seg, field):
                 # a doc contributes one composite key per value (reference
                 # behavior); supported for a single-source composite, where
                 # it degenerates to an ordinal bincount
@@ -4626,44 +4799,113 @@ def _prepare_composite(node: AggNode, seg: Segment, ctx: ShardContext,
                     raise dsl.QueryParseError(
                         "[composite] a multi-valued terms source cannot be "
                         "combined with other sources")
-                subs_mv = tuple(prepare_agg(s, seg, ctx, params,
-                                            f"{prefix}_{i}", nest_stack)
-                                for i, s in enumerate(node.subs))
-                return ("composite_mv", prefix, field,
-                        next_pow2(max(len(col.vocab), 1)), subs_mv)
-            infos.append(("terms", field, len(col.vocab), 0, 0.0, 0.0))
+                return None, field
+            infos.append(("terms", field, len(col.vocab), 0, 0.0, "", desc))
         elif stype == "histogram":
             interval = float(scfg["interval"])
             col = seg.numeric_cols.get(field)
             if col is None or not col.present.any():
-                return ("terms_missing", prefix)
+                return None, None
             mn, mx = col.min_max
             min_b = int(np.floor(mn / interval))
             nb = int(np.floor(mx / interval)) - min_b + 1
-            infos.append(("hist", field, nb, min_b, interval, 0.0))
+            infos.append(("hist", field, nb, min_b, interval, "", desc))
         elif stype == "date_histogram":
             calendar = scfg.get("calendar_interval")
             interval_ms = (0 if calendar else
                            parse_interval_ms(scfg.get("fixed_interval",
                                                       scfg.get("interval", "1d"))))
-            params[f"{prefix}_s{si}"], min_b, nb, _starts = \
-                _date_bucket_plane(seg, field, max(interval_ms, 1), 0,
-                                   calendar)
-            if nb <= 0:
-                return ("terms_missing", prefix)
-            infos.append(("date", field, nb, min_b,
-                          float(max(interval_ms, 1)), calendar or ""))
+            col = seg.numeric_cols.get(field)
+            if col is None or not col.present.any():
+                return None, None
+            infos.append(("date", field, 0, 0, float(max(interval_ms, 1)),
+                          calendar or "", desc))
         else:
             raise dsl.QueryParseError(
                 f"[composite] unsupported source type [{stype}]")
-        total *= max(infos[-1][2], 1)
-    if total > (1 << 22):
-        raise dsl.QueryParseError(
-            f"[composite] too many composite buckets [{total}] "
-            f"(limit {1 << 22})")
+    return infos, None
+
+
+def _composite_source_ordinals(seg: Segment, info: tuple):
+    """(ordinals i32[ndocs] with -1 = no value, number of values,
+    `ComboSpace` source) of one resolved composite source, on the host,
+    as the device would reckon them (a histogram's bucket from the
+    float32 the column holds there)."""
+    stype, field, n, min_b, interval, cal, _desc = info
+    if stype == "terms":
+        col = seg.keyword_cols[field]
+        return col.min_ord[: seg.ndocs], n, ("terms", col.vocab)
+    if stype == "hist":
+        col = seg.numeric_cols[field]
+        o = np.floor(col.values.astype(np.float32)
+                     / np.float32(interval)).astype(np.int64) - min_b
+        o = np.where(col.present & (o >= 0) & (o < n), o, -1)
+        return o.astype(np.int32), n, ("hist", min_b, interval)
+    ids, min_b, nb = _date_bucket_ids(seg, field, int(interval), 0,
+                                      cal or None)
+    return ids, nb, ("date", min_b, interval, cal)
+
+
+def composite_space(seg: Segment, infos: list):
+    """(plane or None, `ComboSpace`) of a composite's resolved sources: one
+    source counts into its own value space and needs no plane (its ordinal
+    is on the device already), several count into the combinations that
+    occur (`_combo_plane`)."""
+    desc = tuple(i[6] for i in infos)
+    if len(infos) > 1:
+        key = (tuple(i[1] for i in infos), "composite",
+               tuple((i[0], i[4], i[5], i[6]) for i in infos))
+
+        def build():
+            got = [_composite_source_ordinals(seg, i) for i in infos]
+            return _combo_space([(o, n) for o, n, _s in got],
+                                [s for _o, _n, s in got], desc, seg.ndocs)
+        return _combo_plane(seg, key, build)
+    stype, field, n, min_b, interval, cal, _desc = infos[0]
+    if stype == "date":     # (the plane is cached: `_date_bucket_plane`)
+        _plane, min_b, n, _starts = _date_bucket_plane(
+            seg, field, int(interval), 0, cal or None)
+        src = ("date", min_b, interval, cal)
+    elif stype == "terms":
+        src = ("terms", seg.keyword_cols[field].vocab)
+    else:
+        src = ("hist", min_b, interval)
+    n = max(n, 1)
+    return None, ComboSpace(np.arange(n, dtype=np.int64), [n], desc, [src])
+
+
+def _prepare_composite(node: AggNode, seg: Segment, ctx: ShardContext,
+                       params: dict, prefix: str, nest_stack):
+    """Composite agg: each doc maps to the number of its sources'
+    combination in key order (`ComboSpace`: the combinations that occur in
+    the segment, so three keyword sources cost their joint cardinality and
+    not their product); one device bincount yields every composite bucket
+    of the segment, and the host makes records of one page of them
+    (reference CompositeAggregator builds the same slot machinery per
+    leaf)."""
+    infos, multi = _composite_sources(node, seg, ctx)
+    if multi is not None:
+        col = seg.keyword_cols[multi]
+        subs_mv = tuple(prepare_agg(s, seg, ctx, params, f"{prefix}_{i}",
+                                    nest_stack)
+                        for i, s in enumerate(node.subs))
+        return ("composite_mv", prefix, multi,
+                next_pow2(max(len(col.vocab), 1)), subs_mv)
+    if infos is None:
+        return ("terms_missing", prefix)
+    plane, space = composite_space(seg, infos)
+    if plane is not None:
+        params[f"{prefix}_cplane"] = plane
+        single = None
+    else:
+        stype, field, _n, min_b, interval, cal, desc = infos[0]
+        if stype == "date":
+            params[f"{prefix}_s0"], min_b, _nb, _starts = \
+                _date_bucket_plane(seg, field, int(interval), 0, cal or None)
+        single = (stype, field, min_b, interval, desc)
     subs = tuple(prepare_agg(s, seg, ctx, params, f"{prefix}_{i}", nest_stack)
                  for i, s in enumerate(node.subs))
-    return ("composite", prefix, tuple(infos), total, subs)
+    return ("composite", prefix, single, len(space), subs)
 
 
 def _resolve_agg_field(node: AggNode, ctx: ShardContext) -> str:
@@ -4672,8 +4914,25 @@ def _resolve_agg_field(node: AggNode, ctx: ShardContext) -> str:
     return ft.name if ft else field
 
 
-def emit_agg(spec, seg_arrays: dict, params: dict, match, scores=None):  # noqa: C901
+# a group-by over keyword ordinals or their combinations, whole (the match
+# gathered by value, the ids, the count, a keyword cardinality's registers),
+# names the stage `aggs.terms` in the device trace, around whatever form
+# (`ops.aggs`' `aggs.dense` / `aggs.scatter`) the count then takes
+TERMS_SCOPE = "aggs.terms"
+_TERMS_STAGE_KINDS = frozenset({"terms", "sig_terms", "multi_terms",
+                                "composite", "composite_mv", "card_kw"})
+
+
+def emit_agg(spec, seg_arrays: dict, params: dict, match, scores=None):
     """-> nested dict of device arrays (this segment's partial)."""
+    if spec[0] in _TERMS_STAGE_KINDS:
+        import jax
+        with jax.named_scope(TERMS_SCOPE):
+            return _emit_agg(spec, seg_arrays, params, match, scores)
+    return _emit_agg(spec, seg_arrays, params, match, scores)
+
+
+def _emit_agg(spec, seg_arrays: dict, params: dict, match, scores=None):  # noqa: C901
     import jax
     import jax.numpy as jnp
 
@@ -4818,22 +5077,25 @@ def emit_agg(spec, seg_arrays: dict, params: dict, match, scores=None):  # noqa:
         return out
 
     if kind == "composite":
-        _, prefix, infos, total, subs = spec
-        combined = jnp.zeros(ndocs_pad, jnp.int32)
+        _, prefix, single, total, subs = spec
         valid = (match > 0) & (seg_arrays["live"] > 0)
-        for si, (stype, field, n, min_b, interval, cal) in enumerate(infos):
+        if single is None:      # several sources: the resident plane
+            o = params[f"{prefix}_cplane"][:ndocs_pad]
+        else:
+            stype, field, min_b, interval, desc = single
             if stype == "terms":
                 o = seg_arrays["keyword"][field]["min_ord"]
             elif stype == "hist":
                 col = seg_arrays["numeric"][field]
                 o = jnp.floor(col["f32"] / interval).astype(jnp.int32) - min_b
-                o = jnp.where(col["present"] & (o >= 0) & (o < n), o, -1)
+                o = jnp.where(col["present"] & (o >= 0) & (o < total), o, -1)
             else:  # date
-                o = params[f"{prefix}_s{si}"][:ndocs_pad]
-            valid = valid & (o >= 0)
-            combined = combined * n + jnp.maximum(o, 0)
+                o = params[f"{prefix}_s0"][:ndocs_pad]
+            if desc:            # slots in key order under the source's order
+                o = jnp.where(o >= 0, total - 1 - o, -1)
+        valid = valid & (o >= 0)
         w = valid.astype(jnp.float32)
-        b = jnp.where(valid, combined, total)
+        b = jnp.where(valid, o, total)
         out = {"counts": agg_ops.bucket_counts(b, w, total)}
         for i, sub in enumerate(subs):
             out.update(_emit_bucketed_sub(jnp, sub, i, b, total, seg_arrays,
@@ -5004,9 +5266,10 @@ def emit_agg(spec, seg_arrays: dict, params: dict, match, scores=None):  # noqa:
 
     if kind == "card_kw":
         _, prefix, field, nvocab_pad = spec
-        return {"registers": agg_ops.cardinality_keyword_registers(
+        registers, distinct = agg_ops.cardinality_keyword_registers(
             seg_arrays["keyword"][field], match, nvocab_pad,
-            params[f"{prefix}_hashes"], HLL_LOG2M)}
+            params[f"{prefix}_hashes"], HLL_LOG2M)
+        return {"registers": registers, "distinct": distinct}
 
     if kind == "card_num":
         _, prefix, field, col_exists = spec
@@ -5694,9 +5957,14 @@ def _count_launch(full_spec, seg_arrays: dict, cparams: dict) -> None:
         EXECUTOR_STATS.inc("agg_bucket_launches", len(forms))
         EXECUTOR_STATS.inc("agg_run_counted", forms.count("runs"))
     if aggs:
-        cost = {"scatter": 0, "blocked": 0, "sub_buckets": 0}
+        cost = {"scatter": 0, "blocked": 0, "sub_buckets": 0,
+                "ordinals": 0, "combinations": 0}
         for _name, aspec in aggs:
             _agg_cost(aspec, seg_arrays, cost)
+        if cost["ordinals"]:
+            AGG_STATS.inc("terms.ordinals", cost["ordinals"])
+        if cost["combinations"]:
+            AGG_STATS.inc("composite.combinations", cost["combinations"])
         if cost["scatter"]:
             AGG_STATS.inc("scatter.updates", cost["scatter"])
         if cost["blocked"]:
@@ -5745,13 +6013,17 @@ def _agg_cost(spec, seg_arrays: dict, cost: dict) -> None:
     """What `emit_agg` builds for `spec`, reckoned from the spec alone (the
     walk mirrors it): rows handed to scatters, rows read by `run_counts`
     and by the dense form (`ops.aggs.dense_buckets`, the predicate the
-    emit chooses by), buckets that carry a metric sub-aggregation. Kinds
-    that reduce nothing per row of the segment add nothing."""
+    emit chooses by), buckets that carry a metric sub-aggregation, and
+    where `cost` has the keys the slots a terms-like group-by counts into
+    (`ordinals`; `combinations` those of a composite). A keyword
+    `cardinality` is the `terms_counts` under its registers. Kinds that
+    reduce nothing per row of the segment add nothing."""
     if not isinstance(spec, tuple) or not spec:
         return
     kind = spec[0]
     n = seg_arrays["live"].shape[0]
     rows = nb = None                    # of this node's own bucket count
+    slots = 0                           # of a terms-like group-by
     if kind == "hist":
         rows, nb, subs = n, spec[6], spec[7]
     elif kind == "date_hist":
@@ -5761,12 +6033,22 @@ def _agg_cost(spec, seg_arrays: dict, cost: dict) -> None:
     elif kind in ("terms", "sig_terms", "composite_mv"):
         rows = seg_arrays["keyword"][spec[2]]["ords"].shape[0]
         nb, subs = spec[3], spec[4]
+        slots = nb
+    elif kind == "card_kw":     # `terms_counts` under the registers
+        rows = seg_arrays["keyword"][spec[2]]["ords"].shape[0]
+        nb, subs = spec[3], ()
     elif kind == "geo_grid":
         rows, nb, subs = n, spec[5], spec[6]
     elif kind == "composite":
         rows, nb, subs = n, spec[3], spec[4]
+        slots = nb
+        if "combinations" in cost:
+            cost["combinations"] += nb
     elif kind == "multi_terms":
         rows, nb, subs = n, spec[2], spec[4]
+        slots = nb
+    if slots and "ordinals" in cost:    # (a caller that wants them asks)
+        cost["ordinals"] += slots
     if rows is None:
         at = _AGG_CONTAINER_SUBS.get(kind)
         for sub in (spec[at] if at is not None else ()):

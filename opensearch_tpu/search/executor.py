@@ -29,9 +29,9 @@ from . import compiler as C
 from . import fastpath
 from . import impactpath
 from . import query_dsl as dsl
-from .aggregations import (AggNode, _apply_bucket_pipelines,
-                           apply_pipelines_tree, finalize, merge_partials,
-                           parse_aggs)
+from .aggregations import (AggNode, OrdinalBuckets, _apply_bucket_pipelines,
+                           apply_pipelines_tree, composite_sources, finalize,
+                           merge_partials, parse_aggs)
 from .highlight import (collect_query_terms, highlight_field,
                         highlight_fvh, highlight_unified)
 
@@ -2181,19 +2181,56 @@ def _extract_source_values(src: dict, path: str) -> List:
     return node if isinstance(node, list) else [node]
 
 
-def _ordinal_buckets(node: AggNode, device_out: dict, vocab) -> dict:
-    """Shared ordinal-bucket partial extraction (terms / significant_terms /
-    geo grids): nonzero counts keyed by vocab + per-bucket stats tuples."""
+def _ordinal_buckets(node: AggNode, device_out: dict, vocab,
+                     ordinals=None) -> dict:
+    """Ordinal-bucket partial extraction as records (significant_terms,
+    rare_terms, geo grids, a composite's page): the non-empty ordinals, or
+    those of them that `ordinals` names, keyed by vocab + per-bucket stats
+    tuples. One Python record a bucket (`aggs.terms.records`): a kind
+    whose response returns a few of many buckets keeps arrays
+    (`_ordinal_arrays`)."""
     counts = np.asarray(device_out["counts"])
     subs = _sub_metric_columns(node, device_out)
+    if ordinals is None:
+        ordinals = np.nonzero(counts[: len(vocab)] > 0)[0]
+    C.AGG_STATS.inc("terms.records", len(ordinals))
     buckets: dict = {}
-    for o in np.nonzero(counts[: len(vocab)] > 0)[0]:
+    for o in ordinals:
         rec: dict = {"doc_count": int(round(float(counts[o])))}
         sub_partials = _bucket_subs(subs, int(o))
         if sub_partials:
             rec["subs"] = sub_partials
         buckets[vocab[o]] = rec
     return buckets
+
+
+def _ordinal_arrays(node: AggNode, device_out: dict, keys) -> OrdinalBuckets:
+    """A `terms` / `multi_terms` partial: the counts stay an array by
+    ordinal beside the sub-metrics' columns, `keys` names an ordinal when
+    `aggregations.finalize` asks (no record is built here)."""
+    n = len(keys)
+    return OrdinalBuckets(
+        keys, np.asarray(device_out["counts"])[:n],
+        {name: {k: np.asarray(v)[:n] for k, v in cols.items()}
+         for name, cols in _sub_metric_columns(node, device_out).items()})
+
+
+def _composite_page(node: AggNode, counts: np.ndarray, space) -> np.ndarray:
+    """The slots of a composite partial that can reach the response: the
+    first `size` non-empty combinations after the request's `after` key
+    in key order (`space` numbers them so). A merged page's buckets each
+    lie among every segment's first `size`, so the rest never become
+    records; an `after` the sources cannot place keeps them all."""
+    size = int(node.body.get("size", 10))
+    after = node.body.get("after")
+    start = 0
+    if after is not None:
+        try:
+            start = space.first_after(tuple(
+                after[nm] for nm, _t, _c, _o in composite_sources(node)))
+        except (TypeError, ValueError, KeyError):
+            size = len(counts)
+    return start + np.flatnonzero(counts[start:] > 0)[:size]
 
 
 def _auto_date_ranges(agg_nodes, qspec, seg: Segment, ctx, params: dict,
@@ -2244,8 +2281,10 @@ def _device_agg_to_partial(node: AggNode, aspec, device_out: Optional[dict],
 
     if kind == "terms":
         _, prefix, f, nvocab_pad, subs = aspec
-        return {"buckets": _ordinal_buckets(node, device_out,
-                                            seg.keyword_cols[f].vocab)}
+        vocab = seg.keyword_cols[f].vocab
+        if node.kind == "terms":
+            return {"buckets": _ordinal_arrays(node, device_out, vocab)}
+        return {"buckets": _ordinal_buckets(node, device_out, vocab)}
 
     if kind == "hist":
         _, prefix, f, interval, offset, min_b, nb, subs = aspec
@@ -2393,32 +2432,12 @@ def _device_agg_to_partial(node: AggNode, aspec, device_out: Optional[dict],
         return {"buckets": {(k,): v for k, v in flat.items()}}
 
     if kind == "composite":
-        _, prefix, infos, total, subs = aspec
-        counts = np.asarray(device_out["counts"])
-        nz = np.nonzero(counts[:total] > 0)[0]
-        sub_cols = _sub_metric_columns(node, device_out)
-        buckets = {}
-        for comb in nz:
-            vals = []
-            rem = int(comb)
-            for stype, field, n, min_b, interval, cal in reversed(infos):
-                o = rem % n
-                rem //= n
-                if stype == "terms":
-                    vals.append(seg.keyword_cols[field].vocab[o])
-                elif stype == "hist":
-                    vals.append((min_b + o) * interval)
-                elif cal:
-                    vals.append(C.calendar_bucket_start_ms(min_b + o, cal))
-                else:
-                    vals.append(int((min_b + o) * interval))
-            key = tuple(reversed(vals))
-            rec = {"doc_count": int(round(float(counts[comb])))}
-            sub_partials = _bucket_subs(sub_cols, int(comb))
-            if sub_partials:
-                rec["subs"] = sub_partials
-            buckets[key] = rec
-        return {"buckets": buckets}
+        _, prefix, single, total, subs = aspec
+        _plane, space = C.composite_space(
+            seg, C._composite_sources(node, seg, ctx)[0])
+        counts = np.asarray(device_out["counts"])[:total]
+        return {"buckets": _ordinal_buckets(
+            node, device_out, space, _composite_page(node, counts, space))}
 
     if kind == "stats":
         if "empty" in device_out:
@@ -2433,7 +2452,10 @@ def _device_agg_to_partial(node: AggNode, aspec, device_out: Optional[dict],
                 "min": 0.0, "max": 0.0, "sumsq": 0.0}
 
     if kind in ("card_kw", "card_num"):
-        return {"registers": np.asarray(device_out["registers"])}
+        out = {"registers": np.asarray(device_out["registers"])}
+        if "distinct" in device_out:    # a keyword's matched ordinals
+            out["distinct"] = int(np.asarray(device_out["distinct"]))
+        return out
 
     if kind == "pctl":
         _, prefix, f, col_exists, percents = aspec
@@ -2479,8 +2501,8 @@ def _device_agg_to_partial(node: AggNode, aspec, device_out: Optional[dict],
     if kind == "multi_terms":
         _, prefix, nord_pad, nvocab, sub_specs = aspec
         fields = tuple(s["field"] for s in node.body.get("terms", []))
-        vocab, _ords = C._multi_terms_cache(seg, ctx, node, fields)
-        return {"buckets": _ordinal_buckets(node, device_out, vocab)}
+        _plane, space = C.multi_terms_plane(seg, ctx, fields)
+        return {"buckets": _ordinal_arrays(node, device_out, space)}
 
     if kind == "adjacency":
         _, prefix, fspecs, sep, sub_specs = aspec

@@ -1,0 +1,149 @@
+"""TPU-hardware check of the group-bys the `big5` cell runs, at its size:
+16,777,216 padded rows, ordinals in no row order. First `ops.aggs` alone at
+the cell's bucket counts against `np.bincount` (a `terms` into 65,536
+slots and a keyword cardinality's registers over 16,384: scatters; a
+`multi_terms` / `composite` plane into 312 and 512 slots: the dense form;
+a composite's 461,089 combinations: a scatter again), each with its time;
+then the seven request shapes through `RestClient.search` over 1,048,576
+generated events (20,968 streams, 5,242 agents: the scatter side of
+`_DENSE_BUCKETS` as at the cell's size) against the kind's plain reference.
+Run on a real chip: `python -m pytest tests_tpu/test_big5_tpu.py -q -s`."""
+
+import os
+import sys
+import time
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from opensearch_tpu.ops import aggs as agg_ops
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path[:0] = [p for p in (os.path.join(ROOT, "benchmark"), ROOT)
+                if p not in sys.path]
+
+pytestmark = pytest.mark.skipif(jax.default_backend() != "tpu",
+                                reason="needs a real TPU chip")
+
+N = 1 << 24
+NDOCS = 16_571_428          # the cell's rows; the rest is padding
+
+
+@pytest.fixture(scope="module")
+def rows():
+    """Host and device planes: Zipf-like ordinals over [0, 2^19) to be
+    folded to a test's slots, -1 on the padded rows, and a 0/1 match that
+    leaves a window of the rows."""
+    rng = np.random.default_rng(43)
+    u = rng.random(N)
+    ords = np.minimum((np.exp(u * np.log(1 << 19)) - 1).astype(np.int32),
+                      (1 << 19) - 1)
+    ords[NDOCS:] = -1
+    match = np.zeros(N, np.float32)
+    match[N // 5: N // 5 * 4] = 1.0
+    return (ords, match), (jnp.asarray(ords), jnp.asarray(match))
+
+
+def _timed(fn, args, reps=5):
+    jfn = jax.jit(fn)
+    out = jax.tree_util.tree_map(np.asarray, jfn(*args))
+    times = []
+    for _ in range(reps):
+        t0 = time.perf_counter()
+        jax.tree_util.tree_map(np.asarray, jfn(*args))
+        times.append((time.perf_counter() - t0) * 1e3)
+    return out, float(np.median(times))
+
+
+def _want(ords_h, match_h, nb):
+    ok = (ords_h >= 0) & (match_h > 0)
+    return np.bincount(ords_h[ok] % nb, minlength=nb)
+
+
+@pytest.mark.parametrize("nb,what", [
+    (312, "composite-terms (12 x 26)"), (512, "multi_terms plane, padded"),
+    (65_536, "terms over 40,000 streams, padded"),
+    (461_089, "composite_terms-keyword's combinations")])
+def test_a_plane_of_ordinals_counts_exactly(rows, nb, what):
+    (ords_h, match_h), dev = rows
+
+    def fn(ords, match):
+        return agg_ops.ord_counts(jnp.where(ords >= 0, ords % nb, -1),
+                                  match, nb)
+    got, ms = _timed(fn, dev)
+    assert got.dtype == np.int32
+    assert np.array_equal(got, _want(ords_h, match_h, nb))
+    assert int(got.max()) > 2048        # past what a float16 would count
+    form = "dense" if agg_ops.dense_buckets(nb) else "scatter"
+    print(f"ord_counts n={N} slots={nb} ({what}): {form} {ms:.2f} ms "
+          f"(launch + read, median of 5)")
+
+
+@pytest.mark.parametrize("nvocab", [32, 16_384, 65_536])
+def test_terms_counts_and_a_keyword_cardinality_by_value(rows, nvocab):
+    """The keyword column's own layout: ordinals by value with the document
+    of each value beside them (here one value a document, in row order),
+    the match gathered through it."""
+    (ords_h, match_h), (ords, match) = rows
+    kw = {"ords": jnp.where(ords >= 0, ords % nvocab, -1),
+          "doc_of_value": jnp.where(ords >= 0, jnp.arange(N, dtype=jnp.int32),
+                                    np.int32(2**31 - 1))}
+    hashes = jnp.asarray(np.random.default_rng(1).integers(
+        0, 1 << 32, nvocab, dtype=np.uint64).astype(np.uint32))
+    want = _want(ords_h, match_h, nvocab)
+    got, ms = _timed(lambda k, m: agg_ops.terms_counts(k, m, nvocab),
+                     (kw, match))
+    assert np.array_equal(got, want)
+    (regs, distinct), ms_card = _timed(
+        lambda k, m, h: agg_ops.cardinality_keyword_registers(
+            k, m, nvocab, h, 14), (kw, match, hashes))
+    assert int(distinct) == int((want > 0).sum())
+    assert regs.shape == (1 << 14,) and int(regs.max()) > 0
+    form = "dense" if agg_ops.dense_buckets(nvocab) else "scatter"
+    print(f"terms_counts n={N} vocabulary={nvocab}: {form} {ms:.2f} ms; "
+          f"cardinality registers + distinct {ms_card:.2f} ms "
+          f"(launch + read, median of 5)")
+
+
+def test_the_seven_shapes_through_the_client_at_a_million_events():
+    os.environ["OPENSEARCH_TPU_MESH"] = "0"
+    import big5_reference as reference
+    import run as harness
+    from opensearch_tpu.rest.client import RestClient
+    from opensearch_tpu.search import compiler as C
+    kind = harness.load_kind("big5")
+    loaded = harness.load_cell("big5.search1.terms")
+    config = dict(loaded["config"], ndocs=1 << 20)
+    client = RestClient()
+    built = kind.build(config, 1, client, harness.INDEX)
+    stream = kind.stream(built, loaded["traffic"], 77)
+    specs = stream.take(14)
+    for s in specs:                     # compile, build the planes
+        client.search(harness.INDEX, stream.twin(s)["body"])
+    before = {k: C.AGG_STATS[k] for k in C.AGG_STATS}
+    h2d = C.EXECUTOR_STATS["params_h2d_bytes"]
+    held, times = [], {}
+    for s in specs:
+        t0 = time.perf_counter()
+        resp = client.search(harness.INDEX, s["body"])
+        times.setdefault(s["shape"], []).append(
+            (time.perf_counter() - t0) * 1e3)
+        held.append((s, resp))
+    out = reference.hold(held, kind.reference_of(built))
+    print("compared", out["numbers"])
+    assert out["correct"] is True and out["compared"] == 14
+    got = {k: C.AGG_STATS[k] - v for k, v in before.items()}
+    n = built["readout"]["rows_padded"]
+    assert built["readout"]["vocabulary"][reference.STREAM] > 2048
+    # four of seven operations scatter, three take the dense form
+    assert got["scatter.updates"] == 2 * 4 * n
+    assert got["blocked.rows"] == 2 * 3 * n
+    assert got["terms.records"] == 2 * (500 + 50 + 10 + 10 + 10)
+    assert C.EXECUTOR_STATS["params_h2d_bytes"] - h2d < 14 * (1 << 18)
+    for shape, ms in times.items():
+        print(f"{shape}: {np.median(ms):.1f} ms a request (n={n}, "
+              f"2 requests)")
+    print("counters", got)

@@ -1,5 +1,5 @@
 """TPU-hardware check that the served path reads the device only where it says
-so: the request shapes of the benchmark's five cells, each kind at a small
+so: the request shapes of the benchmark's six cells, each kind at a small
 size, under `jax_transfer_guard_device_to_host = "disallow"`. An explicit
 `jax.device_get` (every one of the program's sits inside a `device.wait`
 span) passes the guard; an implicit read (`np.asarray`, `int`, `float`,
@@ -67,7 +67,8 @@ CELLS = {"treccovid.search1.long": lambda config, traffic: None,
          "msmarco.search1.selective": _msmarco,
          "httplogs.search1.dashboard": _events,
          "nyctaxis.search1.analyst": _trips,
-         "cohere10m.search1.knn100": _vectors}
+         "cohere10m.search1.knn100": _vectors,
+         "big5.search1.terms": _events}
 
 
 class ImplicitReads:
