@@ -760,10 +760,30 @@ class Segment:
     def ndocs_pad(self) -> int:
         return next_pow2(self.ndocs)
 
+    def kw_multi_valued(self, field: str) -> bool:
+        """Whether some document holds two values of keyword `field` or
+        more, which is whether the column holds more values than
+        documents that have one: a pass over `min_ord`, once a segment
+        (`compiler.drop_segment_planes` forgets it with a rematerialized
+        field), and no temporary the size of the row pointers (their
+        differences are 133 MB of fresh pages at 2^24 rows: as slow as the
+        two planes' padding the single-valued form saves). It decides the
+        column's form on the device (`_kw_field_arrays`) and a
+        composite's over it."""
+        cache = self.__dict__.setdefault("_kw_multi_cache", {})
+        if field not in cache:
+            col = self.keyword_cols[field]
+            cache[field] = len(col.ords) > int(
+                np.count_nonzero(col.min_ord >= 0))
+        return cache[field]
+
     def device_arrays(self, device=None) -> dict:
         """The pytree of device-resident arrays consumed by `ops` kernels.
-        Shapes are padded to pow2 buckets; structure is stable across segments
-        of the same index so jitted plans re-hit the XLA compile cache.
+        Shapes are padded to pow2 buckets. The structure follows the
+        mapping and, for a keyword column, what the segment observes in its
+        own data (`kw_multi_valued`: one plane where no document holds two
+        values, three where one does), so segments of one index mostly
+        share a compiled plan and two that differ there compile one each.
         `device`: re-host on a specific device (replica placement); None =
         the process default."""
         import jax
@@ -829,7 +849,7 @@ class Segment:
                 for f, pb in self.postings.items()}
         ncols = {f: _num_field_arrays(col, dpad, jnp)
                  for f, col in self.numeric_cols.items()}
-        kcols = {f: _kw_field_arrays(col, dpad, jnp)
+        kcols = {f: _kw_field_arrays(col, dpad, jnp, self.kw_multi_valued(f))
                  for f, col in self.keyword_cols.items()}
         vcols = {}
         for f, col in self.vector_cols.items():
@@ -1093,7 +1113,8 @@ class Segment:
             if col is not None:
                 out["keyword"][f] = field(
                     "keyword", f,
-                    lambda col=col: _kw_field_arrays(col, dpad, jnp))
+                    lambda col=col, f=f: _kw_field_arrays(
+                        col, dpad, jnp, self.kw_multi_valued(f)))
         for f in needs.get("geo", ()):
             col = self.geo_cols.get(f)
             if col is not None:
@@ -1425,13 +1446,21 @@ def _num_field_arrays(col: "NumericColumn", dpad: int, jnp) -> dict:
     }
 
 
-def _kw_field_arrays(col: "KeywordColumn", dpad: int, jnp) -> dict:
-    vpad = next_pow2(len(col.ords))
-    return {
-        "ords": jnp.asarray(_pad_to(col.ords, vpad, np.int32(-1))),
-        "doc_of_value": jnp.asarray(_pad_to(col.doc_of_value, vpad, INT32_SENTINEL)),
-        "min_ord": jnp.asarray(_pad_to(col.min_ord, dpad, np.int32(-1))),
-    }
+def _kw_field_arrays(col: "KeywordColumn", dpad: int, jnp,
+                     multi_valued: bool) -> dict:
+    """A keyword column on the device. Where no document holds two values
+    (`Segment.kw_multi_valued`) it is its ordinals by document and nothing
+    else: `min_ord` is then `ords` with a -1 where a document has none, and
+    `doc_of_value` the row numbers of the others, so a group-by counts
+    `min_ord` under the mask (`ops.aggs.counts_by_value` reads the form off
+    this dict's keys, and with them it is part of a program's jit key)."""
+    out = {"min_ord": jnp.asarray(_pad_to(col.min_ord, dpad, np.int32(-1)))}
+    if multi_valued:
+        vpad = next_pow2(len(col.ords))
+        out["ords"] = jnp.asarray(_pad_to(col.ords, vpad, np.int32(-1)))
+        out["doc_of_value"] = jnp.asarray(
+            _pad_to(col.doc_of_value, vpad, INT32_SENTINEL))
+    return out
 
 
 def _geo_field_arrays(col: "GeoColumn", dpad: int, jnp) -> dict:

@@ -23,6 +23,17 @@ date histogram over an append-only log segment): each bucket is one run of
 rows, so a count is a difference of two prefix sums of the weights, read at
 the runs' boundaries. `search/compiler._date_bucket_plane` observes the
 order once a plane and `prepare_agg` selects that form.
+
+A group-by over a keyword column (`terms_counts` and through it a keyword
+`cardinality`, `terms_sub_metric`, `value_count_keyword`) takes the form the
+column's device dict has (`counts_by_value`; `index/segment._kw_field_arrays`
+chooses it from the segment's own data). A column in which no document
+holds two values is its ordinals by document, `min_ord`, and is counted
+under the mask in one streaming pass, as any other id plane. A column in
+which one does is laid out by value (`ords` with `doc_of_value` beside
+them), and the mask is first gathered to the values, one element a value
+(`_gather_match`: 141-144 ms at 2^24 values on a v5e, PERF.md, PR 43). The
+two share `bucket_counts` / `bucketed_sub_metric` and nothing else.
 """
 
 from __future__ import annotations
@@ -40,6 +51,20 @@ F32_MAX = np.float32(3.4e38)  # numpy, not jnp (see ops/scoring.NEG_INF note)
 def _gather_match(match: jnp.ndarray, docs: jnp.ndarray) -> jnp.ndarray:
     safe = jnp.minimum(docs, match.shape[0] - 1)
     return jnp.where(docs < match.shape[0], match[safe], 0.0)
+
+
+def counts_by_value(kw: dict) -> bool:
+    """Whether a group-by over the keyword column `kw` (its device dict)
+    counts the column's flat values, the match gathered to them through
+    `doc_of_value`, and not `min_ord` by document under the mask: the one
+    predicate `terms_counts`, `terms_sub_metric` and `value_count_keyword`
+    choose by, and `compiler._agg_cost` counts by (`group_by_rows`)."""
+    return "doc_of_value" in kw
+
+
+def group_by_rows(kw: dict) -> int:
+    """Length of the plane a group-by over `kw` reads."""
+    return kw["ords" if counts_by_value(kw) else "min_ord"].shape[0]
 
 
 # buckets under which a per-bucket reduction takes the dense form. Its cost
@@ -383,6 +408,8 @@ def bucketed_sub_metric(bucket_ids: jnp.ndarray, v: jnp.ndarray,
 def terms_counts(kw: dict, match: jnp.ndarray, nvocab_pad: int) -> jnp.ndarray:
     """Keyword terms agg: per-ordinal doc counts (reference
     GlobalOrdinalsStringTermsAggregator). Returns i32[nvocab_pad]."""
+    if not counts_by_value(kw):     # (-1 and padded rows: `_held_ids`)
+        return bucket_counts(kw["min_ord"], match, nvocab_pad)
     return bucket_counts(kw["ords"], _gather_match(match, kw["doc_of_value"]),
                          nvocab_pad)
 
@@ -392,7 +419,12 @@ def terms_sub_metric(kw: dict, match: jnp.ndarray, values_f32: jnp.ndarray,
                      sumsq: bool) -> dict:
     """Per-ordinal count / min / max / sum of a numeric column: the metric
     sub-aggregations under a terms bucket (`bucketed_sub_metric` over the
-    flat values' ordinals)."""
+    ordinals by document, the column read in place, or over the flat
+    values' ordinals)."""
+    if not counts_by_value(kw):
+        return bucketed_sub_metric(
+            kw["min_ord"], values_f32, match * jnp.where(present, 1.0, 0.0),
+            nvocab_pad, inv, sumsq)
     docs = kw["doc_of_value"]
     safe = jnp.minimum(docs, values_f32.shape[0] - 1)
     w = _gather_match(match, docs) * jnp.where(present[safe], 1.0, 0.0)
@@ -439,6 +471,8 @@ def stats_agg(values_f32: jnp.ndarray, present: jnp.ndarray,
 
 
 def value_count_keyword(kw: dict, match: jnp.ndarray) -> jnp.ndarray:
+    if not counts_by_value(kw):
+        return jnp.sum(match * (kw["min_ord"] >= 0))
     return jnp.sum(_gather_match(match, kw["doc_of_value"]))
 
 

@@ -101,7 +101,13 @@ EXECUTOR_STATS = CounterGroup(METRICS, "executor", {"params_h2d_bytes": 0,
 # product of the sources' value spaces), and `terms.records` the bucket
 # records the host then built from such counts (`executor` for a partial
 # that is records, `aggregations.finalize` for one that stays arrays: the
-# buckets a response returns, not the vocabulary)
+# buckets a response returns, not the vocabulary); `terms.gathered_rows`
+# the flat values to which a keyword group-by (`terms`, `significant_terms`,
+# a multi-valued `composite` source, a keyword `cardinality` or
+# `value_count`) gathered the match through `doc_of_value`, one element a
+# value: those of the columns laid out by value
+# (`ops.aggs.counts_by_value`), 0 for a column in which no document holds
+# two values, which is counted by document
 AGG_STATS = CounterGroup(METRICS, "aggs", {"scatter.updates": 0,
                                            "blocked.rows": 0,
                                            "bucketed_sub.launches": 0,
@@ -110,6 +116,7 @@ AGG_STATS = CounterGroup(METRICS, "aggs", {"scatter.updates": 0,
                                            "auto_date.refine_launches": 0,
                                            "terms.ordinals": 0,
                                            "terms.records": 0,
+                                           "terms.gathered_rows": 0,
                                            "composite.combinations": 0})
 # what the `knn` nodes of the launches cost, counted at each launch from
 # the static spec (`_count_launch`): `queries` the nodes over a segment that
@@ -4761,17 +4768,6 @@ def _prepare_join_agg(node: AggNode, seg: Segment, ctx: ShardContext,
     return ("parent_agg", prefix, pf, subs)
 
 
-def _kw_multi_valued(seg: Segment, field: str) -> bool:
-    """Whether some document holds two values of keyword `field` or more:
-    a pass over the column's row pointers, once a segment."""
-    cache = seg.__dict__.setdefault("_kw_multi_cache", {})
-    if field not in cache:
-        starts = seg.keyword_cols[field].starts
-        cache[field] = bool(len(starts) > 1
-                            and int(np.max(starts[1:] - starts[:-1])) > 1)
-    return cache[field]
-
-
 def _composite_sources(node: AggNode, seg: Segment, ctx: ShardContext):
     """The sources of a composite over `seg`, resolved -> ([(source type,
     field, number of values, least bucket, interval, calendar, desc)] or
@@ -4791,7 +4787,7 @@ def _composite_sources(node: AggNode, seg: Segment, ctx: ShardContext):
             col = seg.keyword_cols.get(field)
             if col is None:
                 return None, None
-            if _kw_multi_valued(seg, field):
+            if seg.kw_multi_valued(field):
                 # a doc contributes one composite key per value (reference
                 # behavior); supported for a single-source composite, where
                 # it degenerates to an ordinal bincount
@@ -5958,13 +5954,15 @@ def _count_launch(full_spec, seg_arrays: dict, cparams: dict) -> None:
         EXECUTOR_STATS.inc("agg_run_counted", forms.count("runs"))
     if aggs:
         cost = {"scatter": 0, "blocked": 0, "sub_buckets": 0,
-                "ordinals": 0, "combinations": 0}
+                "ordinals": 0, "combinations": 0, "gathered": 0}
         for _name, aspec in aggs:
             _agg_cost(aspec, seg_arrays, cost)
         if cost["ordinals"]:
             AGG_STATS.inc("terms.ordinals", cost["ordinals"])
         if cost["combinations"]:
             AGG_STATS.inc("composite.combinations", cost["combinations"])
+        if cost["gathered"]:
+            AGG_STATS.inc("terms.gathered_rows", cost["gathered"])
         if cost["scatter"]:
             AGG_STATS.inc("scatter.updates", cost["scatter"])
         if cost["blocked"]:
@@ -6015,7 +6013,9 @@ def _agg_cost(spec, seg_arrays: dict, cost: dict) -> None:
     and by the dense form (`ops.aggs.dense_buckets`, the predicate the
     emit chooses by), buckets that carry a metric sub-aggregation, and
     where `cost` has the keys the slots a terms-like group-by counts into
-    (`ordinals`; `combinations` those of a composite). A keyword
+    (`ordinals`; `combinations` those of a composite) and the flat values
+    a keyword group-by gathers the match to (`gathered`: its rows where
+    the column is laid out by value, `ops.aggs.counts_by_value`). A keyword
     `cardinality` is the `terms_counts` under its registers. Kinds that
     reduce nothing per row of the segment add nothing."""
     if not isinstance(spec, tuple) or not spec:
@@ -6030,13 +6030,19 @@ def _agg_cost(spec, seg_arrays: dict, cost: dict) -> None:
         rows, nb, subs = n, spec[7], spec[8]
     elif kind == "auto_date_hist":
         rows, nb, subs = n, spec[7], spec[8]
-    elif kind in ("terms", "sig_terms", "composite_mv"):
-        rows = seg_arrays["keyword"][spec[2]]["ords"].shape[0]
-        nb, subs = spec[3], spec[4]
-        slots = nb
-    elif kind == "card_kw":     # `terms_counts` under the registers
-        rows = seg_arrays["keyword"][spec[2]]["ords"].shape[0]
-        nb, subs = spec[3], ()
+    elif kind in ("terms", "sig_terms", "composite_mv", "card_kw",
+                  "vc_keyword"):
+        kw = seg_arrays["keyword"][spec[2]]
+        rows = agg_ops.group_by_rows(kw)
+        if "gathered" in cost and agg_ops.counts_by_value(kw):
+            cost["gathered"] += rows
+        if kind == "vc_keyword":        # one sum: no bucket count
+            return
+        nb = spec[3]
+        if kind == "card_kw":   # `terms_counts` under the registers
+            subs = ()
+        else:
+            subs, slots = spec[4], nb
     elif kind == "geo_grid":
         rows, nb, subs = n, spec[5], spec[6]
     elif kind == "composite":

@@ -210,8 +210,12 @@ def test_the_dense_ops_carry_the_sub_metric_scope():
 # `_agg_cost` counts by the predicate the emit chooses by
 # ---------------------------------------------------------------------
 N = 4096
+# "k" is laid out by value (three values a document), "k1" by document
 SEG = {"live": np.zeros(N, np.float32),
-       "keyword": {"k": {"ords": np.zeros(3 * N, np.int32)}}}
+       "keyword": {"k": {"ords": np.zeros(3 * N, np.int32),
+                         "doc_of_value": np.zeros(3 * N, np.int32),
+                         "min_ord": np.zeros(N, np.int32)},
+                   "k1": {"min_ord": np.zeros(N, np.int32)}}}
 STATS = ("stats", "s0", "f", True, False)
 EXT = ("stats", "s0", "f", True, True)
 GONE = ("stats", "s0", "f", False, False)       # the column does not exist
@@ -225,8 +229,8 @@ def _spec(kind, nb, subs, form=None):
     if kind == "auto_date_hist":
         return ("auto_date_hist", "p", "f", "d", 20, 0, 4 * nb, nb, subs,
                 form)
-    if kind == "terms":
-        return ("terms", "p", "k", nb, subs)
+    if kind in ("terms", "terms_by_doc"):
+        return ("terms", "p", "k" if kind == "terms" else "k1", nb, subs)
     if kind == "geo_grid":
         return ("geo_grid", "p", "geohash", "f", 5, nb, subs)
     raise AssertionError(kind)
@@ -241,7 +245,7 @@ def _cost(spec):
 @pytest.mark.parametrize("kind,form,rows", [
     ("hist", None, N), ("date_hist", "scatter", N),
     ("auto_date_hist", "scatter", N), ("terms", None, 3 * N),
-    ("geo_grid", None, N)])
+    ("terms_by_doc", None, N), ("geo_grid", None, N)])
 @pytest.mark.parametrize("subs", [(), (STATS,), (EXT,), (STATS, GONE, EXT)])
 def test_agg_cost_follows_the_predicate(kind, form, rows, subs):
     few, many = agg_ops._DENSE_BUCKETS - 1, agg_ops._DENSE_BUCKETS

@@ -258,17 +258,53 @@ def test_the_counters_say_what_a_launch_counted(deployments):
         assert (got["terms.ordinals"], got["terms.records"]) == (0, 0)
 
 
+@pytest.mark.parametrize("shape", reference.SHAPES)
+def test_no_operation_gathers_the_match_by_value(deployments, shape):
+    """Every keyword of the deployment holds one value a document at most
+    (`tags` an array of one), so each is its ordinals by document on the
+    device and no launch gathers the match through `doc_of_value`."""
+    client, _built, stream, _kind = deployments(SEEDS[1])
+    seg = _segment(client)
+    assert not any(seg.kw_multi_valued(f) for f in seg.keyword_cols)
+    assert all(set(kw) == {"min_ord"}
+               for kw in seg.device_arrays()["keyword"].values())
+    spec = next(s for s in stream.take(7) if s["shape"] == shape)
+    got = _counted(client, spec["body"])
+    assert got["launches"] == 1 and got["terms.gathered_rows"] == 0
+
+
+@pytest.mark.parametrize("counters,want", [
+    ({}, None),                                 # a program without it
+    ({"aggs.terms.gathered_rows": 0}, 0.0),
+    ({"aggs.terms.gathered_rows": 4 * (1 << 24)}, 4 * (1 << 24) / 7e6)])
+def test_the_gathered_rows_reader(counters, want):
+    ctx = {"window": {"counters": counters, "queries": 7}}
+    assert harness.read_layer_metric(
+        "terms_gathered_mrows_per_query", ctx) == want
+    # the kind hands the benchmark every counter of the group
+    assert "aggs.terms.gathered_rows" in harness.load_kind(
+        "big5").counters(None)
+
+
+@pytest.mark.parametrize("by_value", [True, False])
 @pytest.mark.parametrize("nb,form", [(300, "blocked"), (5000, "scatter")])
-def test_agg_cost_counts_a_keyword_cardinality_under_its_form(nb, form):
+def test_agg_cost_counts_a_keyword_cardinality_under_its_form(nb, form,
+                                                              by_value):
     n = 1 << 12
-    seg_arrays = {"live": np.zeros(n, np.float32),
-                  "keyword": {"k": {"ords": np.zeros(3 * n, np.int32)}}}
+    kw = {"min_ord": np.zeros(n, np.int32)}
+    if by_value:            # three values a document, and their documents
+        kw.update(ords=np.zeros(3 * n, np.int32),
+                  doc_of_value=np.zeros(3 * n, np.int32))
+    rows = 3 * n if by_value else n
+    seg_arrays = {"live": np.zeros(n, np.float32), "keyword": {"k": kw}}
     cost = {"scatter": 0, "blocked": 0, "sub_buckets": 0, "ordinals": 0,
-            "combinations": 0}
+            "combinations": 0, "gathered": 0}
     C._agg_cost(("card_kw", "p", "k", nb), seg_arrays, cost)
     want = dict.fromkeys(cost, 0)
-    want[form] = 3 * n
+    want[form] = rows
+    want["gathered"] = rows if by_value else 0
     assert cost == want
+    want.pop("gathered")
     cost = {"scatter": 0, "blocked": 0, "sub_buckets": 0, "ordinals": 0,
             "combinations": 0}
     C._agg_cost(("composite", "p", None, nb, ()), seg_arrays, cost)

@@ -3,7 +3,9 @@
 the cell's bucket counts against `np.bincount` (a `terms` into 65,536
 slots and a keyword cardinality's registers over 16,384: scatters; a
 `multi_terms` / `composite` plane into 312 and 512 slots: the dense form;
-a composite's 461,089 combinations: a scatter again), each with its time;
+a composite's 461,089 combinations: a scatter again), each with its time,
+a keyword column's group-bys in both of its layouts, by document and by
+value, side by side;
 then the seven request shapes through `RestClient.search` over 1,048,576
 generated events (20,968 streams, 5,242 agents: the scatter side of
 `_DENSE_BUCKETS` as at the cell's size) against the kind's plain reference.
@@ -83,29 +85,39 @@ def test_a_plane_of_ordinals_counts_exactly(rows, nb, what):
 
 
 @pytest.mark.parametrize("nvocab", [32, 16_384, 65_536])
-def test_terms_counts_and_a_keyword_cardinality_by_value(rows, nvocab):
-    """The keyword column's own layout: ordinals by value with the document
-    of each value beside them (here one value a document, in row order),
-    the match gathered through it."""
+def test_terms_counts_and_a_keyword_cardinality_in_both_forms(rows, nvocab):
+    """A keyword column holding one value a document at most, in its two
+    layouts, in one run on one chip: by document (`min_ord` alone, counted
+    under the mask: what the segment hands over since PR 44) and by value
+    (ordinals with the document of each value beside them, here the row
+    numbers; the match gathered through it: a multi-valued column's)."""
     (ords_h, match_h), (ords, match) = rows
-    kw = {"ords": jnp.where(ords >= 0, ords % nvocab, -1),
-          "doc_of_value": jnp.where(ords >= 0, jnp.arange(N, dtype=jnp.int32),
-                                    np.int32(2**31 - 1))}
+    by_doc = {"min_ord": jnp.where(ords >= 0, ords % nvocab, -1)}
+    by_value = {"ords": by_doc["min_ord"], "min_ord": by_doc["min_ord"],
+                "doc_of_value": jnp.where(
+                    ords >= 0, jnp.arange(N, dtype=jnp.int32),
+                    np.int32(2**31 - 1))}
+    assert agg_ops.counts_by_value(by_value)
+    assert not agg_ops.counts_by_value(by_doc)
     hashes = jnp.asarray(np.random.default_rng(1).integers(
         0, 1 << 32, nvocab, dtype=np.uint64).astype(np.uint32))
     want = _want(ords_h, match_h, nvocab)
-    got, ms = _timed(lambda k, m: agg_ops.terms_counts(k, m, nvocab),
-                     (kw, match))
-    assert np.array_equal(got, want)
-    (regs, distinct), ms_card = _timed(
-        lambda k, m, h: agg_ops.cardinality_keyword_registers(
-            k, m, nvocab, h, 14), (kw, match, hashes))
-    assert int(distinct) == int((want > 0).sum())
-    assert regs.shape == (1 << 14,) and int(regs.max()) > 0
     form = "dense" if agg_ops.dense_buckets(nvocab) else "scatter"
-    print(f"terms_counts n={N} vocabulary={nvocab}: {form} {ms:.2f} ms; "
-          f"cardinality registers + distinct {ms_card:.2f} ms "
-          f"(launch + read, median of 5)")
+    for name, kw in (("by document", by_doc), ("by value", by_value)):
+        got, ms = _timed(lambda k, m: agg_ops.terms_counts(k, m, nvocab),
+                         (kw, match))
+        assert got.dtype == np.int32 and np.array_equal(got, want)
+        (regs, distinct), ms_card = _timed(
+            lambda k, m, h: agg_ops.cardinality_keyword_registers(
+                k, m, nvocab, h, 14), (kw, match, hashes))
+        assert int(distinct) == int((want > 0).sum())
+        assert regs.shape == (1 << 14,) and int(regs.max()) > 0
+        count, ms_vc = _timed(agg_ops.value_count_keyword, (kw, match))
+        assert int(count) == int(want.sum())
+        print(f"terms_counts n={N} vocabulary={nvocab} {name}: {form} "
+              f"{ms:.2f} ms; cardinality registers + distinct "
+              f"{ms_card:.2f} ms; value_count {ms_vc:.2f} ms "
+              f"(launch + read, median of 5)")
 
 
 def test_the_seven_shapes_through_the_client_at_a_million_events():
@@ -137,6 +149,11 @@ def test_the_seven_shapes_through_the_client_at_a_million_events():
     assert out["correct"] is True and out["compared"] == 14
     got = {k: C.AGG_STATS[k] - v for k, v in before.items()}
     n = built["readout"]["rows_padded"]
+    # every keyword is its ordinals by document: nothing gathered by value
+    (seg,) = client.node.indices[harness.INDEX].shards[0].segments
+    assert all(set(kw) == {"min_ord"}
+               for kw in seg.device_arrays()["keyword"].values())
+    assert got["terms.gathered_rows"] == 0
     assert built["readout"]["vocabulary"][reference.STREAM] > 2048
     # four of seven operations scatter, three take the dense form
     assert got["scatter.updates"] == 2 * 4 * n
