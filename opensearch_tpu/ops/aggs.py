@@ -7,17 +7,25 @@ All kernels take `match` — the query's dense f32 0/1 match vector (already
 live-masked) — so aggregations run in the same jitted program as scoring and
 XLA fuses the mask with the reduction.
 
-Three forms reduce rows per bucket, and the sizes choose among them.
+Four forms reduce rows per bucket, and the sizes choose among them.
 Ids in any order (`terms_counts`, `hist`, `geo_grid`, `composite`,
 `multi_terms`, `ord_counts`, a date histogram over a segment whose
-timestamps are out of order) take one of two: where the buckets are few
-(`dense_buckets(nbuckets)`, a static shape) the dense form compares each
-block of rows against every bucket id while the block is on the chip and
-adds, or takes the minimum / maximum, into that block's partial
-accumulators (rows x buckets lane operations and no update a row); at or
-over that many buckets a scatter issues one update a row, which the TPU
-runs one after another (8.7 ns each). Both serve `bucket_counts`,
-`bucket_sums_exact` and `bucketed_sub_metric` and give equal arrays.
+timestamps are out of order) take one of three, named by
+`count_form(nbuckets)` from a static shape and two constants of the chip.
+Where the buckets are few (under `_DENSE_BUCKETS`) the dense form compares
+each block of rows against every bucket id while the block is on the chip
+and adds, or takes the minimum / maximum, into that block's partial
+accumulators (rows x buckets lane operations and no update a row). From
+there up to `_PRODUCT_BUCKETS` a count is a product on the matrix unit: a
+slot is `hi * L + lo`, so the count of slot (hi, lo) is the sum over the
+rows of [the row's hi] x [the row's lo], the one-hot of `hi` times the
+one-hot of `lo` contracted over a block's rows (rows x (H + L) compares
+and rows x buckets multiply-adds of 0 and 1, exact). At or over that a
+scatter issues one update a row, which the TPU runs one after another
+(6.7-8.7 ns each). All serve `bucket_counts` and give equal arrays;
+`bucket_sums_exact` and `bucketed_sub_metric` are dense under
+`_DENSE_BUCKETS` and scatter from there on, but for the metric's count,
+which is a `bucket_counts`.
 `run_counts` serves a plane whose ids are non-decreasing in row order (a
 date histogram over an append-only log segment): each bucket is one run of
 rows, so a count is a difference of two prefix sums of the weights, read at
@@ -76,20 +84,39 @@ def group_by_rows(kw: dict) -> int:
 # scatters read 1,340-1,770: the forms cross near 6,000 buckets, and the
 # constant stands where the dense form still wins four times over
 _DENSE_BUCKETS = 2048
-# the three forms name their ops in the device trace (`jax.named_scope`:
+# buckets under which a count at `_DENSE_BUCKETS` buckets or more is the
+# product of two one-hots and not a scatter. The product's cost is rows x
+# (H + L) compares and rows x H x L multiply-adds where the scatter's is
+# rows x 6.7-8.7 ns, so this crossover too is a property of the chip. On a
+# v5e at 16,777,216 rows (PERF.md, PR 45) a count reads 2.7 / 4.3 / 12.9 /
+# 24.1 / 47.3 / 82.8 / 92.2 ms at 2,048 / 16,384 / 65,536 / 131,072 /
+# 262,144 / 461,089 / 524,288 slots (1.9 ms + 0.17 ms a thousand slots of
+# H x L: the matrix unit at 86% of its bfloat16 peak, int8 operands the
+# same to 0.1 ms) where the scatter reads 112.3-112.8 whatever the slots
+# (ids drawn evenly; 146 over a log's own combinations): the forms cross
+# near 650,000 slots, and the constant stands at the last power of two
+# under that, where the product wins by a fifth at the least and 2.4 times
+# over at half as many
+_PRODUCT_BUCKETS = 1 << 19
+# the forms name their ops in the device trace (`jax.named_scope`:
 # metadata of an op, read by `benchmark/launch_reduce.py`), one scope a
 # form, inside whatever scope the caller stands in
 DENSE_SCOPE = "aggs.dense"
+PRODUCT_SCOPE = "aggs.product"
 SCATTER_SCOPE = "aggs.scatter"
 RUN_COUNTS_SCOPE = "aggs.run_counts"
 
 
-def dense_buckets(nbuckets: int) -> bool:
-    """Whether a reduction into `nbuckets` buckets takes the dense form
-    (compare a block of rows against every bucket) and not a scatter: the
-    one predicate `bucket_counts`, `bucket_sums_exact` and
+def count_form(nbuckets: int) -> str:
+    """The form a reduction into `nbuckets` buckets over ids in any order
+    takes: "dense" (compare a block of rows against every bucket),
+    "product" (a bucket count as two one-hots multiplied on the matrix
+    unit; the sums and the extremes scatter there) or "scatter": the one
+    predicate `bucket_counts`, `bucket_sums_exact` and
     `bucketed_sub_metric` choose by, and `compiler._agg_cost` counts by."""
-    return nbuckets < _DENSE_BUCKETS
+    if nbuckets < _DENSE_BUCKETS:
+        return "dense"
+    return "product" if nbuckets < _PRODUCT_BUCKETS else "scatter"
 
 
 def _held_ids(bucket_ids: jnp.ndarray, w: jnp.ndarray,
@@ -106,6 +133,20 @@ def _held_ids(bucket_ids: jnp.ndarray, w: jnp.ndarray,
 _DENSE_BLOCK = 1 << 15
 
 
+def _row_blocks(x: jnp.ndarray, rows: int, fill) -> jnp.ndarray:
+    """The plane `x` in blocks of `rows` rows, as [blocks, R, 128]: the
+    1-D plane's own tiling on the TPU, so a view, where [blocks, rows] is
+    a relayout of the plane (PERF.md, PR 29, PR 31 and PR 33). More than
+    one block needs `rows` in whole tiles; the tail is padded with `fill`."""
+    n = x.shape[0]
+    nblk = max(-(-n // rows), 1)
+    per = -(-rows // 128) * 128
+    assert nblk == 1 or per == rows, (n, rows)
+    if nblk * per != n:
+        x = jnp.pad(x, (0, nblk * per - n), constant_values=fill)
+    return x.reshape(nblk, per // 128, 128)
+
+
 def _dense_reduce(held: jnp.ndarray, nbuckets: int, rows: int,
                   v: Optional[jnp.ndarray] = None, parts=None,
                   extremes: bool = False) -> tuple:
@@ -119,21 +160,9 @@ def _dense_reduce(held: jnp.ndarray, nbuckets: int, rows: int,
     blocks: each is read from HBM once, laid along the lanes and compared
     against all bucket ids at once, and every accumulator is a reduction
     over that one comparison, so the [buckets, rows] one-hot exists a
-    block at a time, inside a fusion. The planes enter as
-    [blocks, R, 128], the 1-D plane's own tiling on the TPU: a view,
-    where [blocks, rows] is a relayout of each plane (PERF.md, PR 29,
-    PR 31 and PR 33). More than one block needs `rows` in whole tiles;
-    the tail is padded with rows that count nowhere."""
-    n = held.shape[0]
-    nblk = max(-(-n // rows), 1)
+    block at a time, inside a fusion. The planes enter as `_row_blocks`
+    views, the tail padded with rows that count nowhere."""
     per = -(-rows // 128) * 128
-    assert nblk == 1 or per == rows, (n, rows)
-
-    def blocks(x, fill):
-        if nblk * per != n:
-            x = jnp.pad(x, (0, nblk * per - n), constant_values=fill)
-        return x.reshape(nblk, per // 128, 128)
-
     ids = jnp.arange(nbuckets, dtype=jnp.int32)[:, None]
 
     def one(block):
@@ -150,8 +179,59 @@ def _dense_reduce(held: jnp.ndarray, nbuckets: int, rows: int,
         return tuple(out)
 
     with jax.named_scope(DENSE_SCOPE):
-        return jax.lax.map(one, (blocks(held, nbuckets),
-                                 None if v is None else blocks(v, 0.0)))
+        return jax.lax.map(one, (
+            _row_blocks(held, rows, nbuckets),
+            None if v is None else _row_blocks(v, rows, 0.0)))
+
+
+# rows a block of the product form: one `dot_general` contracts a block's
+# rows into float32, exact while no slot's partial passes 2^24, and each
+# block's partial is added as int32, so a block holds at most 2^24 rows
+# (the probe read 2^13 rows 1-2% slower than 2^15, 2^18 and 2^21 another
+# 1-2% faster, 2^24 as 2^15: PERF.md, PR 45)
+_PRODUCT_BLOCK = 1 << 18
+
+
+def product_split(nbuckets: int) -> Tuple[int, int]:
+    """(H, L) of the product form: slot = hi * L + lo with `L` the power
+    of two next above sqrt(`nbuckets`), a whole tile of 128 lanes or more
+    (256 x 256 for 65,536 slots, 128 x 128 for 16,384), and `H` the rows
+    of `L` slots that hold `nbuckets`."""
+    l = max(1 << ((nbuckets - 1).bit_length() + 1) // 2, 128)
+    return -(-nbuckets // l), l
+
+
+def _product_counts(held: jnp.ndarray, nbuckets: int) -> jnp.ndarray:
+    """The product form: the count of the rows of `held` (`_held_ids`) in
+    every bucket -> i32[nbuckets]. A loop over blocks of rows (the plane
+    viewed as `_row_blocks`): a block's ids laid along the lanes,
+    `hi == arange(H)` and `lo == arange(L)` made while the block is on the
+    chip (XLA fuses both comparisons into the product's operands: no
+    one-hot is written) and contracted over the block's rows by one
+    `dot_general` of 0s and 1s, exact in bfloat16, accumulated in float32
+    and added to the [H, L] counts as int32. A row that holds `nbuckets`
+    falls in a slot past the last bucket, or where `H x L` is `nbuckets`
+    in no row of the one-hot at all."""
+    h, l = product_split(nbuckets)
+    per = min(_PRODUCT_BLOCK, -(-max(held.shape[0], 1) // 128) * 128)
+    assert per <= 1 << 24, per
+    shift = l.bit_length() - 1
+    his = jnp.arange(h, dtype=jnp.int32)[:, None]
+    los = jnp.arange(l, dtype=jnp.int32)[:, None]
+
+    def one(acc, t):
+        t = t.reshape(1, per)
+        hot_hi = ((t >> shift) == his).astype(jnp.bfloat16)
+        hot_lo = ((t & (l - 1)) == los).astype(jnp.bfloat16)
+        part = jax.lax.dot_general(
+            hot_hi, hot_lo, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        return acc + part.astype(jnp.int32), None
+
+    with jax.named_scope(PRODUCT_SCOPE):
+        acc, _ = jax.lax.scan(one, jnp.zeros((h, l), jnp.int32),
+                              _row_blocks(held, per, nbuckets))
+        return acc.reshape(h * l)[:nbuckets]
 
 
 def bucket_counts(bucket_ids: jnp.ndarray, w: jnp.ndarray,
@@ -160,12 +240,16 @@ def bucket_counts(bucket_ids: jnp.ndarray, w: jnp.ndarray,
     ids outside [0, nbuckets) are dropped. Counts accumulate in int32: a
     float32 count stops at 2^24 = 16,777,216, and one bucket of a large
     segment can hold more. The forms for ids in any order (the module's
-    docstring): dense under `_DENSE_BUCKETS` buckets, else one scatter
+    docstring, `count_form`): dense under `_DENSE_BUCKETS` buckets, the
+    product of two one-hots under `_PRODUCT_BUCKETS`, else one scatter
     update a row; ids that are sorted by row take `run_counts`."""
     held = _held_ids(bucket_ids, w, nbuckets)
-    if dense_buckets(nbuckets):
+    form = count_form(nbuckets)
+    if form == "dense":
         rows = max(min(held.shape[0], _DENSE_BLOCK), 1)
         return jnp.sum(_dense_reduce(held, nbuckets, rows)[0], axis=0)
+    if form == "product":
+        return _product_counts(held, nbuckets)
     with jax.named_scope(SCATTER_SCOPE):
         return jnp.zeros(nbuckets, jnp.int32).at[held].add(1, mode="drop")
 
@@ -316,10 +400,11 @@ def bucket_sums_exact(bucket_ids: jnp.ndarray, v: jnp.ndarray,
     """Per-bucket sums of `v` over the rows with `w` > 0 and an id in
     [0, nbuckets), as i32[2 x limbs, nbuckets] for `limb_sums_to_f64`:
     each limb summed in int32 by (block of rows, bucket), densely or by
-    one scatter-add a limb (`dense_buckets`)."""
+    one scatter-add a limb (`count_form`: the product form is a count's
+    alone, so its range scatters here)."""
     limbs, bits, rows = sum_limb_plan(bucket_ids.shape[0], nbuckets)
     held = _held_ids(bucket_ids, w, nbuckets)
-    if dense_buckets(nbuckets):
+    if count_form(nbuckets) == "dense":
         return _folded(_dense_reduce(
             held, nbuckets, rows, v,
             lambda vb, ok: _limbs(vb, ok, inv, limbs, bits))[1:])
@@ -356,11 +441,12 @@ def limb_sums_to_f64(parts: np.ndarray, inv) -> np.ndarray:
 
 
 def sub_metric_scatters(n: int, nbuckets: int, sumsq: bool) -> int:
-    """Scatters `bucketed_sub_metric` issues for `n` rows where it
-    scatters (`dense_buckets` false): the count, the minimum, the maximum
-    and a limb each of the sum (and of the squares)."""
+    """Scatters `bucketed_sub_metric` issues for `n` rows where it is not
+    dense (`count_form`): the minimum, the maximum, a limb each of the sum
+    (and of the squares), and the count where that is no product."""
     limbs = sum_limb_plan(n, nbuckets)[0]
-    return 3 + limbs * (2 if sumsq else 1)
+    return ((2 if count_form(nbuckets) == "product" else 3)
+            + limbs * (2 if sumsq else 1))
 
 
 def bucketed_sub_metric(bucket_ids: jnp.ndarray, v: jnp.ndarray,
@@ -372,7 +458,8 @@ def bucketed_sub_metric(bucket_ids: jnp.ndarray, v: jnp.ndarray,
     `sum_scale_inv` of the column, handed back as `scale` for the host.
     Under `_DENSE_BUCKETS` buckets every accumulator is a reduction over
     one comparison of the rows with the bucket ids; else each is a
-    scatter."""
+    scatter, but for the count, which is a `bucket_counts` (a product
+    under `_PRODUCT_BUCKETS`)."""
     # the scope names these ops in the device trace (an op's provenance:
     # the benchmark's `agg_bucketed_sub_share` sums their time)
     with jax.named_scope(SUB_METRIC_SCOPE):
@@ -387,7 +474,7 @@ def bucketed_sub_metric(bucket_ids: jnp.ndarray, v: jnp.ndarray,
                 out += _limbs(v * v, w, inv * inv, limbs, bits)
             return out
 
-        if dense_buckets(nbuckets):     # one pass for every accumulator
+        if count_form(nbuckets) == "dense":     # one pass for them all
             count, *accs, lo, hi = _dense_reduce(b, nbuckets, rows, v,
                                                  parts, extremes=True)
             count = jnp.sum(count, axis=0)

@@ -86,11 +86,12 @@ EXECUTOR_STATS = CounterGroup(METRICS, "executor", {"params_h2d_bytes": 0,
 # the static spec (`_count_launch`): `scatter.updates` the rows handed to
 # every scatter (a bucket count by `ops.aggs.bucket_counts`, and each
 # scatter of a bucketed sub-metric: count, minimum, maximum and a limb a
-# sum), which is what a reduction into `ops.aggs.dense_buckets` buckets
-# or more takes; `blocked.rows` the rows a form that replaces a scatter
-# reads, rows x passes over them (`ops.aggs.run_counts`, and under that
-# many buckets the dense form: one pass a bucket count, one for all of a
-# sub-metric's accumulators); `bucketed_sub.launches` / `.buckets` the
+# sum), which is what `ops.aggs.count_form` names "scatter" (and, for all
+# of a sub-metric but its count, "product"); `blocked.rows` the rows a
+# form that replaces a scatter reads, rows x passes over them
+# (`ops.aggs.run_counts`; the dense form: one pass a bucket count, one
+# for all of a sub-metric's accumulators; the product form: one pass a
+# count); `bucketed_sub.launches` / `.buckets` the
 # launches that carry a metric under a bucket aggregation, and their
 # buckets; `auto_date.requests` the top-level auto_date_histograms a
 # segment was asked, `auto_date.refine_launches` the launches taken first
@@ -6010,8 +6011,9 @@ _AGG_CONTAINER_SUBS = {"filter": 3, "filters": 3, "global": 2, "missing": 4,
 def _agg_cost(spec, seg_arrays: dict, cost: dict) -> None:
     """What `emit_agg` builds for `spec`, reckoned from the spec alone (the
     walk mirrors it): rows handed to scatters, rows read by `run_counts`
-    and by the dense form (`ops.aggs.dense_buckets`, the predicate the
-    emit chooses by), buckets that carry a metric sub-aggregation, and
+    and by the dense and product forms (`ops.aggs.count_form`, the
+    predicate the emit chooses by), buckets that carry a metric
+    sub-aggregation, and
     where `cost` has the keys the slots a terms-like group-by counts into
     (`ordinals`; `combinations` those of a composite) and the flat values
     a keyword group-by gathers the match to (`gathered`: its rows where
@@ -6060,16 +6062,16 @@ def _agg_cost(spec, seg_arrays: dict, cost: dict) -> None:
         for sub in (spec[at] if at is not None else ()):
             _agg_cost(sub, seg_arrays, cost)
         return
-    dense = agg_ops.dense_buckets(nb)
-    if spec[-1] == "runs" or dense:
+    form = agg_ops.count_form(nb)
+    if spec[-1] == "runs" or form != "scatter":
         cost["blocked"] += rows
     else:
         cost["scatter"] += rows
     for sub in subs:
         if sub and sub[0] == "stats" and sub[3]:
-            if dense:
+            if form != "scatter":   # all of it dense, or its count a product
                 cost["blocked"] += rows
-            else:
+            if form != "dense":
                 cost["scatter"] += rows * agg_ops.sub_metric_scatters(
                     rows, nb, sub[4])
             cost["sub_buckets"] += nb

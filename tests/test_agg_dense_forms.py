@@ -1,10 +1,11 @@
-"""The two forms of a per-bucket reduction over ids in any order
-(`ops.aggs`: dense under `_DENSE_BUCKETS` buckets, else a scatter) give
-equal arrays, dtypes included, for `bucket_counts`, `bucket_sums_exact` and
-`bucketed_sub_metric`; the constant alone chooses; and `compiler._agg_cost`
-counts `aggs.blocked.rows` / `aggs.scatter.updates` by the predicate the
-emit chooses by. A test steers the form by moving the constant (the program
-has no option for it)."""
+"""The forms of a per-bucket reduction over ids in any order (`ops.aggs`:
+dense under `_DENSE_BUCKETS` buckets, a count the product of two one-hots
+under `_PRODUCT_BUCKETS`, else a scatter) give equal arrays, dtypes
+included, for `bucket_counts`, `bucket_sums_exact` and
+`bucketed_sub_metric`; the two constants alone choose (`count_form`); and
+`compiler._agg_cost` counts `aggs.blocked.rows` / `aggs.scatter.updates` by
+the predicate the emit chooses by. A test steers the form by moving the
+constants (the program has no option for it)."""
 
 import numpy as np
 import pytest
@@ -14,17 +15,26 @@ import jax
 from opensearch_tpu.ops import aggs as agg_ops
 from opensearch_tpu.search import compiler as C
 
-DENSE, SCATTER = 1 << 30, 0
+# (`_DENSE_BUCKETS`, `_PRODUCT_BUCKETS`) that give every size one form
+DENSE, PRODUCT, SCATTER = (1 << 30, 1 << 30), (0, 1 << 30), (0, 0)
+
+
+def _forms(monkeypatch, forms, fn, *args):
+    """`fn(*args)` jitted under each of `forms` -> their outputs as numpy."""
+    out = []
+    for dense, product in forms:
+        monkeypatch.setattr(agg_ops, "_DENSE_BUCKETS", dense)
+        monkeypatch.setattr(agg_ops, "_PRODUCT_BUCKETS", product)
+        # a new function object a form: `jax.jit` of one it has traced
+        # answers from its cache, whatever the constants have become
+        got = jax.jit(lambda *a: fn(*a))(*args)
+        out.append(jax.tree_util.tree_map(np.asarray, got))
+    return out
 
 
 def _both(monkeypatch, fn, *args):
-    """`fn(*args)` jitted under each form -> (dense, scatter) as numpy."""
-    out = []
-    for constant in (DENSE, SCATTER):
-        monkeypatch.setattr(agg_ops, "_DENSE_BUCKETS", constant)
-        got = jax.jit(fn)(*args)            # a fresh jit: traced anew
-        out.append(jax.tree_util.tree_map(np.asarray, got))
-    return out
+    """-> (dense, scatter)."""
+    return _forms(monkeypatch, (DENSE, SCATTER), fn, *args)
 
 
 def _same(a, b):
@@ -62,6 +72,53 @@ def test_bucket_counts_forms_agree(monkeypatch, n, nb):
     ok = (w > 0) & (b >= 0) & (b < nb)
     assert dense.dtype == np.int32 and dense.shape == (nb,)
     assert np.array_equal(dense, np.bincount(b[ok], minlength=nb))
+
+
+# slots a power of two and not, one that `L` does not divide (461,089 is the
+# big5 composite's), one row, rows a tile of 128 divides and does not
+# (blocks of fewer rows: the test of the float32 block, below); (1, 1) and
+# (4096, 101) are under a tile of lanes a side
+PRODUCT_SIZES = [(1, 1), (4096, 101), (1, 2048), (1000, 2048), (70001, 4097),
+                 (40000, 16384), (33000, 65536), (5000, 461089)]
+
+
+@pytest.mark.parametrize("n,nb", PRODUCT_SIZES)
+def test_bucket_counts_product_equals_the_scatter(monkeypatch, n, nb):
+    """Ids at `nb`, beyond it and below 0 and rows of weight 0 count
+    nowhere in either form; the same int32s slot for slot."""
+    b, _v, w, _inv = _rows(n, nb, 7 * n + nb)
+    product, scatter = _forms(
+        monkeypatch, (PRODUCT, SCATTER),
+        lambda b, w: agg_ops.bucket_counts(b, w, nb), b, w)
+    _same(product, scatter)
+    ok = (w > 0) & (b >= 0) & (b < nb)
+    assert product.dtype == np.int32 and product.shape == (nb,)
+    assert np.array_equal(product, np.bincount(b[ok], minlength=nb))
+
+
+@pytest.mark.parametrize("nb", [2048, 4097, 16384, 65536, 131072, 461089])
+def test_product_split_holds_every_slot(nb):
+    h, l = agg_ops.product_split(nb)
+    assert l >= 128 and l & (l - 1) == 0 and (h - 1) * l < nb <= h * l
+    assert l // 2 < max(nb ** 0.5, 128) <= l
+
+
+@pytest.mark.parametrize("sumsq", [False, True])
+@pytest.mark.parametrize("n,nb", [(1000, 2048), (70001, 4097)])
+def test_bucketed_sub_metric_in_the_products_range(monkeypatch, n, nb,
+                                                   sumsq):
+    """Between the constants only the metric's count is a product: the
+    whole dict equals the scatter's."""
+    b, v, w, inv = _rows(n, nb, 9 * n + nb)
+    product, scatter = _forms(
+        monkeypatch, ((0, 1 << 30), SCATTER),
+        lambda b, v, w: agg_ops.bucketed_sub_metric(b, v, w, nb, inv, sumsq),
+        b, v, w)
+    _same(product, scatter)
+    sums = _forms(
+        monkeypatch, ((0, 1 << 30), SCATTER),
+        lambda b, v, w: agg_ops.bucket_sums_exact(b, v, w, nb, inv), b, v, w)
+    _same(*sums)
 
 
 @pytest.mark.parametrize("n,nb", SIZES)
@@ -175,13 +232,71 @@ def test_a_bucket_of_more_than_2_to_24_rows_worth_of_weight(monkeypatch):
     assert dense.tolist() == [n, 0, 0]
 
 
+@pytest.mark.parametrize("block", [128, 1024])
+def test_a_slot_past_the_products_float32_block(monkeypatch, block):
+    """A slot's count past what one block's float32 partial may hold, in
+    miniature: blocks of `block` rows, and 40,000 rows of one slot (and
+    2,500 of the last) read 40,000 and 2,500 as the scatter reads them:
+    every block's partial is added as int32, none is narrowed."""
+    n, nb = 42_500, 4097
+    b = np.where(np.arange(n) % 17 == 0, nb - 1, 300).astype(np.int32)
+    w = np.ones(n, np.float32)
+    monkeypatch.setattr(agg_ops, "_PRODUCT_BLOCK", block)
+    product, scatter = _forms(
+        monkeypatch, (PRODUCT, SCATTER),
+        lambda b, w: agg_ops.bucket_counts(b, w, nb), b, w)
+    _same(product, scatter)
+    assert product[300] == 40_000 and product[nb - 1] == 2_500
+    assert product.sum() == n
+
+
+@pytest.mark.parametrize("nb", [2048, 4097, 65536])
+def test_between_the_constants_the_count_is_one_product(nb):
+    """From `_DENSE_BUCKETS` up to `_PRODUCT_BUCKETS` the jaxpr of
+    `bucket_counts` holds one `dot_general` and no scatter; the sums and
+    the extremes keep their scatters and only a metric's count rides the
+    product; at `_PRODUCT_BUCKETS` no `dot_general` is left."""
+    assert agg_ops.count_form(nb) == "product"
+    assert agg_ops.count_form(agg_ops._PRODUCT_BUCKETS - 1) == "product"
+    assert agg_ops.count_form(agg_ops._PRODUCT_BUCKETS) == "scatter"
+    b, v, w, inv = _rows(2048, nb, 3)
+    count = str(jax.make_jaxpr(
+        lambda b, w: agg_ops.bucket_counts(b, w, nb))(b, w))
+    assert "scatter" not in count and count.count("dot_general") == 1
+    metric = str(jax.make_jaxpr(
+        lambda b, v, w: agg_ops.bucketed_sub_metric(b, v, w, nb, inv, False)
+    )(b, v, w))
+    limbs = agg_ops.sum_limb_plan(2048, nb)[0]
+    assert metric.count("dot_general") == 1
+    assert (metric.count("scatter-add") + metric.count("scatter_add")
+            == limbs)
+    assert agg_ops.sub_metric_scatters(2048, nb, False) == 2 + limbs
+    at = agg_ops._PRODUCT_BUCKETS
+    over = str(jax.make_jaxpr(
+        lambda b, w: agg_ops.bucket_counts(b, w, at))(b, w))
+    assert "dot_general" not in over and "scatter" in over
+    assert agg_ops.sub_metric_scatters(2048, at, False) == 3 + \
+        agg_ops.sum_limb_plan(2048, at)[0]
+
+
+def test_the_product_ops_carry_their_scope():
+    b, _v, w, _inv = _rows(2048, 4097, 4)
+    text = jax.jit(
+        lambda b, w: agg_ops.bucket_counts(b, w, 4097)
+    ).lower(b, w).as_text(debug_info=True)
+    assert f"{agg_ops.PRODUCT_SCOPE}/" in text
+    assert agg_ops.SCATTER_SCOPE not in text
+    assert "stablehlo.scatter" not in text
+
+
 @pytest.mark.parametrize("nb", [1, 101, 366])
 def test_under_the_constant_no_scatter_is_built(nb):
-    """The form is chosen by `nbuckets` against the one constant: under it
-    the jaxpr of all three entries holds no scatter, at it they all do."""
-    assert agg_ops.dense_buckets(nb)
-    assert agg_ops.dense_buckets(agg_ops._DENSE_BUCKETS - 1)
-    assert not agg_ops.dense_buckets(agg_ops._DENSE_BUCKETS)
+    """The form is chosen by `nbuckets` against the constants: under the
+    first the jaxpr of all three entries holds no scatter, at it they all
+    do (the count alone is a product there)."""
+    assert agg_ops.count_form(nb) == "dense"
+    assert agg_ops.count_form(agg_ops._DENSE_BUCKETS - 1) == "dense"
+    assert agg_ops.count_form(agg_ops._DENSE_BUCKETS) != "dense"
     b, v, w, inv = _rows(2048, nb, 1)
 
     def all_three(nb):
@@ -191,6 +306,7 @@ def test_under_the_constant_no_scatter_is_built(nb):
                     agg_ops.bucketed_sub_metric(b, v, w, nb, inv, True))
         return str(jax.make_jaxpr(fn)(b, v, w))
     assert "scatter" not in all_three(nb)
+    assert "dot_general" not in all_three(nb)
     assert all_three(agg_ops._DENSE_BUCKETS).count("scatter") >= 3
 
 
@@ -248,13 +364,14 @@ def _cost(spec):
     ("terms_by_doc", None, N), ("geo_grid", None, N)])
 @pytest.mark.parametrize("subs", [(), (STATS,), (EXT,), (STATS, GONE, EXT)])
 def test_agg_cost_follows_the_predicate(kind, form, rows, subs):
-    few, many = agg_ops._DENSE_BUCKETS - 1, agg_ops._DENSE_BUCKETS
+    few, many = agg_ops._DENSE_BUCKETS - 1, agg_ops._PRODUCT_BUCKETS
     counted = sum(1 for s in subs if s[3])
-    # under the constant: a pass for the count and one a sub-metric
+    # under the first constant: a pass for the count and one a sub-metric
     got = _cost(_spec(kind, few, subs, form))
     assert got == {"scatter": 0, "blocked": rows * (1 + counted),
                    "sub_buckets": few * counted}
-    # at it: one scatter for the count and `sub_metric_scatters` a metric
+    # at the second: one scatter for the count and `sub_metric_scatters`
+    # a metric
     got = _cost(_spec(kind, many, subs, form))
     scatters = 1 + sum(agg_ops.sub_metric_scatters(rows, many, s[4])
                        for s in subs if s[3])
@@ -262,15 +379,46 @@ def test_agg_cost_follows_the_predicate(kind, form, rows, subs):
                    "sub_buckets": many * counted}
 
 
-@pytest.mark.parametrize("nb", [64, 5000])
+@pytest.mark.parametrize("kind,form,rows", [
+    ("hist", None, N), ("date_hist", "scatter", N),
+    ("auto_date_hist", "scatter", N), ("terms", None, 3 * N),
+    ("terms_by_doc", None, N), ("geo_grid", None, N)])
+@pytest.mark.parametrize("subs", [(), (STATS,), (EXT,), (STATS, GONE, EXT)])
+@pytest.mark.parametrize("at", ["first", "last"])
+def test_agg_cost_between_the_constants(kind, form, rows, subs, at):
+    """In the product's range the count, and each metric's count, is a
+    pass under `aggs.blocked.rows`; a metric's minimum, maximum and limbs
+    are scatters still: one fewer than past the second constant."""
+    nb = (agg_ops._DENSE_BUCKETS if at == "first"
+          else agg_ops._PRODUCT_BUCKETS - 1)
+    assert agg_ops.count_form(nb) == "product"
+    counted = sum(1 for s in subs if s[3])
+    got = _cost(_spec(kind, nb, subs, form))
+    scatters = sum(agg_ops.sub_metric_scatters(rows, nb, s[4])
+                   for s in subs if s[3])
+    limbs = agg_ops.sum_limb_plan(rows, nb)[0]
+    assert scatters == sum(2 + limbs * (2 if s[4] else 1)
+                           for s in subs if s[3])
+    assert got == {"scatter": rows * scatters,
+                   "blocked": rows * (1 + counted),
+                   "sub_buckets": nb * counted}
+
+
+@pytest.mark.parametrize("nb", [64, 5000, 1 << 20])
 def test_agg_cost_of_a_run_counted_plane(nb):
     """The count of a plane in row order is `run_counts`' one pass whatever
     the buckets; the metric under it follows the predicate."""
     got = _cost(_spec("date_hist", nb, (STATS,), "runs"))
-    if agg_ops.dense_buckets(nb):
+    form = agg_ops.count_form(nb)
+    limbs = agg_ops.sum_limb_plan(N, nb)[0]
+    if form == "dense":
         assert got == {"scatter": 0, "blocked": 2 * N, "sub_buckets": nb}
+    elif form == "product":     # the metric's count is a product
+        assert got == {"scatter": (2 + limbs) * N, "blocked": 2 * N,
+                       "sub_buckets": nb}
     else:
-        assert got == {"scatter": 6 * N, "blocked": N, "sub_buckets": nb}
+        assert got == {"scatter": (3 + limbs) * N, "blocked": N,
+                       "sub_buckets": nb}
 
 
 def test_agg_cost_walks_containers():

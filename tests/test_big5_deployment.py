@@ -229,7 +229,7 @@ def test_the_counters_say_what_a_launch_counted(deployments):
         client.search(harness.INDEX, stream.twin(spec)["body"])
     nstreams = len(seg.keyword_cols[STREAM].vocab)
     slots = C.next_pow2(nstreams)
-    assert agg_ops.dense_buckets(slots)     # 400 streams at this size
+    assert agg_ops.count_form(slots) == "dense"     # 400 streams here
     got = _counted(client, specs["keyword-terms"]["body"])
     assert (got["terms.ordinals"], got["blocked.rows"],
             got["scatter.updates"], got["launches"]) == (slots, n, 0, 1)
@@ -248,9 +248,9 @@ def test_the_counters_say_what_a_launch_counted(deployments):
     assert got["terms.records"] == 10
     combos = got["composite.combinations"]
     assert 12 * 26 < combos <= NDOCS
-    # over `_DENSE_BUCKETS` combinations the count scatters
+    # from `_PRODUCT_BUCKETS` combinations on the count scatters
     assert (got["scatter.updates"], got["blocked.rows"]) == (
-        (0, n) if agg_ops.dense_buckets(combos) else (n, 0))
+        (n, 0) if agg_ops.count_form(combos) == "scatter" else (0, n))
     # a keyword cardinality is the `terms_counts` under its registers
     for shape in ("cardinality-agg-low", "cardinality-agg-high"):
         got = _counted(client, specs[shape]["body"])
@@ -287,7 +287,9 @@ def test_the_gathered_rows_reader(counters, want):
 
 
 @pytest.mark.parametrize("by_value", [True, False])
-@pytest.mark.parametrize("nb,form", [(300, "blocked"), (5000, "scatter")])
+@pytest.mark.parametrize("nb,form", [
+    (300, "blocked"), (5000, "blocked"),        # dense; a product
+    (agg_ops._PRODUCT_BUCKETS, "scatter")])
 def test_agg_cost_counts_a_keyword_cardinality_under_its_form(nb, form,
                                                               by_value):
     n = 1 << 12

@@ -189,6 +189,31 @@ def test_dense_bucket_counts_compile_for_v5e(shape_on_chip, nb):
     assert compiled.memory_analysis().temp_size_in_bytes < 160 << 20
 
 
+N_EVENTS = 1 << 24      # the big5 cell's padded rows
+
+
+@pytest.mark.parametrize("nb", [agg_ops._DENSE_BUCKETS, 16_384, 65_536,
+                                agg_ops._PRODUCT_BUCKETS - 1])
+def test_product_bucket_counts_compile_for_v5e(shape_on_chip, nb):
+    """`bucket_counts` between the constants at the big5 cell's size: one
+    loop over the blocks, no scatter, one convolution whose operands are
+    the comparisons themselves (neither one-hot is written: 2^24 x 512 x 2
+    bytes would be 17 GB), the plane viewed and not relaid; no temporary
+    but the held ids (the scatter's own)."""
+    assert agg_ops.count_form(nb) == "product"
+    S = shape_on_chip
+    compiled = jax.jit(lambda b, w: agg_ops.bucket_counts(b, w, nb)).lower(
+        S((N_EVENTS,), jnp.int32), S((N_EVENTS,), jnp.float32)).compile()
+    text = compiled.as_text()
+    assert len(re.findall(r"\bwhile\(", text)) == 1
+    assert "scatter(" not in text
+    assert len(re.findall(r" convolution\(", text)) == 1
+    # no one-hot leaves its fusion, and the plane is not relaid (a copy of
+    # it would be 64 MiB more)
+    assert not re.search(r"bf16\[\d+,%d\]" % agg_ops._PRODUCT_BLOCK, text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 80 << 20
+
+
 # ---------------------------------------------------------------------
 # the real kernels, interpreted, against their numpy stand-ins
 # ---------------------------------------------------------------------

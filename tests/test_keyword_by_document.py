@@ -185,7 +185,7 @@ def test_the_two_forms_give_equal_arrays(one_segment, op, field):
     assert agg_ops.group_by_rows(by_doc) == seg.ndocs_pad
     assert agg_ops.group_by_rows(by_val) == by_val["ords"].shape[0]
     nb = C.next_pow2(FIELDS[field])
-    assert agg_ops.dense_buckets(nb) == (field == "few")
+    assert (agg_ops.count_form(nb) == "dense") == (field == "few")
     # the mask as the program hands it over: live documents that match,
     # and (the program never does) every padded row, which no id holds
     ok = ~cols["deleted"] & (cols["v"] % 3 > 0)

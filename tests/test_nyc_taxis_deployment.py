@@ -109,7 +109,7 @@ def test_the_counters_say_what_a_launch_scattered(deployments):
     # and three limbs of the sum: a few hundred buckets at most, so the
     # dense form reads the rows twice (the count's pass, and one for the
     # stats' six accumulators) where seven scatters took a row at a time
-    assert agg_ops.dense_buckets(366) and agg_ops.sub_metric_scatters(
+    assert agg_ops.count_form(366) == "dense" and agg_ops.sub_metric_scatters(
         n, 64, False) == 6
     got = _counted(client, specs["distance_amount_agg"])
     assert got["blocked.rows"] == 2 * n and got["launches"] == 1

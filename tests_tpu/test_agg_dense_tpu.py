@@ -4,8 +4,9 @@ cell's `distance_amount_agg`, `autohisto_agg`, `date_histogram_agg`): the
 form the constant chooses equals the scatter's output exactly, for
 `bucket_counts` and for `bucketed_sub_metric`, and its time is read beside
 the scatter's; and both are read on either side of `_DENSE_BUCKETS`, which is
-where the constant comes from. A test moves the constant to get the other
-form (the program has no option for it). Run on a real chip:
+where the constant comes from (the product form that takes a count from
+there on is read in `test_big5_tpu.py`). A test moves the constants to get
+another form (the program has no option for it). Run on a real chip:
 `python -m pytest tests_tpu/test_agg_dense_tpu.py -q -s`."""
 
 import time
@@ -73,6 +74,7 @@ def _forms(monkeypatch, make, args):
     monkeypatch.setattr(agg_ops, "_DENSE_BUCKETS", 1 << 30)
     dense = _timed(make(), args, 7)
     monkeypatch.setattr(agg_ops, "_DENSE_BUCKETS", 0)
+    monkeypatch.setattr(agg_ops, "_PRODUCT_BUCKETS", 0)
     scatter = _timed(make(), args, 2)
     return dense, scatter
 
@@ -87,7 +89,7 @@ def _equal(a, b):
 def test_the_chosen_form_equals_the_scatter_at_the_cells_size(
         rows, monkeypatch, nb):
     (ids_h, v_h, w_h), dev, inv = rows
-    assert agg_ops.dense_buckets(nb)            # the form the program takes
+    assert agg_ops.count_form(nb) == "dense"    # the form the program takes
     chosen = jax.tree_util.tree_map(np.asarray, jax.jit(_sub(nb, inv))(*dev))
     (dense, dense_ms), (scatter, scatter_ms) = _forms(
         monkeypatch, lambda: _sub(nb, inv), dev)
@@ -125,5 +127,5 @@ def test_where_the_constant_stands(rows, monkeypatch, nb):
           f" scatter {s_sub:.1f}; bucket_counts dense {d_cnt:.1f} ms,"
           f" scatter {s_cnt:.1f}")
     monkeypatch.undo()
-    if agg_ops.dense_buckets(nb):
+    if agg_ops.count_form(nb) == "dense":
         assert d_sub < s_sub and d_cnt < s_cnt

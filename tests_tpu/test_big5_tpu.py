@@ -1,13 +1,14 @@
 """TPU-hardware check of the group-bys the `big5` cell runs, at its size:
 16,777,216 padded rows, ordinals in no row order. First `ops.aggs` alone at
 the cell's bucket counts against `np.bincount` (a `terms` into 65,536
-slots and a keyword cardinality's registers over 16,384: scatters; a
-`multi_terms` / `composite` plane into 312 and 512 slots: the dense form;
-a composite's 461,089 combinations: a scatter again), each with its time,
-a keyword column's group-bys in both of its layouts, by document and by
-value, side by side;
-then the seven request shapes through `RestClient.search` over 1,048,576
-generated events (20,968 streams, 5,242 agents: the scatter side of
+slots and a keyword cardinality's registers over 16,384: the product of
+two one-hots since PR 45; a `multi_terms` / `composite` plane into 312 and
+512 slots: the dense form; a composite's 461,089 combinations: whatever
+`count_form` names), each with its time, a keyword column's group-bys in
+both of its layouts, by document and by value, side by side; where the
+second constant stands: product against scatter from 2,048 to 524,288
+slots; then the seven request shapes through `RestClient.search` over
+1,048,576 generated events (20,968 streams, 5,242 agents: past
 `_DENSE_BUCKETS` as at the cell's size) against the kind's plain reference.
 Run on a real chip: `python -m pytest tests_tpu/test_big5_tpu.py -q -s`."""
 
@@ -50,7 +51,9 @@ def rows():
 
 
 def _timed(fn, args, reps=5):
-    jfn = jax.jit(fn)
+    # (a new function object a call: `jax.jit` of one it has traced answers
+    # from its cache, whatever a test has made of the constants since)
+    jfn = jax.jit(lambda *a: fn(*a))
     out = jax.tree_util.tree_map(np.asarray, jfn(*args))
     times = []
     for _ in range(reps):
@@ -79,7 +82,7 @@ def test_a_plane_of_ordinals_counts_exactly(rows, nb, what):
     assert got.dtype == np.int32
     assert np.array_equal(got, _want(ords_h, match_h, nb))
     assert int(got.max()) > 2048        # past what a float16 would count
-    form = "dense" if agg_ops.dense_buckets(nb) else "scatter"
+    form = agg_ops.count_form(nb)
     print(f"ord_counts n={N} slots={nb} ({what}): {form} {ms:.2f} ms "
           f"(launch + read, median of 5)")
 
@@ -102,7 +105,7 @@ def test_terms_counts_and_a_keyword_cardinality_in_both_forms(rows, nvocab):
     hashes = jnp.asarray(np.random.default_rng(1).integers(
         0, 1 << 32, nvocab, dtype=np.uint64).astype(np.uint32))
     want = _want(ords_h, match_h, nvocab)
-    form = "dense" if agg_ops.dense_buckets(nvocab) else "scatter"
+    form = agg_ops.count_form(nvocab)
     for name, kw in (("by document", by_doc), ("by value", by_value)):
         got, ms = _timed(lambda k, m: agg_ops.terms_counts(k, m, nvocab),
                          (kw, match))
@@ -118,6 +121,39 @@ def test_terms_counts_and_a_keyword_cardinality_in_both_forms(rows, nvocab):
               f"{ms:.2f} ms; cardinality registers + distinct "
               f"{ms_card:.2f} ms; value_count {ms_vc:.2f} ms "
               f"(launch + read, median of 5)")
+
+
+@pytest.mark.parametrize("nb", [2048, 16_384, 65_536, 131_072, 262_144,
+                                461_089, 524_288])
+def test_where_the_second_constant_stands(rows, monkeypatch, nb):
+    """Product against scatter on either side of `_PRODUCT_BUCKETS`: both
+    equal `np.bincount`; the product's time grows with the slots and the
+    scatter's does not; under the constant the product wins, and the
+    constant lies where it still wins by a wide margin (at or over it the
+    scatter is the program's form whichever reads faster here)."""
+    (ords_h, match_h), dev = rows
+
+    def fn(ords, match):
+        return agg_ops.ord_counts(jnp.where(ords >= 0, ords % nb, -1),
+                                  match, nb)
+    monkeypatch.setattr(agg_ops, "_DENSE_BUCKETS", 0)
+    monkeypatch.setattr(agg_ops, "_PRODUCT_BUCKETS", 1 << 30)
+    product, p_ms = _timed(fn, dev)
+    monkeypatch.setattr(agg_ops, "_PRODUCT_BUCKETS", 0)
+    scatter, s_ms = _timed(fn, dev, reps=3)
+    monkeypatch.undo()
+    want = _want(ords_h, match_h, nb)
+    assert product.dtype == scatter.dtype == np.int32
+    assert np.array_equal(product, want) and np.array_equal(scatter, want)
+    chosen = agg_ops.count_form(nb)
+    h, l = agg_ops.product_split(nb)
+    print(f"bucket_counts n={N} slots={nb}: product ({h} x {l}) "
+          f"{p_ms:.2f} ms, scatter {s_ms:.2f} ms, chosen {chosen} "
+          f"(launch + read, median)")
+    if chosen == "product":
+        assert p_ms < s_ms
+    else:
+        assert chosen == "scatter" and p_ms > 0.5 * s_ms
 
 
 def test_the_seven_shapes_through_the_client_at_a_million_events():
@@ -155,9 +191,11 @@ def test_the_seven_shapes_through_the_client_at_a_million_events():
                for kw in seg.device_arrays()["keyword"].values())
     assert got["terms.gathered_rows"] == 0
     assert built["readout"]["vocabulary"][reference.STREAM] > 2048
-    # four of seven operations scatter, three take the dense form
-    assert got["scatter.updates"] == 2 * 4 * n
-    assert got["blocked.rows"] == 2 * 3 * n
+    # three of seven operations take the dense form, the two `terms` and
+    # the cardinality the product; the composite's combinations whatever
+    # `count_form` names at this size
+    assert got["scatter.updates"] in (0, 2 * n)
+    assert got["scatter.updates"] + got["blocked.rows"] == 2 * 7 * n
     assert got["terms.records"] == 2 * (500 + 50 + 10 + 10 + 10)
     assert C.EXECUTOR_STATS["params_h2d_bytes"] - h2d < 14 * (1 << 18)
     for shape, ms in times.items():
