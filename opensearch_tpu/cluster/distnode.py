@@ -1,7 +1,6 @@
 """Two-process cluster: full Nodes in separate OS processes, one index.
 
-The product promotion of the r4 two-process SPMD experiment
-(`tests/_mh_child.py`): each process runs a complete Node + RestClient +
+Each process runs a complete Node + RestClient +
 HttpServer; cluster membership, state publication, and the search
 scatter/gather all travel over the HTTP wire layer — the analog of the
 reference's netty transport + coordinator
@@ -84,7 +83,7 @@ from typing import Dict, List, Optional, Tuple
 
 from ..rest.client import ApiError, RestClient
 from ..rest.http_server import HttpServer
-from ..search import compiler as C
+from ..search import plan as PL
 from ..search import query_dsl as dsl
 from ..search.aggregations import parse_aggs
 from ..search.executor import (Candidate, ShardQueryResult,
@@ -99,15 +98,14 @@ from .routing import assign_copies, order_copies, shard_for
 # transport cap, NOT the per-hop timeout: every RPC derives its actual
 # socket timeout from the request's remaining deadline budget
 # (min(remaining, cap)); only deadline-less requests see the full cap
-_RPC_TIMEOUT_CAP_S = float(os.environ.get("OPENSEARCH_TPU_RPC_CAP_S",
-                                          30.0))
+_RPC_TIMEOUT_CAP_S = 30.0
 
 # observability scrapes (cluster stats / hot_threads / history fan-out)
 # get a TIGHTER default cap: a monitoring poll against a wedged member
 # must degrade to a per-node `failed` entry in seconds, never hold the
 # coordinator for the full transport cap. A live request deadline still
 # tightens it further (deadline-ctx rides the scrape like any RPC).
-_SCRAPE_CAP_S = float(os.environ.get("OPENSEARCH_TPU_SCRAPE_CAP_S", 5.0))
+_SCRAPE_CAP_S = 5.0
 
 # Failure-detector snapshot for one top-level request.  A hybrid body
 # fans its sub-retrievals out as parallel legs; each sub-search plans
@@ -131,18 +129,14 @@ class RetryPolicy:
     failures instead of a retry storm; `storm_n` is the request-level
     retry count that freezes a flight-recorder dump."""
 
-    def __init__(self, same_member_retries: Optional[int] = None,
-                 budget: Optional[int] = None,
+    def __init__(self, same_member_retries: int = 1,
+                 budget: int = 4,
                  base_backoff_s: float = 0.025,
                  backoff_mult: float = 2.0,
                  max_backoff_s: float = 0.5,
                  storm_n: Optional[int] = None):
-        env = os.environ
-        self.same_member_retries = int(
-            same_member_retries if same_member_retries is not None
-            else env.get("OPENSEARCH_TPU_RPC_RETRIES", 1))
-        self.budget = int(budget if budget is not None
-                          else env.get("OPENSEARCH_TPU_RETRY_BUDGET", 4))
+        self.same_member_retries = int(same_member_retries)
+        self.budget = int(budget)
         self.base_backoff_s = float(base_backoff_s)
         self.backoff_mult = float(backoff_mult)
         self.max_backoff_s = float(max_backoff_s)
@@ -151,8 +145,7 @@ class RetryPolicy:
         # above the budget would make the dump unreachable — retries
         # are capped at the budget)
         self.storm_n = int(storm_n if storm_n is not None
-                           else env.get("OPENSEARCH_TPU_RETRY_STORM_N",
-                                        self.budget))
+                           else self.budget)
 
 
 class _ShardCallFailed(Exception):
@@ -235,7 +228,7 @@ class _RequestState:
 # statistics contexts for the cross-node DFS phase
 # ---------------------------------------------------------------------
 
-class RecordingStatsContext(C.ShardContext):
+class RecordingStatsContext(PL.ShardContext):
     """Wraps the local collection-stats view and records every statistic
     the query rewrite consumes — the node-local half of the DFS phase."""
 
@@ -246,7 +239,7 @@ class RecordingStatsContext(C.ShardContext):
 
     @property
     def num_docs(self) -> int:
-        n = C.ShardContext.num_docs.fget(self)
+        n = PL.ShardContext.num_docs.fget(self)
         self.rec["num_docs"] = n
         return n
 
@@ -266,7 +259,7 @@ class RecordingStatsContext(C.ShardContext):
         return v
 
 
-class GlobalStatsContext(C.ShardContext):
+class GlobalStatsContext(PL.ShardContext):
     """A stats context pinned to coordinator-summed global statistics: every
     node scores with the same idf/avgdl no matter where documents live.
     Statistics the DFS recording did not capture (rare: a fetch-side
@@ -382,9 +375,9 @@ class DistClusterNode:
         # successful probe/RPC (cluster/failure.py)
         self.member_fd = MemberFailureDetector()
         # wire the detector into an already-armed remediation actuator
-        # (OPENSEARCH_TPU_REMEDIATION=1 arms at Node init, BEFORE this
-        # cluster wrapper exists): without this, the deprioritize_member
-        # action would be silently inert on the production arm path
+        # (one armed on the Node BEFORE this cluster wrapper exists):
+        # without this, the deprioritize_member action would be silently
+        # inert
         rem = self.node.remediation
         if rem is not None and rem.member_fd is None:
             rem.member_fd = self.member_fd
@@ -940,8 +933,8 @@ class DistClusterNode:
                 getattr(svc, "field_similarities", None))
             try:
                 from ..search.executor import _collect_named
-                lroot = C.rewrite(dsl.parse_query(body.get("query")), ctx,
-                                  scoring=True)
+                lroot = PL.rewrite(dsl.parse_query(body.get("query")), ctx,
+                                   scoring=True)
                 # named queries are fetch-side state that does not cross
                 # the wire yet; piggyback the check on the rewrite DFS
                 # already does

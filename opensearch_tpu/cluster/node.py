@@ -416,8 +416,8 @@ class Node:
         # time-series retention ring behind `_nodes/stats/history` and
         # the SLO burn-rate engine behind `GET /_slo`. Process singletons
         # like METRICS/RECORDER/LEDGER; the sampler thread does NOT
-        # auto-start (tests tick deterministically) unless
-        # OPENSEARCH_TPU_TS=1 pins always-on retention for servers
+        # auto-start (tests tick deterministically; a server calls
+        # `SAMPLER.ensure_started()`)
         from ..obs.slo import SLO_ENGINE
         from ..obs.timeseries import SAMPLER
         self.timeseries = SAMPLER
@@ -432,16 +432,10 @@ class Node:
         # from a firing slo.burn alert to bounded admission-level action
         # (shed offending shapes, tighten admission, deprioritize a sick
         # member). Process singleton, DISARMED by default — the serving
-        # hot path pays one attribute read; OPENSEARCH_TPU_REMEDIATION=1
-        # arms it against this node's SLO engine at init (servers), and
-        # the traffic harness / tests arm injected instances explicitly
+        # hot path pays one attribute read; a server, the traffic harness
+        # and tests arm it explicitly (`arm(node=...)`)
         from ..serving.remediator import REMEDIATOR
         self.remediation = REMEDIATOR
-        if os.environ.get("OPENSEARCH_TPU_REMEDIATION") \
-                not in (None, "", "0"):
-            REMEDIATOR.arm(node=self)
-        if os.environ.get("OPENSEARCH_TPU_TS") not in (None, "", "0"):
-            SAMPLER.ensure_started()
         # persistent tasks (reference persistent/AllocatedPersistentTask):
         # durable task table + resumable executors; built-in: reindex
         from ..utils.persistent_tasks import PersistentTasksService
@@ -893,14 +887,6 @@ class Node:
         if os.path.exists(man_path):
             with open(man_path) as fh:
                 return json.load(fh)
-        # legacy layout (pre-r4): <repo>/<name>/manifest.json + per-index
-        # directory copies — still restorable
-        legacy = os.path.join(repo_path, snapshot_name, "manifest.json")
-        if os.path.exists(legacy):
-            with open(legacy) as fh:
-                m = json.load(fh)
-            m["_legacy_dir"] = os.path.join(repo_path, snapshot_name)
-            return m
         raise IndexNotFoundError(f"no such snapshot [{snapshot_name}]")
 
     def restore(self, repo_path: str, snapshot_name: str,
@@ -918,18 +904,14 @@ class Node:
             if target in self.indices:
                 raise ResourceAlreadyExistsError(
                     f"cannot restore index [{target}]: already exists")
-            if "_legacy_dir" in manifest:
-                shutil.copytree(os.path.join(manifest["_legacy_dir"], name),
-                                os.path.join(self.data_path, target))
-            else:
-                prefix = name + os.sep
-                for rel, meta in manifest["files"].items():
-                    if not rel.startswith(prefix):
-                        continue
-                    dst = os.path.join(self.data_path, target,
-                                       rel[len(prefix):])
-                    os.makedirs(os.path.dirname(dst), exist_ok=True)
-                    shutil.copy2(os.path.join(blob_dir, meta["md5"]), dst)
+            prefix = name + os.sep
+            for rel, meta in manifest["files"].items():
+                if not rel.startswith(prefix):
+                    continue
+                dst = os.path.join(self.data_path, target,
+                                   rel[len(prefix):])
+                os.makedirs(os.path.dirname(dst), exist_ok=True)
+                shutil.copy2(os.path.join(blob_dir, meta["md5"]), dst)
             # translog/commit are part of the restored state; recover
             # normally
             meta_path = os.path.join(self.data_path, target, "index_meta.json")

@@ -11,8 +11,8 @@ one-in-a-million scheduling that turns the inversion into a deadlock.
 
 Activation:
     OPENSEARCH_TPU_LOCKWITNESS=1         wrap + record (report only)
-    OPENSEARCH_TPU_LOCKWITNESS_STRICT=1  also raise LockOrderInversion
-or programmatically `lockwitness.install(strict=...)` (tests).
+or programmatically `lockwitness.install(strict=...)` (tests; `strict`
+also raises LockOrderInversion).
 
 Mechanics: `install()` patches the `threading.Lock` / `threading.RLock`
 factories. The replacement walks the creating stack frame (skipping
@@ -287,13 +287,10 @@ def _factory(raw):
     return make
 
 
-def install(strict: Optional[bool] = None) -> _WitnessState:
+def install(strict: bool = False) -> _WitnessState:
     """Arm the witness and patch the threading lock factories.
     Idempotent; returns the active state (for tests)."""
     global _STATE, _installed
-    if strict is None:
-        strict = os.environ.get(
-            "OPENSEARCH_TPU_LOCKWITNESS_STRICT") == "1"
     if _STATE is not None and _STATE.armed:
         _STATE.strict = bool(strict)
         return _STATE

@@ -12,9 +12,8 @@ import numpy as np
 
 from ..obs import ingest_obs as _iobs
 from ..ops import device_merge
-from .segment import (CODEC_V2, GeoColumn, KeywordColumn, NumericColumn,
-                      PostingsBlock, Segment, TextFieldStats, VectorColumn,
-                      default_codec_version)
+from .segment import (GeoColumn, KeywordColumn, NumericColumn,
+                      PostingsBlock, Segment, TextFieldStats, VectorColumn)
 
 
 class TieredMergePolicy:
@@ -309,39 +308,37 @@ def merge_segments(name: str, segments: List[Segment]) -> Segment:
         merged.__dict__["_tie_rank"] = np.concatenate(parts) if parts \
             else np.zeros(0, np.int64)
         merged.__dict__["_reordered"] = True
-    # codec propagation: merges emit the PROCESS-DEFAULT codec — they are
-    # the natural rebuild point for the format rev (a v1+v2 merge
-    # upgrades the v1 half; under the OPENSEARCH_TPU_CODEC=1 rollback pin
-    # every merge demotes to v1, so the index converges back). Impacts
+    # codec propagation: merges emit codec v2 — they are the natural
+    # rebuild point for the format rev (a v1+v2 merge upgrades the v1
+    # half). Impacts
     # are REBUILT from the merged tf/doc-len planes (the merged field's
     # avgdl differs from every input's, so carried quantized values would
     # bake a stale norm); the O(P) quantize map itself runs on device
     # past the size threshold (ops/device_merge.quantize_impacts).
     _reorder_s = 0.0
     _reordered = False
-    if default_codec_version() >= CODEC_V2:
-        # feature planes (rank_features index_impacts opt-in) rebuild
-        # whenever ANY input carried one for the field — the opt-in
-        # travels with the data, so merges never need the mappings
-        ffields = {f for s in segments for f, pb in s.postings.items()
-                   if pb.impact is not None and pb.impact.kind == "feature"}
-        _q0 = time.perf_counter()
-        merged.build_impacts(feature_fields=ffields)
-        _iobs.note_stage("quantize", time.perf_counter() - _q0)
-        if "/" not in name:
-            # BP-style impact-clustered doc-id reordering (index/reorder.py):
-            # merges are the one point the whole doc set is in hand and the
-            # impact planes are fresh — nested CHILD merges (name carries a
-            # "/") skip, because the parent's apply_permutation re-sorts
-            # children against the permuted parent ids itself. The pass is
-            # deterministic, so copy holders re-running this merge stay
-            # byte-identical (PR-9 replication contract).
-            from .reorder import maybe_reorder
-            _r0 = time.perf_counter()
-            _pre = merged
-            merged = maybe_reorder(merged)
-            _reorder_s = time.perf_counter() - _r0
-            _reordered = merged is not _pre
+    # feature planes (rank_features index_impacts opt-in) rebuild
+    # whenever ANY input carried one for the field — the opt-in
+    # travels with the data, so merges never need the mappings
+    ffields = {f for s in segments for f, pb in s.postings.items()
+               if pb.impact is not None and pb.impact.kind == "feature"}
+    _q0 = time.perf_counter()
+    merged.build_impacts(feature_fields=ffields)
+    _iobs.note_stage("quantize", time.perf_counter() - _q0)
+    if "/" not in name:
+        # BP-style impact-clustered doc-id reordering (index/reorder.py):
+        # merges are the one point the whole doc set is in hand and the
+        # impact planes are fresh — nested CHILD merges (name carries a
+        # "/") skip, because the parent's apply_permutation re-sorts
+        # children against the permuted parent ids itself. The pass is
+        # deterministic, so copy holders re-running this merge stay
+        # byte-identical (PR-9 replication contract).
+        from .reorder import maybe_reorder
+        _r0 = time.perf_counter()
+        _pre = merged
+        merged = maybe_reorder(merged)
+        _reorder_s = time.perf_counter() - _r0
+        _reordered = merged is not _pre
     if _obs:
         # input counts pre-compaction (deleted docs included) so
         # input_docs - output_docs reads as "deletes reclaimed"

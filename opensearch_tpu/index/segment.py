@@ -71,10 +71,10 @@ IMPACT_B = 0.75           # drift is bounded by ImpactPlane.drift_bound
 
 
 def default_codec_version() -> int:
-    """Codec for NEW segments (refresh/merge). OPENSEARCH_TPU_CODEC=1
-    pins the legacy tf-only format (compat tests, rollback)."""
-    return CODEC_V1 if os.environ.get("OPENSEARCH_TPU_CODEC") == "1" \
-        else CODEC_V2
+    """Codec of NEW segments (refresh/merge): v2. v1 is what an older
+    commit on disk loads as and what `Segment.drop_impacts` demotes to;
+    nothing builds it. (The benchmark's corpus builders call this.)"""
+    return CODEC_V2
 
 
 def default_impact_bits() -> int:
@@ -263,12 +263,6 @@ def build_feature_impact_plane(pb: "PostingsBlock",
 # (segment, device) pytree build, released by a weakref finalizer when
 # the segment is GC'd (segments are immutable and replaced wholesale on
 # refresh/merge) or eagerly by `drop_device`.
-
-
-def set_breaker(breaker) -> None:
-    """Legacy wiring shim: the breaker now lives on the ledger."""
-    from ..obs.hbm_ledger import LEDGER
-    LEDGER.set_breaker(breaker)
 
 
 def _tree_nbytes(tree) -> int:
@@ -764,7 +758,7 @@ class Segment:
         """Whether some document holds two values of keyword `field` or
         more, which is whether the column holds more values than
         documents that have one: a pass over `min_ord`, once a segment
-        (`compiler.drop_segment_planes` forgets it with a rematerialized
+        (`planes.drop_segment_planes` forgets it with a rematerialized
         field), and no temporary the size of the row pointers (their
         differences are 133 MB of fresh pages at 2^24 rows: as slow as the
         two planes' padding the single-valued form saves). It decides the
@@ -1797,16 +1791,15 @@ def build_segment(name: str, parsed_docs: list, mappings: Mappings,
                   doc_lens, text_stats, ids, sources, seq_nos=seq,
                   vector_cols=vector_cols, nested=nested,
                   shape_cols=shape_cols, stored_vals=stored_vals)
-    if default_codec_version() >= CODEC_V2:
-        # codec v2: eager quantized impacts + block-max sidecar per
-        # text-scored field (nested children recurse in build_impacts),
-        # plus FEATURE planes for rank_features/sparse_vector fields
-        # whose mapping opted into index_impacts (learned-sparse on the
-        # impact ladder, docs/HYBRID.md)
-        _t_q = time.perf_counter()
-        seg.build_impacts(feature_fields=feature_impact_fields(
-            mappings, feat_fields))
-        note_stage("quantize", time.perf_counter() - _t_q)
+    # codec v2: eager quantized impacts + block-max sidecar per
+    # text-scored field (nested children recurse in build_impacts),
+    # plus FEATURE planes for rank_features/sparse_vector fields
+    # whose mapping opted into index_impacts (learned-sparse on the
+    # impact ladder, docs/HYBRID.md)
+    _t_q = time.perf_counter()
+    seg.build_impacts(feature_fields=feature_impact_fields(
+        mappings, feat_fields))
+    note_stage("quantize", time.perf_counter() - _t_q)
     # term_vector=with_positions_offsets fields: per-doc (term, pos, start,
     # end) for the FVH path (host-only, like _source)
     seg.term_vectors = term_vectors
@@ -2243,19 +2236,18 @@ class StreamingSegmentBuilder:
                           stored_vals=(self._stored if self._any_stored
                                        else None))
             note_stage("chunk_merge", time.perf_counter() - _t_merge)
-            if default_codec_version() >= CODEC_V2:
-                # no feature_fields here BY INVARIANT: docs carrying
-                # rank_features are not stream-eligible
-                # (`stream_eligible` rejects pd.features), so the
-                # refresh path routes them to `build_segment`, which
-                # derives the index_impacts opt-in from the mappings.
-                # If streaming ever learns feature postings, thread
-                # `feature_impact_fields(self.mappings, ...)` through
-                # here or big-buffer refreshes silently lose the plane
-                # (and merges of such segments lose the opt-in forever).
-                _t_q = time.perf_counter()
-                seg.build_impacts()
-                note_stage("quantize", time.perf_counter() - _t_q)
+            # no feature_fields here BY INVARIANT: docs carrying
+            # rank_features are not stream-eligible
+            # (`stream_eligible` rejects pd.features), so the
+            # refresh path routes them to `build_segment`, which
+            # derives the index_impacts opt-in from the mappings.
+            # If streaming ever learns feature postings, thread
+            # `feature_impact_fields(self.mappings, ...)` through
+            # here or big-buffer refreshes silently lose the plane
+            # (and merges of such segments lose the opt-in forever).
+            _t_q = time.perf_counter()
+            seg.build_impacts()
+            note_stage("quantize", time.perf_counter() - _t_q)
             seg.term_vectors = None
             return seg
         finally:

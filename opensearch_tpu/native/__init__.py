@@ -4,9 +4,6 @@ Builds `_opensearch_native.so` from the adjacent C++ source with g++ on first
 import (cached; rebuilt when the source is newer). Everything here has a
 pure-Python/numpy fallback at its call sites — if the toolchain or the build
 is unavailable, `available()` returns False and callers take the fallback.
-
-Set ``OPENSEARCH_TPU_NATIVE=0`` to force the fallback paths (used by parity
-tests).
 """
 
 from __future__ import annotations
@@ -50,8 +47,6 @@ def _load():
     if _tried:
         return _lib
     _tried = True
-    if os.environ.get("OPENSEARCH_TPU_NATIVE", "1") == "0":
-        return None
     try:
         if (not os.path.exists(_SO)
                 or os.path.getmtime(_SO) < os.path.getmtime(_SRC)):

@@ -47,7 +47,6 @@ from __future__ import annotations
 
 import contextvars
 import itertools
-import os
 import threading
 import time
 from collections import OrderedDict, deque
@@ -77,13 +76,6 @@ def reset_current(token) -> None:
     _current_tl.reset(token)
 
 
-def _truthy_env(name: str, default: bool) -> bool:
-    v = os.environ.get(name)
-    if v is None:
-        return default
-    return v not in ("", "0", "false", "no")
-
-
 def _jsonable(v):
     if isinstance(v, (str, int, float, bool)) or v is None:
         return v
@@ -107,22 +99,16 @@ class FlightRecorder:
     _COOLDOWN_REASONS = ("rejection_burst", "slowlog", "oracle_mismatch",
                          "retry_storm", "slo_burn", "refresh_stall")
 
-    def __init__(self, capacity: Optional[int] = None,
-                 max_dumps: Optional[int] = None,
-                 enabled: Optional[bool] = None,
+    def __init__(self, capacity: int = 4096, max_dumps: int = 16,
+                 enabled: bool = True,
                  max_dump_timelines: int = 32,
                  max_timeline_events: int = 512,
                  cooldown_s: float = 0.25,
                  burst_n: int = 8, burst_window_s: float = 1.0):
-        env = os.environ
-        self.capacity = int(capacity if capacity is not None
-                            else env.get("OPENSEARCH_TPU_FR_CAPACITY", 4096))
+        self.capacity = int(capacity)
         if self.capacity < 16:
             raise ValueError("flight recorder capacity must be >= 16")
-        self.max_dumps = int(max_dumps if max_dumps is not None
-                             else env.get("OPENSEARCH_TPU_FR_MAX_DUMPS", 16))
-        if enabled is None:
-            enabled = _truthy_env("OPENSEARCH_TPU_FLIGHT_RECORDER", True)
+        self.max_dumps = int(max_dumps)
         self.enabled = bool(enabled)
         self.max_dump_timelines = int(max_dump_timelines)
         self.max_timeline_events = int(max_timeline_events)

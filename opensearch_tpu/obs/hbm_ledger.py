@@ -51,7 +51,6 @@ journal as scheduler and ladder events.
 from __future__ import annotations
 
 import itertools
-import os
 import threading
 import weakref
 from typing import Any, Dict, List, Optional
@@ -60,6 +59,10 @@ from ..utils.metrics import METRICS
 from . import flight_recorder as _fr
 
 __all__ = ["Allocation", "HBMLedger", "LEDGER"]
+
+# ledger-vs-allocator drift `check_device` lets pass whatever the device
+# holds: XLA's scratch and program memory, which the ledger does not model
+_DRIFT_FLOOR_BYTES = 64 << 20
 
 # tenant kinds (docs/OBSERVABILITY.md "memory and cost"): free-form
 # strings are accepted, but the known kinds keep dashboards stable
@@ -73,9 +76,9 @@ KINDS = (
     "filtered_postings",    # filter-specialized aligned copies
     "filter_list",          # cached FilterList device doc lists
     "quality_tier",         # static-pruning view masks/doc lists
-    "nested_sort",          # compiler _nested_sort_values columns
+    "nested_sort",          # planes.nested_sort_values columns
     "sort_rank_plane",      # compiler prepare_sort: i32 ranks per (segment, field)
-    "agg_bucket_plane",     # compiler _date_bucket_plane: i32 bucket ids
+    "agg_bucket_plane",     # planes.date_bucket_plane: i32 bucket ids
     "phrase_pairs",         # resident phrase (doc, pos) pair arrays
     "mesh_postings",        # SPMD stacked per-shard postings/pairs
     "mesh_columns",         # SPMD stacked agg columns/ordinals/masks
@@ -500,9 +503,7 @@ class HBMLedger:
         in_use = int(stats["bytes_in_use"])
         ledger = self.total_bytes()
         drift = abs(in_use - ledger)
-        floor = int(os.environ.get("OPENSEARCH_TPU_HBM_DRIFT_FLOOR",
-                                   64 << 20))
-        limit = max(int(in_use * threshold), floor)
+        limit = max(int(in_use * threshold), _DRIFT_FLOOR_BYTES)
         out = {"device": str(device), "bytes_in_use": in_use,
                "ledger_bytes": ledger, "drift_bytes": drift,
                "drift_limit": limit, "ok": drift <= limit}

@@ -6,8 +6,7 @@ attribution, segment merge + BP reorder, translog, replica write-through
 — records into the ONE process registry (`utils/metrics.METRICS`) under
 the `indexing.` prefix. This module owns the pieces they share:
 
-- the enable flag (`enabled()` / `set_enabled()`, env
-  `OPENSEARCH_TPU_INGEST_OBS`);
+- the enable flag (`enabled()` / `set_enabled()`);
 - the build-stage collector (`stage_scope()` / `note_stage()`): a
   thread-local dict the segment builders and the merge drop wall-time
   attributions into (pack / spill / chunk_merge / quantize /
@@ -60,7 +59,7 @@ PREFIX = "indexing."
 DEFAULT_REFRESH_STALL_MS = 5_000.0
 
 _enabled_lock = threading.Lock()
-_enabled = os.environ.get("OPENSEARCH_TPU_INGEST_OBS", "1") != "0"
+_enabled = True
 
 
 def enabled() -> bool:

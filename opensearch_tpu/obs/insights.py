@@ -41,7 +41,7 @@ Design constraints:
 - **The hot path is one lock + O(1) dict ops.** Recording at the
   `Node.search` boundary takes the sketch lock for a dict upsert;
   eviction's O(capacity) min-scan only runs when a NEW shape arrives
-  at a full sketch. Disabled (`OPENSEARCH_TPU_INSIGHTS=0`) the
+  at a full sketch. Disabled (`enabled = False`) the
   per-search cost is one attribute read (the flight-recorder
   discipline; tests pin the guard).
 
@@ -58,7 +58,6 @@ from __future__ import annotations
 
 import contextvars
 import hashlib
-import os
 import threading
 import time
 from collections import deque
@@ -671,19 +670,10 @@ class QueryInsights:
     """Process-singleton insights engine: the sketch, the bounded
     recent-activity ring (windowed queries), and the read surfaces."""
 
-    def __init__(self, capacity: Optional[int] = None,
-                 window_capacity: Optional[int] = None,
-                 enabled: Optional[bool] = None):
-        env = os.environ
-        self.capacity = int(
-            capacity if capacity is not None
-            else env.get("OPENSEARCH_TPU_INSIGHTS_CAPACITY", 256))
-        self.window_capacity = int(
-            window_capacity if window_capacity is not None
-            else env.get("OPENSEARCH_TPU_INSIGHTS_WINDOW_CAP", 4096))
-        if enabled is None:
-            v = env.get("OPENSEARCH_TPU_INSIGHTS")
-            enabled = v not in ("0", "false", "no")
+    def __init__(self, capacity: int = 256, window_capacity: int = 4096,
+                 enabled: bool = True):
+        self.capacity = int(capacity)
+        self.window_capacity = int(window_capacity)
         self.enabled = bool(enabled)
         self.sketch = SpaceSavingSketch(self.capacity)
         # recent activity: (t_mono, key, latency_ms, bytes) — bounded

@@ -30,13 +30,11 @@ Threading: one daemon thread per sampler, parked on an `Event.wait`
 METRICS/RECORDER/LEDGER — one node per process is the deployment
 reality; co-resident test nodes share the ring exactly like they share
 `/_metrics`. The thread does NOT auto-start: tests drive `sample_once()`
-deterministically, servers and benches call `ensure_started()` (or set
-`OPENSEARCH_TPU_TS=1`, which `cluster/node.py` honors at Node init).
+deterministically, servers and benches call `ensure_started()`.
 """
 
 from __future__ import annotations
 
-import os
 import threading
 import time
 from collections import deque
@@ -69,17 +67,12 @@ class TimeSeriesSampler:
     delta/rate derivation on read."""
 
     def __init__(self, registry: Optional[MetricsRegistry] = None,
-                 interval_s: Optional[float] = None,
-                 capacity: Optional[int] = None):
-        env = os.environ
+                 interval_s: float = 1.0, capacity: int = 512):
         self.registry = registry if registry is not None else METRICS
-        self.interval_s = float(
-            interval_s if interval_s is not None
-            else env.get("OPENSEARCH_TPU_TS_INTERVAL_S", 1.0))
+        self.interval_s = float(interval_s)
         if self.interval_s <= 0:
             raise ValueError("sampler interval must be > 0")
-        self.capacity = int(capacity if capacity is not None
-                            else env.get("OPENSEARCH_TPU_TS_CAPACITY", 512))
+        self.capacity = int(capacity)
         if self.capacity < 2:
             raise ValueError("sampler capacity must be >= 2 (rates need "
                              "two points)")

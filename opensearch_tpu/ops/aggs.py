@@ -29,7 +29,7 @@ which is a `bucket_counts`.
 `run_counts` serves a plane whose ids are non-decreasing in row order (a
 date histogram over an append-only log segment): each bucket is one run of
 rows, so a count is a difference of two prefix sums of the weights, read at
-the runs' boundaries. `search/compiler._date_bucket_plane` observes the
+the runs' boundaries. `search/planes.date_bucket_plane` observes the
 order once a plane and `prepare_agg` selects that form.
 
 A group-by over a keyword column (`terms_counts` and through it a keyword
@@ -66,7 +66,7 @@ def counts_by_value(kw: dict) -> bool:
     counts the column's flat values, the match gathered to them through
     `doc_of_value`, and not `min_ord` by document under the mask: the one
     predicate `terms_counts`, `terms_sub_metric` and `value_count_keyword`
-    choose by, and `compiler._agg_cost` counts by (`group_by_rows`)."""
+    choose by, and `programs.agg_cost` counts by (`group_by_rows`)."""
     return "doc_of_value" in kw
 
 
@@ -113,7 +113,7 @@ def count_form(nbuckets: int) -> str:
     "product" (a bucket count as two one-hots multiplied on the matrix
     unit; the sums and the extremes scatter there) or "scatter": the one
     predicate `bucket_counts`, `bucket_sums_exact` and
-    `bucketed_sub_metric` choose by, and `compiler._agg_cost` counts by."""
+    `bucketed_sub_metric` choose by, and `programs.agg_cost` counts by."""
     if nbuckets < _DENSE_BUCKETS:
         return "dense"
     return "product" if nbuckets < _PRODUCT_BUCKETS else "scatter"

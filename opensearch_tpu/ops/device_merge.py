@@ -65,9 +65,6 @@ def merge_sorted_runs(rows: np.ndarray, docs: np.ndarray, tfs: np.ndarray,
 
 
 def use_device_merge(total_postings: int) -> bool:
-    import os
-    if os.environ.get("OPENSEARCH_TPU_NO_DEVICE_MERGE"):
-        return False
     return total_postings >= DEVICE_MERGE_MIN
 
 
@@ -117,7 +114,4 @@ def quantize_impacts(tfs: np.ndarray, dl_of: np.ndarray, k1: float,
 
 
 def use_device_impacts(total_postings: int) -> bool:
-    import os
-    if os.environ.get("OPENSEARCH_TPU_NO_DEVICE_MERGE"):
-        return False
     return total_postings >= DEVICE_IMPACT_MIN

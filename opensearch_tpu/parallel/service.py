@@ -24,8 +24,8 @@ import numpy as np
 
 from ..index.segment import next_pow2
 from ..obs import flight_recorder as _fr
-from ..search.compiler import (coerce_agg_ranges, grid_agg_precision,
-                               hist_agg_interval, range_agg_spec)
+from ..search.agg_compiler import (coerce_agg_ranges, grid_agg_precision,
+                                   hist_agg_interval, range_agg_spec)
 from ..utils.metrics import METRICS
 from ..utils.trace import TRACER
 from .spmd import (INT32_SENTINEL, StackedPhrasePairs, StackedShardIndex,
@@ -386,13 +386,13 @@ class MeshSearchService:
         import jax
         from jax.sharding import NamedSharding, PartitionSpec as P
 
-        from ..search.compiler import _geo_grid_cache
+        from ..search.planes import geo_grid_cache
 
         key = ("grid", name, field, kind, precision)
         cached = self._stacked_cols.get(key)
         if cached is not None and cached[0] == svc.generation:
             return cached[1]
-        per_seg = [[_geo_grid_cache(seg, field, kind, precision)
+        per_seg = [[geo_grid_cache(seg, field, kind, precision)
                     for seg in segs] for segs in shard_segs]
         return self._stack_global_ords(key, svc, per_seg, shard_segs,
                                        d_pad, mesh)
@@ -435,7 +435,7 @@ class MeshSearchService:
         (-1 = doc missing any source) + the key-tuple vocab union — the
         per-segment combined ords from the host cache remapped into one
         index-wide ordinal space. Cached per generation."""
-        from ..search.compiler import _multi_terms_cache
+        from ..search.planes import multi_terms_cache
 
         key = ("mterms", name, fields)
         cached = self._stacked_cols.get(key)
@@ -446,8 +446,8 @@ class MeshSearchService:
             row = []
             for seg in segs:
                 try:
-                    row.append(_multi_terms_cache(seg, stats[si], an,
-                                                  fields))
+                    row.append(multi_terms_cache(seg, stats[si], an,
+                                                 fields))
                 except Exception:
                     self._stacked_cols.put(key, (svc.generation, None), 0)
                     return None
@@ -500,7 +500,7 @@ class MeshSearchService:
         masks (same machinery as the query-level guardrail filters).
         Returns False when any clause can't be masked (caller falls back);
         resolved (key, combo, masks) lists ride on the AggNode."""
-        from ..search import compiler as C
+        from ..search import agg_compiler as AC, plan as PL
         from ..search import query_dsl as dsl
 
         for an in (agg_nodes or []):
@@ -515,12 +515,12 @@ class MeshSearchService:
             elif an.kind == "missing":
                 items = [("_f", {"exists": {"field": an.body["field"]}})]
             else:
-                items = C.filters_agg_items(an.body)
+                items = AC.filters_agg_items(an.body)
             nodes = []
             for fname, f in items:
                 try:
-                    lnode = C.rewrite(dsl.parse_query(f), stats[0],
-                                      scoring=False)
+                    lnode = PL.rewrite(dsl.parse_query(f), stats[0],
+                                       scoring=False)
                 except dsl.QueryParseError:
                     return False
                 if not self._maskable(lnode):
@@ -570,7 +570,7 @@ class MeshSearchService:
         every shard (segments WITHOUT the column still contribute their
         live docs — reference supersetSize semantics). Cached per
         generation; the host path computes the same per segment."""
-        from ..search.compiler import _kw_doc_counts
+        from ..search.planes import kw_doc_counts
 
         key = ("sigbg", name, field)
         cached = self._stacked_cols.get(key)
@@ -582,7 +582,7 @@ class MeshSearchService:
             for seg in segs:
                 bg_total += seg.live_count
                 if field in seg.keyword_cols:
-                    for k, c in _kw_doc_counts(seg, field).items():
+                    for k, c in kw_doc_counts(seg, field).items():
                         bg[k] = bg.get(k, 0) + c
         out = (bg, bg_total)
         self._stacked_cols.put(key, (svc.generation, out),
@@ -917,7 +917,7 @@ class MeshSearchService:
         coordinator-side result assembly and returns the per-body
         response list (None entries -> host loop)."""
         from ..search.launch import LaunchHandle
-        from ..search import compiler as C
+        from ..search import plan as PL
         from ..search import query_dsl as dsl
         from ..search.executor import (_global_stats_contexts,
                                        _norm_sort_specs, parse_aggs,
@@ -955,7 +955,7 @@ class MeshSearchService:
                 # attributed shape, never the flat query_shape bucket
                 self._fall("query_hybrid")
                 continue
-            lroot = C.rewrite(query, ctx, scoring=True)
+            lroot = PL.rewrite(query, ctx, scoring=True)
             sort_specs = _norm_sort_specs(body)
             agg_nodes = parse_aggs(body.get("aggs",
                                             body.get("aggregations")))
@@ -1004,7 +1004,7 @@ class MeshSearchService:
                      if sim is not None and lt.has_norms else 0.0)
             k_class = min(next_pow2(max(window, 16)), MAX_WINDOW)
             fkey = fpair[0] if fpair is not None else None
-            is_phrase = isinstance(lt, C.LPhrase)
+            is_phrase = isinstance(lt, PL.LPhrase)
             nt_key = len(lt.terms) if is_phrase else 0
             groups.setdefault((is_phrase, nt_key, lt.field, k1, b_eff,
                                k_class, fkey), []).append(item)
@@ -1344,7 +1344,7 @@ class MeshSearchService:
                 # vocab hashes cached per generation (the O(vocab) python
                 # crc32 loop must not run per request), byte-bounded like
                 # every other per-(index, field) cache here
-                from ..search.compiler import crc32_vocab_hashes
+                from ..search.planes import crc32_vocab_hashes
                 hkey = (name, f)
                 hcached = self._card_hashes.get(hkey)
                 if hcached is not None and hcached[0] == svc.generation:
@@ -1709,7 +1709,7 @@ class MeshSearchService:
                             "registers": card_results[an.body["field"]][bi]}]
                         continue
                     if an.kind == "percentiles":
-                        from ..search.compiler import DEFAULT_PERCENTS
+                        from ..search.agg_compiler import DEFAULT_PERCENTS
                         percents = list(an.body.get("percents",
                                                     DEFAULT_PERCENTS))
                         results[0].agg_partials[an.name] = [{
@@ -1971,7 +1971,7 @@ class MeshSearchService:
         filter/must_not clauses, plain relevance order, metric or keyword
         `terms` aggregations. Returns (lt, filter_nodes, must_not_nodes,
         bool_boost) or None (-> host loop)."""
-        from ..search import compiler as C
+        from ..search import plan as PL
         from ..search.fastpath import MAX_T
         from ..ops import scoring as ops
 
@@ -2156,7 +2156,7 @@ class MeshSearchService:
         qboost = 1.0
         msm_eff = None           # None -> use the term group's own msm
         lt = lroot
-        if isinstance(lroot, C.LBool):
+        if isinstance(lroot, PL.LBool):
             if lroot.shoulds:
                 if lroot.musts or len(lroot.shoulds) != 1 or lroot.msm > 1:
                     return None
@@ -2178,7 +2178,7 @@ class MeshSearchService:
             qboost = float(lroot.boost or 1.0)
             if not all(self._maskable(n) for n in fnodes + notnodes):
                 return None
-        if isinstance(lt, C.LPhrase):
+        if isinstance(lt, PL.LPhrase):
             # plain/filtered match_phrase on the mesh: the positional
             # pair-join program (spmd.build_distributed_phrase). Span
             # family (ordered/gap_cost), prefix expansion, and agg
@@ -2193,7 +2193,7 @@ class MeshSearchService:
             if not 2 <= len(lt.terms) <= MAX_PHRASE_T:
                 return None
             return (lt, fnodes, notnodes, qboost, msm_eff)
-        if not isinstance(lt, C.LTerms):
+        if not isinstance(lt, PL.LTerms):
             return None
         if lt.mode not in ("score", "filter"):
             return None
@@ -2213,16 +2213,16 @@ class MeshSearchService:
         """Filter-context clauses the mesh serves via cached dense masks
         (compiler filter-mask cache) — the common guardrail kinds. Unknown
         kinds decline to the host loop, never guess."""
-        from ..search import compiler as C
+        from ..search import plan as PL
 
-        if isinstance(node, (C.LRange, C.LExists, C.LMatchAll,
-                             C.LMatchNone, C.LIds, C.LExpandTerms)):
+        if isinstance(node, (PL.LRange, PL.LExists, PL.LMatchAll,
+                             PL.LMatchNone, PL.LIds, PL.LExpandTerms)):
             return True
-        if isinstance(node, C.LTerms):
+        if isinstance(node, PL.LTerms):
             return True
-        if isinstance(node, C.LConstScore):
+        if isinstance(node, PL.LConstScore):
             return self._maskable(node.child)
-        if isinstance(node, C.LBool):
+        if isinstance(node, PL.LBool):
             return all(self._maskable(c) for c in
                        node.musts + node.shoulds + node.must_nots
                        + node.filters)

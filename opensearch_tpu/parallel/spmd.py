@@ -498,7 +498,7 @@ def build_distributed_bincount(mesh: Mesh, bucket: int, ndocs_pad: int,
     each query's match mask shard-locally, scatter-add it over a
     host-precomputed per-doc bin-id array (global bin space; -1 = no value
     or out of range), and psum the counts — the distributed analog of the
-    host 'hist' kernel (`search/compiler.py` emit_agg "hist") + the
+    host 'hist' kernel (`search/agg_compiler.py` emit_agg "hist") + the
     coordinator reduce. Returns a callable:
         (tree, rows [S,QB,T], boosts [QB,T], msm [QB], cscore [QB],
          bins i32[S, D_pad] [, fmask]) -> i32[QB, nb] global counts."""
@@ -707,7 +707,7 @@ def build_distributed_cardinality(mesh: Mesh, bucket: int, ndocs_pad: int,
     from ..ops import aggs as agg_ops
     # the ONE precision constant: mesh registers must stay the same
     # shape/precision as the host's or the max-merge silently drifts
-    from ..search.compiler import HLL_LOG2M as log2m
+    from ..search.agg_compiler import HLL_LOG2M as log2m
 
     def per_device(tree, rows, boosts, msm, cscore, *rest):
         fmask = rest[-1] if filtered else None

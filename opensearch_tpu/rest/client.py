@@ -20,7 +20,7 @@ from ..cluster.state import IndexNotFoundError
 from ..index.engine import VersionConflictError
 from ..ingest.pipeline import DropDocument
 from ..search.executor import ShardSearcher, explain_doc, search_shards
-from ..search import compiler as C
+from ..search import compiler as C, plan as PL
 from ..search import fastpath as _fastpath
 from ..search import query_dsl as dsl
 from ..search.pipeline import SearchPipelineException
@@ -876,9 +876,9 @@ class RestClient:
         for n in names:
             svc = self.node.indices[n]
             segs = [s for sh in svc.shards for s in sh.segments]
-            ctx = C.ShardContext(svc.mappings, segs, svc.default_sim)
+            ctx = PL.ShardContext(svc.mappings, segs, svc.default_sim)
             try:
-                detail = C.describe_plan(C.rewrite(q, ctx, scoring=True))
+                detail = C.describe_plan(PL.rewrite(q, ctx, scoring=True))
                 explanations.append({
                     "index": n, "valid": True,
                     "explanation":
@@ -1202,8 +1202,7 @@ class RestClient:
         rec = self.node.flight_recorder
         if not rec.enabled:
             raise ApiError(400, "illegal_argument_exception",
-                           "flight recorder is disabled on this node "
-                           "(OPENSEARCH_TPU_FLIGHT_RECORDER=0)")
+                           "flight recorder is disabled on this node")
         bundle = rec.trigger("manual", None, note=note, force=True)
         return {"acknowledged": True, "dump": bundle}
 
@@ -1425,10 +1424,10 @@ class RestClient:
         if loc is None or loc.in_buffer:
             raise ApiError(404, "document_missing_exception", f"[{id}] missing")
         seg, doc = loc.segment, loc.local_doc
-        ctx = C.ShardContext(svc.mappings, eng.segments, svc.default_sim)
+        ctx = PL.ShardContext(svc.mappings, eng.segments, svc.default_sim)
         qdict = (self._resolve_percolate_refs(body["query"])
                  if body.get("query") is not None else None)
-        lroot = C.rewrite(dsl.parse_query(qdict), ctx, scoring=True)
+        lroot = PL.rewrite(dsl.parse_query(qdict), ctx, scoring=True)
         expl = explain_doc(lroot, seg, doc, ctx)
         return {"_index": svc.meta.name, "_id": id,
                 "matched": expl["value"] > 0, "explanation": expl}

@@ -2,7 +2,7 @@
 Analog of reference `search/aggregations/` (AggregatorFactories parse tree,
 InternalAggregation#reduce, and the response XContent shapes).
 
-Device emission lives in `compiler.py` (same jitted program as scoring);
+Device emission lives in `agg_compiler.py` (same jitted program as scoring);
 this module is host-only: it defines the agg tree, merges per-segment
 partials (the analog of InternalAggregation.reduce), and renders the
 OpenSearch-shaped response JSON.
@@ -34,6 +34,49 @@ from dataclasses import dataclass, field as dc_field
 from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
+
+from ..utils.metrics import METRICS, CounterGroup
+from .planes import (AUTO_ROUNDINGS, auto_inner_for, auto_unit_ids,
+                     auto_unit_start_ms, calendar_bucket_ids,
+                     calendar_bucket_start_ms)
+
+# what the aggregations of the launches cost, counted at each launch from
+# the static spec (`programs._count_launch`): `scatter.updates` the rows
+# handed to every scatter (a bucket count by `ops.aggs.bucket_counts`, each
+# scatter of a bucketed sub-metric: count, minimum, maximum and a limb a
+# sum), which is what `ops.aggs.count_form` names "scatter" (and, for all
+# of a sub-metric but its count, "product"); `blocked.rows` the rows a
+# form that replaces a scatter reads, rows x passes over them
+# (`ops.aggs.run_counts`; the dense form: one pass a bucket count, one
+# for all of a sub-metric's accumulators; the product form: one pass a
+# count); `bucketed_sub.launches` / `.buckets` the
+# launches that carry a metric under a bucket aggregation, and their
+# buckets; `auto_date.requests` the top-level auto_date_histograms a
+# segment was asked, `auto_date.refine_launches` the launches taken first
+# to learn their matched range (`auto_date_range`); `terms.ordinals` the
+# vocabulary or combination slots the launches' `terms`, `multi_terms` and
+# `composite` group-bys counted into, `composite.combinations` those of the
+# composites alone (the combinations that occur in the segment, not the
+# product of the sources' value spaces), and `terms.records` the bucket
+# records the host then built from such counts (`executor` for a partial
+# that is records, `aggregations.finalize` for one that stays arrays: the
+# buckets a response returns, not the vocabulary); `terms.gathered_rows`
+# the flat values to which a keyword group-by (`terms`, `significant_terms`,
+# a multi-valued `composite` source, a keyword `cardinality` or
+# `value_count`) gathered the match through `doc_of_value`, one element a
+# value: those of the columns laid out by value
+# (`ops.aggs.counts_by_value`), 0 for a column in which no document holds
+# two values, which is counted by document
+AGG_STATS = CounterGroup(METRICS, "aggs", {"scatter.updates": 0,
+                                           "blocked.rows": 0,
+                                           "bucketed_sub.launches": 0,
+                                           "bucketed_sub.buckets": 0,
+                                           "auto_date.requests": 0,
+                                           "auto_date.refine_launches": 0,
+                                           "terms.ordinals": 0,
+                                           "terms.records": 0,
+                                           "terms.gathered_rows": 0,
+                                           "composite.combinations": 0})
 
 BUCKET_KINDS = {"terms", "histogram", "date_histogram", "range", "date_range",
                 "geo_distance",
@@ -73,7 +116,7 @@ _STAT_ADD = ("count", "sum", "sumsq")
 class OrdinalBuckets:
     """A `terms` or `multi_terms` partial's buckets as arrays: `counts`
     int[n] by ordinal, `keys` the sequence that names an ordinal's key
-    (a segment's sorted vocabulary, a `compiler.ComboSpace`: ordinal order
+    (a segment's sorted vocabulary, a `planes.ComboSpace`: ordinal order
     is key order), `subs` {sub-aggregation name: {"count", "sum", "min",
     "max", "sumsq": float[n]}} for the metric sub-aggregations the launch
     carried. `items()` spells the non-empty buckets as the records the
@@ -90,7 +133,6 @@ class OrdinalBuckets:
                 for name, cols in self.subs.items()}
 
     def items(self):
-        from .compiler import AGG_STATS
         held = np.flatnonzero(self.counts > 0)
         AGG_STATS.inc("terms.records", len(held))
         out = []
@@ -167,7 +209,7 @@ def parse_aggs(aggs: Optional[dict]) -> List[AggNode]:
 def merge_partials(node: AggNode, partials: List[dict]) -> dict:
     """Merge per-segment/per-shard partials for one agg node (reference:
     InternalAggregation#reduce). Each partial is a host dict produced by the
-    compiler's device run + segment context."""
+    compiled program's device run + segment context."""
     parts = [p for p in partials if p is not None]
     if not parts:
         return {}
@@ -301,8 +343,7 @@ def _with_empty_buckets(held: dict, calendar: Optional[str]) -> dict:
             return held
         return {b: held.get(b, empty)
                 for b in range(min(held), max(held) + 1)}
-    from .compiler import _calendar_bucket_ids, calendar_bucket_start_ms
-    lo, hi = (int(x) for x in _calendar_bucket_ids(
+    lo, hi = (int(x) for x in calendar_bucket_ids(
         [min(held), max(held)], calendar))
     if hi - lo >= _MAX_FILLED_BUCKETS:
         return held
@@ -317,13 +358,12 @@ def _auto_accumulate(node: AggNode, parts: List[Tuple[dict, int]],
     """auto_date_histogram buckets (keyed by their start in epoch ms under
     each part's own rounding) brought to rounding `unit` and accumulated:
     `parts` is [(buckets, their unit)]."""
-    from . import compiler as C
     acc: Dict[Any, dict] = {}
     for buckets, from_unit in parts:
         for key, rec in buckets.items():
             if from_unit != unit:
-                key = C.auto_unit_start_ms(
-                    int(C.auto_unit_ids(key, unit)), unit)
+                key = auto_unit_start_ms(
+                    int(auto_unit_ids(key, unit)), unit)
             slot = acc.setdefault(key, {"doc_count": 0, "subs": []})
             slot["doc_count"] += rec["doc_count"]
             slot["subs"].append(rec.get("subs"))
@@ -353,7 +393,6 @@ def _finalize_ordinal(node: AggNode, held: OrdinalBuckets,
     order, so a tie in the count breaks by key as the reference's does),
     and only they become records; `sum_other_doc_count` is the exact
     rest."""
-    from .compiler import AGG_STATS
     terms = node.kind == "terms"
     size = int(node.body.get("size", 10))
     okey, odir = "_count", "desc"
@@ -663,16 +702,15 @@ def finalize(node: AggNode, merged: dict, pipelines: bool = True) -> dict:
         # greatest non-empty one number at most `buckets`; buckets of an
         # inner interval over 1 are merged from the least one on, empty
         # buckets between are part of the answer
-        from . import compiler as C
         target = max(int(node.body.get("buckets", 10)), 1)
         unit = merged.get("unit", 0)
         buckets = {k: v for k, v in merged.get("buckets", {}).items()
                    if v["doc_count"] > 0}
         out_buckets, inner = [], 1
         while buckets:
-            ids = {int(C.auto_unit_ids(k, unit)): k for k in buckets}
+            ids = {int(auto_unit_ids(k, unit)): k for k in buckets}
             lo, hi = min(ids), max(ids)
-            inner = C.auto_inner_for(hi - lo + 1, unit, target)
+            inner = auto_inner_for(hi - lo + 1, unit, target)
             if inner is not None:
                 break
             unit += 1
@@ -683,7 +721,7 @@ def finalize(node: AggNode, merged: dict, pipelines: bool = True) -> dict:
                 groups.setdefault((i - lo) // inner, []).append(buckets[k])
             for g in range((hi - lo) // inner + 1):
                 recs = groups.get(g, [])
-                key = C.auto_unit_start_ms(lo + g * inner, unit)
+                key = auto_unit_start_ms(lo + g * inner, unit)
                 entry = {"key": key, "key_as_string": _format_epoch_ms(key),
                          "doc_count": int(sum(r["doc_count"] for r in recs))}
                 subs = _merge_sub_metrics(node.subs,
@@ -692,7 +730,7 @@ def finalize(node: AggNode, merged: dict, pipelines: bool = True) -> dict:
                     entry[sub.name] = finalize(sub, subs.get(sub.name, {}),
                                                pipelines)
                 out_buckets.append(entry)
-        interval = f"{inner}{C.AUTO_ROUNDINGS[unit][0]}"
+        interval = f"{inner}{AUTO_ROUNDINGS[unit][0]}"
         result = {"buckets": out_buckets, "interval": interval}
         _apply_bucket_pipelines(node, result, "all" if pipelines else "early")
         return result

@@ -149,7 +149,7 @@ def _purge_query_caches(seg, names: List[str]) -> None:
     its old column: the device pytree, per-field device arrays, cached
     filter masks and fastpath filter lists/aligned layouts, sort ordinals,
     and date buckets."""
-    from . import compiler as C
+    from . import compiler as C, planes as PN
     from . import fastpath as FP
 
     # SWAP, don't clear in place: Segment.device_arrays readers hold a
@@ -168,12 +168,12 @@ def _purge_query_caches(seg, names: List[str]) -> None:
             LEDGER.release(alloc)
     for alloc in seg.__dict__.pop("_field_device_allocs", {}).values():
         LEDGER.release(alloc)
-    C._purge_masks_for_uid(seg.uid)
+    C.purge_masks_for_uid(seg.uid)
     FP._purge_filtered_for_uid(seg.uid)
     seg.__dict__.get("_fastpath_filters", {}).clear()
     for name in names:
         seg.__dict__.get("_fastpath_aligned", {}).pop(name, None)
-        C.drop_segment_planes(seg, name)    # sort ranks, date buckets
+        PN.drop_segment_planes(seg, name)    # sort ranks, date buckets
         c = seg.__dict__.get("_nested_sort_cache")
         if c:
             for k in [k for k in c if k[0] == name]:

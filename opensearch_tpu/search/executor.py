@@ -19,19 +19,25 @@ from typing import Any, Dict, List, Optional, Tuple
 import numpy as np
 
 from ..index.engine import Engine
-from ..index.segment import Segment, next_pow2
+from ..index.segment import CODEC_V1, CODEC_V2, Segment, next_pow2
 from ..obs import flight_recorder as _flight
 from ..obs import query_cost as _qcost
+from ..ops import aggs as agg_ops
 from ..script.painless_lite import ScriptError as _ScriptError
 from ..utils import deadline as _dl
 from ..utils.trace import TRACER
+from . import agg_compiler as AC
 from . import compiler as C
+from . import plan as PL
+from . import planes as PN
+from . import programs as PG
 from . import fastpath
 from . import impactpath
 from . import query_dsl as dsl
-from .aggregations import (AggNode, OrdinalBuckets, _apply_bucket_pipelines,
-                           apply_pipelines_tree, composite_sources, finalize,
-                           merge_partials, parse_aggs)
+from .aggregations import (AGG_STATS, AggNode, OrdinalBuckets,
+                           _apply_bucket_pipelines, apply_pipelines_tree,
+                           composite_sources, finalize, merge_partials,
+                           parse_aggs)
 from .highlight import (collect_query_terms, highlight_field,
                         highlight_fvh, highlight_unified)
 
@@ -158,20 +164,20 @@ def _cost_predicted(lroot, seg, window: int) -> None:
         if node is None:
             continue
         terms = None
-        if isinstance(node, (C.LTerms, C.LPhrase, C.LSourcePhrase)):
+        if isinstance(node, (PL.LTerms, PL.LPhrase, PL.LSourcePhrase)):
             terms = node.terms
-        elif isinstance(node, C.LSparseDot):
+        elif isinstance(node, PL.LSparseDot):
             terms = node.tokens
         if terms:
             pb = seg.postings.get(node.field)
             if pb is not None:
                 df = sum(pb.doc_freq(t) for t in terms)
                 npost += df
-                v2 = (getattr(seg, "codec_version", C.CODEC_V1)
-                      >= C.CODEC_V2 and pb.impact is not None)
-                if v2 and ((isinstance(node, C.LTerms)
+                v2 = (getattr(seg, "codec_version", CODEC_V1)
+                      >= CODEC_V2 and pb.impact is not None)
+                if v2 and ((isinstance(node, PL.LTerms)
                             and node.mode == "score")
-                           or (isinstance(node, C.LSparseDot)
+                           or (isinstance(node, PL.LSparseDot)
                                and pb.impact.kind == "feature")):
                     # codec v2: the eager plane replaces the f32 tf slot
                     # with a u8/u16 impact — predict the SMALLER volume
@@ -238,15 +244,15 @@ class ShardSearcher:
         self.device = device
         self.replica = None
 
-    def context(self) -> C.ShardContext:
-        return C.ShardContext(self.engine.mappings, self.engine.segments,
-                              self.similarity, self.field_similarities)
+    def context(self) -> PL.ShardContext:
+        return PL.ShardContext(self.engine.mappings, self.engine.segments,
+                               self.similarity, self.field_similarities)
 
     # ---------------- QUERY phase ----------------
 
     def query_phase(self, body: dict, segments: Optional[List[Segment]] = None,
                     shard_ord: Optional[int] = None,
-                    stats_ctx: Optional[C.ShardContext] = None,
+                    stats_ctx: Optional[PL.ShardContext] = None,
                     task=None) -> ShardQueryResult:
         """`shard_ord` overrides the candidate shard tag so a coordinator can
         search shards of several indices in one pass without id collisions.
@@ -260,8 +266,8 @@ class ShardSearcher:
             segments = (list(self.replica.segments) if self.replica is not None
                         else list(self.engine.segments))
         with TRACER.span("search.plan"):
-            ctx = stats_ctx or C.ShardContext(self.engine.mappings, segments,
-                                              self.similarity, self.field_similarities)
+            ctx = stats_ctx or PL.ShardContext(self.engine.mappings, segments,
+                                               self.similarity, self.field_similarities)
             # derived (runtime) fields: mapping-level + search-body defs
             # materialize into per-segment columns before rewrite sees them
             ddefs = dict(getattr(ctx.mappings, "derived", {}) or {})
@@ -287,7 +293,7 @@ class ShardSearcher:
                     except (ScriptError, ValueError) as e:
                         raise dsl.QueryParseError(f"derived field: {e}")
             query = compose_knn_query(body)
-            lroot = C.rewrite(query, ctx, scoring=True)
+            lroot = PL.rewrite(query, ctx, scoring=True)
             ctx._current_lroot = lroot  # children/parent aggs join against it
 
             size = int(body.get("size", 10))
@@ -447,7 +453,7 @@ class ShardSearcher:
                         for i, an in enumerate(agg_nodes):
                             if an.kind == "top_hits":
                                 continue  # from this segment's top-k below
-                            agg_specs.append((an.name, C.prepare_agg(
+                            agg_specs.append((an.name, AC.prepare_agg(
                                 an, seg, ctx, params, f"a{i}",
                                 auto_range=auto_ranges.get(an.name))))
                 named_specs = []
@@ -465,11 +471,11 @@ class ShardSearcher:
                 cspec = C.prepare_collapse(collapse, seg, ctx, params)
             while True:
                 try:
-                    out = C.run_segment(qspec, sspec, agg_specs,
-                                        named_specs, k_pad,
-                                        seg.device_arrays(self.device),
-                                        params, has_after,
-                                        collapse_spec=cspec)
+                    out = PG.run_segment(qspec, sspec, agg_specs,
+                                         named_specs, k_pad,
+                                         seg.device_arrays(self.device),
+                                         params, has_after,
+                                         collapse_spec=cspec)
                 except _ScriptError as e:
                     # device-script trace failures are user errors (HTTP 400)
                     raise dsl.QueryParseError(f"script compile error: {e}")
@@ -660,8 +666,8 @@ class ShardSearcher:
                 for seg in ran_segs:
                     params: Dict[str, Any] = {}
                     qspec = C.prepare(lroot, seg, ctx, params)
-                    aspec = C.prepare_agg(an, seg, ctx, params, "rs")
-                    out = _fetch_agg_outputs(C.run_agg_only(
+                    aspec = AC.prepare_agg(an, seg, ctx, params, "rs")
+                    out = _fetch_agg_outputs(PG.run_agg_only(
                         qspec, aspec, seg.device_arrays(self.device), params))
                     new_parts.append(_device_agg_to_partial(an, aspec, out, seg, ctx))
                 result.agg_partials[an.name] = new_parts
@@ -676,7 +682,7 @@ class ShardSearcher:
             qw = float(spec.get("query_weight", 1.0))
             rw = float(spec.get("rescore_query_weight", 1.0))
             mode = spec.get("score_mode", "total")
-            lr = C.rewrite(rq, ctx, scoring=True)
+            lr = PL.rewrite(rq, ctx, scoring=True)
             params: Dict[str, Any] = {}
             rspec = C.prepare(lr, seg, ctx, params)
             docs = np.where(valid, idx, INT32_SENTINEL % seg.ndocs_pad).astype(np.int32)
@@ -693,13 +699,13 @@ class ShardSearcher:
     # ---------------- FETCH phase ----------------
 
     def fetch_phase(self, result: ShardQueryResult, selected: List[Candidate],
-                    body: dict, stats_ctx: Optional[C.ShardContext] = None) -> List[dict]:
+                    body: dict, stats_ctx: Optional[PL.ShardContext] = None) -> List[dict]:
         # explain must recompute with the SAME collection-wide statistics the
         # query phase scored with, or _explanation diverges from _score
-        ctx = stats_ctx or C.ShardContext(self.engine.mappings, result.segments,
-                                          self.similarity, self.field_similarities)
+        ctx = stats_ctx or PL.ShardContext(self.engine.mappings, result.segments,
+                                           self.similarity, self.field_similarities)
         qtree = dsl.parse_query(body.get("query"))
-        lroot = C.rewrite(qtree, ctx, scoring=True)
+        lroot = PL.rewrite(qtree, ctx, scoring=True)
         hl_terms = collect_query_terms(lroot) if body.get("highlight") else {}
         nested_ihs = _nested_queries_with_inner_hits(qtree)
         join_ihs = _join_queries_with_inner_hits(qtree)
@@ -774,7 +780,7 @@ class ShardSearcher:
                                     filter=[dsl.TermQuery(field=jf, value=jq.type)])
             lkey = ("jihc", id(jq))
             if lkey not in ih_cache:
-                ih_cache[lkey] = C.rewrite(inner_q, ctx, scoring=True)
+                ih_cache[lkey] = PL.rewrite(inner_q, ctx, scoring=True)
             lnode = ih_cache[lkey]
             kids = []
             for cseg, cd in ji.children_of(ji.seg_base(seg) + c.local_doc):
@@ -807,7 +813,7 @@ class ShardSearcher:
                                                           value=jq.parent_type)])
             lkey = ("jihp", id(jq))
             if lkey not in ih_cache:
-                ih_cache[lkey] = C.rewrite(inner_q, ctx, scoring=True)
+                ih_cache[lkey] = PL.rewrite(inner_q, ctx, scoring=True)
             sc, cm = self._join_child_scores(id(jq), ih_cache[lkey], pseg, ctx,
                                              ih_cache)
             if cm[pd] and pseg.live[pd]:
@@ -833,8 +839,8 @@ class ShardSearcher:
         name = ih.get("name", nq.path)
         key = (id(nq), c.seg_ord)
         if key not in ih_cache:
-            child_ctx = C.nested_context(ctx, nq.path)
-            inner_l = C.rewrite(nq.query, child_ctx, scoring=True)
+            child_ctx = PL.nested_context(ctx, nq.path)
+            inner_l = PL.rewrite(nq.query, child_ctx, scoring=True)
             cparams: Dict[str, Any] = {}
             cspec = C.prepare(inner_l, blk.child, child_ctx, cparams)
             docs = np.arange(blk.child.ndocs_pad, dtype=np.int32)
@@ -1176,7 +1182,7 @@ def launch_msearch_batched(searchers: List[ShardSearcher],
                     continue
                 body, query, sort_specs, window = p
                 try:
-                    lroot = C.rewrite(query, ctx, scoring=True)
+                    lroot = PL.rewrite(query, ctx, scoring=True)
                 except dsl.QueryParseError:
                     ok[bi] = False
                     continue
@@ -1192,11 +1198,11 @@ def launch_msearch_batched(searchers: List[ShardSearcher],
                     # through the vmapped twin of the SAME general program
                     # the direct path runs — first-class vector serving
                     # (ISSUE 15), byte-identical per query by construction
-                    if isinstance(lroot, C.LKnn) \
+                    if isinstance(lroot, PL.LKnn) \
                             and _knn_batch_body_ok(sort_specs, body, window):
                         kroots[bi] = lroot
                     else:
-                        if isinstance(lroot, C.LKnn):
+                        if isinstance(lroot, PL.LKnn):
                             from ..search import fusion as _fusion
                             _fusion.STATS.inc("knn_batch_declined")
                         ok[bi] = False
@@ -1348,7 +1354,7 @@ def _launch_knn_segment(s: ShardSearcher, ctx, seg: Segment, seg_ord: int,
     spec/params via canon_query — structurally identical bodies share
     one compiled program), enqueue every per-query invocation of the
     DIRECT-path executor unfetched, and defer the device sync to one
-    fetch sweep (compiler.launch_segment_batch — deliberately not a
+    fetch sweep (programs.launch_segment_batch — deliberately not a
     vmapped mega-program; see its docstring for the byte-parity
     rationale). Returns [(shard_i, s, ctx, seg, seg_ord, [bi...],
     fetch_fn)] or None to decline the whole segment (BP-reordered
@@ -1368,10 +1374,10 @@ def _launch_knn_segment(s: ShardSearcher, ctx, seg: Segment, seg_ord: int,
             sspec = C.prepare_sort(sort_specs, seg, params)
         except dsl.QueryParseError:
             return None
-        full, cparams = C.canon_query(qspec, sspec, k_pad, params)
+        full, cparams = PG.canon_query(qspec, sspec, k_pad, params)
         prepared.append((full, cparams))
         bis.append(bi)
-    fetch_fn = C.launch_segment_batch(prepared, seg.device_arrays(s.device))
+    fetch_fn = PG.launch_segment_batch(prepared, seg.device_arrays(s.device))
     _fusion.STATS.inc("knn_batch_launches")
     _fusion.STATS.inc("knn_batched", len(prepared))
     return [(shard_i, s, ctx, seg, seg_ord, bis, fetch_fn)]
@@ -1471,8 +1477,8 @@ def _finish_search(searchers: List[ShardSearcher],
             # the root carries the measured phase time (children fused=true).
             try:
                 plan_tree = C.describe_plan(
-                    C.rewrite(dsl.parse_query(body.get("query")),
-                              stats[0], scoring=True)) if stats else None
+                    PL.rewrite(dsl.parse_query(body.get("query")),
+                               stats[0], scoring=True)) if stats else None
             except Exception:
                 plan_tree = None
             # device attribution: what this request cost the jit layer (cache
@@ -1730,7 +1736,7 @@ def _bucket_filter(node: AggNode, bucket: dict) -> Optional[dict]:
         key = int(bucket["key"])
         # the chosen interval is in the finalized result, threaded onto the
         # bucket by _refine via the parent result's "interval"
-        return {"range": {field: {"gte": key, "lt": C.auto_bucket_end_ms(
+        return {"range": {field: {"gte": key, "lt": PN.auto_bucket_end_ms(
             key, bucket.get("_interval", "1s"))}}}
     if kind == "histogram":
         interval = float(body["interval"])
@@ -1742,8 +1748,8 @@ def _bucket_filter(node: AggNode, bucket: dict) -> Optional[dict]:
         if cal:
             end = _next_calendar_ms(key, cal)
         else:
-            end = key + C.parse_interval_ms(body.get("fixed_interval",
-                                                     body.get("interval", "1d")))
+            end = key + PN.parse_interval_ms(body.get("fixed_interval",
+                                                      body.get("interval", "1d")))
         return {"range": {field: {"gte": key, "lt": end}}}
     if kind in ("geohash_grid", "geotile_grid"):
         lat_lo, lat_hi, lon_lo, lon_hi = (
@@ -1766,7 +1772,7 @@ def _bucket_filter(node: AggNode, bucket: dict) -> Optional[dict]:
             else:
                 cal = scfg.get("calendar_interval")
                 end = (_next_calendar_ms(int(v), cal) if cal else
-                       int(v) + C.parse_interval_ms(scfg.get(
+                       int(v) + PN.parse_interval_ms(scfg.get(
                            "fixed_interval", scfg.get("interval", "1d"))))
                 flt.append({"range": {f: {"gte": int(v), "lt": end}}})
         return {"bool": {"filter": flt}} if len(flt) != 1 else flt[0]
@@ -1814,7 +1820,7 @@ def _refine_complex_subs(searchers: List[ShardSearcher], body: dict,
                                  filters + [node.body])
         return
     if kind == "filters":
-        fmap = dict(C.filters_agg_items(node.body))
+        fmap = dict(AC.filters_agg_items(node.body))
         for key, bucket in (result.get("buckets") or {}).items():
             bf = fmap.get(key)
             if bf is None:
@@ -1880,8 +1886,8 @@ def _global_stats_contexts(searchers: List[ShardSearcher]) -> List[Any]:
     for s in searchers:
         group_segs.setdefault(s.index_key, []).extend(
             getattr(s, "_snapshot_segments", None) or s.engine.segments)
-    return [C.ShardContext(s.engine.mappings, group_segs[s.index_key],
-                           s.similarity, s.field_similarities)
+    return [PL.ShardContext(s.engine.mappings, group_segs[s.index_key],
+                            s.similarity, s.field_similarities)
             for s in searchers]
 
 
@@ -2017,7 +2023,7 @@ def _host_sort_values(sort_specs: List[dict], seg: Segment, doc: int,
             continue
         nspec = spec.get("nested")
         if nspec and nspec.get("path"):
-            vals, present = C._nested_sort_values(
+            vals, present = PN.nested_sort_values(
                 seg, f, nspec["path"],
                 spec.get("mode", "max" if desc else "min"))
             if vals is not None and present[doc]:
@@ -2193,7 +2199,7 @@ def _ordinal_buckets(node: AggNode, device_out: dict, vocab,
     subs = _sub_metric_columns(node, device_out)
     if ordinals is None:
         ordinals = np.nonzero(counts[: len(vocab)] > 0)[0]
-    C.AGG_STATS.inc("terms.records", len(ordinals))
+    AGG_STATS.inc("terms.records", len(ordinals))
     buckets: dict = {}
     for o in ordinals:
         rec: dict = {"doc_count": int(round(float(counts[o])))}
@@ -2243,16 +2249,16 @@ def _auto_date_ranges(agg_nodes, qspec, seg: Segment, ctx, params: dict,
     fields = {}
     for an in agg_nodes:
         if an.kind == "auto_date_histogram":
-            f = C._resolve_agg_field(an, ctx)
+            f = AC.resolve_agg_field(an, ctx)
             col = seg.numeric_cols.get(f)
             if col is not None and col.kind == "int":
                 fields[an.name] = f
     if not fields:
         return {}
-    C.AGG_STATS.inc("auto_date.requests", len(fields))
+    AGG_STATS.inc("auto_date.requests", len(fields))
     with TRACER.span("search.aggs.refine"):
-        got = C.auto_date_range(qspec, tuple(sorted(set(fields.values()))),
-                                seg.device_arrays(device), params)
+        got = PG.auto_date_range(qspec, tuple(sorted(set(fields.values()))),
+                                 seg.device_arrays(device), params)
     return {name: got[f] for name, f in fields.items() if got[f]}
 
 
@@ -2299,7 +2305,7 @@ def _device_agg_to_partial(node: AggNode, aspec, device_out: Optional[dict],
             sub_cols = _sub_metric_columns(node, device_out)
             buckets = {}
             for j in np.nonzero(counts > 0)[0]:
-                epoch = C.calendar_bucket_start_ms(min_b + int(j), calendar)
+                epoch = PN.calendar_bucket_start_ms(min_b + int(j), calendar)
                 rec = {"doc_count": int(round(float(counts[j])))}
                 rec["subs"] = _bucket_subs(sub_cols, int(j))
                 buckets[epoch] = rec
@@ -2368,7 +2374,7 @@ def _device_agg_to_partial(node: AggNode, aspec, device_out: Optional[dict],
         return {"buckets": _ordinal_buckets(node, device_out,
                                             seg.keyword_cols[f].vocab),
                 "fg_total": int(round(float(np.asarray(device_out["fg_total"])))),
-                "bg": C._kw_doc_counts(seg, f),
+                "bg": PN.kw_doc_counts(seg, f),
                 "bg_total": seg.live_count}
 
     if kind in ("sampler", "dsampler"):
@@ -2386,7 +2392,7 @@ def _device_agg_to_partial(node: AggNode, aspec, device_out: Optional[dict],
 
     if kind == "geo_grid":
         _, prefix, gkind, f, precision, nb, subs = aspec
-        vocab, _ords = C._geo_grid_cache(seg, f, gkind, precision)
+        vocab, _ords = PN.geo_grid_cache(seg, f, gkind, precision)
         return {"buckets": _ordinal_buckets(node, device_out, vocab)}
 
     if kind == "matrix_stats":
@@ -2433,8 +2439,8 @@ def _device_agg_to_partial(node: AggNode, aspec, device_out: Optional[dict],
 
     if kind == "composite":
         _, prefix, single, total, subs = aspec
-        _plane, space = C.composite_space(
-            seg, C._composite_sources(node, seg, ctx)[0])
+        _plane, space = AC.composite_space(
+            seg, AC.bind_composite_sources(node, seg, ctx)[0])
         counts = np.asarray(device_out["counts"])[:total]
         return {"buckets": _ordinal_buckets(
             node, device_out, space, _composite_page(node, counts, space))}
@@ -2501,7 +2507,7 @@ def _device_agg_to_partial(node: AggNode, aspec, device_out: Optional[dict],
     if kind == "multi_terms":
         _, prefix, nord_pad, nvocab, sub_specs = aspec
         fields = tuple(s["field"] for s in node.body.get("terms", []))
-        _plane, space = C.multi_terms_plane(seg, ctx, fields)
+        _plane, space = PN.multi_terms_plane(seg, ctx, fields)
         return {"buckets": _ordinal_arrays(node, device_out, space)}
 
     if kind == "adjacency":
@@ -2530,7 +2536,7 @@ def _device_agg_to_partial(node: AggNode, aspec, device_out: Optional[dict],
         part = _hist_partial(node, device_out, first, 1.0, 0.0)
         # keyed by the unit bucket's start in epoch ms (the merge coarsens
         # across shards' units)
-        return {"buckets": {C.auto_unit_start_ms(b, unit): rec
+        return {"buckets": {PN.auto_unit_start_ms(b, unit): rec
                             for b, rec in part["buckets"].items()},
                 "unit": unit}
 
@@ -2605,7 +2611,7 @@ def _significant_text_partial(node: AggNode, device_out: dict, seg: Segment,
     if len(docs) > shard_size:
         order = np.argsort(-scores[docs], kind="stable")
         docs = docs[order[:shard_size]]
-    from .compiler import _analyze_query_text
+    from .plan import analyze_query_text
     fg: Dict[str, int] = {}
     for d in docs:
         src = seg.sources[int(d)]
@@ -2615,7 +2621,7 @@ def _significant_text_partial(node: AggNode, device_out: dict, seg: Segment,
         texts = v if isinstance(v, list) else [v]
         seen = set()
         for t in texts:
-            for tok in _analyze_query_text(field, str(t), ctx):
+            for tok in analyze_query_text(field, str(t), ctx):
                 seen.add(tok)
         for tok in seen:
             fg[tok] = fg.get(tok, 0) + 1
@@ -2641,9 +2647,9 @@ def _sub_metric_arrays(out: dict) -> dict:
     "sumsq"} as numpy: the limb sums finished in float64."""
     inv = float(np.asarray(out["scale"]))
     cols = {"count": np.asarray(out["count"]),
-            "sum": C.agg_ops.limb_sums_to_f64(out["sum"], inv),
+            "sum": agg_ops.limb_sums_to_f64(out["sum"], inv),
             "min": np.asarray(out["min"]), "max": np.asarray(out["max"])}
-    cols["sumsq"] = (C.agg_ops.limb_sums_to_f64(out["sumsq"], inv * inv)
+    cols["sumsq"] = (agg_ops.limb_sums_to_f64(out["sumsq"], inv * inv)
                      if "sumsq" in out else np.zeros_like(cols["sum"]))
     return cols
 
@@ -2679,7 +2685,7 @@ def _hist_partial(node: AggNode, device_out: dict, min_b: int, interval: float,
 
 def _host_phrase_freq(node, seg: Segment, doc: int) -> float:
     """Host mirror of ops.positions.phrase_freqs for one doc (explain)."""
-    from .compiler import _prefix_rows
+    from .plan import prefix_rows
 
     pb = seg.postings.get(node.field)
     if pb is None or pb.pos_starts is None:
@@ -2688,7 +2694,7 @@ def _host_phrase_freq(node, seg: Segment, doc: int) -> float:
     last = len(node.terms) - 1
     for i, t in enumerate(node.terms):
         if node.prefix_last and i == last:
-            rows = list(_prefix_rows(pb, t, node.max_expansions))
+            rows = list(prefix_rows(pb, t, node.max_expansions))
         else:
             r = pb.row(t)
             rows = [r] if r >= 0 else []
@@ -2738,11 +2744,11 @@ def _host_phrase_freq(node, seg: Segment, doc: int) -> float:
     return freq
 
 def explain_doc(lroot, seg: Segment, doc: int, ctx) -> dict:
-    from .compiler import LBool, LConstScore, LDisMax, LPhrase, LTerms
+    from .plan import LBool, LConstScore, LDisMax, LPhrase, LTerms
     from ..ops.scoring import SIM_BM25
 
     def walk(n) -> Tuple[float, dict]:
-        if isinstance(n, C.LSpanHost):
+        if isinstance(n, PL.LSpanHost):
             freq = float(n._freqs.get(seg.uid, np.zeros(1))[doc]
                          if doc < len(n._freqs.get(seg.uid, [])) else 0.0)
             dl = float(seg.doc_lens.get(n.field, np.zeros(seg.ndocs))[doc]) \
@@ -2818,7 +2824,7 @@ def explain_doc(lroot, seg: Segment, doc: int, ctx) -> dict:
             total = best + n.tie_breaker * (sum(v for v, _ in vals) - best)
             return total, {"value": total, "description": "max plus tie_breaker of:",
                            "details": [d for _, d in vals]}
-        from .compiler import LExists, LMatchAll, LRange
+        from .plan import LExists, LMatchAll, LRange
         if isinstance(n, LRange):
             col = seg.numeric_cols.get(n.field)
             ok = col is not None and bool(col.present[doc])
@@ -2840,7 +2846,7 @@ def explain_doc(lroot, seg: Segment, doc: int, ctx) -> dict:
             val = n.boost if ok else 0.0
             return val, {"value": val,
                          "description": f"exists [{n.field}]", "details": []}
-        from .compiler import LNested
+        from .plan import LNested
         if isinstance(n, LNested):
             blk = seg.nested.get(n.path)
             if blk is None or blk.child.ndocs == 0:
@@ -2873,7 +2879,7 @@ def explain_doc(lroot, seg: Segment, doc: int, ctx) -> dict:
             return total, {"value": total,
                            "description": f"nested [{n.path}] {mode} of children:",
                            "details": details}
-        from .compiler import LHasChild, LHasParent
+        from .plan import LHasChild, LHasParent
         if isinstance(n, LHasChild):
             from . import compiler as _C
             ji = n.join_index

@@ -105,14 +105,10 @@ def set_rescore_mode(mode: Optional[str]) -> None:
 
 def rescore_mode() -> str:
     """Where the candidate-union rescore runs. Auto: device on TPU, host
-    numpy under JAX_PLATFORMS=cpu (the fallback + parity oracle). Env
-    OPENSEARCH_TPU_RESCORE=device|host overrides; set_rescore_mode wins."""
-    import os
+    numpy under JAX_PLATFORMS=cpu (the fallback + parity oracle).
+    `set_rescore_mode` overrides."""
     if _rescore_override in ("device", "host"):
         return _rescore_override
-    env = os.environ.get("OPENSEARCH_TPU_RESCORE", "").lower()
-    if env in ("device", "host"):
-        return env
     import jax
     return "device" if jax.default_backend() == "tpu" else "host"
 
@@ -126,12 +122,6 @@ def rescore_stats() -> dict:
 # charge path (oslint OSL506). Released when the owning layout object
 # (or its segment) is GC'd; segments are immutable and replaced on
 # refresh/merge.
-
-
-def set_breaker(breaker) -> None:
-    """Legacy wiring shim: the breaker now lives on the ledger."""
-    from ..obs.hbm_ledger import LEDGER
-    LEDGER.set_breaker(breaker)
 
 
 def set_enabled(flag: bool) -> None:
@@ -462,9 +452,9 @@ def _body_eligible(sort_specs: List[dict], agg_nodes, named_nodes,
 
 def _ok_group(lt) -> bool:
     """LTerms usable as a fastpath scoring clause (plain BM25 term group)."""
-    from . import compiler as C
+    from . import plan as PL
 
-    if not isinstance(lt, C.LTerms):
+    if not isinstance(lt, PL.LTerms):
         return False
     if lt.mode != "score" or lt.sim is None or lt.sim.sim_id != ops.SIM_BM25:
         return False
@@ -525,14 +515,14 @@ class FastSpec:
 def _flatten_bool(lroot) -> Optional[FastSpec]:
     """Map an LBool/LConstScore tree onto the weighted-threshold slot model;
     None = not expressible (falls back to the XLA plan path)."""
-    from . import compiler as C
+    from . import plan as PL
 
-    if isinstance(lroot, C.LConstScore):
+    if isinstance(lroot, PL.LConstScore):
         if lroot.child is None or lroot.boost < 0:
             return None
         return FastSpec("bool", filter_clauses=[(lroot.child, False)],
                         const_score=float(lroot.boost), boost=1.0)
-    if not isinstance(lroot, C.LBool):
+    if not isinstance(lroot, PL.LBool):
         return None
     b = lroot
     if b.boost <= 0:
@@ -1990,7 +1980,7 @@ def _filter_list(seg: Segment, ctx, clauses) -> Optional[FilterList]:
     for node, neg in clauses:
         local: dict = {}
         spec = C.prepare(node, seg, ctx, local)
-        mkey, mapping = C._filter_cache_key(spec, local, seg)
+        mkey, mapping = C.filter_cache_key(spec, local, seg)
         if mkey is None:
             return None
         key_parts.append((mkey, neg))
@@ -2004,8 +1994,8 @@ def _filter_list(seg: Segment, ctx, clauses) -> Optional[FilterList]:
     combined = np.ones(nd, bool)
     for (node, neg), (mkey, spec, local, mapping, _n) in zip(clauses,
                                                              prepped):
-        mask = np.asarray(C._mask_for_key(mkey, spec, local, mapping, seg,
-                                          needs=C.node_needs(node)))
+        mask = np.asarray(C.mask_for_key(mkey, spec, local, mapping, seg,
+                                         needs=C.node_needs(node)))
         m = mask[:nd].astype(bool)
         combined &= ~m if neg else m
     docs = np.nonzero(combined)[0].astype(np.int32)
@@ -2428,7 +2418,7 @@ def _run_bool(seg: Segment, ctx, specs: Sequence[FastSpec], K: int
 def segment_search(seg: Segment, ctx, spec: FastSpec, k: int
                    ) -> Optional[dict]:
     """Run the fused kernel for one FastSpec over one segment. Returns a
-    dict shaped like compiler.run_segment output, or None to fall back."""
+    dict shaped like programs.run_segment output, or None to fall back."""
     res = batch_search(seg, ctx, [spec], k)
     return res[0] if res else None
 

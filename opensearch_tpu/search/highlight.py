@@ -148,8 +148,8 @@ def _mark(text: str, spans: List[tuple], pre: str, post: str) -> str:
 
 def collect_query_terms(lnode) -> Dict[str, Set[str]]:
     """field -> query terms, walked from the logical plan (for highlighting)."""
-    from .compiler import (LBool, LBoosting, LConstScore, LDisMax, LFuncScore,
-                           LPhrase, LTerms)
+    from .plan import (LBool, LBoosting, LConstScore, LDisMax, LFuncScore,
+                       LPhrase, LTerms)
 
     out: Dict[str, Set[str]] = {}
 

@@ -148,9 +148,9 @@ def _ok_sparse(lroot) -> bool:
     """LSparseDot usable as the sparse impact-ladder root: a plain
     `neural_sparse` dot product (non-negative token weights — the plan's
     witness/remainder bounds assume monotone contributions)."""
-    from . import compiler as C
+    from . import plan as PL
 
-    if not isinstance(lroot, C.LSparseDot):
+    if not isinstance(lroot, PL.LSparseDot):
         return False
     if not len(lroot.tokens):
         return False

@@ -21,7 +21,7 @@ import numpy as np
 
 from ..index.mappings import Mappings
 from ..index.segment import Segment, build_segment
-from . import compiler as C
+from . import compiler as C, plan as PL
 from . import query_dsl as dsl
 
 # ---------------------------------------------------------------------------
@@ -33,19 +33,19 @@ def _extract(n) -> Optional[Set[Tuple[str, str]]]:
     """A set of (field, term) pairs such that a doc can only match `n` if it
     contains at least one of them — or None when no such guarantee exists
     (the stored query must then always be evaluated)."""
-    if isinstance(n, C.LTerms):
+    if isinstance(n, PL.LTerms):
         if not n.terms:
             return None
         if n.msm >= len(n.terms):
             # conjunction: every term is individually necessary; one suffices
             return {(n.field, n.terms[0])}
         return {(n.field, t) for t in n.terms}
-    if isinstance(n, C.LPhrase):
+    if isinstance(n, PL.LPhrase):
         terms = n.terms[:-1] if n.prefix_last and len(n.terms) > 1 else n.terms
         if not terms or (n.prefix_last and len(n.terms) == 1):
             return None
         return {(n.field, terms[0])}
-    if isinstance(n, C.LBool):
+    if isinstance(n, PL.LBool):
         best: Optional[Set] = None
         for c in n.musts + n.filters:
             s = _extract(c)
@@ -62,11 +62,11 @@ def _extract(n) -> Optional[Set[Tuple[str, str]]]:
                 union |= s
             return union
         return None
-    if isinstance(n, C.LConstScore):
+    if isinstance(n, PL.LConstScore):
         return _extract(n.child)
-    if isinstance(n, C.LBoosting):
+    if isinstance(n, PL.LBoosting):
         return _extract(n.positive)
-    if isinstance(n, C.LDisMax):
+    if isinstance(n, PL.LDisMax):
         union = set()
         for c in n.children:
             s = _extract(c)
@@ -74,11 +74,11 @@ def _extract(n) -> Optional[Set[Tuple[str, str]]]:
                 return None
             union |= s
         return union
-    if isinstance(n, C.LFuncScore):
+    if isinstance(n, PL.LFuncScore):
         return _extract(n.child)
-    if isinstance(n, C.LNested):
+    if isinstance(n, PL.LNested):
         return _extract(n.child)
-    if isinstance(n, C.LMatchNone):
+    if isinstance(n, PL.LMatchNone):
         return set()  # never matches; empty necessary set keeps it skippable
     return None
 
@@ -87,8 +87,8 @@ def extract_index_terms(qdict: dict, mappings: Mappings) -> Tuple[List[str], boo
     """Parse+validate a stored percolator query and extract its pre-filter
     terms. Returns (["field\\0term", ...], always_run)."""
     q = dsl.parse_query(qdict)
-    ctx = C.ShardContext(mappings, [])
-    lroot = C.rewrite(q, ctx, scoring=False)
+    ctx = PL.ShardContext(mappings, [])
+    lroot = PL.rewrite(q, ctx, scoring=False)
     s = _extract(lroot)
     if s is None:
         return [], True
@@ -117,7 +117,7 @@ def build_mini(mappings: Mappings, documents: List[dict]):
     m2 = _clone_mappings(mappings)
     parsed = [m2.parse(str(i), doc) for i, doc in enumerate(documents)]
     seg = build_segment("_percolate", parsed, m2)
-    ctx = C.ShardContext(m2, [seg])
+    ctx = PL.ShardContext(m2, [seg])
     return seg, ctx
 
 
@@ -135,17 +135,17 @@ def candidate_terms(seg: Segment) -> Set[str]:
 # ---------------------------------------------------------------------------
 
 
-def host_eval(n, seg: Segment, ctx: C.ShardContext) -> np.ndarray:
+def host_eval(n, seg: Segment, ctx: PL.ShardContext) -> np.ndarray:
     """bool[ndocs] matched mask for one LNode over a host-resident segment.
     Mirrors emit()'s matched semantics; falls back to the jitted device path
     for node kinds it doesn't model."""
     live = seg.live[: seg.ndocs]
 
-    if isinstance(n, C.LMatchAll):
+    if isinstance(n, PL.LMatchAll):
         return live.copy()
-    if isinstance(n, C.LMatchNone):
+    if isinstance(n, PL.LMatchNone):
         return np.zeros(seg.ndocs, bool)
-    if isinstance(n, C.LTerms):
+    if isinstance(n, PL.LTerms):
         pb = seg.postings.get(n.field)
         if pb is None:
             return np.zeros(seg.ndocs, bool)
@@ -156,7 +156,7 @@ def host_eval(n, seg: Segment, ctx: C.ShardContext) -> np.ndarray:
                 a, b = pb.row_slice(r)
                 count[pb.doc_ids[a:b]] += 1
         return (count >= max(n.msm, 1)) & live
-    if isinstance(n, C.LExpandTerms):
+    if isinstance(n, PL.LExpandTerms):
         rows = n.expander(seg)
         pb = seg.postings.get(n.field)
         mask = np.zeros(seg.ndocs, bool)
@@ -165,14 +165,14 @@ def host_eval(n, seg: Segment, ctx: C.ShardContext) -> np.ndarray:
                 a, b = pb.row_slice(int(r))
                 mask[pb.doc_ids[a:b]] = True
         return mask & live
-    if isinstance(n, C.LPhrase):
+    if isinstance(n, PL.LPhrase):
         from .executor import _host_phrase_freq
         mask = np.zeros(seg.ndocs, bool)
         for d in range(seg.ndocs):
             if live[d] and _host_phrase_freq(n, seg, d) > 0:
                 mask[d] = True
         return mask
-    if isinstance(n, C.LRange):
+    if isinstance(n, PL.LRange):
         col = seg.numeric_cols.get(n.field)
         if col is None:
             return np.zeros(seg.ndocs, bool)
@@ -183,7 +183,7 @@ def host_eval(n, seg: Segment, ctx: C.ShardContext) -> np.ndarray:
         if n.hi is not None:
             mask &= (v <= n.hi) if n.include_hi else (v < n.hi)
         return mask & live
-    if isinstance(n, C.LExists):
+    if isinstance(n, PL.LExists):
         f = n.field
         if f in seg.numeric_cols:
             present = seg.numeric_cols[f].present[: seg.ndocs]
@@ -196,14 +196,14 @@ def host_eval(n, seg: Segment, ctx: C.ShardContext) -> np.ndarray:
         else:
             return np.zeros(seg.ndocs, bool)
         return np.asarray(present, bool) & live
-    if isinstance(n, C.LIds):
+    if isinstance(n, PL.LIds):
         mask = np.zeros(seg.ndocs, bool)
         for i in n.ids:
             d = seg.id2doc.get(i)
             if d is not None:
                 mask[d] = True
         return mask & live
-    if isinstance(n, C.LBool):
+    if isinstance(n, PL.LBool):
         mask = live.copy()
         for c in n.musts + n.filters:
             mask &= host_eval(c, seg, ctx)
@@ -215,18 +215,18 @@ def host_eval(n, seg: Segment, ctx: C.ShardContext) -> np.ndarray:
                 cnt += host_eval(c, seg, ctx)
             mask &= cnt >= n.msm
         return mask
-    if isinstance(n, C.LConstScore):
+    if isinstance(n, PL.LConstScore):
         return host_eval(n.child, seg, ctx)
-    if isinstance(n, C.LBoosting):
+    if isinstance(n, PL.LBoosting):
         return host_eval(n.positive, seg, ctx)
-    if isinstance(n, C.LDisMax):
+    if isinstance(n, PL.LDisMax):
         mask = np.zeros(seg.ndocs, bool)
         for c in n.children:
             mask |= host_eval(c, seg, ctx)
         return mask
-    if isinstance(n, C.LFuncScore) and n.min_score is None:
+    if isinstance(n, PL.LFuncScore) and n.min_score is None:
         return host_eval(n.child, seg, ctx)
-    if isinstance(n, C.LNested):
+    if isinstance(n, PL.LNested):
         blk = seg.nested.get(n.path)
         if blk is None or blk.child.ndocs == 0:
             return np.zeros(seg.ndocs, bool)
@@ -234,7 +234,7 @@ def host_eval(n, seg: Segment, ctx: C.ShardContext) -> np.ndarray:
         mask = np.zeros(seg.ndocs, bool)
         np.logical_or.at(mask, blk.parent_of[cm], True)
         return mask & live
-    if isinstance(n, C.LGeoDist):
+    if isinstance(n, PL.LGeoDist):
         col = seg.geo_cols.get(n.field)
         if col is None:
             return np.zeros(seg.ndocs, bool)
@@ -246,7 +246,7 @@ def host_eval(n, seg: Segment, ctx: C.ShardContext) -> np.ndarray:
         a = np.sin(dphi / 2) ** 2 + np.cos(p1) * np.cos(p2) * np.sin(dlmb / 2) ** 2
         d = 2 * r * np.arcsin(np.sqrt(np.clip(a, 0.0, 1.0)))
         return (d <= n.radius_m) & col.present[: seg.ndocs] & live
-    if isinstance(n, C.LGeoBox):
+    if isinstance(n, PL.LGeoBox):
         col = seg.geo_cols.get(n.field)
         if col is None:
             return np.zeros(seg.ndocs, bool)
@@ -303,7 +303,7 @@ def candidate_docs(seg: Segment, field: str, cand: Set[str]) -> np.ndarray:
     return run & seg.live[: seg.ndocs]
 
 
-def segment_mask(field: str, mini_seg: Segment, mini_ctx: C.ShardContext,
+def segment_mask(field: str, mini_seg: Segment, mini_ctx: PL.ShardContext,
                  seg: Segment) -> np.ndarray:
     """f32[ndocs_pad]: 1.0 for each stored query in `seg` that matches at
     least one candidate doc."""
@@ -313,18 +313,18 @@ def segment_mask(field: str, mini_seg: Segment, mini_ctx: C.ShardContext,
         q = _stored_query(seg, int(doc), field)
         if q is None:
             continue
-        lq = C.rewrite(q, mini_ctx, scoring=False)
+        lq = PL.rewrite(q, mini_ctx, scoring=False)
         if host_eval(lq, mini_seg, mini_ctx).any():
             mask[doc] = 1.0
     return mask
 
 
-def document_slots(field: str, mini_seg: Segment, mini_ctx: C.ShardContext,
+def document_slots(field: str, mini_seg: Segment, mini_ctx: PL.ShardContext,
                    seg: Segment, doc: int) -> List[int]:
     """Which candidate documents one stored query matched (fetch-phase
     `_percolator_document_slot`)."""
     q = _stored_query(seg, doc, field)
     if q is None:
         return []
-    lq = C.rewrite(q, mini_ctx, scoring=False)
+    lq = PL.rewrite(q, mini_ctx, scoring=False)
     return [int(i) for i in np.nonzero(host_eval(lq, mini_seg, mini_ctx))[0]]

@@ -2,7 +2,7 @@
 `index/query/*QueryBuilder.java` fromXContent parsers (same DSL surface).
 
 The tree is *unrewritten*: analysis, multi-term expansion, and idf weighting
-happen in `compiler.rewrite` (the analog of QueryBuilder.rewrite +
+happen in `plan.rewrite` (the analog of QueryBuilder.rewrite +
 Query.createWeight, which need index statistics).
 """
 

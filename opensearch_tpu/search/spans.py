@@ -301,10 +301,10 @@ class SpanEvalError(dsl.QueryParseError):
 
 def eval_span_query(q, seg, ctx) -> Tuple[str, SpanSet, List[str]]:
     """-> (field, spans, terms involved) for a span query tree."""
-    from . import compiler as C
+    from . import plan as PL
 
     if isinstance(q, dsl.SpanTermQuery):
-        term = C._index_term(q.field, q.value, ctx)
+        term = PL.index_term(q.field, q.value, ctx)
         ft = ctx.mappings.resolve_field(q.field)
         field = ft.name if ft else q.field
         return field, term_spans(seg, field, term), [term]
@@ -361,20 +361,20 @@ def eval_span_query(q, seg, ctx) -> Tuple[str, SpanSet, List[str]]:
 
 
 def _eval_span_multi(q, seg, ctx):
-    from . import compiler as C
+    from . import plan as PL
 
     inner = q.match
     if isinstance(inner, dsl.PrefixQuery):
-        field, expander = inner.field, C._prefix_expander(
+        field, expander = inner.field, PL.prefix_expander(
             inner.field, inner.value, False)
     elif isinstance(inner, dsl.WildcardQuery):
-        field, expander = inner.field, C._wildcard_expander(
+        field, expander = inner.field, PL.wildcard_expander(
             inner.field, inner.value, False)
     elif isinstance(inner, dsl.FuzzyQuery):
-        field, expander = inner.field, C._fuzzy_expander(
+        field, expander = inner.field, PL.fuzzy_expander(
             inner.field, inner.value, inner.fuzziness, inner.prefix_length)
     elif isinstance(inner, dsl.RegexpQuery):
-        field, expander = inner.field, C._regexp_expander(
+        field, expander = inner.field, PL.regexp_expander(
             inner.field, inner.value)
     else:
         raise SpanEvalError(
@@ -389,10 +389,10 @@ def _eval_span_multi(q, seg, ctx):
 
 def eval_interval_rule(rule: dsl.IntervalRule, field: str, seg, ctx
                        ) -> Tuple[SpanSet, List[str]]:
-    from . import compiler as C
+    from . import plan as PL
 
     if rule.kind == "match":
-        terms = C._analyze_query_text(field, rule.query, ctx, rule.analyzer)
+        terms = PL.analyze_query_text(field, rule.query, ctx, rule.analyzer)
         sets = [term_spans(seg, field, t) for t in terms]
         if len(sets) == 1:
             spans = sets[0]
@@ -400,11 +400,11 @@ def eval_interval_rule(rule: dsl.IntervalRule, field: str, seg, ctx
             spans = near_spans(sets, rule.max_gaps, rule.ordered)
     elif rule.kind in ("prefix", "wildcard", "fuzzy"):
         if rule.kind == "prefix":
-            expander = C._prefix_expander(field, rule.query, False)
+            expander = PL.prefix_expander(field, rule.query, False)
         elif rule.kind == "wildcard":
-            expander = C._wildcard_expander(field, rule.query, False)
+            expander = PL.wildcard_expander(field, rule.query, False)
         else:
-            expander = C._fuzzy_expander(field, rule.query, rule.fuzziness,
+            expander = PL.fuzzy_expander(field, rule.query, rule.fuzziness,
                                          rule.prefix_length)
         rows = expander(seg)
         pb = seg.postings.get(field)
@@ -469,7 +469,7 @@ def _difference(all_s: SpanSet, minus: SpanSet) -> SpanSet:
 def collect_terms(query, ctx, cap: int = 16) -> List[str]:
     """Light term collection for the pseudo-term idf weight: no positional
     evaluation, only term-dict scans for expansions (cheap)."""
-    from . import compiler as C
+    from . import plan as PL
 
     out: List[str] = []
 
@@ -485,7 +485,7 @@ def collect_terms(query, ctx, cap: int = 16) -> List[str]:
 
     def walk(q):
         if isinstance(q, dsl.SpanTermQuery):
-            out.append(C._index_term(q.field, q.value, ctx))
+            out.append(PL.index_term(q.field, q.value, ctx))
         elif isinstance(q, (dsl.SpanNearQuery, dsl.SpanOrQuery)):
             for c in q.clauses:
                 walk(c)
@@ -502,28 +502,28 @@ def collect_terms(query, ctx, cap: int = 16) -> List[str]:
         elif isinstance(q, dsl.SpanMultiQuery):
             inner = q.match
             if isinstance(inner, dsl.PrefixQuery):
-                expand(inner.field, lambda f: C._prefix_expander(
+                expand(inner.field, lambda f: PL.prefix_expander(
                     f, inner.value, False))
             elif isinstance(inner, dsl.WildcardQuery):
-                expand(inner.field, lambda f: C._wildcard_expander(
+                expand(inner.field, lambda f: PL.wildcard_expander(
                     f, inner.value, False))
             elif isinstance(inner, dsl.FuzzyQuery):
-                expand(inner.field, lambda f: C._fuzzy_expander(
+                expand(inner.field, lambda f: PL.fuzzy_expander(
                     f, inner.value, inner.fuzziness, inner.prefix_length))
             elif isinstance(inner, dsl.RegexpQuery):
-                expand(inner.field, lambda f: C._regexp_expander(
+                expand(inner.field, lambda f: PL.regexp_expander(
                     f, inner.value))
 
     def walk_rule(rule, field):
         if rule.kind == "match":
-            out.extend(C._analyze_query_text(field, rule.query, ctx,
+            out.extend(PL.analyze_query_text(field, rule.query, ctx,
                                              rule.analyzer))
         elif rule.kind == "prefix":
-            expand(field, lambda f: C._prefix_expander(f, rule.query, False))
+            expand(field, lambda f: PL.prefix_expander(f, rule.query, False))
         elif rule.kind == "wildcard":
-            expand(field, lambda f: C._wildcard_expander(f, rule.query, False))
+            expand(field, lambda f: PL.wildcard_expander(f, rule.query, False))
         elif rule.kind == "fuzzy":
-            expand(field, lambda f: C._fuzzy_expander(
+            expand(field, lambda f: PL.fuzzy_expander(
                 f, rule.query, rule.fuzziness, rule.prefix_length))
         else:
             for r in rule.rules:

@@ -61,7 +61,6 @@ the obs_registry pattern.
 
 from __future__ import annotations
 
-import os
 import threading
 import time
 from collections import OrderedDict, deque
@@ -90,37 +89,28 @@ class RemediationConfig:
     """Bounds and clocks for every action the actuator may take (the
     action table in docs/RESILIENCE.md "Self-healing loop")."""
 
-    def __init__(self, ttl_s: Optional[float] = None,
-                 green_hold_s: Optional[float] = None,
-                 engage_cooldown_s: Optional[float] = None,
+    def __init__(self, ttl_s: float = 60.0,
+                 green_hold_s: float = 2.0,
+                 engage_cooldown_s: float = 1.0,
                  max_actions: int = 8,
                  max_shed_shapes: int = 3,
-                 admission_factor: Optional[float] = None,
+                 admission_factor: float = 0.5,
                  wlm_cost: float = 2.0,
                  retry_after_s: float = 1.0):
-        env = os.environ
         # hard auto-release bound: an engaged action with a dead
         # evaluation loop still expires (checked lazily at admission too)
-        self.ttl_s = float(
-            ttl_s if ttl_s is not None
-            else env.get("OPENSEARCH_TPU_REMEDIATION_TTL_S", 60.0))
+        self.ttl_s = float(ttl_s)
         # release hysteresis: the alerting SLO must read ok continuously
         # this long before the action lifts (a single green tick between
         # two burn windows must not flap the actuator)
-        self.green_hold_s = float(
-            green_hold_s if green_hold_s is not None
-            else env.get("OPENSEARCH_TPU_REMEDIATION_HOLD_S", 2.0))
+        self.green_hold_s = float(green_hold_s)
         # engage hysteresis: re-alerts inside the cooldown refresh TTLs
         # instead of stacking new actions
-        self.engage_cooldown_s = float(
-            engage_cooldown_s if engage_cooldown_s is not None
-            else env.get("OPENSEARCH_TPU_REMEDIATION_COOLDOWN_S", 1.0))
+        self.engage_cooldown_s = float(engage_cooldown_s)
         self.max_actions = int(max_actions)
         self.max_shed_shapes = int(max_shed_shapes)
         # scheduler queue-cap contraction while tighten_admission holds
-        self.admission_factor = float(
-            admission_factor if admission_factor is not None
-            else env.get("OPENSEARCH_TPU_REMEDIATION_ADMISSION", 0.5))
+        self.admission_factor = float(admission_factor)
         # wlm token cost per admission while tighten_admission holds
         self.wlm_cost = float(wlm_cost)
         self.retry_after_s = float(retry_after_s)
@@ -666,6 +656,5 @@ class Remediator:
 
 
 # process-default actuator (one node per process, like METRICS/RECORDER);
-# disarmed until a Node with OPENSEARCH_TPU_REMEDIATION=1, the traffic
-# harness, or an operator arms it
+# disarmed until the traffic harness or an operator arms it
 REMEDIATOR = Remediator()

@@ -21,8 +21,8 @@ Contracts:
   composition (pow2 query padding, per-row f32 accumulation, per-query
   top-k merge), so a coalesced search serves the same pages, scores and
   tie-breaks as a direct one — the f32 tie-serve contract from
-  docs/FASTPATH.md is untouched. `SchedulerConfig.oracle` (env
-  `OPENSEARCH_TPU_SCHED_ORACLE=1`) re-runs every coalesced body through
+  docs/FASTPATH.md is untouched. `SchedulerConfig.oracle` re-runs every
+  coalesced body through
   the direct path on the dispatcher thread and counts mismatches.
 - **Graceful degradation.** Non-coalescable shapes bypass the queue
   unchanged (`accepts`); a closed scheduler, an entry still queued at
@@ -56,7 +56,7 @@ launches enter a bounded in-flight window and a completion worker thread
 performs the device sync, oracle re-check, response rendering and future
 resolution — so host assembly of batch N+1 overlaps device execution of
 batch N. `SchedulerConfig.pipeline_depth` bounds the window
-(`OPENSEARCH_TPU_PIPELINE_DEPTH`, default 2); depth 1 is byte-for-byte
+(default 2); depth 1 is byte-for-byte
 the old synchronous dispatcher (and the `JAX_PLATFORMS=cpu` oracle
 baseline). Degradation ladders extend to the new stage: a wedged
 completion worker abandons the claimed entry to direct execution on the
@@ -112,30 +112,20 @@ _QUEUED, _CLAIMED, _DONE, _ABANDONED = "queued", "claimed", "done", "abandoned"
 
 
 class SchedulerConfig:
-    """Tuning knobs (env defaults; see docs/SERVING.md for the
-    latency/throughput trade-off each one moves)."""
+    """Tuning knobs (see docs/SERVING.md for the latency/throughput
+    trade-off each one moves)."""
 
-    def __init__(self, max_batch: Optional[int] = None,
-                 max_wait_us: Optional[int] = None,
-                 queue_cap: Optional[int] = None,
-                 oracle: Optional[bool] = None,
+    def __init__(self, max_batch: int = 32,
+                 max_wait_us: int = 1000,
+                 queue_cap: int = 256,
+                 oracle: bool = False,
                  kernel_batching: bool = True,
                  request_timeout_s: float = 30.0,
                  idle_timeout_s: float = 5.0,
-                 pipeline_depth: Optional[int] = None):
-        env = os.environ
-        self.max_batch = int(max_batch if max_batch is not None
-                             else env.get("OPENSEARCH_TPU_SCHED_MAX_BATCH",
-                                          32))
-        self.max_wait_us = int(max_wait_us if max_wait_us is not None
-                               else env.get(
-                                   "OPENSEARCH_TPU_SCHED_MAX_WAIT_US", 1000))
-        self.queue_cap = int(queue_cap if queue_cap is not None
-                             else env.get("OPENSEARCH_TPU_SCHED_QUEUE_CAP",
-                                          256))
-        if oracle is None:
-            oracle = env.get("OPENSEARCH_TPU_SCHED_ORACLE",
-                             "") not in ("", "0")
+                 pipeline_depth: int = 2):
+        self.max_batch = int(max_batch)
+        self.max_wait_us = int(max_wait_us)
+        self.queue_cap = int(queue_cap)
         self.oracle = bool(oracle)
         # also coalesce mesh-declined / mesh-less bodies through the
         # fastpath's grouped kernel launches (executor.msearch_batched)
@@ -147,9 +137,7 @@ class SchedulerConfig:
         # grow without bound. Depth 1 == the synchronous dispatcher the
         # scheduler shipped with (launch+fetch on one thread) — the
         # JAX_PLATFORMS=cpu oracle baseline for pipeline parity.
-        self.pipeline_depth = int(
-            pipeline_depth if pipeline_depth is not None
-            else env.get("OPENSEARCH_TPU_PIPELINE_DEPTH", 2))
+        self.pipeline_depth = int(pipeline_depth)
         if self.max_batch < 1:
             raise ValueError("max_batch must be >= 1")
         if self.max_wait_us < 0:

@@ -3,7 +3,7 @@ dense under `_DENSE_BUCKETS` buckets, a count the product of two one-hots
 under `_PRODUCT_BUCKETS`, else a scatter) give equal arrays, dtypes
 included, for `bucket_counts`, `bucket_sums_exact` and
 `bucketed_sub_metric`; the two constants alone choose (`count_form`); and
-`compiler._agg_cost` counts `aggs.blocked.rows` / `aggs.scatter.updates` by
+`programs.agg_cost` counts `aggs.blocked.rows` / `aggs.scatter.updates` by
 the predicate the emit chooses by. A test steers the form by moving the
 constants (the program has no option for it)."""
 
@@ -13,7 +13,7 @@ import pytest
 import jax
 
 from opensearch_tpu.ops import aggs as agg_ops
-from opensearch_tpu.search import compiler as C
+from opensearch_tpu.search import programs as PG
 
 # (`_DENSE_BUCKETS`, `_PRODUCT_BUCKETS`) that give every size one form
 DENSE, PRODUCT, SCATTER = (1 << 30, 1 << 30), (0, 1 << 30), (0, 0)
@@ -323,7 +323,7 @@ def test_the_dense_ops_carry_the_sub_metric_scope():
 
 
 # ---------------------------------------------------------------------
-# `_agg_cost` counts by the predicate the emit chooses by
+# `agg_cost` counts by the predicate the emit chooses by
 # ---------------------------------------------------------------------
 N = 4096
 # "k" is laid out by value (three values a document), "k1" by document
@@ -354,7 +354,7 @@ def _spec(kind, nb, subs, form=None):
 
 def _cost(spec):
     cost = {"scatter": 0, "blocked": 0, "sub_buckets": 0}
-    C._agg_cost(spec, SEG, cost)
+    PG.agg_cost(spec, SEG, cost)
     return cost
 
 

@@ -9,7 +9,7 @@ import itertools
 import numpy as np
 import pytest
 
-from opensearch_tpu.search import compiler as C
+from opensearch_tpu.search import compiler as C, planes as PN
 
 NDOCS = 600
 T0 = 893_980_800_000                # 1998-05-01T00:00:00Z, epoch ms
@@ -170,7 +170,7 @@ def test_the_boundaries_live_and_die_with_the_plane(client):
                       for p, _m, _n, st in seg._date_bucket_cache.values())
         assert charged >= seg.ndocs_pad * 4 + (nb + 1) * 4 * has_starts
         before = LEDGER.snapshot()["tenants"]["agg_bucket_plane"]["bytes"]
-        C.drop_segment_planes(seg, "ts")
+        PN.drop_segment_planes(seg, "ts")
         assert not [k for k in seg._date_bucket_cache if k[0] == "ts"]
         after = LEDGER.snapshot()["tenants"]["agg_bucket_plane"]["bytes"]
         assert before - after == charged

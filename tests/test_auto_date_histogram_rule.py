@@ -10,7 +10,7 @@ import datetime as dt
 import numpy as np
 import pytest
 
-from opensearch_tpu.search import compiler as C
+from opensearch_tpu.search import agg_compiler as AC, planes as PN
 
 YEAR0 = int(dt.datetime(2015, 1, 1, tzinfo=dt.timezone.utc).timestamp()) * 1000
 DAY = 86_400_000
@@ -86,15 +86,15 @@ def test_the_interval_follows_the_matched_range(client, index, start, length,
     assert sum(b["doc_count"] for b in got["buckets"]) == total > 0
     # keys are the rounding's own: every bucket starts where its unit does,
     # `inner` units after the one before, from the least matched unit on
-    unit = next(u for u, r in enumerate(C.AUTO_ROUNDINGS)
+    unit = next(u for u, r in enumerate(PN.AUTO_ROUNDINGS)
                 if r[0] == interval[-1])
     inner = int(interval[:-1])
-    ids = [int(C.auto_unit_ids(k, unit)) for k in keys]
-    assert keys == [C.auto_unit_start_ms(i, unit) for i in ids]
+    ids = [int(PN.auto_unit_ids(k, unit)) for k in keys]
+    assert keys == [PN.auto_unit_start_ms(i, unit) for i in ids]
     assert ids == [ids[0] + j * inner for j in range(len(ids))]
     step = INDEXES[index][0]
     first = -(-(lo - YEAR0) // step) * step + YEAR0    # least matched value
-    assert ids[0] == int(C.auto_unit_ids(first, unit))
+    assert ids[0] == int(PN.auto_unit_ids(first, unit))
 
 
 def test_fifteen_days_inside_a_year_are_fifteen_daily_buckets(client):
@@ -173,7 +173,7 @@ def test_an_ordered_segment_still_counts_runs():
         want = np.bincount(ids[match > 0], minlength=nb + window)[
             first: first + window]
         for form in ("runs", "scatter"):
-            counts, b = C._date_bucket_counts(
+            counts, b = AC._date_bucket_counts(
                 jnp, params, "p", jnp.asarray(match), nb, form,
                 jnp.int32(first), window)
             assert np.array_equal(np.asarray(counts)[: len(want)],

@@ -24,7 +24,10 @@ import run as harness                      # noqa: E402
 
 from opensearch_tpu.ops import aggs as agg_ops         # noqa: E402
 from opensearch_tpu.search import aggregations as A    # noqa: E402
-from opensearch_tpu.search import compiler as C        # noqa: E402
+from opensearch_tpu.index.segment import next_pow2      # noqa: E402
+from opensearch_tpu.search import (agg_compiler as AC,     # noqa: E402
+                                   compiler as C, planes as PN,
+                                   programs as PG)
 
 CELL = "big5.search1.terms"
 NDOCS = 20_000
@@ -196,7 +199,7 @@ def test_three_keyword_sources_past_2_to_the_22_answer(deployments):
     sizes = [len(seg.keyword_cols[f].vocab)
              for f in ("event.id", STREAM, PROCESS)]
     assert sizes[0] * sizes[1] * sizes[2] > 1 << 22
-    before = C.AGG_STATS["composite.combinations"]
+    before = A.AGG_STATS["composite.combinations"]
     sources = [{"e": {"terms": {"field": "event.id"}}},
                {"s": {"terms": {"field": STREAM}}},
                {"p": {"terms": {"field": PROCESS}}}]
@@ -205,17 +208,17 @@ def test_three_keyword_sources_past_2_to_the_22_answer(deployments):
     assert agg["buckets"][0]["key"]["e"] == seg.keyword_cols[
         "event.id"].vocab[0]
     # the bucket space is the combinations that occur: at most the rows
-    assert 0 < C.AGG_STATS["composite.combinations"] - before <= NDOCS
+    assert 0 < A.AGG_STATS["composite.combinations"] - before <= NDOCS
     assert sum(c for _k, c in _composite_pages(client, sources, 4000)) \
         == NDOCS
 
 
 def _counted(client, body: dict) -> dict:
-    before = {k: C.AGG_STATS[k] for k in C.AGG_STATS}
+    before = {k: A.AGG_STATS[k] for k in A.AGG_STATS}
     stats = {k: C.EXECUTOR_STATS[k] for k in C.EXECUTOR_STATS}
     resp = client.search(harness.INDEX, body)
     assert "error" not in resp
-    out = {k: C.AGG_STATS[k] - v for k, v in before.items()}
+    out = {k: A.AGG_STATS[k] - v for k, v in before.items()}
     out.update({k: C.EXECUTOR_STATS[k] - v for k, v in stats.items()})
     return out
 
@@ -228,7 +231,7 @@ def test_the_counters_say_what_a_launch_counted(deployments):
     for spec in specs.values():         # planes built
         client.search(harness.INDEX, stream.twin(spec)["body"])
     nstreams = len(seg.keyword_cols[STREAM].vocab)
-    slots = C.next_pow2(nstreams)
+    slots = next_pow2(nstreams)
     assert agg_ops.count_form(slots) == "dense"     # 400 streams here
     got = _counted(client, specs["keyword-terms"]["body"])
     assert (got["terms.ordinals"], got["blocked.rows"],
@@ -239,7 +242,7 @@ def test_the_counters_say_what_a_launch_counted(deployments):
     assert got["terms.records"] == 50
     got = _counted(client, specs["multi_terms-keyword"]["body"])
     assert got["terms.records"] == 10 and got["blocked.rows"] == n
-    assert 0 < got["terms.ordinals"] <= C.next_pow2(12 * 26)
+    assert 0 < got["terms.ordinals"] <= next_pow2(12 * 26)
     got = _counted(client, specs["composite-terms"]["body"])
     assert got["terms.records"] == 10
     assert 0 < got["composite.combinations"] == got["terms.ordinals"] \
@@ -301,7 +304,7 @@ def test_agg_cost_counts_a_keyword_cardinality_under_its_form(nb, form,
     seg_arrays = {"live": np.zeros(n, np.float32), "keyword": {"k": kw}}
     cost = {"scatter": 0, "blocked": 0, "sub_buckets": 0, "ordinals": 0,
             "combinations": 0, "gathered": 0}
-    C._agg_cost(("card_kw", "p", "k", nb), seg_arrays, cost)
+    PG.agg_cost(("card_kw", "p", "k", nb), seg_arrays, cost)
     want = dict.fromkeys(cost, 0)
     want[form] = rows
     want["gathered"] = rows if by_value else 0
@@ -309,7 +312,7 @@ def test_agg_cost_counts_a_keyword_cardinality_under_its_form(nb, form,
     want.pop("gathered")
     cost = {"scatter": 0, "blocked": 0, "sub_buckets": 0, "ordinals": 0,
             "combinations": 0}
-    C._agg_cost(("composite", "p", None, nb, ()), seg_arrays, cost)
+    PG.agg_cost(("composite", "p", None, nb, ()), seg_arrays, cost)
     assert cost == dict(want, **{form: n, "ordinals": nb,
                                  "combinations": nb})
 
@@ -326,7 +329,7 @@ def test_a_request_ships_no_plane_from_the_host(deployments, shape):
     resp = client.search(harness.INDEX, spec["body"])
     assert "error" not in resp
     shipped = C.EXECUTOR_STATS["params_h2d_bytes"] - before
-    table = 4 * C.next_pow2(len(seg.keyword_cols[reference.AGENT].vocab))
+    table = 4 * next_pow2(len(seg.keyword_cols[reference.AGENT].vocab))
     assert 0 < shipped <= 256 + (table if "cardinality" in shape else 0)
     assert shipped < seg.ndocs_pad
 
@@ -342,27 +345,27 @@ def test_the_combination_planes_are_resident_and_leave_with_their_field(
         return LEDGER.snapshot()["tenants"].get("agg_bucket_plane",
                                                 {"bytes": 0})["bytes"]
     for f in (PROCESS, STREAM):
-        C.drop_segment_planes(seg, f)
+        PN.drop_segment_planes(seg, f)
     base = planes()
-    builds = C.BUCKET_PLANE_STATS["builds"]
+    builds = PN.BUCKET_PLANE_STATS["builds"]
     for shape in ("multi_terms-keyword", "composite-terms",
                   "composite_terms-keyword"):
         client.search(harness.INDEX, specs[shape]["body"])
     # three planes of a value a padded row, each built once
-    assert C.BUCKET_PLANE_STATS["builds"] - builds == 3
+    assert PN.BUCKET_PLANE_STATS["builds"] - builds == 3
     assert planes() - base == 3 * 4 * seg.ndocs_pad
-    hits = C.BUCKET_PLANE_STATS["hits"]
+    hits = PN.BUCKET_PLANE_STATS["hits"]
     for shape in ("multi_terms-keyword", "composite_terms-keyword"):
         client.search(harness.INDEX, stream.twin(specs[shape])["body"])
-    assert C.BUCKET_PLANE_STATS["builds"] - builds == 3
-    assert C.BUCKET_PLANE_STATS["hits"] > hits
+    assert PN.BUCKET_PLANE_STATS["builds"] - builds == 3
+    assert PN.BUCKET_PLANE_STATS["hits"] > hits
     keys = set(seg._combo_plane_cache)
     assert {k[0] for k in keys} == {(PROCESS, REGION),
                                     (PROCESS, REGION, STREAM)}
     # a rematerialized field takes every plane it is part of with it
-    C.drop_segment_planes(seg, STREAM)
+    PN.drop_segment_planes(seg, STREAM)
     assert planes() - base == 2 * 4 * seg.ndocs_pad
-    C.drop_segment_planes(seg, REGION)
+    PN.drop_segment_planes(seg, REGION)
     assert planes() == base and not seg._combo_plane_cache
     # and the next request builds anew, the answer the same
     _search(client, reference.agg_body("multi_terms-keyword"), SPAN[0] + 7)
@@ -422,12 +425,12 @@ def test_segments_merge_by_vocabulary_array_to_array(three_segments):
                   "composite_terms-keyword"):
         spec = dict(spec, shape=shape)
         body = {"size": 0, "aggs": reference.agg_body(shape)}
-        before = C.AGG_STATS["terms.records"]
+        before = A.AGG_STATS["terms.records"]
         resp = client.search("logs", body)
         got = reference.compare(spec, resp, ref.answer(spec))
         assert not any(got.values()), (shape, got)
         # three partials, and still no record a vocabulary entry
-        assert C.AGG_STATS["terms.records"] - before <= (
+        assert A.AGG_STATS["terms.records"] - before <= (
             500 if shape == "keyword-terms" else 30)
     # a metric under the buckets rides the arrays too
     resp = client.search("logs", {"size": 0, "aggs": {"s": {
@@ -451,7 +454,7 @@ def test_a_merged_cardinality_holds_within_three_standard_errors(
             "c": {"cardinality": {"field": field}}}})
         value = resp["aggregations"]["c"]["value"]
         assert abs(value - exact) <= max(
-            3 * 1.04 / np.sqrt(1 << C.HLL_LOG2M) * exact, 0.5), field
+            3 * 1.04 / np.sqrt(1 << AC.HLL_LOG2M) * exact, 0.5), field
 
 
 def test_ordinal_buckets_merge_and_finalize_like_records():
@@ -483,9 +486,9 @@ def test_an_array_partial_crosses_a_process_boundary():
     """`cluster/distnode.py` pickles a shard's partials: the arrays, and a
     `ComboSpace` as their keys, come back whole."""
     import pickle
-    space = C.ComboSpace(np.asarray([0, 3, 4, 7], np.int64), [2, 4],
-                         (True, False), [("terms", ["a", "b"]),
-                                         ("terms", ["w", "x", "y", "z"])])
+    space = PN.ComboSpace(np.asarray([0, 3, 4, 7], np.int64), [2, 4],
+                          (True, False), [("terms", ["a", "b"]),
+                                          ("terms", ["w", "x", "y", "z"])])
     ob = A.OrdinalBuckets(space, np.asarray([5, 0, 2, 1]), {})
     back = pickle.loads(pickle.dumps({"buckets": ob}))["buckets"]
     assert list(back.keys) == list(space) == [

@@ -19,8 +19,7 @@ from opensearch_tpu.index.mappings import Mappings
 from opensearch_tpu.index.merge import merge_segments
 from opensearch_tpu.index.segment import (CODEC_V1, CODEC_V2, IMPACT_BLOCK,
                                           ImpactPlane, Segment,
-                                          build_impact_plane, build_segment,
-                                          default_codec_version)
+                                          build_impact_plane, build_segment)
 from opensearch_tpu.ops.device_merge import quantize_impacts
 from opensearch_tpu.ops.scoring import dequant_impact_np
 from opensearch_tpu.rest.client import RestClient
@@ -38,6 +37,14 @@ def _mk_docs(m, rng, n, vocab=50, lo=3, hi=40, prefix=""):
 
 def _mappings():
     return Mappings({"properties": {"body": {"type": "text"}}})
+
+
+def _v1_segment(name, docs, m):
+    """What a commit from before the format rev loads as: nothing builds
+    codec v1 any more, so a built segment is demoted."""
+    seg = build_segment(name, docs, m)
+    seg.drop_impacts()
+    return seg
 
 
 def _client(nshards=1):
@@ -144,14 +151,12 @@ class TestPersistenceAndCompat:
         assert (ip2.scale, ip2.bits, ip2.avgdl) == (ip.scale, ip.bits,
                                                     ip.avgdl)
 
-    def test_v1_segment_loads_and_has_no_plane(self, tmp_path, monkeypatch):
-        monkeypatch.setenv("OPENSEARCH_TPU_CODEC", "1")
+    def test_v1_segment_loads_and_has_no_plane(self, tmp_path):
         m = _mappings()
         rng = np.random.default_rng(5)
-        seg = build_segment("_0", _mk_docs(m, rng, 80), m)
+        seg = _v1_segment("_0", _mk_docs(m, rng, 80), m)
         assert seg.codec_version == CODEC_V1
         seg.save(str(tmp_path / "s"))
-        monkeypatch.delenv("OPENSEARCH_TPU_CODEC")
         seg2 = Segment.load(str(tmp_path / "s"))
         assert seg2.codec_version == CODEC_V1
         assert seg2.postings["body"].impact is None
@@ -161,12 +166,9 @@ class TestPersistenceAndCompat:
         assert "impacts" not in arrs["postings"]["body"]
         seg2.drop_device()
 
-    def test_pre_rev_meta_without_codec_key_loads_as_v1(self, tmp_path,
-                                                        monkeypatch):
-        monkeypatch.setenv("OPENSEARCH_TPU_CODEC", "1")
+    def test_pre_rev_meta_without_codec_key_loads_as_v1(self, tmp_path):
         m = _mappings()
-        seg = build_segment("_0", _mk_docs(m, np.random.default_rng(6), 20),
-                            m)
+        seg = _v1_segment("_0", _mk_docs(m, np.random.default_rng(6), 20), m)
         seg.save(str(tmp_path / "s"))
         meta_path = tmp_path / "s" / "meta.json"
         meta = json.loads(meta_path.read_text())
@@ -176,12 +178,10 @@ class TestPersistenceAndCompat:
         seg2 = Segment.load(str(tmp_path / "s"))
         assert seg2.codec_version == CODEC_V1
 
-    def test_v1_plus_v2_merge_yields_v2(self, monkeypatch):
+    def test_v1_plus_v2_merge_yields_v2(self):
         m = _mappings()
         rng = np.random.default_rng(7)
-        monkeypatch.setenv("OPENSEARCH_TPU_CODEC", "1")
-        v1 = build_segment("_0", _mk_docs(m, rng, 60, prefix="a"), m)
-        monkeypatch.delenv("OPENSEARCH_TPU_CODEC")
+        v1 = _v1_segment("_0", _mk_docs(m, rng, 60, prefix="a"), m)
         v2 = build_segment("_1", _mk_docs(m, rng, 60, prefix="b"), m)
         assert (v1.codec_version, v2.codec_version) == (CODEC_V1, CODEC_V2)
         merged = merge_segments("_m0", [v1, v2])
@@ -193,20 +193,16 @@ class TestPersistenceAndCompat:
         st = merged.text_stats["body"]
         assert ip.avgdl == pytest.approx(st.sum_dl / st.doc_count)
 
-    def test_all_v1_merge_stays_v1_when_pinned(self, monkeypatch):
-        monkeypatch.setenv("OPENSEARCH_TPU_CODEC", "1")
+    def test_all_v1_merge_yields_v2(self):
+        """A merge is where an index written before the format rev is
+        upgraded: no input has a plane, the output has one."""
         m = _mappings()
         rng = np.random.default_rng(8)
-        a = build_segment("_0", _mk_docs(m, rng, 30, prefix="a"), m)
-        b = build_segment("_1", _mk_docs(m, rng, 30, prefix="b"), m)
+        a = _v1_segment("_0", _mk_docs(m, rng, 30, prefix="a"), m)
+        b = _v1_segment("_1", _mk_docs(m, rng, 30, prefix="b"), m)
         merged = merge_segments("_m0", [a, b])
-        assert merged.codec_version == CODEC_V1
-        assert merged.postings["body"].impact is None
-
-    def test_default_codec_env(self, monkeypatch):
-        assert default_codec_version() == CODEC_V2
-        monkeypatch.setenv("OPENSEARCH_TPU_CODEC", "1")
-        assert default_codec_version() == CODEC_V1
+        assert merged.codec_version == CODEC_V2
+        assert merged.postings["body"].impact is not None
 
 
 def _hits(resp):

@@ -129,14 +129,14 @@ class TestProfilePlanTree:
 
 class TestCanMatchBreadth:
     def test_new_kinds(self, client):
-        from opensearch_tpu.search import compiler as C
+        from opensearch_tpu.search import compiler as C, plan as PL
         from opensearch_tpu.search import query_dsl as dsl
         svc = client.node.get_index("d")
         seg = svc.shards[0].segments[0]
-        ctx = C.ShardContext(svc.mappings, [seg], svc.default_sim, {})
+        ctx = PL.ShardContext(svc.mappings, [seg], svc.default_sim, {})
 
         def cm(q):
-            return C.can_match(C.rewrite(dsl.parse_query(q), ctx, True), seg)
+            return C.can_match(PL.rewrite(dsl.parse_query(q), ctx, True), seg)
 
         assert cm({"exists": {"field": "txt"}})
         assert not cm({"exists": {"field": "ghost"}})

@@ -1,7 +1,8 @@
 """`docs/OPTIONS.md` held to the package: every `OPENSEARCH_TPU_*` name the
 package reads has a row (default, sort, and for a path switch who sets it
-today), the table names nothing the package does not read, and the count
-does not grow unseen (ROADMAP D3). No JAX: the package is read as text."""
+today), the table names nothing the package does not read, the count
+does not grow unseen, and a name the document lists as retired is not read
+again (ROADMAP D3). No JAX: the package is read as text."""
 
 import os
 import re
@@ -12,7 +13,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 NAME = re.compile(r"OPENSEARCH_TPU_[A-Z0-9_]*")
 SORTS = {"deployment setting", "path switch", "observability toggle",
          "test hook"}
-CEILING = 46        # the count when the table was made (PR 30)
+CEILING = 15        # 46 when the table was made (PR 30); PR 46 retired 31
 
 
 def _package_names() -> set:
@@ -36,8 +37,20 @@ def _table() -> dict:
     return rows
 
 
+def _retired() -> dict:
+    """name -> what its value is now, from the rows under "Retired"."""
+    rows = {}
+    with open(os.path.join(ROOT, "docs", "OPTIONS.md")) as fh:
+        for line in fh:
+            cells = [c.strip() for c in line.strip().strip("|").split("|")]
+            if len(cells) == 2 and NAME.fullmatch(cells[0].strip("`")):
+                rows[cells[0].strip("`")] = cells[1]
+    return rows
+
+
 READ = sorted(_package_names())
 TABLE = _table()
+RETIRED = _retired()
 
 
 @pytest.mark.parametrize("name", READ)
@@ -50,11 +63,19 @@ def test_an_option_the_package_reads_is_in_the_table(name):
         assert set_by, f"{name}: a path switch's row says who sets it"
 
 
+@pytest.mark.parametrize("name", sorted(RETIRED))
+def test_a_retired_option_is_not_read_again(name):
+    assert name not in READ, f"{name} was retired (docs/OPTIONS.md): the " \
+                             f"package reads it again"
+    assert name not in TABLE and RETIRED[name]
+
+
 def test_the_table_names_nothing_the_package_does_not_read():
     assert sorted(set(TABLE) - set(READ)) == []
 
 
 def test_the_count_does_not_grow_unseen():
+    assert len(RETIRED) == 31
     assert len(READ) <= CEILING, \
         f"{len(READ)} options: a new one needs a decision, not a row " \
         f"(simplicity-review, Options; ROADMAP D3)"

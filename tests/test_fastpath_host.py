@@ -10,7 +10,7 @@ from opensearch_tpu.index.engine import Engine
 from opensearch_tpu.index.mappings import Mappings
 from opensearch_tpu.ops.pallas_bm25 import (DL_BITS, DL_MASK, HBM_ALIGN,
                                             LANES, align_csr_rows)
-from opensearch_tpu.search import compiler as C
+from opensearch_tpu.search import plan as PL
 from opensearch_tpu.search import fastpath
 from opensearch_tpu.search import query_dsl as dsl
 from opensearch_tpu.search.executor import ShardSearcher
@@ -35,8 +35,8 @@ def seg_ctx():
 
 def _lterms(ctx, text, field="body"):
     q = dsl.parse_query({"match": {field: text}})
-    node = C.rewrite(q, ctx, scoring=True)
-    assert isinstance(node, C.LTerms)
+    node = PL.rewrite(q, ctx, scoring=True)
+    assert isinstance(node, PL.LTerms)
     return node
 
 
@@ -149,7 +149,7 @@ class TestEligibility:
 
 def _spec(ctx, qbody, **kw):
     q = dsl.parse_query(qbody)
-    node = C.rewrite(q, ctx, scoring=True)
+    node = PL.rewrite(q, ctx, scoring=True)
     return fastpath.make_spec(node, kw.get("sort", []), kw.get("aggs", []),
                               kw.get("named", []), kw.get("after"),
                               kw.get("window", 10), kw.get("body", {}))
@@ -251,7 +251,7 @@ class TestBoolSpec:
     def test_filter_list_build(self, seg_ctx):
         seg, ctx = seg_ctx
         q = dsl.parse_query({"term": {"body": "common"}})
-        node = C.rewrite(q, ctx, scoring=False)
+        node = PL.rewrite(q, ctx, scoring=False)
         fl = fastpath._filter_list(seg, ctx, [(node, False)])
         assert fl is not None
         pb = seg.postings["body"]

@@ -312,11 +312,14 @@ class TestQueryCost:
         assert cost["predicted_vs_actual_pct"] == pytest.approx(
             100.0 * 48 / 12288, abs=0.01)
 
-    def test_profile_cost_v1_oracle(self, monkeypatch):
-        """The legacy codec keeps the 8-byte slot model and the XLA
-        bucket-gather actuals."""
-        monkeypatch.setenv("OPENSEARCH_TPU_CODEC", "1")
+    def test_profile_cost_v1_oracle(self):
+        """The legacy codec (what a commit from before the format rev
+        loads as) keeps the 8-byte slot model and the XLA bucket-gather
+        actuals."""
         c = self._fixed_corpus()
+        for sh in c.node.indices["hbmt"].shards:
+            for seg in sh.segments:
+                seg.drop_impacts()
         r = c.search("hbmt", {"query": {"match": {
             "body": "alpha beta gamma"}}, "profile": True})
         cost = r["profile"]["cost"]

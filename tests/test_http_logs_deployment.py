@@ -20,7 +20,8 @@ import http_logs_reference as reference    # noqa: E402
 import run as harness                      # noqa: E402
 
 from opensearch_tpu.ops import aggs as agg_ops         # noqa: E402
-from opensearch_tpu.search import compiler as C        # noqa: E402
+from opensearch_tpu.search import (agg_compiler as AC, compiler as C,
+                                   planes as PN)        # noqa: E402
 
 CELL = "httplogs.search1.dashboard"
 NDOCS = 20_000
@@ -88,8 +89,8 @@ def test_planes_are_built_once_and_attributed(deployments):
     seg = client.node.indices[harness.INDEX].shards[0].segments[0]
     specs = {s["shape"]: s for s in stream.take(8)}
     for name, stats, shape in (
-            ("_date_bucket_cache", C.BUCKET_PLANE_STATS, "hourly_agg"),
-            ("_sort_dev_cache", C.RANK_PLANE_STATS, "desc_sort_size")):
+            ("_date_bucket_cache", PN.BUCKET_PLANE_STATS, "hourly_agg"),
+            ("_sort_dev_cache", PN.RANK_PLANE_STATS, "desc_sort_size")):
         for _ in range(2):
             client.search(harness.INDEX, stream.twin(specs[shape])["body"])
         b0, h0 = stats["builds"], stats["hits"]
@@ -101,7 +102,7 @@ def test_planes_are_built_once_and_attributed(deployments):
     assert tenants["sort_rank_plane"]["bytes"] >= seg.ndocs_pad * 4
     # a rematerialized field drops its planes and their bytes
     before = tenants["sort_rank_plane"]["bytes"]
-    C.drop_segment_planes(seg, "size")
+    PN.drop_segment_planes(seg, "size")
     assert ("size",) not in seg._sort_dev_cache
     after = LEDGER.snapshot()["tenants"]["sort_rank_plane"]["bytes"]
     assert before - after == seg.ndocs_pad * 4
@@ -145,14 +146,14 @@ def _edges() -> np.ndarray:
 def test_calendar_bucket_ids_equal_the_per_row_walk(calendar, alias):
     ms = _edges()
     want = np.asarray([_oracle(v, calendar) for v in ms], np.int64)
-    got = C._calendar_bucket_ids(ms, calendar)
+    got = PN.calendar_bucket_ids(ms, calendar)
     assert got.dtype == np.int64 and np.array_equal(got, want)
-    assert np.array_equal(C._calendar_bucket_ids(ms, alias), want)
+    assert np.array_equal(PN.calendar_bucket_ids(ms, alias), want)
 
 
 def test_an_unknown_calendar_interval_is_an_error():
     with pytest.raises(ValueError, match="unknown calendar_interval"):
-        C._calendar_bucket_ids(np.zeros(3, np.int64), "fortnight")
+        PN.calendar_bucket_ids(np.zeros(3, np.int64), "fortnight")
 
 
 # ---------------------------------------------------------------------
@@ -189,7 +190,7 @@ def _bare(kind: str, form: str = "scatter"):
             "terms": ("terms", "a0", "f", 16, ()),
             "range": ("range", "a0", "f", ("lo", "hi"), True, (),
                       ((-1.0, 5.0), (5.0, 9.0)))}[kind]
-    out = jax.jit(lambda s, p, m: C.emit_agg(spec, s, p, m))(
+    out = jax.jit(lambda s, p, m: AC.emit_agg(spec, s, p, m))(
         seg_arrays, params, live)
     return np.asarray(out["counts"])
 

@@ -1,7 +1,7 @@
 """Parent-child join tests. Reference semantics: modules/parent-join
 (ParentJoinFieldMapper, HasChildQueryBuilder, HasParentQueryBuilder,
 ParentIdQueryBuilder, inner hits). Ours: shard-global slot space + two-pass
-device scatter/gather (search/join.py, compiler LHasChild/LHasParent)."""
+device scatter/gather (search/join.py, plan LHasChild/LHasParent)."""
 
 import pytest
 

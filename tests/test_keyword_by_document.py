@@ -14,8 +14,10 @@ import numpy as np
 import pytest
 
 from opensearch_tpu.index import segment as segment_mod
+from opensearch_tpu.index.segment import next_pow2
 from opensearch_tpu.ops import aggs as agg_ops
-from opensearch_tpu.search import compiler as C
+from opensearch_tpu.search import (agg_compiler as AC, aggregations as AGG,
+                                   compiler as C, planes as PN)
 
 NDOCS = 6000                    # pads to 8,192 rows
 FIELDS = {"few": 30, "many": 3000}      # values: the dense form, a scatter
@@ -130,9 +132,9 @@ def test_a_single_valued_column_is_counted_by_document(
     body = {"size": 0, "query": QUERY, "aggs": {"a": AGGS[kind](field)}}
     kw = seg.device_arrays()["keyword"][field]
     assert set(kw) == {"min_ord"} and not agg_ops.counts_by_value(kw)
-    before = C.AGG_STATS["terms.gathered_rows"]
+    before = AGG.AGG_STATS["terms.gathered_rows"]
     got = client.search("t", body)["aggregations"]["a"]
-    assert C.AGG_STATS["terms.gathered_rows"] == before
+    assert AGG.AGG_STATS["terms.gathered_rows"] == before
     # the same segment through the by-value form: the same response
     _by_value(seg, monkeypatch)
     try:
@@ -141,7 +143,7 @@ def test_a_single_valued_column_is_counted_by_document(
         # (another body to the request cache, the same request)
         forced = client.search("t", dict(body, **{"from": 0}))[
             "aggregations"]["a"]
-        assert C.AGG_STATS["terms.gathered_rows"] - before \
+        assert AGG.AGG_STATS["terms.gathered_rows"] - before \
             == kw["ords"].shape[0]
     finally:
         monkeypatch.undo()
@@ -184,7 +186,7 @@ def test_the_two_forms_give_equal_arrays(one_segment, op, field):
     assert agg_ops.counts_by_value(by_val)
     assert agg_ops.group_by_rows(by_doc) == seg.ndocs_pad
     assert agg_ops.group_by_rows(by_val) == by_val["ords"].shape[0]
-    nb = C.next_pow2(FIELDS[field])
+    nb = next_pow2(FIELDS[field])
     assert (agg_ops.count_form(nb) == "dense") == (field == "few")
     # the mask as the program hands it over: live documents that match,
     # and (the program never does) every padded row, which no id holds
@@ -202,7 +204,7 @@ def test_the_two_forms_give_equal_arrays(one_segment, op, field):
             kw, match, num["f32"], num["present"], nb, inv, True),
         "value_count": lambda kw: agg_ops.value_count_keyword(kw, match),
         "cardinality": lambda kw: agg_ops.cardinality_keyword_registers(
-            kw, match, nb, hashes, C.HLL_LOG2M),
+            kw, match, nb, hashes, AC.HLL_LOG2M),
     }[op]
     a, b = jax.jit(fn)(by_doc), jax.jit(fn)(by_val)
     for x, y in zip(jax.tree_util.tree_leaves(a),
@@ -265,17 +267,17 @@ def test_a_multi_valued_column_still_gathers(structures):
     assert "doc_of_value" in kw and agg_ops.counts_by_value(kw)
     # `many` holds one value a document in both
     assert set(multi.device_arrays()["keyword"]["many"]) == {"min_ord"}
-    before = C.AGG_STATS["terms.gathered_rows"]
+    before = AGG.AGG_STATS["terms.gathered_rows"]
     two.search("two", {"size": 0, "aggs": {
         "a": {"terms": {"field": "few"}},
         "n": {"value_count": {"field": "few"}},
         "m": {"terms": {"field": "many"}}}})
     # the multi-valued segment's `terms` and its value count, nothing else
-    assert C.AGG_STATS["terms.gathered_rows"] - before \
+    assert AGG.AGG_STATS["terms.gathered_rows"] - before \
         == 2 * kw["ords"].shape[0] > 0
     # a rematerialized column is observed again
     multi.__dict__["_kw_multi_cache"]["few"] = False
-    C.drop_segment_planes(multi, "few")
+    PN.drop_segment_planes(multi, "few")
     assert multi.kw_multi_valued("few")
 
 

@@ -18,7 +18,7 @@ import pytest
 from opensearch_tpu.ops import aggs as agg_ops
 from opensearch_tpu.ops import scoring as ops
 from opensearch_tpu.ops.rescore import exact_rescore_batch
-from opensearch_tpu.search import compiler as C
+from opensearch_tpu.search import compiler as C, programs as PG
 from opensearch_tpu.search import fastpath
 from opensearch_tpu.utils.metrics import METRICS
 from opensearch_tpu.utils.trace import TRACER
@@ -300,7 +300,7 @@ def test_the_executor_programs_stages(client, monkeypatch):
     arguments it was called with: equal op for op without the scopes, and
     every stage of PERF.md section 3 on its ops' paths with them."""
     calls = []
-    build = C._build_executor
+    build = PG._build_executor
 
     def spy(full_spec):
         prog = build(full_spec)
@@ -309,13 +309,13 @@ def test_the_executor_programs_stages(client, monkeypatch):
             calls.append((full_spec, a))
             return prog(*a)
         return call
-    monkeypatch.setattr(C, "_build_executor", spy)
+    monkeypatch.setattr(PG, "_build_executor", spy)
     client.search("launches", REQUESTS["aggregation"](31))
     client.search("launches", dict(REQUESTS["executor"](32), size=3))
     assert len(calls) == 2
     stages = set()
     for full_spec, a in calls:
-        scoped, bare, debug = _texts(C._executor_run_fn(full_spec), *a)
+        scoped, bare, debug = _texts(PG._executor_run_fn(full_spec), *a)
         assert scoped == bare
         stages |= {s for s in ("executor.match", "executor.sort_key",
                                "executor.topk", "executor.total",

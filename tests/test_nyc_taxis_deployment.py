@@ -20,7 +20,7 @@ import nyc_taxis_reference as reference    # noqa: E402
 import run as harness                      # noqa: E402
 
 from opensearch_tpu.ops import aggs as agg_ops         # noqa: E402
-from opensearch_tpu.search import compiler as C        # noqa: E402
+from opensearch_tpu.search import aggregations as AGG, compiler as C        # noqa: E402
 
 CELL = "nyctaxis.search1.analyst"
 NDOCS = 6_000
@@ -91,11 +91,11 @@ def test_all_eighteen_fields_are_in_the_mapping_and_the_segment(deployments):
 
 
 def _counted(client, spec) -> dict:
-    before = {k: C.AGG_STATS[k] for k in C.AGG_STATS}
+    before = {k: AGG.AGG_STATS[k] for k in AGG.AGG_STATS}
     launches = C.EXECUTOR_STATS["launches"]
     resp = client.search(harness.INDEX, spec["body"])
     assert "error" not in resp
-    out = {k: C.AGG_STATS[k] - v for k, v in before.items()}
+    out = {k: AGG.AGG_STATS[k] - v for k, v in before.items()}
     out["launches"] = C.EXECUTOR_STATS["launches"] - launches
     return out
 

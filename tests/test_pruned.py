@@ -12,7 +12,7 @@ import pytest
 from opensearch_tpu.index.engine import Engine
 from opensearch_tpu.index.mappings import Mappings
 from opensearch_tpu.ops.pallas_bm25 import DL_BITS, DL_MASK, LANES
-from opensearch_tpu.search import compiler as C
+from opensearch_tpu.search import plan as PL
 from opensearch_tpu.search import fastpath
 from opensearch_tpu.search import query_dsl as dsl
 from opensearch_tpu.search.executor import ShardSearcher
@@ -135,7 +135,7 @@ def corpus():
 
 def _spec(ctx, body_query, window=10, body=None):
     q = dsl.parse_query(body_query)
-    node = C.rewrite(q, ctx, scoring=True)
+    node = PL.rewrite(q, ctx, scoring=True)
     return fastpath.make_spec(node, [], [], [], None, window, body or {})
 
 

@@ -19,7 +19,7 @@ from opensearch_tpu.ops import rescore
 from opensearch_tpu.ops.rescore import (exact_rescore_batch,
                                         host_exact_rescore_batch,
                                         probe_rounds)
-from opensearch_tpu.search import compiler as C
+from opensearch_tpu.search import compiler as C, plan as PL
 from opensearch_tpu.search import fastpath
 from opensearch_tpu.search import query_dsl as dsl
 from opensearch_tpu.search.executor import ShardSearcher
@@ -226,7 +226,7 @@ def small_head(monkeypatch):
 
 
 def _spec(ctx, q, window):
-    node = C.rewrite(dsl.parse_query(q), ctx, scoring=True)
+    node = PL.rewrite(dsl.parse_query(q), ctx, scoring=True)
     return fastpath.make_spec(node, [], [], [], None, window, {})
 
 
@@ -251,7 +251,7 @@ class TestOracleParity:
         prune = [True] * len(QUERIES)
         lts = []
         for q, _w in QUERIES:
-            node = C.rewrite(dsl.parse_query(q), ctx, scoring=True)
+            node = PL.rewrite(dsl.parse_query(q), ctx, scoring=True)
             lts.append(node)
         vq_lists = fastpath._prepare_vqueries(seg, ctx, lts, {}, prune)
         jobs = []
@@ -350,8 +350,8 @@ class TestOracleParity:
         pb = seg.postings["body"]
 
         def jobs_of(*texts):
-            nodes = [C.rewrite(dsl.parse_query({"match": {"body": t}}), ctx,
-                               scoring=True) for t in texts]
+            nodes = [PL.rewrite(dsl.parse_query({"match": {"body": t}}), ctx,
+                                scoring=True) for t in texts]
             vqs = fastpath._prepare_vqueries(seg, ctx, nodes, {},
                                              [True] * len(nodes))
             return [(v[0], fastpath._p2_candidates(v[0], pb,

@@ -1,7 +1,7 @@
 """`ops.aggs.run_counts`: per-bucket counts of a plane whose ids are
 non-decreasing in row order, read as differences of prefix sums at the
 runs' boundaries, against `np.bincount` and the scatter-add
-(`bucket_counts`) it stands in for; and `compiler._run_starts`, which
+(`bucket_counts`) it stands in for; and `planes.run_starts`, which
 observes the order and places the boundaries."""
 
 import numpy as np
@@ -11,7 +11,7 @@ import jax
 import jax.numpy as jnp
 
 from opensearch_tpu.ops import aggs as agg_ops
-from opensearch_tpu.search import compiler as C
+from opensearch_tpu.search import planes as PN
 
 
 def _ids(kind: str, n: int, nb: int, rng) -> np.ndarray:
@@ -47,7 +47,7 @@ def _mask(kind: str, n: int, rng) -> np.ndarray:
 def test_run_counts_equal_bincount_and_the_scatter_add(n, nb, ids_kind, mask):
     rng = np.random.default_rng([n, nb, len(ids_kind), len(mask)])
     ids, m = _ids(ids_kind, n, nb, rng), _mask(mask, n, rng)
-    starts = C._run_starts(ids, nb, n)
+    starts = PN.run_starts(ids, nb, n)
     assert starts is not None and starts.dtype == np.int32
     assert starts.shape == (nb + 1,) and starts[-1] == n
     assert (np.diff(starts) >= 0).all()
@@ -110,7 +110,7 @@ def test_where_no_cut_can_be_built_the_scatter_add_counts(n, nb):
     pre = np.concatenate([[0], np.cumsum(w)])
     assert got.dtype == np.int32
     assert np.array_equal(got, np.diff(pre[starts]))
-    assert C._run_starts(ids, nb, n) is None
+    assert PN.run_starts(ids, nb, n) is None
 
 
 @pytest.mark.parametrize("ids,want", [
@@ -124,7 +124,7 @@ def test_run_starts_observes_the_order_of_the_rows_that_have_a_value(ids,
                                                                      want):
     ids = np.asarray(ids, np.int32)
     nb = int(ids.max()) + 1
-    got = C._run_starts(ids, nb, 1024)
+    got = PN.run_starts(ids, nb, 1024)
     if want is None:
         assert got is None
     else:

@@ -735,14 +735,13 @@ class TestBreakerFolding:
     segment GC (the two retired OSL301 baseline entries)."""
 
     def test_device_arrays_charges_and_releases(self):
-        from opensearch_tpu.index import segment as segmod
         from opensearch_tpu.index.engine import Engine
         from opensearch_tpu.index.mappings import Mappings
         from opensearch_tpu.utils.breaker import CircuitBreaker
         br = CircuitBreaker("fielddata-test", 1 << 30)
         from opensearch_tpu.obs.hbm_ledger import LEDGER
         old = LEDGER.breaker
-        segmod.set_breaker(br)     # shim -> LEDGER.set_breaker (OSL506)
+        LEDGER.set_breaker(br)
         try:
             eng = Engine(Mappings({"properties": {
                 "body": {"type": "text"}}}))
@@ -762,18 +761,17 @@ class TestBreakerFolding:
             gc.collect()
             assert br.used == 0
         finally:
-            segmod.set_breaker(old)
+            LEDGER.set_breaker(old)
 
     def test_nested_sort_values_charge(self):
-        from opensearch_tpu.index import segment as segmod
-        from opensearch_tpu.search import compiler as C
+        from opensearch_tpu.search import planes as PN
         from opensearch_tpu.index.engine import Engine
         from opensearch_tpu.index.mappings import Mappings
         from opensearch_tpu.utils.breaker import CircuitBreaker
         br = CircuitBreaker("fielddata-test", 1 << 30)
         from opensearch_tpu.obs.hbm_ledger import LEDGER
         old = LEDGER.breaker
-        segmod.set_breaker(br)     # shim -> LEDGER.set_breaker (OSL506)
+        LEDGER.set_breaker(br)
         try:
             eng = Engine(Mappings({"properties": {
                 "items": {"type": "nested", "properties": {
@@ -783,12 +781,12 @@ class TestBreakerFolding:
             eng.refresh()
             seg = eng.segments[0]
             before = br.used
-            vals, present = C._nested_sort_values(seg, "items.qty",
+            vals, present = PN.nested_sort_values(seg, "items.qty",
                                                   "items", "min")
             assert vals is not None
             assert br.used > before
             charged = br.used
-            C._nested_sort_values(seg, "items.qty", "items", "min")
+            PN.nested_sort_values(seg, "items.qty", "items", "min")
             assert br.used == charged         # cache hit: no re-charge
             del seg, vals, present
             eng.close()
@@ -796,4 +794,4 @@ class TestBreakerFolding:
             gc.collect()
             assert br.used == before
         finally:
-            segmod.set_breaker(old)
+            LEDGER.set_breaker(old)

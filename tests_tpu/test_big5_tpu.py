@@ -161,7 +161,7 @@ def test_the_seven_shapes_through_the_client_at_a_million_events():
     import big5_reference as reference
     import run as harness
     from opensearch_tpu.rest.client import RestClient
-    from opensearch_tpu.search import compiler as C
+    from opensearch_tpu.search import aggregations as AGG, compiler as C
     kind = harness.load_kind("big5")
     loaded = harness.load_cell("big5.search1.terms")
     config = dict(loaded["config"], ndocs=1 << 20)
@@ -171,7 +171,7 @@ def test_the_seven_shapes_through_the_client_at_a_million_events():
     specs = stream.take(14)
     for s in specs:                     # compile, build the planes
         client.search(harness.INDEX, stream.twin(s)["body"])
-    before = {k: C.AGG_STATS[k] for k in C.AGG_STATS}
+    before = {k: AGG.AGG_STATS[k] for k in AGG.AGG_STATS}
     h2d = C.EXECUTOR_STATS["params_h2d_bytes"]
     held, times = [], {}
     for s in specs:
@@ -183,7 +183,7 @@ def test_the_seven_shapes_through_the_client_at_a_million_events():
     out = reference.hold(held, kind.reference_of(built))
     print("compared", out["numbers"])
     assert out["correct"] is True and out["compared"] == 14
-    got = {k: C.AGG_STATS[k] - v for k, v in before.items()}
+    got = {k: AGG.AGG_STATS[k] - v for k, v in before.items()}
     n = built["readout"]["rows_padded"]
     # every keyword is its ordinals by document: nothing gathered by value
     (seg,) = client.node.indices[harness.INDEX].shards[0].segments

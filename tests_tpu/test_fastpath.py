@@ -86,14 +86,14 @@ def test_chunked_oversized_rows(client):
     fastpath.MAX_L, fastpath.MAX_TL = 1 << 11, 1 << 12
     try:
         # prove the decomposition actually engages at these caps
-        from opensearch_tpu.search import compiler as C
+        from opensearch_tpu.search import plan as PL
         from opensearch_tpu.search import query_dsl as dsl
         from opensearch_tpu.search.executor import ShardSearcher
         eng = client.node.indices["idx"].shards[0]
         s = ShardSearcher(eng)
         ctx = s.context()
-        lt = C.rewrite(dsl.parse_query({"match": {"body": "common w17"}}),
-                       ctx, scoring=True)
+        lt = PL.rewrite(dsl.parse_query({"match": {"body": "common w17"}}),
+                        ctx, scoring=True)
         vls = fastpath._prepare_vqueries(eng.segments[0], ctx, [lt], {})
         assert vls[0] is not None and len(vls[0]) >= 2
         body = {"query": {"match": {"body": "common w17"}}, "size": 10,

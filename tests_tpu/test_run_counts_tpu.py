@@ -13,7 +13,7 @@ import jax
 import jax.numpy as jnp
 
 from opensearch_tpu.ops import aggs as agg_ops
-from opensearch_tpu.search import compiler as C
+from opensearch_tpu.search import planes as PN
 
 pytestmark = pytest.mark.skipif(jax.default_backend() != "tpu",
                                 reason="needs a real TPU chip")
@@ -29,7 +29,7 @@ def plane():
     ids = np.full(N, -1, np.int32)
     ids[:NDOCS] = np.searchsorted(cuts, np.arange(NDOCS), side="right")
     ids[:NDOCS][rng.random(NDOCS) < 0.01] = -1      # rows without a value
-    starts = C._run_starts(ids[:NDOCS], NB, N)
+    starts = PN.run_starts(ids[:NDOCS], NB, N)
     assert starts is not None and starts[-1] == NDOCS
     return ids, jnp.asarray(ids), jnp.asarray(starts)
 
