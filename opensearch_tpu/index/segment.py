@@ -637,6 +637,9 @@ class Segment:
         # replicas re-host the SAME immutable arrays on their own device
         # (segment replication, reference indices/replication/)
         self._device_cache: Dict[Any, dict] = {}
+        # the positional planes of the text fields, by the same key, beside
+        # the pytree (`device_positions`); swapped with it
+        self._device_positions: Dict[Any, dict] = {}
         self._device_live_dirty: Dict[Any, bool] = {}
         # segment codec (CODEC_V1 | CODEC_V2): consumers branching on the
         # posting layout consult this attribute (oslint OSL507)
@@ -900,6 +903,14 @@ class Segment:
             "postings": post, "numeric": ncols, "keyword": kcols, "geo": gcols,
             "vector": vcols, "doc_lens": dls, "nested": nst,
         }
+        # the positional planes of the fields that hold positions: resident
+        # with the segment, beside the pytree and not in it (a phrase
+        # program is handed its field's planes by `compiler.prepare`; no
+        # other program's signature knows them)
+        pos_planes = {f: _position_planes(pb, jnp)
+                      for f, pb in self.postings.items()
+                      if pb.positions is not None and len(pb.positions)}
+        self._device_positions[key] = pos_planes
         # attributed only while a refresh/merge build is collecting —
         # lazy query-time promotion hits the no-op path
         note_stage("device_promote", time.perf_counter() - _t_dev)
@@ -952,6 +963,12 @@ class Segment:
                     "ann_ivf", ivf_bytes, owner=self, segment=self,
                     device=key, label=f"segment-ivf[{self.name}]",
                     evictor=self.evict_device))
+            if pos_planes:
+                allocs.append(LEDGER.register(
+                    "position_planes", _tree_nbytes(pos_planes), owner=self,
+                    segment=self, device=key,
+                    label=f"segment-positions[{self.name}]",
+                    evictor=self.evict_device))
             if imp_bytes:
                 allocs.append(LEDGER.register(
                     "impact_postings", imp_bytes, owner=self, segment=self,
@@ -975,6 +992,7 @@ class Segment:
             for a in allocs:
                 LEDGER.release(a)
             del self._device_cache[key]
+            del self._device_positions[key]
             raise
         self.__dict__.setdefault("_hbm_allocs", {}).setdefault(
             key, []).extend(allocs)
@@ -993,6 +1011,28 @@ class Segment:
             for ck in [c for c in fallocs if c[0] == key]:
                 LEDGER.release(fallocs.pop(ck))
         self._device_live_dirty[key] = True
+
+    def device_positions(self, field: str, device=None) -> Optional[dict]:
+        """The resident positional planes of `field` ({"doc", "pos"}: one
+        slot a position, in postings order, so a term's positions are one
+        window sorted by (doc, position)), promoted with the segment's
+        device arrays on `device` and dropped with them; None where the
+        field holds no position. The same discipline as `device_arrays`:
+        `_device_positions` is swapped, never emptied in place, so the
+        dict read here stays whole whatever is dropped meanwhile, and a
+        miss promotes and reads under the build lock, which the pressure
+        evictor has to take too: nothing is evicted between the two."""
+        pb = self.postings.get(field)
+        if pb is None or pb.positions is None or not len(pb.positions):
+            return None
+        planes = self._device_positions
+        if device not in planes:
+            lock = self.__dict__.setdefault(
+                "_device_build_lock", _BuildLock())
+            with lock:
+                self.device_arrays(device)
+                planes = self._device_positions
+        return planes[device].get(field)
 
     def ensure_device_tfs(self, field: str, device=None) -> None:
         """Promote the f32 tf plane of one codec-v2 field back onto the
@@ -1176,8 +1216,12 @@ class Segment:
     def drop_device(self) -> None:
         from ..obs.hbm_ledger import LEDGER
         self._device_cache = {}
+        self._device_positions = {}
         self._device_live_dirty = {}
         self.__dict__.pop("_field_device_cache", None)
+        # a match_phrase_prefix's merged unions (compiler._union_pairs)
+        for held in self.__dict__.pop("_phrase_unions", {}).values():
+            LEDGER.release(held[3])
         # eager release: the arrays are gone NOW, so the ledger (and the
         # derived breaker charge) must not wait for the segment's GC
         for allocs in self.__dict__.pop("_hbm_allocs", {}).values():
@@ -1418,6 +1462,39 @@ def _post_field_arrays(pb: "PostingsBlock", jnp, with_tfs: bool = True,
     if with_impacts and pb.impact is not None:
         out["impacts"] = jnp.asarray(
             _pad_to(pb.impact.q, ppad, pb.impact.q.dtype.type(0)))
+    return out
+
+
+def position_slots(n: int) -> int:
+    """Slots of a positional plane of `n` positions: the next power of two
+    (segments of like size share their programs), and past 2^24 the next
+    multiple of an eighth of it (a plane of 666M positions is 2.7 GB, not
+    the 4.3 of 2^30 slots)."""
+    pow2 = next_pow2(n)
+    if pow2 <= 1 << 24:
+        return pow2
+    step = pow2 >> 3
+    return -(-int(n) // step) * step
+
+
+def _position_planes(pb: "PostingsBlock", jnp) -> dict:
+    """Device planes of one field's positions, in postings order: `pos` the
+    positions as the host holds them, `doc` the document of each (the
+    posting's doc id, once a position). The padding's doc is the sentinel,
+    past every document."""
+    n, slots = len(pb.positions), position_slots(len(pb.positions))
+    doc = np.empty(slots, np.int32)
+    doc[n:] = INT32_SENTINEL
+    step = 1 << 24              # postings a pass: no temporary a plane long
+    for lo in range(0, pb.size, step):
+        at = pb.pos_starts[lo: min(lo + step, pb.size) + 1]
+        doc[at[0]: at[-1]] = np.repeat(
+            pb.doc_ids[lo: lo + len(at) - 1].astype(np.int32, copy=False),
+            np.diff(at))
+    out = {"doc": jnp.asarray(doc)}
+    del doc
+    out["pos"] = jnp.asarray(_pad_to(pb.positions.astype(np.int32, copy=False),
+                                     slots, np.int32(0)))
     return out
 
 

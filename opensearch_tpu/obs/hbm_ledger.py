@@ -79,7 +79,8 @@ KINDS = (
     "nested_sort",          # planes.nested_sort_values columns
     "sort_rank_plane",      # compiler prepare_sort: i32 ranks per (segment, field)
     "agg_bucket_plane",     # planes.date_bucket_plane: i32 bucket ids
-    "phrase_pairs",         # resident phrase (doc, pos) pair arrays
+    "position_planes",      # a text field's (doc, pos) planes, with the segment
+    "phrase_pairs",         # match_phrase_prefix unions merged on the host
     "mesh_postings",        # SPMD stacked per-shard postings/pairs
     "mesh_columns",         # SPMD stacked agg columns/ordinals/masks
     "program",              # compiled-program footprints (advisory)

@@ -227,10 +227,11 @@ def spec_gather_shape(spec) -> Tuple[int, int]:
                 slots += node[bi]
             elif kind == "phrase" and len(node) > 4 \
                     and isinstance(node[4], tuple):
-                # phrase pair arrays: (doc i32, pos i32) per slot
-                for b in node[4]:
-                    if isinstance(b, int):
-                        bytes_ += b * POSTING_SLOT_BYTES
-                        slots += b
+                # (doc i32, pos i32) a slot: the anchor's window, and a
+                # pair a search round for each of its slots and other terms
+                bucket, depth = node[4]
+                read = bucket * (1 + (node[3] - 1) * depth)
+                bytes_ += read * POSTING_SLOT_BYTES
+                slots += read
         stack.extend(node)
     return bytes_, slots

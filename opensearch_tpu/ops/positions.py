@@ -4,21 +4,36 @@ Replaces Lucene's ExactPhraseMatcher / SloppyPhraseMatcher doc-at-a-time
 position merging (reference: `search/` via Lucene PhraseQuery,
 SpanNearQuery) with a fully vectorized formulation:
 
-- Each query term i carries a flat, lexicographically sorted array of
-  (doc_id, position - i) pairs for the whole segment (built on the host from
-  the CSR positional postings; padded to pow2 with an INT32_MAX sentinel).
-- Term 0's pairs are the *candidate anchors*. For every anchor (d, base) we
-  binary-search each other term's array for the nearest adjusted position in
-  the same doc; the per-term displacement |p_adj - base| is that term's move
-  cost. A phrase occurrence exists when every term occurs in the doc and the
-  total move cost <= slop (exact phrase: slop 0 forces full adjacency).
+- A text field's positions live on the device as two planes of the segment
+  (`Segment.device_positions`: `doc` and `pos`, one slot a position, in
+  postings order). A term's positions are contiguous there and
+  sorted by (doc, position), so a query term is a WINDOW of the planes: an
+  offset and a length, two scalars a request. (A `match_phrase_prefix`
+  whose last term expands to several rows is the one term that is no
+  window: its union is merged on the host and handed over as an array of
+  its own, which the same code reads as a window of itself.)
+- One term's window holds the *candidate anchors*: a sloppy or span query's
+  first term, an exact phrase's term of fewest positions (every exact
+  occurrence has one position of every slot, so the count is the same
+  whichever slot anchors, and the shifts follow). For every anchor (d,
+  base) we binary-search each other term's window for the nearest adjusted
+  position in the same doc; the per-term displacement |p_adj - base| is
+  that term's move cost. A phrase occurrence exists when every term occurs
+  in the doc and the total move cost <= slop (at slop 0: every term
+  stands at its own place, whichever slot anchors).
 - The per-anchor weight 1/(1+cost) is Lucene's sloppyFreq; scatter-adding it
   per doc yields the phrase frequency that feeds the normal BM25 tf curve.
 
-Everything is static-shaped: the binary search is a statically unrolled
-log2(N) loop of gathers (compare on (doc, pos) i32 pairs — no 64-bit keys
-needed), so one XLA program serves all phrase queries with equal bucket
-shapes.
+Everything is static-shaped: the anchor window is a slice of a power-of-four
+bucket of slots and the binary search a statically unrolled loop of `depth`
+rounds of gathers (compare on (doc, pos) i32 pairs — no 64-bit keys needed),
+so one XLA program serves all phrase queries of one `phrase_shape`: at most
+`len(ANCHOR_BUCKETS) * len(SEARCH_DEPTHS)` programs a term count and segment
+shape. The stages name themselves in the device trace (`jax.named_scope`,
+under the executor's prefix, inside its `executor.match`):
+`executor.phrase_join` (the searches and the cost),
+`executor.phrase_accumulate` (the scatter-add into the document plane),
+`executor.phrase_score` (tf curve, mask).
 
 Semantics note (documented deviation): Lucene's SloppyPhraseMatcher computes
 the minimal *total* movement over a simultaneous alignment, with repeats
@@ -30,7 +45,7 @@ per anchor.
 
 from __future__ import annotations
 
-from typing import List, Optional, Tuple
+from typing import List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -43,56 +58,127 @@ INT32_SENTINEL = np.int32(2**31 - 1)
 BIG_COST = np.float32(1e9)
 
 
-def pair_searchsorted(dA: jnp.ndarray, pA: jnp.ndarray,
-                      dq: jnp.ndarray, pq: jnp.ndarray) -> jnp.ndarray:
-    """Index of the first element of the lex-sorted pair array (dA, pA) that
-    is >= (dq, pq), vectorized over queries. Statically unrolled binary
-    search: log2(N)+1 rounds of 2 gathers each."""
+# the shapes a phrase program is compiled for: the anchor's window is padded
+# to a power of four of slots, the searches run the deepest other window's
+# bit length rounded up to a multiple of four (the planes' slots are counted
+# in int32, so no window passes 2^31)
+ANCHOR_BUCKETS = tuple(1 << e for e in range(6, 32, 2))
+SEARCH_DEPTHS = tuple(range(4, 33, 4))
+JOIN_SCOPE = "executor.phrase_join"
+ACCUMULATE_SCOPE = "executor.phrase_accumulate"
+SCORE_SCOPE = "executor.phrase_score"
+
+
+def anchor_bucket(n: int) -> int:
+    """Slots of the anchor window of `n` positions: the next power of four,
+    64 at least."""
+    return next((b for b in ANCHOR_BUCKETS if b >= n), 1 << 31)
+
+
+def search_depth(n: int) -> int:
+    """Rounds of a binary search over a window of `n` positions: its bit
+    length, rounded up to a multiple of four."""
+    return next(d for d in SEARCH_DEPTHS if d >= max(int(n), 1).bit_length())
+
+
+def phrase_shape(lens) -> Tuple[int, int]:
+    """(anchor bucket, search depth) of a phrase whose terms hold `lens`
+    positions, the anchor's first: the part of a phrase program's key that
+    follows the terms (with the term count and the planes' own shape)."""
+    return (anchor_bucket(int(lens[0])),
+            search_depth(max([int(n) for n in lens[1:]] or [1])))
+
+
+def probe_elems(bucket: int, nothers: int, depth: int) -> int:
+    """Elements the join gathers one at a time for one phrase: a search
+    reads a (doc, position) pair a round and slot, and the slot it lands on
+    and its left neighbour are read again (the nearest of the two)."""
+    return bucket * nothers * (2 * depth + 4)
+
+
+class Window(NamedTuple):
+    """A term's positions: slots [lo, lo + n) of the planes (d, p), which
+    are sorted by (doc, position) there; `depth` >= n's bit length."""
+    d: jnp.ndarray
+    p: jnp.ndarray
+    lo: jnp.ndarray
+    n: jnp.ndarray
+    depth: int
+
+
+def whole(dA: jnp.ndarray, pA: jnp.ndarray) -> Window:
+    """An array of pairs of its own (sentinel-padded) as a window."""
     n = dA.shape[0]
-    lo = jnp.zeros(dq.shape, jnp.int32)
-    hi = jnp.full(dq.shape, n, jnp.int32)
-    for _ in range(int(n).bit_length()):
-        mid = (lo + hi) >> 1
-        m = jnp.minimum(mid, n - 1)
-        dm = dA[m]
-        pm = pA[m]
-        less = (dm < dq) | ((dm == dq) & (pm < pq))
+    return Window(dA, pA, np.int32(0), np.int32(n), int(n).bit_length())
+
+
+def anchor_window(w: Window, bucket: int):
+    """The anchors: `bucket` slots of the planes that cover the window
+    (bucket >= n; the planes' length where they are shorter), as (doc,
+    position) with the sentinel doc in every slot outside it."""
+    size = min(bucket, w.d.shape[0])
+    with jax.named_scope(JOIN_SCOPE):
+        start = jnp.clip(w.lo, 0, w.d.shape[0] - size).astype(jnp.int32)
+        slot = start + jnp.arange(size, dtype=jnp.int32)
+        inside = (slot >= w.lo) & (slot < w.lo + w.n)
+        d = jax.lax.dynamic_slice(w.d, (start,), (size,))
+        p = jax.lax.dynamic_slice(w.p, (start,), (size,))
+        return jnp.where(inside, d, INT32_SENTINEL), jnp.where(inside, p, 0)
+
+
+def window_searchsorted(w: Window, dq: jnp.ndarray,
+                        pq: jnp.ndarray) -> jnp.ndarray:
+    """Slot of the first pair of the window that is >= (dq, pq), `lo + n`
+    where none is, vectorized over queries. Statically unrolled binary
+    search: `depth` rounds of 2 gathers each."""
+    last = w.d.shape[0] - 1
+    end = (w.lo + w.n).astype(jnp.int32)
+    lo = jnp.full(dq.shape, w.lo, jnp.int32)
+    hi = jnp.full(dq.shape, end, jnp.int32)
+    for _ in range(w.depth):
+        mid = lo + ((hi - lo) >> 1)
+        m = jnp.minimum(mid, last)
+        dm = w.d[m]
+        pm = w.p[m]
+        # mid == hi once the interval is empty: the slot past the window
+        # is another term's and decides nothing
+        less = (mid < hi) & ((dm < dq) | ((dm == dq) & (pm < pq)))
         lo = jnp.where(less, mid + 1, lo)
         hi = jnp.where(less, hi, mid)
     return lo
 
 
-def nearest_delta(dA: jnp.ndarray, pA: jnp.ndarray,
-                  d0: jnp.ndarray, base: jnp.ndarray, shift=0):
+def nearest_delta(w: Window, d0: jnp.ndarray, base: jnp.ndarray, shift=0):
     """Signed displacement (adjusted position - base) of the term occurrence
     nearest to the anchor within the anchor's doc, and a found flag.
-    `shift` is the query-position offset of this term: the pair arrays stay
-    RAW (device-resident per segment term), adjusted position = pA - shift —
-    shipping pre-shifted copies per query would re-upload megabytes of
-    positions on every search."""
-    n = dA.shape[0]
-    idx = pair_searchsorted(dA, pA, d0, base + shift)
-    ridx = jnp.minimum(idx, n - 1)
-    right_ok = (idx < n) & (dA[ridx] == d0)
-    right_delta = (pA[ridx] - shift - base).astype(jnp.float32)
+    `shift` is the query-position offset of this term against the anchor's:
+    the planes stay RAW (device-resident per segment), adjusted position =
+    p - shift."""
+    last = w.d.shape[0] - 1
+    idx = window_searchsorted(w, d0, base + shift)
+    ridx = jnp.minimum(idx, last)
+    right_ok = (idx < w.lo + w.n) & (w.d[ridx] == d0)
+    right_delta = (w.p[ridx] - shift - base).astype(jnp.float32)
     right_cost = jnp.where(right_ok, right_delta, BIG_COST)
     lidx = jnp.maximum(idx - 1, 0)
-    left_ok = (idx > 0) & (dA[lidx] == d0)
-    left_delta = (pA[lidx] - shift - base).astype(jnp.float32)
+    left_ok = (idx > w.lo) & (w.d[lidx] == d0)
+    left_delta = (w.p[lidx] - shift - base).astype(jnp.float32)
     left_cost = jnp.where(left_ok, -left_delta, BIG_COST)
     delta = jnp.where(right_cost <= left_cost, right_delta, left_delta)
     return delta, right_ok | left_ok
 
 
 def phrase_freqs(anchor_d: jnp.ndarray, anchor_p: jnp.ndarray,
-                 others: List[Tuple[jnp.ndarray, jnp.ndarray]],
-                 slop: jnp.ndarray, ndocs_pad: int,
+                 others: List, slop: jnp.ndarray, ndocs_pad: int,
                  ordered: bool = False, gap_cost: bool = False,
                  shifts: Optional[List] = None) -> jnp.ndarray:
     """Dense per-doc sloppy phrase frequency f32[ndocs_pad].
 
-    anchor_d/anchor_p: term 0's (doc, adjusted position) pairs (sentinel
-    padded). others: the remaining terms' sorted pair arrays.
+    anchor_d/anchor_p: the anchor term's (doc, position) pairs (sentinel
+    doc in every unused slot: `anchor_window`). others: the remaining
+    terms, a `Window` each (a bare (d, p) pair of sorted, sentinel-padded
+    arrays is read as `whole`); `shifts`: each one's query position less
+    the anchor's (negative before it).
 
     Cost of an occurrence, compared against `slop`:
     - default (match_phrase slop): total movement against the OPTIMAL common
@@ -100,6 +186,10 @@ def phrase_freqs(anchor_d: jnp.ndarray, anchor_p: jnp.ndarray,
       deltas — matching Lucene SloppyPhraseMatcher's "total movement" slop
       (all terms may move, e.g. `quick and nimble brown fox` vs `quick brown
       fox` costs 2, not 4, because brown+fox stay put and quick moves).
+      At slop 0 the cost is 0 only where every delta is 0: every other
+      window holds (d, base + shift) itself, so whichever term anchors, the
+      count is the same (`compiler.prepare` anchors an exact phrase on its
+      term of fewest positions).
     - gap_cost=True (span_near slop / intervals max_gaps): positions inside
       the matched span not covered by a query term (span_width - m) — so an
       adjacent transposition costs 0 gaps but 2 moves.
@@ -110,47 +200,51 @@ def phrase_freqs(anchor_d: jnp.ndarray, anchor_p: jnp.ndarray,
     ordered existence anchored at each term-0 occurrence, and the resulting
     gap count is simply the last delta. Ordered implies gap cost (both its
     callers are span-family queries)."""
-    ok = anchor_d != INT32_SENTINEL
+    others = [o if isinstance(o, Window) else whole(*o) for o in others]
     m = len(others) + 1
     if shifts is None:
         shifts = [0] * len(others)
-    if ordered:
-        prev = jnp.zeros(anchor_p.shape, jnp.int32)  # delta_0 = 0
-        for (dA, pA), sh in zip(others, shifts):
-            n = dA.shape[0]
-            idx = pair_searchsorted(dA, pA, anchor_d, anchor_p + prev + sh)
-            safe = jnp.minimum(idx, n - 1)
-            found = (idx < n) & (dA[safe] == anchor_d)
-            prev = pA[safe] - sh - anchor_p
-            ok = ok & found
-        cost = prev.astype(jnp.float32)  # = pos_last - pos_0 + 1 - m = gaps
-    elif m > 1:
-        deltas = [jnp.zeros(anchor_d.shape, jnp.float32)]
-        for (dA, pA), sh in zip(others, shifts):
-            di, found = nearest_delta(dA, pA, anchor_d, anchor_p, sh)
-            ok = ok & found
-            deltas.append(di)
-        if gap_cost:
-            # unordered gaps: span width over nearest-per-term choices — a
-            # superset-leaning heuristic (exact when terms don't compete)
-            abs_off = [di + jnp.float32(i) for i, di in enumerate(deltas)]
-            span_hi = abs_off[0]
-            span_lo = abs_off[0]
-            for a in abs_off[1:]:
-                span_hi = jnp.maximum(span_hi, a)
-                span_lo = jnp.minimum(span_lo, a)
-            cost = span_hi - span_lo + 1.0 - jnp.float32(m)
+    with jax.named_scope(JOIN_SCOPE):
+        ok = anchor_d != INT32_SENTINEL
+        if ordered:
+            prev = jnp.zeros(anchor_p.shape, jnp.int32)  # delta_0 = 0
+            for w, sh in zip(others, shifts):
+                idx = window_searchsorted(w, anchor_d, anchor_p + prev + sh)
+                safe = jnp.minimum(idx, w.d.shape[0] - 1)
+                found = (idx < w.lo + w.n) & (w.d[safe] == anchor_d)
+                prev = w.p[safe] - sh - anchor_p
+                ok = ok & found
+            # = pos_last - pos_0 + 1 - m = gaps
+            cost = prev.astype(jnp.float32)
+        elif m > 1:
+            deltas = [jnp.zeros(anchor_d.shape, jnp.float32)]
+            for w, sh in zip(others, shifts):
+                di, found = nearest_delta(w, anchor_d, anchor_p, sh)
+                ok = ok & found
+                deltas.append(di)
+            if gap_cost:
+                # unordered gaps: span width over nearest-per-term choices — a
+                # superset-leaning heuristic (exact when terms don't compete)
+                abs_off = [di + jnp.float32(i) for i, di in enumerate(deltas)]
+                span_hi = abs_off[0]
+                span_lo = abs_off[0]
+                for a in abs_off[1:]:
+                    span_hi = jnp.maximum(span_hi, a)
+                    span_lo = jnp.minimum(span_lo, a)
+                cost = span_hi - span_lo + 1.0 - jnp.float32(m)
+            else:
+                stacked = jnp.sort(jnp.stack(deltas, axis=0), axis=0)
+                med = stacked[m // 2]
+                cost = jnp.zeros(anchor_d.shape, jnp.float32)
+                for di in deltas:
+                    cost = cost + jnp.abs(di - med)
         else:
-            stacked = jnp.sort(jnp.stack(deltas, axis=0), axis=0)
-            med = stacked[m // 2]
             cost = jnp.zeros(anchor_d.shape, jnp.float32)
-            for di in deltas:
-                cost = cost + jnp.abs(di - med)
-    else:
-        cost = jnp.zeros(anchor_d.shape, jnp.float32)
-    ok = ok & (cost <= slop)
-    w = jnp.where(ok, 1.0 / (1.0 + cost), 0.0)  # Lucene sloppyFreq
-    return jnp.zeros(ndocs_pad, jnp.float32).at[anchor_d].add(w, mode="drop")
+        ok = ok & (cost <= slop)
+        w = jnp.where(ok, 1.0 / (1.0 + cost), 0.0)  # Lucene sloppyFreq
+    with jax.named_scope(ACCUMULATE_SCOPE):
+        return jnp.zeros(ndocs_pad, jnp.float32).at[anchor_d].add(
+            w, mode="drop")
 
 
 def phrase_score(freq: jnp.ndarray, dl: jnp.ndarray, live: jnp.ndarray,
@@ -158,7 +252,8 @@ def phrase_score(freq: jnp.ndarray, dl: jnp.ndarray, live: jnp.ndarray,
                  avgdl: jnp.ndarray):
     """BM25 over the phrase frequency: weight = sum of the terms' idf*boost
     (Lucene PhraseWeight scores the phrase as one pseudo-term)."""
-    k = k1 * (1.0 - b + b * dl / avgdl)
-    scores = weight * freq / (freq + k)
-    matched = (freq > 0) & (live > 0)
-    return jnp.where(matched, scores, 0.0), matched
+    with jax.named_scope(SCORE_SCOPE):
+        k = k1 * (1.0 - b + b * dl / avgdl)
+        scores = weight * freq / (freq + k)
+        matched = (freq > 0) & (live > 0)
+        return jnp.where(matched, scores, 0.0), matched

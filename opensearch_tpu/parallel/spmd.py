@@ -992,8 +992,9 @@ def build_distributed_range_counts(mesh: Mesh, bucket: int, ndocs_pad: int,
 class StackedPhrasePairs:
     """Per-shard positional (doc, position) pair arrays in the SAME
     term-row space as a StackedShardIndex — the mesh-resident form of the
-    host path's per-segment `_phrase_pair_cache` (search/compiler.py
-    `_phrase_pairs`). Rows are the stacked index's shard term-union rows;
+    host path's per-segment positional planes (`Segment.device_positions`;
+    there a term is a window of the planes, here a row's own pairs). Rows
+    are the stacked index's shard term-union rows;
     each row's pairs concatenate the shard's segments (doc ids offset by
     segment base) and are lex-sorted by (doc, position), sentinel padded."""
 

@@ -120,6 +120,24 @@ KNN_STATS = CounterGroup(METRICS, "knn", {"queries": 0, "ann_queries": 0,
                                           "candidate_slots": 0,
                                           "rows_by_id": 0,
                                           "query_vector_bytes": 0})
+# what the `phrase` nodes of the launches cost, counted at each launch from
+# the static spec and the request's window lengths (`programs.count_phrase`):
+# `queries` the nodes over a segment that holds positions, `anchor_slots`
+# the padded slots of their anchor windows and `anchor_positions` the
+# positions those hold, `probe_elems` the elements the binary searches
+# gather one at a time (`ops.positions.probe_elems`), `window_positions`
+# the positions of all the phrases' terms, `programs` the distinct phrase
+# shapes launched so far, `host_pair_builds` the (doc, position) arrays
+# merged on the host (a `match_phrase_prefix` whose last term expands to
+# several rows: `_union_pairs`; every other term is a window of the
+# segment's resident planes and builds nothing)
+PHRASE_STATS = CounterGroup(METRICS, "phrase", {"queries": 0,
+                                                "anchor_slots": 0,
+                                                "anchor_positions": 0,
+                                                "probe_elems": 0,
+                                                "window_positions": 0,
+                                                "host_pair_builds": 0,
+                                                "programs": 0})
 # the precision a `knn` node's scoring product names. Unnamed, a batch of
 # queries a launch (the vmapped `msearch` twin) is ONE bfloat16 pass of the
 # chip's matrix unit, scores 4e-4 relative off where the 100th neighbour
@@ -305,31 +323,45 @@ def _i64_bounds(params, nid: int, lo, hi) -> Tuple[str, str, str, str]:
             put_param(params, f"q{nid}_hihi", hi_hi[0]), put_param(params, f"q{nid}_hilo", hi_lo[0]))
 
 
-def _phrase_pairs(seg: Segment, pb, rows: Tuple[int, ...]):
-    """Unshifted (doc, position) pairs for a term's postings (union over
-    `rows` for prefix expansion), lex-sorted; cached per segment and shared
-    across query positions (the caller subtracts the phrase offset when
-    padding — a constant shift keeps lex order)."""
-    cache = getattr(seg, "_phrase_pair_cache", None)
-    if cache is None:
-        cache = seg._phrase_pair_cache = {}
+UNION_PAIRS_MAX = 64        # a segment's cached prefix unions
+
+
+def _union_pairs(seg: Segment, pb, rows: Tuple[int, ...]):
+    """(doc plane, position plane, positions) of the union of several
+    terms' positions (a `match_phrase_prefix`'s expanded last term: the one
+    phrase term that is no window of the segment's resident planes),
+    lex-sorted by (doc, position), sentinel-padded to the anchor bucket of
+    its length and put on the device; the segment keeps the last
+    `UNION_PAIRS_MAX`, charged to the HBM ledger as `phrase_pairs` and
+    released when evicted or when the segment's device arrays are dropped
+    (`Segment.drop_device`)."""
+    import jax
+
+    from ..obs.hbm_ledger import LEDGER
+    from ..ops.positions import anchor_bucket
+    cache = seg.__dict__.setdefault("_phrase_unions", {})
     key = (pb.field, rows)
-    if key in cache:
-        return cache[key]
-    docs_parts, pos_parts = [], []
-    for r in rows:
-        a, b = pb.row_slice(r)
-        counts = pb.pos_starts[a + 1: b + 1] - pb.pos_starts[a: b]
-        docs_parts.append(np.repeat(pb.doc_ids[a:b], counts))
-        pos_parts.append(pb.positions[pb.pos_starts[a]: pb.pos_starts[b]])
-    d = np.concatenate(docs_parts) if docs_parts else np.empty(0, np.int32)
-    p = np.concatenate(pos_parts) if pos_parts else np.empty(0, np.int32)
-    if len(rows) > 1 and len(d):
-        order = np.lexsort((p, d))
-        d, p = d[order], p[order]
-    res = (d.astype(np.int32), p.astype(np.int32))
-    cache[key] = res
-    return res
+    held = cache.get(key)
+    if held is not None:
+        return held[:3]
+    PHRASE_STATS.inc("host_pair_builds")
+    slices = [pb.row_slice(r) for r in rows]
+    d = np.concatenate([np.repeat(pb.doc_ids[a:b],
+                                  np.diff(pb.pos_starts[a: b + 1]))
+                        for a, b in slices])
+    p = np.concatenate([pb.positions[pb.pos_starts[a]: pb.pos_starts[b]]
+                        for a, b in slices])
+    order = np.lexsort((p, d))
+    bucket = anchor_bucket(len(d))
+    d_dev = jax.device_put(_pad_to_sentinel(d[order].astype(np.int32), bucket))
+    p_dev = jax.device_put(_pad_to_sentinel(p[order].astype(np.int32), bucket))
+    alloc = LEDGER.register(
+        "phrase_pairs", int(d_dev.nbytes + p_dev.nbytes), owner=seg,
+        segment=seg, label=f"phrase-pairs[{seg.name}][{pb.field}]")
+    while len(cache) >= UNION_PAIRS_MAX:
+        LEDGER.release(cache.pop(next(iter(cache)))[3])
+    cache[key] = (d_dev, p_dev, len(d), alloc)
+    return d_dev, p_dev, len(d)
 
 
 def _source_phrase_match(seg: Segment, doc: int, field: str,
@@ -480,10 +512,16 @@ def prepare(node: LNode, seg: Segment, ctx: ShardContext, params: dict):  # noqa
         pb = seg.postings.get(node.field)
         if pb is None or pb.pos_starts is None:
             return ("match_none", nid)
+        from ..ops import positions as pos_ops
+        planes = seg.device_positions(node.field, ctx.device)
+        if planes is None:
+            return ("match_none", nid)
         m_terms = len(node.terms)
         last = m_terms - 1
-        arrays = []
-        term_rows = []
+        # a term is a window of the field's resident planes: (positions,
+        # first slot, None); a prefix that expands to several rows is the
+        # union's own arrays: (positions, 0, (doc, pos))
+        wins = []
         for i, t in enumerate(node.terms):
             if node.prefix_last and i == last:
                 rows = list(prefix_rows(pb, t, node.max_expansions))
@@ -492,48 +530,46 @@ def prepare(node: LNode, seg: Segment, ctx: ShardContext, params: dict):  # noqa
                 rows = [r] if r >= 0 else []
             if not rows:
                 return ("match_none", nid)  # phrase needs every term
-            arrays.append(_phrase_pairs(seg, pb, tuple(rows)))
-            term_rows.append(tuple(rows))
-        buckets = []
-        # pair arrays are RAW and DEVICE-RESIDENT per (segment, term,
-        # bucket): the query position rides as a scalar shift, so repeated
-        # phrase queries never re-upload megabytes of positions (the
-        # positional analog of the resident CSR postings)
-        dev_cache = seg.__dict__.setdefault("_phrase_dev_cache", {})
-        for i, (d, p) in enumerate(arrays):
-            # coarse pow4 buckets: pair-array pads land on 1 of ~6 sizes so
-            # phrase programs compile once per coarse shape, not per df
-            bucket = next_pow2(max(len(d), 1), floor=64)
-            if bucket.bit_length() % 2 == 0:   # odd exponent -> round up
-                bucket <<= 1
-            ck = (node.field, term_rows[i], bucket)
-            dev = dev_cache.get(ck)
-            if dev is None:
-                import jax
-
-                from ..obs.hbm_ledger import LEDGER
-                d_dev = jax.device_put(_pad_to_sentinel(d, bucket))
-                p_dev = jax.device_put(_pad_to_sentinel(p, bucket))
-                alloc = LEDGER.register(
-                    "phrase_pairs", int(d_dev.nbytes + p_dev.nbytes),
-                    owner=seg, segment=seg,
-                    label=f"phrase-pairs[{seg.name}][{node.field}]")
-                dev = (d_dev, p_dev, alloc)
-                while len(dev_cache) >= 1024:
-                    evicted = dev_cache.pop(next(iter(dev_cache)))
-                    LEDGER.release(evicted[2])
-                dev_cache[ck] = dev
-            put_param(params, f"q{nid}_d{i}", dev[0])
-            put_param(params, f"q{nid}_p{i}", dev[1])
-            scalar_i32(params, f"q{nid}_shift{i}", i)
-            buckets.append(bucket)
+            if len(rows) == 1:
+                a, b = pb.row_slice(rows[0])
+                lo = int(pb.pos_starts[a])
+                wins.append((int(pb.pos_starts[b]) - lo, lo, None))
+            else:
+                d_dev, p_dev, n = _union_pairs(seg, pb, tuple(rows))
+                wins.append((n, 0, (d_dev, p_dev)))
+        # an exact phrase anchors on its term of fewest positions (Lucene
+        # leads a phrase by its cheapest term): every occurrence has one
+        # position of every slot, so the count is the same and the shifts
+        # follow. A sloppy or span form keeps its first term
+        exact = node.slop == 0 and not node.ordered and not node.gap_cost
+        anchor = min(range(m_terms), key=lambda i: (wins[i][0], i)) \
+            if exact else 0
+        order = [anchor] + [i for i in range(m_terms) if i != anchor]
+        shape = pos_ops.phrase_shape([wins[i][0] for i in order])
+        own = []        # a slot's own arrays' length; 0: the planes
+        for slot, i in enumerate(order):
+            arrays = wins[i][2]
+            own.append(0 if arrays is None else int(arrays[0].shape[0]))
+            if arrays is not None:
+                put_param(params, f"q{nid}_d{slot}", arrays[0])
+                put_param(params, f"q{nid}_p{slot}", arrays[1])
+        if not all(own):
+            put_param(params, f"q{nid}_posd", planes["doc"])
+            put_param(params, f"q{nid}_posp", planes["pos"])
+        put_param(params, f"q{nid}_len",
+                  np.asarray([wins[i][0] for i in order], np.int32))
+        put_param(params, f"q{nid}_off",
+                  np.asarray([wins[i][1] for i in order], np.int32))
+        put_param(params, f"q{nid}_shift",
+                  np.asarray([i - anchor for i in order], np.int32))
         sim = node.sim
         b_eff = sim.b if node.has_norms else 0.0
         scalar_f32(params, f"q{nid}_w", node.weight)
         scalar_f32(params, f"q{nid}_slop", node.slop)
         scalar_f32(params, f"q{nid}_avgdl", ctx.avgdl(node.field))
-        return ("phrase", nid, node.field, m_terms, tuple(buckets),
-                float(sim.k1), float(b_eff), node.ordered, node.gap_cost)
+        return ("phrase", nid, node.field, m_terms, shape,
+                float(sim.k1), float(b_eff), node.ordered, node.gap_cost,
+                tuple(own))
 
     if isinstance(node, LExpandTerms):
         rows_np = node.expander(seg)
@@ -1292,17 +1328,22 @@ def emit(spec, seg_arrays: dict, params: dict) -> ops.ScoredMask:  # noqa: C901
     if kind == "phrase":
         from ..ops import positions as pos_ops
 
-        _, _, field, m_terms, buckets, k1, b, ordered, gap_cost = spec
+        (_, _, field, m_terms, (bucket, depth), k1, b, ordered, gap_cost,
+         own) = spec
         dl = seg_arrays["doc_lens"].get(field, zeros)
-        anchor_d = params[f"q{nid}_d0"]
-        anchor_p = params[f"q{nid}_p0"]
-        others = [(params[f"q{nid}_d{i}"], params[f"q{nid}_p{i}"])
-                  for i in range(1, m_terms)]
-        shifts = [params[f"q{nid}_shift{i}"] for i in range(1, m_terms)]
-        freq = pos_ops.phrase_freqs(anchor_d, anchor_p, others,
-                                    params[f"q{nid}_slop"], ndocs_pad,
-                                    ordered=ordered, gap_cost=gap_cost,
-                                    shifts=shifts)
+        off, length = params[f"q{nid}_off"], params[f"q{nid}_len"]
+        shift = params[f"q{nid}_shift"]
+
+        def window(slot):
+            who = (f"q{nid}_d{slot}", f"q{nid}_p{slot}") if own[slot] \
+                else (f"q{nid}_posd", f"q{nid}_posp")
+            return pos_ops.Window(params[who[0]], params[who[1]], off[slot],
+                                  length[slot], depth)
+        anchor_d, anchor_p = pos_ops.anchor_window(window(0), bucket)
+        freq = pos_ops.phrase_freqs(
+            anchor_d, anchor_p, [window(i) for i in range(1, m_terms)],
+            params[f"q{nid}_slop"], ndocs_pad, ordered=ordered,
+            gap_cost=gap_cost, shifts=[shift[i] for i in range(1, m_terms)])
         scores, matched = pos_ops.phrase_score(freq, dl, live, params[f"q{nid}_w"],
                                                k1, b, params[f"q{nid}_avgdl"])
         return ops.ScoredMask(scores, matched.astype(jnp.float32))

@@ -161,6 +161,7 @@ def _purge_query_caches(seg, names: List[str]) -> None:
     # pressure eviction (or trips) of other tenants
     from ..obs.hbm_ledger import LEDGER
     seg._device_cache = {}
+    seg._device_positions = {}
     seg._device_live_dirty = {}
     seg.__dict__.pop("_field_device_cache", None)
     for allocs in seg.__dict__.pop("_hbm_allocs", {}).values():

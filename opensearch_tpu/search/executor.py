@@ -246,7 +246,8 @@ class ShardSearcher:
 
     def context(self) -> PL.ShardContext:
         return PL.ShardContext(self.engine.mappings, self.engine.segments,
-                               self.similarity, self.field_similarities)
+                               self.similarity, self.field_similarities,
+                               device=self.device)
 
     # ---------------- QUERY phase ----------------
 
@@ -266,8 +267,9 @@ class ShardSearcher:
             segments = (list(self.replica.segments) if self.replica is not None
                         else list(self.engine.segments))
         with TRACER.span("search.plan"):
-            ctx = stats_ctx or PL.ShardContext(self.engine.mappings, segments,
-                                               self.similarity, self.field_similarities)
+            ctx = stats_ctx or PL.ShardContext(
+                self.engine.mappings, segments, self.similarity,
+                self.field_similarities, device=self.device)
             # derived (runtime) fields: mapping-level + search-body defs
             # materialize into per-segment columns before rewrite sees them
             ddefs = dict(getattr(ctx.mappings, "derived", {}) or {})
@@ -702,8 +704,9 @@ class ShardSearcher:
                     body: dict, stats_ctx: Optional[PL.ShardContext] = None) -> List[dict]:
         # explain must recompute with the SAME collection-wide statistics the
         # query phase scored with, or _explanation diverges from _score
-        ctx = stats_ctx or PL.ShardContext(self.engine.mappings, result.segments,
-                                           self.similarity, self.field_similarities)
+        ctx = stats_ctx or PL.ShardContext(
+            self.engine.mappings, result.segments, self.similarity,
+            self.field_similarities, device=self.device)
         qtree = dsl.parse_query(body.get("query"))
         lroot = PL.rewrite(qtree, ctx, scoring=True)
         hl_terms = collect_query_terms(lroot) if body.get("highlight") else {}
@@ -1887,7 +1890,8 @@ def _global_stats_contexts(searchers: List[ShardSearcher]) -> List[Any]:
         group_segs.setdefault(s.index_key, []).extend(
             getattr(s, "_snapshot_segments", None) or s.engine.segments)
     return [PL.ShardContext(s.engine.mappings, group_segs[s.index_key],
-                            s.similarity, s.field_similarities)
+                            s.similarity, s.field_similarities,
+                            device=s.device)
             for s in searchers]
 
 

@@ -34,9 +34,14 @@ class ShardContext:
     """Index-wide view used during rewrite (reference QueryShardContext)."""
 
     def __init__(self, mappings: Mappings, segments: List[Segment],
-                 similarity=None, field_similarities: Optional[dict] = None):
+                 similarity=None, field_similarities: Optional[dict] = None,
+                 device=None):
         self.mappings = mappings
         self.segments = segments
+        # where the searcher's segments are hosted (None: the process
+        # default; a replica's own device): `compiler.prepare` asks a
+        # segment's resident planes of that residency, not of a second one
+        self.device = device
         self.default_sim = resolve_similarity(similarity)
         self.field_sims = {f: resolve_similarity(s)
                            for f, s in (field_similarities or {}).items()}
@@ -1403,7 +1408,8 @@ def nested_context(ctx: ShardContext, path: str) -> ShardContext:
     child_segs = [s.nested[path].child for s in ctx.segments if path in s.nested]
     return ShardContext(ctx.mappings, child_segs,
                         similarity=ctx.default_sim,
-                        field_similarities=ctx.field_sims)
+                        field_similarities=ctx.field_sims,
+                        device=ctx.device)
 
 
 def _rewrite_query_string(q, ctx: ShardContext, scoring: bool) -> LNode:

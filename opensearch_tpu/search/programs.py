@@ -17,14 +17,19 @@ from ..ops import scoring as ops
 from ..utils.trace import TRACER
 from .agg_compiler import emit_agg
 from .aggregations import AGG_STATS
-from .compiler import (EXECUTOR_STATS, KNN_STATS, canon_param_key, canon_spec,
-                       emit, emit_sort_key, instrumented_program_cache)
+from .compiler import (EXECUTOR_STATS, KNN_STATS, PHRASE_STATS,
+                       canon_param_key, canon_spec, emit, emit_sort_key,
+                       instrumented_program_cache)
 
 
 @instrumented_program_cache("executor", maxsize=512)
 def _build_executor(full_spec):
     import jax
 
+    # a miss of the program cache: `phrase.programs` counts those of a spec
+    # with a `phrase` node (the phrase shapes met so far)
+    if next(_nodes_of(("phrase",), full_spec[0]), None) is not None:
+        PHRASE_STATS.inc("programs")
     return jax.jit(_executor_run_fn(full_spec))
 
 
@@ -147,8 +152,11 @@ def _count_launch(full_spec, seg_arrays: dict, cparams: dict) -> None:
         EXECUTOR_STATS.inc("topk_keys_sorted", ops.topk_keys_sorted(
             seg_arrays["live"].shape[0], k_pad))
     EXECUTOR_STATS.inc("launches")
-    for node in _knn_nodes(_query):
-        count_knn(node, seg_arrays, cparams)
+    for node in _nodes_of(("knn", "phrase"), _query):
+        if node[0] == "knn":
+            count_knn(node, seg_arrays, cparams)
+        else:
+            count_phrase(node, cparams)
     forms = list(_date_count_forms(aggs))
     if forms:
         EXECUTOR_STATS.inc("agg_bucket_launches", len(forms))
@@ -173,13 +181,27 @@ def _count_launch(full_spec, seg_arrays: dict, cparams: dict) -> None:
             AGG_STATS.inc("bucketed_sub.buckets", cost["sub_buckets"])
 
 
-def _knn_nodes(spec):
-    """The `knn` nodes of a query spec, its filters' included."""
+def _nodes_of(kinds: tuple, spec):
+    """The nodes of a query spec whose kind is among `kinds`, its filters'
+    included."""
     if isinstance(spec, (tuple, list)):
-        if spec and spec[0] == "knn":
+        if spec and isinstance(spec[0], str) and spec[0] in kinds:
             yield spec
         for part in spec:
-            yield from _knn_nodes(part)
+            yield from _nodes_of(kinds, part)
+
+
+def count_phrase(node, cparams: dict) -> None:
+    """One `phrase` node of a launch into `PHRASE_STATS`: what its static
+    shape makes the join read, and what the request's windows hold."""
+    from ..ops.positions import probe_elems
+    _, nid, _field, m_terms, (bucket, depth) = node[:5]
+    lens = cparams[f"q{nid}_len"]
+    PHRASE_STATS.inc("queries")
+    PHRASE_STATS.inc("anchor_slots", bucket)
+    PHRASE_STATS.inc("anchor_positions", int(lens[0]))
+    PHRASE_STATS.inc("window_positions", int(lens.sum()))
+    PHRASE_STATS.inc("probe_elems", probe_elems(bucket, m_terms - 1, depth))
 
 
 def count_knn(node, seg_arrays: dict, cparams: dict) -> None:

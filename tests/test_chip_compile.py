@@ -4,8 +4,8 @@ Two families, both in this ONE file (only one process at a time may load
 the TPU's library; a second file could land on another xdist worker and
 skip in silence):
 
-* the three live Pallas kernels, `exact_rescore_batch` and the dense form
-  of `ops.aggs`' per-bucket reductions COMPILED for a
+* the three live Pallas kernels, `exact_rescore_batch`, the dense form
+  of `ops.aggs`' per-bucket reductions and the phrase join COMPILED for a
   described (not attached) `v5e:2x2` device at real buckets — what the
   compiler refuses here costs no chip time. The topology, the sharding
   and the shapes are built inside module-scoped fixtures that skip when
@@ -212,6 +212,40 @@ def test_product_bucket_counts_compile_for_v5e(shape_on_chip, nb):
     # it would be 64 MiB more)
     assert not re.search(r"bf16\[\d+,%d\]" % agg_ops._PRODUCT_BLOCK, text)
     assert compiled.memory_analysis().temp_size_in_bytes < 80 << 20
+
+
+POSITION_SLOTS = 5 << 27    # a PMC shard's positional planes (667M positions)
+
+
+@pytest.mark.parametrize("m,bucket,depth", [(2, 1 << 16, 20),
+                                            (3, 1 << 20, 28)])
+def test_phrase_join_compiles_for_v5e(shape_on_chip, m, bucket, depth):
+    """The phrase join (slop 0) over resident planes at the `pmc` shard's
+    size: the anchor's window is a dynamic slice of the planes (not a
+    gather of `bucket` slots), nothing a plane long is written, and the
+    only scatter is the one into the document plane."""
+    from opensearch_tpu.ops import positions as pos_ops
+    S = shape_on_chip
+    ndocs_pad = 1 << 17
+
+    def join(d, p, off, n, shift, dl, live, w, avgdl):
+        wins = [pos_ops.Window(d, p, off[i], n[i], depth) for i in range(m)]
+        ad, ap = pos_ops.anchor_window(wins[0], bucket)
+        freq = pos_ops.phrase_freqs(
+            ad, ap, wins[1:], jnp.float32(0), ndocs_pad,
+            shifts=[shift[i] for i in range(1, m)])
+        return pos_ops.phrase_score(freq, dl, live, w, K1, B, avgdl)
+    i32, f32 = jnp.int32, jnp.float32
+    compiled = jax.jit(join).lower(
+        S((POSITION_SLOTS,), i32), S((POSITION_SLOTS,), i32), S((m,), i32),
+        S((m,), i32), S((m,), i32), S((ndocs_pad,), f32),
+        S((ndocs_pad,), f32), S((), f32), S((), f32)).compile()
+    text = compiled.as_text()
+    assert len(re.findall(r" dynamic-slice\(", text)) >= 2
+    assert len(re.findall(r" scatter\(", text)) == 1
+    assert not re.search(r"s32\[%d\]\{0\} (copy|fusion)\(" % POSITION_SLOTS,
+                         text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
 
 
 # ---------------------------------------------------------------------
