@@ -1015,9 +1015,10 @@ class Segment:
     def device_positions(self, field: str, device=None) -> Optional[dict]:
         """The resident positional planes of `field` ({"doc", "pos"}: one
         slot a position, in postings order, so a term's positions are one
-        window sorted by (doc, position)), promoted with the segment's
-        device arrays on `device` and dropped with them; None where the
-        field holds no position. The same discipline as `device_arrays`:
+        window sorted by (doc, position); and their fence levels,
+        {"doc_f1", "pos_f1", ...}: `_position_planes`), promoted with the
+        segment's device arrays on `device` and dropped with them; None
+        where the field holds no position. The same discipline as `device_arrays`:
         `_device_positions` is swapped, never emptied in place, so the
         dict read here stays whole whatever is dropped meanwhile, and a
         miss promotes and reads under the build lock, which the pressure
@@ -1470,7 +1471,7 @@ def position_slots(n: int) -> int:
     (segments of like size share their programs), and past 2^24 the next
     multiple of an eighth of it (a plane of 666M positions is 2.7 GB, not
     the 4.3 of 2^30 slots)."""
-    pow2 = next_pow2(n)
+    pow2 = next_pow2(n, floor=128)  # whole rows of `ops.positions.ROW`
     if pow2 <= 1 << 24:
         return pow2
     step = pow2 >> 3
@@ -1481,7 +1482,11 @@ def _position_planes(pb: "PostingsBlock", jnp) -> dict:
     """Device planes of one field's positions, in postings order: `pos` the
     positions as the host holds them, `doc` the document of each (the
     posting's doc id, once a position). The padding's doc is the sentinel,
-    past every document."""
+    past every document. Beside each plane its fence levels, made on the
+    device from the plane (`ops.positions.fences`: `doc_f1` every 128th
+    slot of `doc`, `doc_f2` every 16,384th ...; flat keys, every value an
+    array): what a probe of the phrase join's search reads a row of."""
+    from ..ops.positions import fences, plane_key
     n, slots = len(pb.positions), position_slots(len(pb.positions))
     doc = np.empty(slots, np.int32)
     doc[n:] = INT32_SENTINEL
@@ -1495,6 +1500,9 @@ def _position_planes(pb: "PostingsBlock", jnp) -> dict:
     del doc
     out["pos"] = jnp.asarray(_pad_to(pb.positions.astype(np.int32, copy=False),
                                      slots, np.int32(0)))
+    for plane in ("doc", "pos"):
+        for k, level in enumerate(fences(out[plane]), 1):
+            out[plane_key(plane, k)] = level
     return out
 
 

@@ -124,8 +124,10 @@ KNN_STATS = CounterGroup(METRICS, "knn", {"queries": 0, "ann_queries": 0,
 # the static spec and the request's window lengths (`programs.count_phrase`):
 # `queries` the nodes over a segment that holds positions, `anchor_slots`
 # the padded slots of their anchor windows and `anchor_positions` the
-# positions those hold, `probe_elems` the elements the binary searches
-# gather one at a time (`ops.positions.probe_elems`), `window_positions`
+# positions those hold, `probe_elems` the indices the join gathers one at
+# a time (`ops.positions.probe_elems`: a row of the search is one index),
+# `probe_rows` those of them that fetch a row of the planes or of their
+# fences (`ops.positions.probe_rows`), `window_positions`
 # the positions of all the phrases' terms, `programs` the distinct phrase
 # shapes launched so far, `host_pair_builds` the (doc, position) arrays
 # merged on the host (a `match_phrase_prefix` whose last term expands to
@@ -135,6 +137,7 @@ PHRASE_STATS = CounterGroup(METRICS, "phrase", {"queries": 0,
                                                 "anchor_slots": 0,
                                                 "anchor_positions": 0,
                                                 "probe_elems": 0,
+                                                "probe_rows": 0,
                                                 "window_positions": 0,
                                                 "host_pair_builds": 0,
                                                 "programs": 0})
@@ -553,9 +556,9 @@ def prepare(node: LNode, seg: Segment, ctx: ShardContext, params: dict):  # noqa
             if arrays is not None:
                 put_param(params, f"q{nid}_d{slot}", arrays[0])
                 put_param(params, f"q{nid}_p{slot}", arrays[1])
-        if not all(own):
-            put_param(params, f"q{nid}_posd", planes["doc"])
-            put_param(params, f"q{nid}_posp", planes["pos"])
+        if not all(own):    # the planes, and the fence levels searched
+            for key in pos_ops.plane_keys(shape[1]):
+                put_param(params, f"q{nid}_pos_{key}", planes[key])
         put_param(params, f"q{nid}_len",
                   np.asarray([wins[i][0] for i in order], np.int32))
         put_param(params, f"q{nid}_off",
@@ -1328,17 +1331,21 @@ def emit(spec, seg_arrays: dict, params: dict) -> ops.ScoredMask:  # noqa: C901
     if kind == "phrase":
         from ..ops import positions as pos_ops
 
-        (_, _, field, m_terms, (bucket, depth), k1, b, ordered, gap_cost,
+        (_, _, field, m_terms, (bucket, levels), k1, b, ordered, gap_cost,
          own) = spec
         dl = seg_arrays["doc_lens"].get(field, zeros)
         off, length = params[f"q{nid}_off"], params[f"q{nid}_len"]
         shift = params[f"q{nid}_shift"]
 
         def window(slot):
-            who = (f"q{nid}_d{slot}", f"q{nid}_p{slot}") if own[slot] \
-                else (f"q{nid}_posd", f"q{nid}_posp")
-            return pos_ops.Window(params[who[0]], params[who[1]], off[slot],
-                                  length[slot], depth)
+            if own[slot]:
+                return pos_ops.whole(params[f"q{nid}_d{slot}"],
+                                     params[f"q{nid}_p{slot}"],
+                                     length[slot], levels)
+            return pos_ops.resident(
+                {key: params[f"q{nid}_pos_{key}"]
+                 for key in pos_ops.plane_keys(levels)},
+                off[slot], length[slot], levels)
         anchor_d, anchor_p = pos_ops.anchor_window(window(0), bucket)
         freq = pos_ops.phrase_freqs(
             anchor_d, anchor_p, [window(i) for i in range(1, m_terms)],

@@ -194,14 +194,15 @@ def _nodes_of(kinds: tuple, spec):
 def count_phrase(node, cparams: dict) -> None:
     """One `phrase` node of a launch into `PHRASE_STATS`: what its static
     shape makes the join read, and what the request's windows hold."""
-    from ..ops.positions import probe_elems
-    _, nid, _field, m_terms, (bucket, depth) = node[:5]
+    from ..ops.positions import probe_elems, probe_rows
+    _, nid, _field, m_terms, (bucket, levels) = node[:5]
     lens = cparams[f"q{nid}_len"]
     PHRASE_STATS.inc("queries")
     PHRASE_STATS.inc("anchor_slots", bucket)
     PHRASE_STATS.inc("anchor_positions", int(lens[0]))
     PHRASE_STATS.inc("window_positions", int(lens.sum()))
-    PHRASE_STATS.inc("probe_elems", probe_elems(bucket, m_terms - 1, depth))
+    PHRASE_STATS.inc("probe_elems", probe_elems(bucket, m_terms - 1, levels))
+    PHRASE_STATS.inc("probe_rows", probe_rows(bucket, m_terms - 1, levels))
 
 
 def count_knn(node, seg_arrays: dict, cparams: dict) -> None:

@@ -217,35 +217,56 @@ def test_product_bucket_counts_compile_for_v5e(shape_on_chip, nb):
 POSITION_SLOTS = 5 << 27    # a PMC shard's positional planes (667M positions)
 
 
-@pytest.mark.parametrize("m,bucket,depth", [(2, 1 << 16, 20),
-                                            (3, 1 << 20, 28)])
-def test_phrase_join_compiles_for_v5e(shape_on_chip, m, bucket, depth):
-    """The phrase join (slop 0) over resident planes at the `pmc` shard's
-    size: the anchor's window is a dynamic slice of the planes (not a
-    gather of `bucket` slots), nothing a plane long is written, and the
-    only scatter is the one into the document plane."""
+@pytest.mark.parametrize("m,bucket,levels", [(2, 1 << 16, 3),
+                                             (3, 1 << 20, 4),
+                                             (2, 1 << 22, 4)])
+def test_phrase_join_compiles_for_v5e(shape_on_chip, m, bucket, levels):
+    """The phrase join (slop 0) over resident planes and their fence levels
+    at the `pmc` shard's size: the anchor's window and the searches' top
+    level are dynamic slices (not gathers of `bucket` slots), a probe below
+    the top gathers a row of 128 a plane and not an element, nothing a
+    plane long is written, the only scatter is the one into the document
+    plane, a gathered block keeps its layout (no copy of it before the
+    compare), and the gathered rows of a pass (2^16 anchors: 32 MiB a
+    plane) bound the temporaries whatever the bucket."""
     from opensearch_tpu.ops import positions as pos_ops
     S = shape_on_chip
     ndocs_pad = 1 << 17
+    assert pos_ops.search_levels(POSITION_SLOTS) == 5
 
-    def join(d, p, off, n, shift, dl, live, w, avgdl):
-        wins = [pos_ops.Window(d, p, off[i], n[i], depth) for i in range(m)]
+    def join(planes, off, n, shift, dl, live, w, avgdl):
+        wins = [pos_ops.resident(planes, off[i], n[i], levels)
+                for i in range(m)]
         ad, ap = pos_ops.anchor_window(wins[0], bucket)
         freq = pos_ops.phrase_freqs(
             ad, ap, wins[1:], jnp.float32(0), ndocs_pad,
             shifts=[shift[i] for i in range(1, m)])
         return pos_ops.phrase_score(freq, dl, live, w, K1, B, avgdl)
     i32, f32 = jnp.int32, jnp.float32
+    planes = {}
+    for k in range(levels):     # a level's entries, in whole rows
+        rows = -(-POSITION_SLOTS // pos_ops.ROW ** (k + 1))
+        for plane in ("doc", "pos"):
+            planes[pos_ops.plane_key(plane, k)] = S((rows * pos_ops.ROW,), i32)
     compiled = jax.jit(join).lower(
-        S((POSITION_SLOTS,), i32), S((POSITION_SLOTS,), i32), S((m,), i32),
-        S((m,), i32), S((m,), i32), S((ndocs_pad,), f32),
-        S((ndocs_pad,), f32), S((), f32), S((), f32)).compile()
+        planes, S((m,), i32), S((m,), i32), S((m,), i32),
+        S((ndocs_pad,), f32), S((ndocs_pad,), f32), S((), f32),
+        S((), f32)).compile()
     text = compiled.as_text()
-    assert len(re.findall(r" dynamic-slice\(", text)) >= 2
+    assert len(re.findall(r" dynamic-slice\(", text)) >= 4
     assert len(re.findall(r" scatter\(", text)) == 1
     assert not re.search(r"s32\[%d\]\{0\} (copy|fusion)\(" % POSITION_SLOTS,
                          text)
-    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+    # the searches' gathers fetch rows: (levels - 1) a plane and further
+    # term; the element gathers left are the landing slot's and its left
+    # neighbour's (`nearest_delta`)
+    per_pass = min(bucket, pos_ops.PASS)
+    rows = re.findall(r"s32\[%d,%d\]\S* gather\(" % (per_pass, pos_ops.ROW),
+                      text)
+    assert len(rows) == 2 * (levels - 1) * (m - 1), len(rows)
+    assert not re.search(r"s32\[%d,%d\]\S* copy\(" % (per_pass, pos_ops.ROW),
+                         text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 128 << 20
 
 
 # ---------------------------------------------------------------------

@@ -4,6 +4,7 @@ PhraseQuery / SloppyPhraseMatcher via `index/query/MatchPhraseQueryBuilder`)."""
 
 import math
 
+import numpy as np
 import pytest
 
 from opensearch_tpu.index.engine import Engine
@@ -266,3 +267,155 @@ def test_phrase_across_segments_and_deletes():
     s2 = ShardSearcher(e)
     r = search(s2, {"query": {"match_phrase": {"body": "red green"}}})
     assert set(ids(r)) == {"a"}
+
+
+# ---------------------------------------------------------------------
+# the join's search alone: `ops.positions.window_searchsorted`, a 128-ary
+# descent through fence levels, against numpy's searchsorted over packed
+# int64 keys
+# ---------------------------------------------------------------------
+
+SIZES = [0, 1, 127, 128, 129, 16_383, 16_384, 16_385, (1 << 21) + 1]
+PER_DOC = 64        # positions a document: a pair is key // 64, key % 64
+
+
+def _pairs(rng, n, span):
+    """`n` distinct sorted (doc, position) pairs with keys under `span`."""
+    keys = np.sort(rng.choice(span, n, replace=False)).astype(np.int64)
+    return (keys // PER_DOC).astype(np.int32), (keys % PER_DOC).astype(
+        np.int32)
+
+
+def _pack(d, p):
+    return (np.asarray(d, np.int64) << 32) + np.asarray(p, np.int64)
+
+
+def _plane(rng, n, place):
+    """A (doc, pos) plane that holds a window of `n` pairs and its offset.
+    `first`: the window begins at slot 0; `last`: it ends at the plane's
+    last slot, a whole number of rows in; `middle`: it begins and ends off
+    a row boundary, a sentinel-padded tail behind its right neighbour. The
+    neighbours are other terms' windows over the same key range: by value
+    their pairs lie "inside"."""
+    from opensearch_tpu.ops import positions as pos_ops
+    span = 4 * n + 1024
+    left = {"first": 0, "last": (-n) % pos_ops.ROW + 3 * pos_ops.ROW,
+            "middle": 301 if (301 + n) % pos_ops.ROW else 300}[place]
+    right = 0 if place == "last" else 333
+    parts = [_pairs(rng, k, span) for k in (left, n, right)]
+    d = np.concatenate([x[0] for x in parts])
+    p = np.concatenate([x[1] for x in parts])
+    if place == "middle":
+        tail = 77 + (-len(d)) % pos_ops.ROW
+        d = np.concatenate([d, np.full(tail, pos_ops.INT32_SENTINEL)])
+        p = np.concatenate([p, np.zeros(tail, np.int32)])
+        assert left % pos_ops.ROW and (left + n) % pos_ops.ROW
+    return d.astype(np.int32), p.astype(np.int32), left
+
+
+def _queries(rng, d, p, lo, n, count=300):
+    """Keys below, inside, equal to and above the window's pairs."""
+    span = 4 * n + 1024
+    qd, qp = _pairs(rng, count, span)
+    qd, qp = list(qd), list(qp)
+    qd += [0, 0, -1, int(np.int32(2**31 - 1)), span // PER_DOC + 1]
+    qp += [0, -5, 3, 0, 0]
+    if n:
+        at = rng.integers(lo, lo + n, 64)
+        for i in [lo, lo + n - 1] + at.tolist():
+            qd += [int(d[i])] * 3
+            qp += [int(p[i]) - 1, int(p[i]), int(p[i]) + 1]
+    return np.asarray(qd, np.int32), np.asarray(qp, np.int32)
+
+
+def _want(d, p, lo, n, qd, qp):
+    return lo + np.searchsorted(_pack(d[lo: lo + n], p[lo: lo + n]),
+                                _pack(qd, qp), side="left")
+
+
+@pytest.mark.parametrize("place", ["first", "last", "middle"])
+@pytest.mark.parametrize("n", SIZES)
+def test_window_searchsorted_is_numpys(n, place):
+    """The slot of the first pair >= the key, `lo + n` where none is, for
+    windows of every level count up to 4 wherever they lie in the plane,
+    with the levels the window's length asks for and with every level the
+    plane has."""
+    import jax
+    import jax.numpy as jnp
+    from opensearch_tpu.ops import positions as pos_ops
+    rng = np.random.default_rng([n, len(place)])
+    d, p, lo = _plane(rng, n, place)
+    qd, qp = _queries(rng, d, p, lo, n)
+    dj, pj = jnp.asarray(d), jnp.asarray(p)
+    planes = {"doc": dj, "pos": pj}
+    for plane in ("doc", "pos"):
+        for k, level in enumerate(pos_ops.fences(planes[plane]), 1):
+            assert level.shape[0] % pos_ops.ROW == 0
+            planes[pos_ops.plane_key(plane, k)] = level
+    whole_plane = pos_ops.search_levels(len(d))
+    assert set(planes) == set(pos_ops.plane_keys(whole_plane))
+    want = _want(d, p, lo, n, qd, qp)
+    for levels in sorted({pos_ops.search_levels(n), whole_plane}):
+        search = jax.jit(lambda planes, lo, n, qd, qp, levels=levels:
+                         pos_ops.window_searchsorted(
+                             pos_ops.resident(planes, lo, n, levels), qd, qp))
+        got = np.asarray(search(planes, np.int32(lo), np.int32(n), qd, qp))
+        assert np.array_equal(got, want), (levels, np.flatnonzero(got != want))
+    assert pos_ops.search_levels(n) == (
+        1 if n <= 128 else 2 if n <= 16_384 else 3 if n <= 1 << 21 else 4)
+
+
+@pytest.mark.parametrize("length", [1, 5, 64, 127, 200, 1000, 16_385, 20_001])
+def test_window_searchsorted_over_arrays_of_its_own(length):
+    """`whole`: an array of pairs whose length is no multiple of a row, its
+    tail sentinel-padded, its fences made in the program; alone and a batch
+    under `jax.vmap` (the mesh path's form)."""
+    import jax
+    import jax.numpy as jnp
+    from opensearch_tpu.ops import positions as pos_ops
+    rng = np.random.default_rng(length)
+    batch = []
+    for real in sorted({length, max(length - 3, 0), length // 2}):
+        d, p = _pairs(rng, real, 4 * length + 1024)
+        d = np.concatenate([d, np.full(length - real, pos_ops.INT32_SENTINEL)])
+        p = np.concatenate([p, np.full(length - real, pos_ops.INT32_SENTINEL)])
+        qd, qp = _queries(rng, d, p, 0, real)
+        batch.append((d.astype(np.int32), p.astype(np.int32), qd, qp, real))
+
+    def search(d, p, qd, qp):
+        return pos_ops.window_searchsorted(pos_ops.whole(d, p), qd, qp)
+    for d, p, qd, qp, real in batch:
+        # the padding is part of the array's window: a key past every real
+        # pair lands on the first sentinel, the sentinel key itself too
+        got = np.asarray(jax.jit(search)(d, p, qd, qp))
+        assert np.array_equal(got, _want(d, p, 0, length, qd, qp))
+        for levels in (None, 4):    # its own, and a longer term's
+            counted = pos_ops.whole(jnp.asarray(d), jnp.asarray(p),
+                                    np.int32(real), levels)
+            got = np.asarray(pos_ops.window_searchsorted(counted, qd, qp))
+            assert np.array_equal(got, _want(d, p, 0, real, qd, qp))
+    nq = min(len(b[2]) for b in batch)
+    stacked = [np.stack([b[i][:nq] if i > 1 else b[i] for b in batch])
+               for i in range(4)]
+    got = np.asarray(jax.jit(jax.vmap(search))(*stacked))
+    for row, (d, p, qd, qp, _real) in zip(got, batch):
+        assert np.array_equal(row, _want(d, p, 0, length, qd[:nq], qp[:nq]))
+
+
+def test_window_searchsorted_walks_the_anchors_in_passes(monkeypatch):
+    """More queries than a pass holds: the same slots, whatever the pass
+    (the `pmc` cell's buckets of 2^18 and 2^20 slots walk 2^16 a pass)."""
+    import jax.numpy as jnp
+    from opensearch_tpu.ops import positions as pos_ops
+    rng = np.random.default_rng(7)
+    d, p, lo = _plane(rng, 16_385, "middle")
+    qd, qp = _queries(rng, d, p, lo, 16_385, count=1500)
+    w = pos_ops.whole(jnp.asarray(d), jnp.asarray(p))._replace(
+        lo=np.int32(lo), n=np.int32(16_385))
+    want = _want(d, p, lo, 16_385, qd, qp)
+    assert np.array_equal(np.asarray(
+        pos_ops.window_searchsorted(w, qd, qp)), want)
+    monkeypatch.setattr(pos_ops, "PASS", 256)
+    assert len(qd) % 256 and len(qd) > 4 * 256
+    assert np.array_equal(np.asarray(
+        pos_ops.window_searchsorted(w, qd, qp)), want)

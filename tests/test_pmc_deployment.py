@@ -209,8 +209,9 @@ def test_every_slot_as_anchor_counts_the_same(deployments):
         for t in terms:
             a, b = pb.row_slice(pb.row(arts["words"][t]))
             lo, hi = int(pb.pos_starts[a]), int(pb.pos_starts[b])
-            wins.append(pos_ops.Window(planes["doc"], planes["pos"],
-                                       np.int32(lo), np.int32(hi - lo), 24))
+            wins.append(pos_ops.resident(
+                planes, np.int32(lo), np.int32(hi - lo),
+                pos_ops.search_levels(hi - lo)))
         for anchor in range(len(terms)):
             others = [i for i in range(len(terms)) if i != anchor]
             ad, ap = pos_ops.anchor_window(
@@ -236,8 +237,11 @@ def test_an_exact_phrase_anchors_on_its_cheapest_slot(deployments):
     assert exact["anchor_positions"] == cf[terms[1]]
     assert exact["anchor_slots"] == pos_ops.anchor_bucket(int(cf[terms[1]]))
     assert exact["window_positions"] == cf[terms[0]] + cf[terms[1]]
+    levels = pos_ops.search_levels(int(cf[terms[0]]))
     assert exact["probe_elems"] == pos_ops.probe_elems(
-        exact["anchor_slots"], 1, pos_ops.search_depth(int(cf[terms[0]])))
+        exact["anchor_slots"], 1, levels) \
+        == exact["anchor_slots"] * (2 * (levels - 1) + 4)
+    assert exact["probe_rows"] == exact["anchor_slots"] * 2 * (levels - 1)
     before = dict(C.PHRASE_STATS)
     sloppy = _phrase(client, arts, terms, slop=1)
     moved = {k: C.PHRASE_STATS[k] - before[k] for k in before}
@@ -265,10 +269,23 @@ def test_the_planes_are_resident_and_leave_with_the_segment(deployments):
         return sum(a["bytes"] for a in LEDGER.top_tenants(10 ** 6)
                    if a["kind"] == "position_planes"
                    and a["label"] == f"segment-positions[{seg.name}]")
-    want = sum(2 * 4 * p["doc"].shape[0] for p in
-               seg._device_positions[None].values())
+    # beside each plane its fence levels (every 128th slot, every
+    # 16,384th ...: what a probe of the join's search reads a row of),
+    # made with the planes, booked with them and dropped with them
+    levels = pos_ops.search_levels(slots)
+    assert levels >= 3 and set(planes) == set(pos_ops.plane_keys(levels))
+    for plane in ("doc", "pos"):
+        for k in range(1, levels):
+            level = np.asarray(planes[pos_ops.plane_key(plane, k)])
+            every = np.asarray(planes[plane])[::pos_ops.ROW ** k]
+            assert len(level) % pos_ops.ROW == 0
+            assert np.array_equal(level[: len(every)], every)
+    want = sum(int(a.nbytes) for p in seg._device_positions[None].values()
+               for a in p.values())
     held = mine()           # this segment's, and its namesakes' elsewhere
-    assert held >= want >= 2 * 4 * slots
+    assert held >= want > 2 * 4 * slots
+    assert sum(int(a.nbytes) for a in planes.values()) \
+        <= 2 * 4 * (slots + slots // 127 + 3 * pos_ops.ROW)
     seg.drop_device()
     assert mine() == held - want and seg._device_positions == {}
     # and they come back with the next request
