@@ -387,6 +387,29 @@ class PostingsBlock:
         return int(self.starts[row]), int(self.starts[row + 1])
 
 
+def rows_in_order(values: np.ndarray,
+                  present: np.ndarray) -> Optional[np.ndarray]:
+    """`values` with every row that has none (`present` false) taking the
+    value of the nearest row before it that has one (the type's least
+    before the first), a non-decreasing array a binary search can read,
+    where the values are non-decreasing in row order; None where they are
+    not. The rows without a value take no part: the running maximum
+    forward-fills them, and a row in order is one that is its own running
+    maximum. The one predicate a date plane's `planes.run_starts` and a
+    column's `NumericColumn.in_row_order` ask."""
+    floats = values.dtype.kind == "f"
+    if present.all():
+        filled = values
+        ordered = not (values[1:] < values[:-1]).any()
+    else:
+        least = -np.inf if floats else np.iinfo(values.dtype).min
+        filled = np.maximum.accumulate(np.where(present, values, least))
+        ordered = not (present & (values < filled)).any()
+    if not ordered or (floats and np.isnan(filled).any()):  # (no order
+        return None                                         # holds a NaN)
+    return filled
+
+
 @dataclass
 class NumericColumn:
     field: str
@@ -396,6 +419,14 @@ class NumericColumn:
 
     _sort_ords: Optional[np.ndarray] = None
     _min_max: Optional[Tuple[float, float]] = None
+
+    @cached_property
+    def in_row_order(self) -> Optional[np.ndarray]:
+        """`rows_in_order` of the column, computed once (the column is
+        immutable; one pass, and no copy where every row has a value):
+        what `compiler.row_span` searches for a range's first and last
+        row. None for a column in no row order."""
+        return rows_in_order(self.values, self.present)
 
     @property
     def min_max(self) -> Tuple[float, float]:

@@ -26,6 +26,13 @@ scatter issues one update a row, which the TPU runs one after another
 `bucket_sums_exact` and `bucketed_sub_metric` are dense under
 `_DENSE_BUCKETS` and scatter from there on, but for the metric's count,
 which is a `bucket_counts`.
+The dense and the product form are loops over blocks of rows, and a caller
+that knows a half-open range of rows outside which no row weighs anything
+(`span`: `search/compiler.row_span`, a `range` over a column in row order)
+hands it down: the loops then visit the blocks that meet it and no other
+(`_block_range`). The weights still decide every row of those blocks, so
+the arrays are the whole plane's, bit for bit; the default is the whole
+plane.
 `run_counts` serves a plane whose ids are non-decreasing in row order (a
 date histogram over an append-only log segment): each bucket is one run of
 rows, so a count is a difference of two prefix sums of the weights, read at
@@ -147,9 +154,38 @@ def _row_blocks(x: jnp.ndarray, rows: int, fill) -> jnp.ndarray:
     return x.reshape(nblk, per // 128, 128)
 
 
+def _block_range(span, rows: int, nblk: int) -> tuple:
+    """(first, end) of the blocks of `rows` rows, of `nblk`, that meet the
+    rows [lo, hi) of `span` (None is the whole plane): what the block loops
+    run over. An empty or inverted span meets none. Two int32 scalars of a
+    trace give traced bounds; the host's integers (`span_rows`) give
+    numpy's, by the same arithmetic."""
+    if span is None:
+        return 0, nblk
+    lo, hi = span
+    xp = np if isinstance(lo, (int, np.integer)) else jnp
+    first = xp.clip(lo // rows, 0, nblk)
+    return first, xp.where(hi > lo, xp.clip(-(-hi // rows), first, nblk),
+                           first)
+
+
+def span_rows(span, rows: int, n: int) -> int:
+    """Host: the rows, of a plane of `n`, in the blocks of `rows` rows that
+    `_block_range` names for `span`: what the loop reads."""
+    first, end = _block_range(
+        span if span is None else (int(span[0]), int(span[1])), rows,
+        max(-(-n // rows), 1))
+    return int(min(end * rows, n) - min(first * rows, n))
+
+
+def _block_at(blocks: Optional[jnp.ndarray], i) -> Optional[jnp.ndarray]:
+    return None if blocks is None else jax.lax.dynamic_index_in_dim(
+        blocks, i, 0, keepdims=False)
+
+
 def _dense_reduce(held: jnp.ndarray, nbuckets: int, rows: int,
                   v: Optional[jnp.ndarray] = None, parts=None,
-                  extremes: bool = False) -> tuple:
+                  extremes: bool = False, span=None) -> tuple:
     """The dense form: for every block of `rows` rows and every bucket, the
     count of the rows of `held` (`_held_ids`) in it; the sum over them of
     each int32 plane that `parts(block of v, block of rows that count)`
@@ -157,16 +193,17 @@ def _dense_reduce(held: jnp.ndarray, nbuckets: int, rows: int,
     they never exist as whole planes); with `extremes` the least and the
     greatest of `v` among them -> i32[blocks, nbuckets] each, the extremes
     f32 (an empty bucket reads 0, `F32_MAX`, `-F32_MAX`). A loop over the
-    blocks: each is read from HBM once, laid along the lanes and compared
-    against all bucket ids at once, and every accumulator is a reduction
-    over that one comparison, so the [buckets, rows] one-hot exists a
-    block at a time, inside a fusion. The planes enter as `_row_blocks`
-    views, the tail padded with rows that count nowhere."""
+    blocks that meet `span` (`_block_range`; a block it does not visit reads
+    as one in which no row counts): each is read from HBM once, laid along
+    the lanes and compared against all bucket ids at once, and every
+    accumulator is a reduction over that one comparison, so the [buckets,
+    rows] one-hot exists a block at a time, inside a fusion. The planes
+    enter as `_row_blocks` views, the tail padded with rows that count
+    nowhere."""
     per = -(-rows // 128) * 128
     ids = jnp.arange(nbuckets, dtype=jnp.int32)[:, None]
 
-    def one(block):
-        t, vals = block
+    def one(t, vals):
         hot = t.reshape(1, per) == ids
         out = [jnp.sum(hot.astype(jnp.int32), axis=1)]
         if parts is not None:
@@ -179,17 +216,40 @@ def _dense_reduce(held: jnp.ndarray, nbuckets: int, rows: int,
         return tuple(out)
 
     with jax.named_scope(DENSE_SCOPE):
-        return jax.lax.map(one, (
-            _row_blocks(held, rows, nbuckets),
-            None if v is None else _row_blocks(v, rows, 0.0)))
+        blocks = _row_blocks(held, rows, nbuckets)
+        vblocks = None if v is None else _row_blocks(v, rows, 0.0)
+        nblk = blocks.shape[0]
+        outs = jax.eval_shape(
+            lambda: one(_block_at(blocks, 0), _block_at(vblocks, 0)))
+        empty = [0] * len(outs)
+        if extremes:
+            empty[-2:] = F32_MAX, -F32_MAX
+        first, end = _block_range(span, rows, nblk)
+        return jax.lax.fori_loop(
+            first, end,
+            lambda i, acc: tuple(
+                jax.lax.dynamic_update_index_in_dim(a, got, i, 0)
+                for a, got in zip(
+                    acc, one(_block_at(blocks, i), _block_at(vblocks, i)))),
+            tuple(jnp.full((nblk,) + o.shape, e, o.dtype)
+                  for o, e in zip(outs, empty)))
 
 
 # rows a block of the product form: one `dot_general` contracts a block's
 # rows into float32, exact while no slot's partial passes 2^24, and each
-# block's partial is added as int32, so a block holds at most 2^24 rows
-# (the probe read 2^13 rows 1-2% slower than 2^15, 2^18 and 2^21 another
-# 1-2% faster, 2^24 as 2^15: PERF.md, PR 45)
-_PRODUCT_BLOCK = 1 << 18
+# block's partial is added as int32, so a block holds at most 2^24 rows.
+# A span's ends each read a whole block, so a narrow span wants small
+# blocks and the whole plane does not mind: into 461,089 slots at 2^24
+# rows, spans of 0.6% / 2.6% / 7% / 100% of the rows read 3.33 / 4.51 /
+# 8.22 / 83.6 ms at 2^18 rows a block, 2.51 / 4.05 / 7.63 / 82.6 at 2^16,
+# 2.29 / 4.18 / 7.93 / 83.9 at 2^14 (launch and read included: PERF.md,
+# PR 49; PR 45 had read whole planes alone and 2^18 a percent under 2^15)
+_PRODUCT_BLOCK = 1 << 16
+
+
+def product_block_rows(n: int) -> int:
+    """Rows a block of the product form over a plane of `n` rows."""
+    return min(_PRODUCT_BLOCK, -(-max(n, 1) // 128) * 128)
 
 
 def product_split(nbuckets: int) -> Tuple[int, int]:
@@ -201,10 +261,12 @@ def product_split(nbuckets: int) -> Tuple[int, int]:
     return -(-nbuckets // l), l
 
 
-def _product_counts(held: jnp.ndarray, nbuckets: int) -> jnp.ndarray:
+def _product_counts(held: jnp.ndarray, nbuckets: int,
+                    span=None) -> jnp.ndarray:
     """The product form: the count of the rows of `held` (`_held_ids`) in
-    every bucket -> i32[nbuckets]. A loop over blocks of rows (the plane
-    viewed as `_row_blocks`): a block's ids laid along the lanes,
+    every bucket -> i32[nbuckets]. A loop over the blocks of rows that meet
+    `span` (the plane viewed as `_row_blocks`; `_block_range`): a block's
+    ids laid along the lanes,
     `hi == arange(H)` and `lo == arange(L)` made while the block is on the
     chip (XLA fuses both comparisons into the product's operands: no
     one-hot is written) and contracted over the block's rows by one
@@ -213,43 +275,55 @@ def _product_counts(held: jnp.ndarray, nbuckets: int) -> jnp.ndarray:
     falls in a slot past the last bucket, or where `H x L` is `nbuckets`
     in no row of the one-hot at all."""
     h, l = product_split(nbuckets)
-    per = min(_PRODUCT_BLOCK, -(-max(held.shape[0], 1) // 128) * 128)
+    per = product_block_rows(held.shape[0])
     assert per <= 1 << 24, per
     shift = l.bit_length() - 1
     his = jnp.arange(h, dtype=jnp.int32)[:, None]
     los = jnp.arange(l, dtype=jnp.int32)[:, None]
 
-    def one(acc, t):
-        t = t.reshape(1, per)
-        hot_hi = ((t >> shift) == his).astype(jnp.bfloat16)
-        hot_lo = ((t & (l - 1)) == los).astype(jnp.bfloat16)
-        part = jax.lax.dot_general(
-            hot_hi, hot_lo, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        return acc + part.astype(jnp.int32), None
-
     with jax.named_scope(PRODUCT_SCOPE):
-        acc, _ = jax.lax.scan(one, jnp.zeros((h, l), jnp.int32),
-                              _row_blocks(held, per, nbuckets))
+        blocks = _row_blocks(held, per, nbuckets)
+
+        def one(i, acc):
+            t = _block_at(blocks, i).reshape(1, per)
+            hot_hi = ((t >> shift) == his).astype(jnp.bfloat16)
+            hot_lo = ((t & (l - 1)) == los).astype(jnp.bfloat16)
+            part = jax.lax.dot_general(
+                hot_hi, hot_lo, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            return acc + part.astype(jnp.int32)
+
+        acc = jax.lax.fori_loop(
+            *_block_range(span, per, blocks.shape[0]), one,
+            jnp.zeros((h, l), jnp.int32))
         return acc.reshape(h * l)[:nbuckets]
 
 
+def dense_block_rows(n: int) -> int:
+    """Rows a block of the dense count over a plane of `n` rows."""
+    return max(min(n, _DENSE_BLOCK), 1)
+
+
 def bucket_counts(bucket_ids: jnp.ndarray, w: jnp.ndarray,
-                  nbuckets: int) -> jnp.ndarray:
+                  nbuckets: int, span=None) -> jnp.ndarray:
     """Documents per bucket, i32[nbuckets]: `w` is a 0/1 weight per row and
     ids outside [0, nbuckets) are dropped. Counts accumulate in int32: a
     float32 count stops at 2^24 = 16,777,216, and one bucket of a large
     segment can hold more. The forms for ids in any order (the module's
     docstring, `count_form`): dense under `_DENSE_BUCKETS` buckets, the
     product of two one-hots under `_PRODUCT_BUCKETS`, else one scatter
-    update a row; ids that are sorted by row take `run_counts`."""
+    update a row; ids that are sorted by row take `run_counts`. No row
+    outside `span` has weight (the module's docstring): the dense and the
+    product form read the blocks that meet it, the scatter issues an update
+    a row whatever it is."""
     held = _held_ids(bucket_ids, w, nbuckets)
     form = count_form(nbuckets)
     if form == "dense":
-        rows = max(min(held.shape[0], _DENSE_BLOCK), 1)
-        return jnp.sum(_dense_reduce(held, nbuckets, rows)[0], axis=0)
+        rows = dense_block_rows(held.shape[0])
+        return jnp.sum(_dense_reduce(held, nbuckets, rows, span=span)[0],
+                       axis=0)
     if form == "product":
-        return _product_counts(held, nbuckets)
+        return _product_counts(held, nbuckets, span)
     with jax.named_scope(SCATTER_SCOPE):
         return jnp.zeros(nbuckets, jnp.int32).at[held].add(1, mode="drop")
 
@@ -396,18 +470,20 @@ def _folded(accs: list) -> jnp.ndarray:
 
 
 def bucket_sums_exact(bucket_ids: jnp.ndarray, v: jnp.ndarray,
-                      w: jnp.ndarray, nbuckets: int, inv) -> jnp.ndarray:
+                      w: jnp.ndarray, nbuckets: int, inv,
+                      span=None) -> jnp.ndarray:
     """Per-bucket sums of `v` over the rows with `w` > 0 and an id in
     [0, nbuckets), as i32[2 x limbs, nbuckets] for `limb_sums_to_f64`:
     each limb summed in int32 by (block of rows, bucket), densely or by
     one scatter-add a limb (`count_form`: the product form is a count's
-    alone, so its range scatters here)."""
+    alone, so its range scatters here). `span` as `bucket_counts` takes it:
+    a block the dense form does not visit hands on zero partials."""
     limbs, bits, rows = sum_limb_plan(bucket_ids.shape[0], nbuckets)
     held = _held_ids(bucket_ids, w, nbuckets)
     if count_form(nbuckets) == "dense":
         return _folded(_dense_reduce(
             held, nbuckets, rows, v,
-            lambda vb, ok: _limbs(vb, ok, inv, limbs, bits))[1:])
+            lambda vb, ok: _limbs(vb, ok, inv, limbs, bits), span=span)[1:])
     return _folded(_scatter_block_sums(
         held, _limbs(v, w, inv, limbs, bits), nbuckets, rows))
 
@@ -451,7 +527,7 @@ def sub_metric_scatters(n: int, nbuckets: int, sumsq: bool) -> int:
 
 def bucketed_sub_metric(bucket_ids: jnp.ndarray, v: jnp.ndarray,
                         w: jnp.ndarray, nbuckets: int, inv,
-                        sumsq: bool) -> dict:
+                        sumsq: bool, span=None) -> dict:
     """count / min / max / sum (and the sum of squares where `sumsq`) of
     `v` per bucket over the rows with `w` > 0: counts in int32, extremes as
     they are stored, sums in limbs (`bucket_sums_exact`). `inv` is
@@ -459,7 +535,7 @@ def bucketed_sub_metric(bucket_ids: jnp.ndarray, v: jnp.ndarray,
     Under `_DENSE_BUCKETS` buckets every accumulator is a reduction over
     one comparison of the rows with the bucket ids; else each is a
     scatter, but for the count, which is a `bucket_counts` (a product
-    under `_PRODUCT_BUCKETS`)."""
+    under `_PRODUCT_BUCKETS`). `span` as `bucket_counts` takes it."""
     # the scope names these ops in the device trace (an op's provenance:
     # the benchmark's `agg_bucketed_sub_share` sums their time)
     with jax.named_scope(SUB_METRIC_SCOPE):
@@ -475,12 +551,12 @@ def bucketed_sub_metric(bucket_ids: jnp.ndarray, v: jnp.ndarray,
             return out
 
         if count_form(nbuckets) == "dense":     # one pass for them all
-            count, *accs, lo, hi = _dense_reduce(b, nbuckets, rows, v,
-                                                 parts, extremes=True)
+            count, *accs, lo, hi = _dense_reduce(
+                b, nbuckets, rows, v, parts, extremes=True, span=span)
             count = jnp.sum(count, axis=0)
             lo, hi = jnp.min(lo, axis=0), jnp.max(hi, axis=0)
         else:
-            count = bucket_counts(b, w, nbuckets)
+            count = bucket_counts(b, w, nbuckets, span)
             with jax.named_scope(SCATTER_SCOPE):
                 lo = jnp.full(nbuckets, F32_MAX).at[b].min(v, mode="drop")
                 hi = jnp.full(nbuckets, -F32_MAX).at[b].max(v, mode="drop")
@@ -492,26 +568,29 @@ def bucketed_sub_metric(bucket_ids: jnp.ndarray, v: jnp.ndarray,
     return out
 
 
-def terms_counts(kw: dict, match: jnp.ndarray, nvocab_pad: int) -> jnp.ndarray:
+def terms_counts(kw: dict, match: jnp.ndarray, nvocab_pad: int,
+                 span=None) -> jnp.ndarray:
     """Keyword terms agg: per-ordinal doc counts (reference
-    GlobalOrdinalsStringTermsAggregator). Returns i32[nvocab_pad]."""
+    GlobalOrdinalsStringTermsAggregator). Returns i32[nvocab_pad]. `span`
+    is in documents: a column laid out by value (its rows are values)
+    reads every block."""
     if not counts_by_value(kw):     # (-1 and padded rows: `_held_ids`)
-        return bucket_counts(kw["min_ord"], match, nvocab_pad)
+        return bucket_counts(kw["min_ord"], match, nvocab_pad, span)
     return bucket_counts(kw["ords"], _gather_match(match, kw["doc_of_value"]),
                          nvocab_pad)
 
 
 def terms_sub_metric(kw: dict, match: jnp.ndarray, values_f32: jnp.ndarray,
                      present: jnp.ndarray, nvocab_pad: int, inv,
-                     sumsq: bool) -> dict:
+                     sumsq: bool, span=None) -> dict:
     """Per-ordinal count / min / max / sum of a numeric column: the metric
     sub-aggregations under a terms bucket (`bucketed_sub_metric` over the
     ordinals by document, the column read in place, or over the flat
-    values' ordinals)."""
+    values' ordinals). `span` as `terms_counts` takes it."""
     if not counts_by_value(kw):
         return bucketed_sub_metric(
             kw["min_ord"], values_f32, match * jnp.where(present, 1.0, 0.0),
-            nvocab_pad, inv, sumsq)
+            nvocab_pad, inv, sumsq, span)
     docs = kw["doc_of_value"]
     safe = jnp.minimum(docs, values_f32.shape[0] - 1)
     w = _gather_match(match, docs) * jnp.where(present[safe], 1.0, 0.0)
@@ -602,12 +681,12 @@ def geo_centroid_agg(lat: jnp.ndarray, lon: jnp.ndarray, present: jnp.ndarray,
     return jnp.sum(w * lat), jnp.sum(w * lon), jnp.sum(w)
 
 
-def ord_counts(ords: jnp.ndarray, match: jnp.ndarray, nord_pad: int
-               ) -> jnp.ndarray:
+def ord_counts(ords: jnp.ndarray, match: jnp.ndarray, nord_pad: int,
+               span=None) -> jnp.ndarray:
     """Doc-major single-valued ordinal bincount (multi_terms combined ords,
     grid ords): ord < 0 = missing -> dropped."""
     o = jnp.where(ords >= 0, ords, nord_pad)
-    return bucket_counts(o, match, nord_pad)
+    return bucket_counts(o, match, nord_pad, span)
 
 
 def _hash_f32(v: jnp.ndarray) -> jnp.ndarray:
@@ -644,12 +723,13 @@ def cardinality_numeric_registers(values_f32: jnp.ndarray, present: jnp.ndarray,
 
 
 def cardinality_keyword_registers(kw: dict, match: jnp.ndarray, nvocab_pad: int,
-                                  ord_hashes_u32: jnp.ndarray, log2m: int = 14):
+                                  ord_hashes_u32: jnp.ndarray, log2m: int = 14,
+                                  span=None):
     """Keyword cardinality: HLL over per-ordinal string hashes (host-computed
     once per segment), activated by matched ordinals -> (registers, the
     number of matched ordinals: the segment's exact distinct count, which
     is the answer where one segment gives it and nothing is merged)."""
-    held = terms_counts(kw, match, nvocab_pad) > 0
+    held = terms_counts(kw, match, nvocab_pad, span) > 0
     return (hll_registers(ord_hashes_u32, held, log2m),
             jnp.sum(held.astype(jnp.int32)))
 

@@ -775,16 +775,25 @@ _TERMS_STAGE_KINDS = frozenset({"terms", "sig_terms", "multi_terms",
                                 "composite", "composite_mv", "card_kw"})
 
 
-def emit_agg(spec, seg_arrays: dict, params: dict, match, scores=None):
-    """-> nested dict of device arrays (this segment's partial)."""
+def emit_agg(spec, seg_arrays: dict, params: dict, match, scores=None,
+             span=None):
+    """-> nested dict of device arrays (this segment's partial). `span` is
+    the launch's row span (`compiler.row_span`: two traced int32 scalars,
+    rows of `seg_arrays` outside which `match` is 0; None the whole
+    segment), handed to the group-bys that reduce `match` over this
+    segment's rows (`ops.aggs.bucket_counts`) and through the containers
+    that only narrow it; it is whole again where the match or the rows are
+    not the query's (`global`, the nested and join kinds, a keyword column
+    laid out by value) and under the two containers `programs.agg_cost`
+    does not walk (`diversified_sampler`, `ip_range`)."""
     if spec[0] in _TERMS_STAGE_KINDS:
         import jax
         with jax.named_scope(TERMS_SCOPE):
-            return _emit_agg(spec, seg_arrays, params, match, scores)
-    return _emit_agg(spec, seg_arrays, params, match, scores)
+            return _emit_agg(spec, seg_arrays, params, match, scores, span)
+    return _emit_agg(spec, seg_arrays, params, match, scores, span)
 
 
-def _emit_agg(spec, seg_arrays: dict, params: dict, match, scores=None):  # noqa: C901
+def _emit_agg(spec, seg_arrays: dict, params: dict, match, scores, span):  # noqa: C901
     import jax
     import jax.numpy as jnp
 
@@ -800,7 +809,7 @@ def _emit_agg(spec, seg_arrays: dict, params: dict, match, scores=None):  # noqa
     if kind == "sig_terms":
         _, prefix, field, nvocab_pad, subs = spec
         kw = seg_arrays["keyword"][field]
-        out = {"counts": agg_ops.terms_counts(kw, match, nvocab_pad),
+        out = {"counts": agg_ops.terms_counts(kw, match, nvocab_pad, span),
                "fg_total": jnp.sum(match)}
         for i, sub in enumerate(subs):
             if sub and sub[0] == "stats":
@@ -809,7 +818,7 @@ def _emit_agg(spec, seg_arrays: dict, params: dict, match, scores=None):  # noqa
                     col = seg_arrays["numeric"][sfield]
                     out[f"sub{i}"] = agg_ops.terms_sub_metric(
                         kw, match, col["f32"], col["present"], nvocab_pad,
-                        params[f"{sprefix}_sinv"], sumsq)
+                        params[f"{sprefix}_sinv"], sumsq, span)
         return out
 
     if kind == "sampler":
@@ -834,7 +843,7 @@ def _emit_agg(spec, seg_arrays: dict, params: dict, match, scores=None):  # noqa
             out["topscores"] = vals
         out["doc_count"] = jnp.sum(sel)
         for i, sub in enumerate(subs):
-            res = emit_agg(sub, seg_arrays, params, sel, scores)
+            res = emit_agg(sub, seg_arrays, params, sel, scores, span)
             if res:
                 out[f"sub{i}"] = res
         return out
@@ -844,10 +853,10 @@ def _emit_agg(spec, seg_arrays: dict, params: dict, match, scores=None):  # noqa
         ords = params[f"{prefix}_gords"][:ndocs_pad]
         w = match * (ords >= 0).astype(jnp.float32)
         b = jnp.where(w > 0, ords, nb)
-        out = {"counts": agg_ops.bucket_counts(b, w, nb)}
+        out = {"counts": agg_ops.bucket_counts(b, w, nb, span)}
         for i, sub in enumerate(subs):
             out.update(_emit_bucketed_sub(jnp, sub, i, b, nb, seg_arrays,
-                                          match, params))
+                                          match, params, span))
         return out
 
     if kind == "nested_agg":
@@ -917,7 +926,7 @@ def _emit_agg(spec, seg_arrays: dict, params: dict, match, scores=None):  # noqa
     if kind == "composite_mv":
         _, prefix, field, nb, subs = spec
         kw = seg_arrays["keyword"][field]
-        out = {"counts": agg_ops.terms_counts(kw, match, nb)}
+        out = {"counts": agg_ops.terms_counts(kw, match, nb, span)}
         for i, sub in enumerate(subs):
             if sub and sub[0] == "stats":
                 _, sprefix, sfield, col_exists, sumsq = sub
@@ -925,7 +934,7 @@ def _emit_agg(spec, seg_arrays: dict, params: dict, match, scores=None):  # noqa
                     col = seg_arrays["numeric"][sfield]
                     out[f"sub{i}"] = agg_ops.terms_sub_metric(
                         kw, match, col["f32"], col["present"], nb,
-                        params[f"{sprefix}_sinv"], sumsq)
+                        params[f"{sprefix}_sinv"], sumsq, span)
         return out
 
     if kind == "composite":
@@ -948,10 +957,10 @@ def _emit_agg(spec, seg_arrays: dict, params: dict, match, scores=None):  # noqa
         valid = valid & (o >= 0)
         w = valid.astype(jnp.float32)
         b = jnp.where(valid, o, total)
-        out = {"counts": agg_ops.bucket_counts(b, w, total)}
+        out = {"counts": agg_ops.bucket_counts(b, w, total, span)}
         for i, sub in enumerate(subs):
             out.update(_emit_bucketed_sub(jnp, sub, i, b, total, seg_arrays,
-                                          match * w, params))
+                                          match * w, params, span))
         return out
 
     if kind == "matrix_stats":
@@ -979,7 +988,7 @@ def _emit_agg(spec, seg_arrays: dict, params: dict, match, scores=None):  # noqa
     if kind == "terms":
         _, prefix, field, nvocab_pad, subs = spec
         kw = seg_arrays["keyword"][field]
-        out = {"counts": agg_ops.terms_counts(kw, match, nvocab_pad)}
+        out = {"counts": agg_ops.terms_counts(kw, match, nvocab_pad, span)}
         for i, sub in enumerate(subs):
             if sub and sub[0] == "stats":
                 _, sprefix, sfield, col_exists, sumsq = sub
@@ -987,7 +996,7 @@ def _emit_agg(spec, seg_arrays: dict, params: dict, match, scores=None):  # noqa
                     col = seg_arrays["numeric"][sfield]
                     out[f"sub{i}"] = agg_ops.terms_sub_metric(
                         kw, match, col["f32"], col["present"], nvocab_pad,
-                        params[f"{sprefix}_sinv"], sumsq)
+                        params[f"{sprefix}_sinv"], sumsq, span)
         return out
 
     if kind == "hist":
@@ -996,20 +1005,21 @@ def _emit_agg(spec, seg_arrays: dict, params: dict, match, scores=None):  # noqa
         w = match * jnp.where(col["present"], 1.0, 0.0)
         b = jnp.floor((col["f32"] - offset) / interval).astype(jnp.int32) - min_b
         b = jnp.where((b >= 0) & (b < nb) & (w > 0), b, nb)
-        out = {"counts": agg_ops.bucket_counts(b, w, nb)}
+        out = {"counts": agg_ops.bucket_counts(b, w, nb, span)}
         for i, sub in enumerate(subs):
             out.update(_emit_bucketed_sub(jnp, sub, i, b, nb, seg_arrays,
-                                          match, params))
+                                          match, params, span))
         return out
 
     if kind == "date_hist":
         (_, prefix, field, interval_ms, offset_ms, calendar, min_b, nb, subs,
          form) = spec
-        counts, b = _date_bucket_counts(jnp, params, prefix, match, nb, form)
+        counts, b = _date_bucket_counts(jnp, params, prefix, match, nb, form,
+                                        span=span)
         out = {"counts": counts}
         for i, sub in enumerate(subs):
             out.update(_emit_bucketed_sub(jnp, sub, i, b, nb, seg_arrays,
-                                          match, params))
+                                          match, params, span))
         return out
 
     if kind == "range":
@@ -1027,7 +1037,8 @@ def _emit_agg(spec, seg_arrays: dict, params: dict, match, scores=None):  # noqa
             bucket_match = match * ((col["f32"] >= lo) & (col["f32"] < hi) &
                                     col["present"]).astype(jnp.float32)
             for i, sub in enumerate(subs):
-                res = emit_agg(sub, seg_arrays, params, bucket_match, scores)
+                res = emit_agg(sub, seg_arrays, params, bucket_match, scores,
+                               span)
                 if res:
                     out[f"r{ri}_sub{i}"] = res
         return out
@@ -1048,7 +1059,8 @@ def _emit_agg(spec, seg_arrays: dict, params: dict, match, scores=None):  # noqa
             bucket_match = match * ((dist >= lo) & (dist < hi) &
                                     geo["present"]).astype(jnp.float32)
             for i, sub in enumerate(subs):
-                res = emit_agg(sub, seg_arrays, params, bucket_match, scores)
+                res = emit_agg(sub, seg_arrays, params, bucket_match, scores,
+                               span)
                 if res:
                     out[f"r{ri}_sub{i}"] = res
         return out
@@ -1059,7 +1071,8 @@ def _emit_agg(spec, seg_arrays: dict, params: dict, match, scores=None):  # noqa
         bucket_match = match * fmask.astype(jnp.float32)
         out = {"count": jnp.sum(bucket_match)}
         for i, sub in enumerate(subs):
-            res = emit_agg(sub, seg_arrays, params, bucket_match, scores)
+            res = emit_agg(sub, seg_arrays, params, bucket_match, scores,
+                           span)
             if res:
                 out[f"sub{i}"] = res
         return out
@@ -1072,7 +1085,8 @@ def _emit_agg(spec, seg_arrays: dict, params: dict, match, scores=None):  # noqa
             bucket_match = match * fmask.astype(jnp.float32)
             entry = {"count": jnp.sum(bucket_match)}
             for i, sub in enumerate(subs):
-                res = emit_agg(sub, seg_arrays, params, bucket_match, scores)
+                res = emit_agg(sub, seg_arrays, params, bucket_match, scores,
+                               span)
                 if res:
                     entry[f"sub{i}"] = res
             out[f"k{ki}"] = entry
@@ -1099,7 +1113,8 @@ def _emit_agg(spec, seg_arrays: dict, params: dict, match, scores=None):  # noqa
         bucket_match = match * (~present).astype(jnp.float32)
         out = {"count": jnp.sum(bucket_match)}
         for i, sub in enumerate(subs):
-            res = emit_agg(sub, seg_arrays, params, bucket_match, scores)
+            res = emit_agg(sub, seg_arrays, params, bucket_match, scores,
+                           span)
             if res:
                 out[f"sub{i}"] = res
         return out
@@ -1120,7 +1135,7 @@ def _emit_agg(spec, seg_arrays: dict, params: dict, match, scores=None):  # noqa
         _, prefix, field, nvocab_pad = spec
         registers, distinct = agg_ops.cardinality_keyword_registers(
             seg_arrays["keyword"][field], match, nvocab_pad,
-            params[f"{prefix}_hashes"], HLL_LOG2M)
+            params[f"{prefix}_hashes"], HLL_LOG2M, span)
         return {"registers": registers, "distinct": distinct}
 
     if kind == "card_num":
@@ -1266,11 +1281,11 @@ def _emit_agg(spec, seg_arrays: dict, params: dict, match, scores=None):  # noqa
     if kind == "multi_terms":
         _, prefix, nord_pad, nvocab, subs = spec
         ords = params[f"{prefix}_mords"][:ndocs_pad]
-        out = {"counts": agg_ops.ord_counts(ords, match, nord_pad)}
+        out = {"counts": agg_ops.ord_counts(ords, match, nord_pad, span)}
         b = jnp.where(ords >= 0, ords, nord_pad)
         for i, sub in enumerate(subs):
             out.update(_emit_bucketed_sub(jnp, sub, i, b, nord_pad,
-                                          seg_arrays, match, params))
+                                          seg_arrays, match, params, span))
         return out
 
     if kind == "adjacency":
@@ -1284,7 +1299,7 @@ def _emit_agg(spec, seg_arrays: dict, params: dict, match, scores=None):  # noqa
             sel = match * ma.astype(jnp.float32)
             out[f"c{idx}"] = jnp.sum(sel)
             for i, sub in enumerate(subs):
-                res = emit_agg(sub, seg_arrays, params, sel, scores)
+                res = emit_agg(sub, seg_arrays, params, sel, scores, span)
                 if res:
                     out[f"c{idx}_sub{i}"] = res
             idx += 1
@@ -1294,7 +1309,8 @@ def _emit_agg(spec, seg_arrays: dict, params: dict, match, scores=None):  # noqa
                 sel = match * (ma & mb).astype(jnp.float32)
                 out[f"c{idx}"] = jnp.sum(sel)
                 for i, sub in enumerate(subs):
-                    res = emit_agg(sub, seg_arrays, params, sel, scores)
+                    res = emit_agg(sub, seg_arrays, params, sel, scores,
+                                   span)
                     if res:
                         out[f"c{idx}_sub{i}"] = res
                 idx += 1
@@ -1305,11 +1321,11 @@ def _emit_agg(spec, seg_arrays: dict, params: dict, match, scores=None):  # noqa
          form) = spec
         first = params[f"{prefix}_dfirst"]
         counts, b = _date_bucket_counts(jnp, params, prefix, match, nb, form,
-                                        first, window)
+                                        first, window, span)
         out = {"counts": counts, "first": first}
         for i, sub in enumerate(subs):
             out.update(_emit_bucketed_sub(jnp, sub, i, b, window, seg_arrays,
-                                          match, params))
+                                          match, params, span))
         return out
 
     if kind in ("scripted", "sig_text"):
@@ -1321,13 +1337,15 @@ def _emit_agg(spec, seg_arrays: dict, params: dict, match, scores=None):  # noqa
 
 
 def _date_bucket_counts(jnp, params: dict, prefix: str, match, nb: int,
-                        form: str, first=None, window: Optional[int] = None):
+                        form: str, first=None, window: Optional[int] = None,
+                        span=None):
     """A date histogram's counts over its resident bucket plane: ->
     (counts i32[nb], per-row bucket ids with `nb` where the row does not
     count, for the sub-aggregations). A row counts where it matches and
     has a value; `form` "runs" reads the counts at the runs' boundaries
     (`ops.aggs.run_counts`), "scatter" takes `ops.aggs.bucket_counts`,
-    whose bucket count chooses between its dense form and a scatter-add.
+    whose bucket count chooses between its dense form and a scatter-add
+    and whose block loop `span` bounds (`emit_agg`).
     With `first` (a traced scalar) and `window` the counts are those of
     the plane's buckets [first, first + window) alone, i32[window]
     (`auto_date_histogram`: the plane spans the column, the response a few
@@ -1345,11 +1363,11 @@ def _date_bucket_counts(jnp, params: dict, prefix: str, match, nb: int,
     b = jnp.where(held, ids, nb)
     if form == "runs":
         return agg_ops.run_counts(held.astype(jnp.int32), starts), b
-    return agg_ops.bucket_counts(b, held, nb), b
+    return agg_ops.bucket_counts(b, held, nb, span), b
 
 
 def _emit_bucketed_sub(jnp, sub, i: int, bucket_ids, nb: int, seg_arrays, match,
-                       params: dict):
+                       params: dict, span=None):
     """Metric sub-agg under an ordinal bucket agg: per-bucket accumulators
     (`ops.aggs.bucketed_sub_metric`: int32 counts, sums in limbs)."""
     if not sub or sub[0] != "stats":
@@ -1360,4 +1378,5 @@ def _emit_bucketed_sub(jnp, sub, i: int, bucket_ids, nb: int, seg_arrays, match,
     col = seg_arrays["numeric"][sfield]
     w = match * jnp.where(col["present"], 1.0, 0.0)
     return {f"sub{i}": agg_ops.bucketed_sub_metric(
-        bucket_ids, col["f32"], w, nb, params[f"{sprefix}_sinv"], sumsq)}
+        bucket_ids, col["f32"], w, nb, params[f"{sprefix}_sinv"], sumsq,
+        span)}

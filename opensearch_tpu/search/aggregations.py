@@ -49,7 +49,12 @@ from .planes import (AUTO_ROUNDINGS, auto_inner_for, auto_unit_ids,
 # form that replaces a scatter reads, rows x passes over them
 # (`ops.aggs.run_counts`; the dense form: one pass a bucket count, one
 # for all of a sub-metric's accumulators; the product form: one pass a
-# count); `bucketed_sub.launches` / `.buckets` the
+# count; of the dense and the product form the rows of the blocks the loop
+# visits under the launch's row span, `ops.aggs.span_rows`); `span.rows` the
+# rows of that span (`compiler.row_span`: what a `range` over a column in
+# row order leaves of the segment) and `span.segment_rows` the segment's, of
+# every launch that carries aggregations; `bucketed_sub.launches` /
+# `.buckets` the
 # launches that carry a metric under a bucket aggregation, and their
 # buckets; `auto_date.requests` the top-level auto_date_histograms a
 # segment was asked, `auto_date.refine_launches` the launches taken first
@@ -69,6 +74,8 @@ from .planes import (AUTO_ROUNDINGS, auto_inner_for, auto_unit_ids,
 # two values, which is counted by document
 AGG_STATS = CounterGroup(METRICS, "aggs", {"scatter.updates": 0,
                                            "blocked.rows": 0,
+                                           "span.rows": 0,
+                                           "span.segment_rows": 0,
                                            "bucketed_sub.launches": 0,
                                            "bucketed_sub.buckets": 0,
                                            "auto_date.requests": 0,

@@ -1279,6 +1279,69 @@ def can_match(node: LNode, seg: Segment) -> bool:
     return True
 
 
+# the request-level key of a launch's row span in `params` (not numbered by
+# node: `canon_param_key` leaves it, and the program's key, alone)
+ROW_SPAN = "row_span"
+
+
+def row_span(node: LNode, seg: Segment) -> Optional[Tuple[int, int]]:
+    """The rows [lo, hi) of `seg` outside which `node` matches nothing, from
+    the host's columns alone, or None where nothing narrows them (the whole
+    segment): a `range` over a column whose values are in row order
+    (`NumericColumn.in_row_order`) is two binary searches, a `bool` the
+    intersection of what it requires (`should` and `must_not` narrow
+    nothing), anything else None. It holds every row the device's mask
+    accepts: the search reads the representation the mask compares (the
+    int64 values of kind `int`; of kind `float` the float64 values against
+    the float32 neighbours of the rounded bounds, which rounding, being
+    monotone, cannot pass), and the mask still decides every row inside it.
+    Which of the two it is follows from the query's structure and the
+    column's order, not from a bound's value. `can_match` is the case that
+    the span is empty, and keeps its own contract."""
+    if isinstance(node, LRange):
+        col = seg.numeric_cols.get(node.field)
+        filled = None if col is None else col.in_row_order
+        if filled is None:
+            return None
+        lo, hi = node.lo, node.hi
+        left, right = node.include_lo, node.include_hi
+        if node.kind == "int":
+            lo, hi = (None if b is None else int(b) for b in (lo, hi))
+        else:       # one float32 outward, and inclusive: a superset
+            lo = None if lo is None else np.nextafter(np.float32(lo),
+                                                      np.float32(-np.inf))
+            hi = None if hi is None else np.nextafter(np.float32(hi),
+                                                      np.float32(np.inf))
+            left = right = True
+        first = 0 if lo is None else int(np.searchsorted(
+            filled, lo, side="left" if left else "right"))
+        end = len(filled) if hi is None else int(np.searchsorted(
+            filled, hi, side="right" if right else "left"))
+        return first, max(end, first)
+    if isinstance(node, LBool):
+        spans = [s for s in (row_span(c, seg)
+                             for c in node.musts + node.filters) if s]
+        if not spans:
+            return None
+        first = max(lo for lo, _hi in spans)
+        return first, max(min(hi for _lo, hi in spans), first)
+    return None
+
+
+def bind_row_span(node: LNode, seg: Segment, params: dict) -> None:
+    """`row_span` of a launch's query into `params`, as int32 [lo, hi],
+    that the program's aggregations bound their block loops by
+    (`programs.launch_span`): a window of another length is the same
+    program. Nothing where nothing narrows the rows: such a launch runs the
+    loops at their static length and carries no argument for it. One array
+    and not two scalars: every host value a launch carries is a copy of
+    its own to the device, 0.18 ms of the dispatch each on a v5e's host
+    (PERF.md, PR 49)."""
+    span = row_span(node, seg)
+    if span is not None:
+        put_param(params, ROW_SPAN, np.asarray(span, np.int32))
+
+
 # =====================================================================
 # emit: spec -> traced device computation (runs under jit trace)
 # =====================================================================

@@ -449,6 +449,7 @@ class ShardSearcher:
                 sspec = C.prepare_sort(sort_specs, seg, params)
                 agg_specs = []
                 if agg_nodes:
+                    C.bind_row_span(lroot, seg, params)
                     auto_ranges = _auto_date_ranges(
                         agg_nodes, qspec, seg, ctx, params, self.device)
                     with TRACER.span("search.aggs.prepare"):
@@ -668,6 +669,7 @@ class ShardSearcher:
                 for seg in ran_segs:
                     params: Dict[str, Any] = {}
                     qspec = C.prepare(lroot, seg, ctx, params)
+                    C.bind_row_span(lroot, seg, params)
                     aspec = AC.prepare_agg(an, seg, ctx, params, "rs")
                     out = _fetch_agg_outputs(PG.run_agg_only(
                         qspec, aspec, seg.device_arrays(self.device), params))

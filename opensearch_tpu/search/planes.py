@@ -15,7 +15,7 @@ from typing import TYPE_CHECKING, Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
-from ..index.segment import Segment, next_pow2
+from ..index.segment import Segment, next_pow2, rows_in_order
 from ..ops import aggs as agg_ops
 from ..utils.metrics import METRICS, CounterGroup
 from . import query_dsl as dsl
@@ -199,14 +199,12 @@ def run_starts(ids: np.ndarray, nbuckets: int,
     row whose id, or the id of the nearest row before it that has one, is
     at least b, so `starts[nbuckets]` = `len(ids)`. None where the ids of
     the rows that have a value (id >= 0; the others weigh nothing) are not
-    non-decreasing in row order, or `run_blocks` has no cut for the sizes:
-    such a plane is counted by scatter-add."""
+    non-decreasing in row order (`rows_in_order`), or `run_blocks` has no
+    cut for the sizes: such a plane is counted by scatter-add."""
     if agg_ops.run_blocks(ndocs_pad, nbuckets + 1) is None:
         return None
-    # the running maximum forward-fills the rows without a value (-1), and
-    # a row in order is one that is its own running maximum
-    filled = np.maximum.accumulate(ids)
-    if ((ids >= 0) & (ids < filled)).any():
+    filled = rows_in_order(ids, ids >= 0)
+    if filled is None:
         return None
     return np.searchsorted(filled, np.arange(nbuckets + 1),
                            side="left").astype(np.int32)

@@ -17,9 +17,17 @@ from ..ops import scoring as ops
 from ..utils.trace import TRACER
 from .agg_compiler import emit_agg
 from .aggregations import AGG_STATS
-from .compiler import (EXECUTOR_STATS, KNN_STATS, PHRASE_STATS,
+from .compiler import (EXECUTOR_STATS, KNN_STATS, PHRASE_STATS, ROW_SPAN,
                        canon_param_key, canon_spec, emit, emit_sort_key,
                        instrumented_program_cache)
+
+
+def launch_span(params: dict) -> Optional[Tuple]:
+    """(lo, hi) of the row span a launch's `params` carry
+    (`compiler.bind_row_span`: traced scalars inside a program, numpy's at
+    the call), None where they carry none: the whole segment."""
+    span = params.get(ROW_SPAN)
+    return None if span is None else (span[0], span[1])
 
 
 @instrumented_program_cache("executor", maxsize=512)
@@ -88,7 +96,8 @@ def _executor_run_fn(full_spec):
             match_f = (sm.matched.astype(jnp.float32)
                        * jnp.where(live > 0, 1.0, 0.0))
             for name, aspec in agg_specs:
-                res = emit_agg(aspec, seg_arrays, params, match_f, sm.scores)
+                res = emit_agg(aspec, seg_arrays, params, match_f, sm.scores,
+                               launch_span(params))
                 if res:  # oslint: disable=OSL201 -- host dict truthiness, trace-static
                     aggs[name] = res
         if aggs:  # oslint: disable=OSL201 -- host dict truthiness, trace-static
@@ -143,7 +152,10 @@ def _count_launch(full_spec, seg_arrays: dict, cparams: dict) -> None:
     counted). `executor.topk_keys_sorted`: the keys its `ops.topk_docs`
     hands to `lax.top_k` (a collapse launch takes `collapse_topk`: none).
     `executor.agg_bucket_launches` / `agg_run_counted`: its date-histogram
-    bucket counts, and those whose spec says "runs"."""
+    bucket counts, and those whose spec says "runs". `aggs.span.rows` /
+    `aggs.span.segment_rows`: of a launch that carries aggregations, the
+    rows of its span (`launch_span`: the segment's where it carries none)
+    and the segment's, both of the padded planes."""
     EXECUTOR_STATS.inc("params_h2d_bytes", sum(
         v.nbytes for v in cparams.values()
         if isinstance(v, (np.ndarray, np.generic))))
@@ -164,8 +176,13 @@ def _count_launch(full_spec, seg_arrays: dict, cparams: dict) -> None:
     if aggs:
         cost = {"scatter": 0, "blocked": 0, "sub_buckets": 0,
                 "ordinals": 0, "combinations": 0, "gathered": 0}
+        span = launch_span(cparams)
         for _name, aspec in aggs:
-            agg_cost(aspec, seg_arrays, cost)
+            agg_cost(aspec, seg_arrays, cost, span)
+        n = seg_arrays["live"].shape[0]
+        AGG_STATS.inc("span.segment_rows", n)
+        AGG_STATS.inc("span.rows", n if span is None
+                      else max(int(span[1]) - int(span[0]), 0))
         if cost["ordinals"]:
             AGG_STATS.inc("terms.ordinals", cost["ordinals"])
         if cost["combinations"]:
@@ -231,11 +248,13 @@ _AGG_CONTAINER_SUBS = {"filter": 3, "filters": 3, "global": 2, "missing": 4,
                        "adjacency": 4}
 
 
-def agg_cost(spec, seg_arrays: dict, cost: dict) -> None:
-    """What `emit_agg` builds for `spec`, reckoned from the spec alone (the
-    walk mirrors it): rows handed to scatters, rows read by `run_counts`
-    and by the dense and product forms (`ops.aggs.count_form`, the
-    predicate the emit chooses by), buckets that carry a metric
+def agg_cost(spec, seg_arrays: dict, cost: dict, span=None) -> None:
+    """What `emit_agg` builds for `spec`, reckoned from the spec and the
+    launch's row span (the walk mirrors it): rows handed to scatters, rows
+    read by `run_counts` and by the dense and product forms
+    (`ops.aggs.count_form`, the predicate the emit chooses by: the rows of
+    the blocks their loops visit under `span`, `ops.aggs.span_rows`, where
+    `emit_agg` hands it down), buckets that carry a metric
     sub-aggregation, and
     where `cost` has the keys the slots a terms-like group-by counts into
     (`ordinals`; `combinations` those of a composite) and the flat values
@@ -259,8 +278,10 @@ def agg_cost(spec, seg_arrays: dict, cost: dict) -> None:
                   "vc_keyword"):
         kw = seg_arrays["keyword"][spec[2]]
         rows = agg_ops.group_by_rows(kw)
-        if "gathered" in cost and agg_ops.counts_by_value(kw):
-            cost["gathered"] += rows
+        if agg_ops.counts_by_value(kw):     # its rows are no documents
+            span = None
+            if "gathered" in cost:
+                cost["gathered"] += rows
         if kind == "vc_keyword":        # one sum: no bucket count
             return
         nb = spec[3]
@@ -283,17 +304,26 @@ def agg_cost(spec, seg_arrays: dict, cost: dict) -> None:
     if rows is None:
         at = _AGG_CONTAINER_SUBS.get(kind)
         for sub in (spec[at] if at is not None else ()):
-            agg_cost(sub, seg_arrays, cost)
+            agg_cost(sub, seg_arrays, cost, None if kind == "global" else span)
         return
     form = agg_ops.count_form(nb)
-    if spec[-1] == "runs" or form != "scatter":
+    # the rows of the blocks a count's loop visits, by its form
+    counted = agg_ops.span_rows(span, (
+        agg_ops.dense_block_rows if form == "dense"
+        else agg_ops.product_block_rows)(rows), rows)
+    if spec[-1] == "runs":
         cost["blocked"] += rows
+    elif form != "scatter":
+        cost["blocked"] += counted
     else:
         cost["scatter"] += rows
     for sub in subs:
         if sub and sub[0] == "stats" and sub[3]:
-            if form != "scatter":   # all of it dense, or its count a product
-                cost["blocked"] += rows
+            if form == "dense":     # all of it, in the sums' blocks
+                cost["blocked"] += agg_ops.span_rows(
+                    span, agg_ops.sum_limb_plan(rows, nb)[2], rows)
+            elif form == "product":     # its count
+                cost["blocked"] += counted
             if form != "dense":
                 cost["scatter"] += rows * agg_ops.sub_metric_scatters(
                     rows, nb, sub[4])
@@ -357,7 +387,7 @@ def _build_agg_executor(key):
             match_f = (sm.matched.astype(jnp.float32)
                        * jnp.where(live > 0, 1.0, 0.0))
             return emit_agg(agg_spec, seg_arrays, params, match_f,
-                            sm.scores)
+                            sm.scores, launch_span(params))
 
     return jax.jit(agg_program)
 

@@ -323,6 +323,134 @@ def test_the_dense_ops_carry_the_sub_metric_scope():
 
 
 # ---------------------------------------------------------------------
+# a row span bounds the blocks the dense and the product form read
+# ---------------------------------------------------------------------
+SPAN_BLOCK = 1024
+SPAN_N = 5 * SPAN_BLOCK + 300           # the last block is part padding
+SPANS = {
+    "whole": (0, SPAN_N),
+    "empty": (2000, 2000),
+    "inside one block": (SPAN_BLOCK + 5, SPAN_BLOCK + 77),
+    "straddling two": (2 * SPAN_BLOCK - 3, 2 * SPAN_BLOCK + 9),
+    "ending in the padded tail": (4 * SPAN_BLOCK + 1, SPAN_N),
+    "not aligned to 128": (131, 3 * SPAN_BLOCK + 1),
+}
+# what reduces under a span: a count in the dense and in the product form,
+# sums and a metric's accumulators with its extremes in the dense form
+SPAN_FORMS = {
+    "dense": (DENSE, 101, lambda nb, inv, span: lambda b, v, w, lo, hi:
+              agg_ops.bucket_counts(b, w, nb, span(lo, hi))),
+    "product": (PRODUCT, 4097, lambda nb, inv, span: lambda b, v, w, lo, hi:
+                agg_ops.bucket_counts(b, w, nb, span(lo, hi))),
+    "dense sums": (DENSE, 64, lambda nb, inv, span: lambda b, v, w, lo, hi:
+                   agg_ops.bucket_sums_exact(b, v, w, nb, inv,
+                                             span(lo, hi))),
+    "dense metric and extremes": (
+        DENSE, 7, lambda nb, inv, span: lambda b, v, w, lo, hi:
+        agg_ops.bucketed_sub_metric(b, v, w, nb, inv, True, span(lo, hi))),
+}
+
+
+def _span_blocks(monkeypatch):
+    """Blocks of `SPAN_BLOCK` rows in every form, the sums' included."""
+    monkeypatch.setattr(agg_ops, "_DENSE_BLOCK", SPAN_BLOCK)
+    monkeypatch.setattr(agg_ops, "_PRODUCT_BLOCK", SPAN_BLOCK)
+    monkeypatch.setattr(agg_ops, "_LIMB_BITS", {3: 16})
+    monkeypatch.setattr(agg_ops, "sum_limb_plan", lambda n, nb: (
+        3, 16, min(SPAN_BLOCK, max(n, 1))))
+
+
+def _span_rows(form, span, outside=False):
+    _consts, nb, _fn = SPAN_FORMS[form]
+    b, v, w, inv = _rows(SPAN_N, nb, 13 * nb + span[1])
+    inside = np.zeros(SPAN_N, bool)
+    inside[span[0]:span[1]] = True
+    return nb, b, v, (w if outside else w * inside), inv
+
+
+def _span_run(monkeypatch, form, args, span, traced=True):
+    consts, nb, make = SPAN_FORMS[form]
+    _span_blocks(monkeypatch)
+    b, v, w, inv = args
+    lo, hi = np.int32(span[0]), np.int32(span[1])
+    fn = make(nb, inv, (lambda lo, hi: (lo, hi)) if traced
+              else (lambda lo, hi: None))
+    (got,) = _forms(monkeypatch, (consts,), fn, b, v, w, lo, hi)
+    return got
+
+
+@pytest.mark.parametrize("span", SPANS)
+@pytest.mark.parametrize("form", SPAN_FORMS)
+def test_a_form_under_a_row_span_equals_numpy_over_the_same_rows(
+        monkeypatch, form, span):
+    """No row outside the span weighs anything (the contract): what the
+    loop over the span's blocks returns is numpy's over the same rows, and
+    the call without a span's, dtypes included."""
+    nb, b, v, w, inv = _span_rows(form, SPANS[span])
+    got = _span_run(monkeypatch, form, (b, v, w, inv), SPANS[span])
+    _same(got, _span_run(monkeypatch, form, (b, v, w, inv), SPANS[span],
+                         traced=False))
+    ok = (w > 0) & (b >= 0) & (b < nb)
+    counts = np.bincount(b[ok], minlength=nb)
+    if form in ("dense", "product"):
+        assert got.dtype == np.int32 and np.array_equal(got, counts)
+        return
+    want = np.bincount(b[ok], weights=v[ok].astype(np.float64), minlength=nb)
+    sums = got if form == "dense sums" else got["sum"]
+    assert np.allclose(agg_ops.limb_sums_to_f64(sums, inv), want, rtol=0,
+                       atol=1e-9 * SPAN_N)
+    if form == "dense sums":
+        return
+    assert np.array_equal(got["count"], counts)
+    for k in range(nb):
+        vals = v[ok & (b == k)]
+        assert got["min"][k] == (vals.min() if vals.size else agg_ops.F32_MAX)
+        assert got["max"][k] == (vals.max() if vals.size
+                                 else -agg_ops.F32_MAX)
+
+
+@pytest.mark.parametrize("span", ["inside one block", "straddling two",
+                                  "empty"])
+@pytest.mark.parametrize("form", SPAN_FORMS)
+def test_a_block_the_span_does_not_meet_is_not_read(monkeypatch, form, span):
+    """Rows that weigh something outside the span (what no caller hands
+    over) show which blocks the loop visits: those of the blocks that meet
+    the span count, the mask deciding each of them; no other block does."""
+    lo, hi = SPANS[span]
+    nb, b, v, w, inv = _span_rows(form, (lo, hi), outside=True)
+    got = _span_run(monkeypatch, form, (b, v, w, inv), (lo, hi))
+    visited = np.zeros(SPAN_N, bool)
+    if hi > lo:
+        visited[lo // SPAN_BLOCK * SPAN_BLOCK: -(-hi // SPAN_BLOCK)
+                * SPAN_BLOCK] = True
+    _same(got, _span_run(monkeypatch, form, (b, v, w * visited, inv),
+                         (0, SPAN_N)))
+    counts = got if form in ("dense", "product") else (
+        got["count"] if isinstance(got, dict) else None)
+    if counts is not None:
+        ok = visited & (w > 0) & (b >= 0) & (b < nb)
+        assert counts.sum() == ok.sum() < ((w > 0) & (b >= 0)
+                                           & (b < nb)).sum()
+
+
+@pytest.mark.parametrize("span,rows,n,want", [
+    (None, 1024, 5420, 5420), ((0, 5420), 1024, 5420, 5420),
+    ((2000, 2000), 1024, 5420, 0), ((2048, 2048), 1024, 5420, 0),
+    ((1029, 1101), 1024, 5420, 1024), ((2045, 2057), 1024, 5420, 2048),
+    ((4097, 5420), 1024, 5420, 1324), ((0, 1 << 30), 1024, 5420, 5420),
+    ((7000, 9000), 1024, 5420, 0), ((300, 200), 1024, 5420, 0),
+    ((5, 9), 4096, 100, 100)])
+def test_span_rows_counts_the_blocks_the_loop_visits(span, rows, n, want):
+    assert agg_ops.span_rows(span, rows, n) == want
+    if span is not None:        # the host's reckoning is the trace's
+        nblk = max(-(-n // rows), 1)
+        first, end = jax.jit(
+            lambda lo, hi: agg_ops._block_range((lo, hi), rows, nblk))(
+                np.int32(span[0]), np.int32(span[1]))
+        assert min(int(end) * rows, n) - min(int(first) * rows, n) == want
+
+
+# ---------------------------------------------------------------------
 # `agg_cost` counts by the predicate the emit chooses by
 # ---------------------------------------------------------------------
 N = 4096
@@ -419,6 +547,49 @@ def test_agg_cost_of_a_run_counted_plane(nb):
     else:
         assert got == {"scatter": (3 + limbs) * N, "blocked": N,
                        "sub_buckets": nb}
+
+
+@pytest.mark.parametrize("kind", ["hist", "date_hist", "terms_by_doc",
+                                  "geo_grid"])
+@pytest.mark.parametrize("at", ["dense", "product"])
+def test_agg_cost_under_a_row_span(monkeypatch, kind, at):
+    """`aggs.blocked.rows` is the rows of the blocks each loop visits: the
+    count's by its form, a dense metric's by the sums' blocks; a scatter,
+    `run_counts` and a keyword column laid out by value read every row
+    whatever the span; under `global` the span is whole again."""
+    monkeypatch.setattr(agg_ops, "_DENSE_BLOCK", 256)
+    monkeypatch.setattr(agg_ops, "_PRODUCT_BLOCK", 512)
+    monkeypatch.setattr(agg_ops, "sum_limb_plan",
+                        lambda n, nb: (3, 16, min(1024, n)))
+    nb = 50 if at == "dense" else agg_ops._DENSE_BUCKETS
+    span = (1000, 1100)     # 256: [768, 1280); 512: [512, 1536); 1024: two
+    spec = _spec(kind, nb, (STATS,), "scatter")
+    cost = {"scatter": 0, "blocked": 0, "sub_buckets": 0}
+    PG.agg_cost(spec, SEG, cost, span)
+    if at == "dense":
+        assert cost == {"scatter": 0, "blocked": 512 + 2048,
+                        "sub_buckets": nb}
+    else:       # the count and the metric's count are products
+        limbs = 3
+        assert cost == {"scatter": N * (2 + limbs), "blocked": 2 * 1024,
+                        "sub_buckets": nb}
+    whole = {"scatter": 0, "blocked": 0, "sub_buckets": 0}
+    PG.agg_cost(("global", "g", (spec,)), SEG, whole, span)
+    assert whole == _cost(spec)
+    same = {"scatter": 0, "blocked": 0, "sub_buckets": 0}
+    PG.agg_cost(("filter", "p", "q", (spec,)), SEG, same, span)
+    assert same == cost
+
+
+@pytest.mark.parametrize("spec", [
+    ("terms", "p", "k", 50, ()),                        # laid out by value
+    ("date_hist", "p", "f", 1, 0, None, 0, 50, (), "runs"),
+    ("hist", "p", "f", 1.0, 0.0, 0, 1 << 20, ())])      # a scatter
+def test_agg_cost_of_what_takes_no_span(monkeypatch, spec):
+    monkeypatch.setattr(agg_ops, "_DENSE_BLOCK", 256)
+    cost = {"scatter": 0, "blocked": 0, "sub_buckets": 0}
+    PG.agg_cost(spec, SEG, cost, (1000, 1100))
+    assert cost == _cost(spec)
 
 
 def test_agg_cost_walks_containers():

@@ -220,6 +220,7 @@ def _counted(client, body: dict) -> dict:
     assert "error" not in resp
     out = {k: A.AGG_STATS[k] - v for k, v in before.items()}
     out.update({k: C.EXECUTOR_STATS[k] - v for k, v in stats.items()})
+    out["hits"] = resp["hits"]["total"]["value"]
     return out
 
 
@@ -233,21 +234,24 @@ def test_the_counters_say_what_a_launch_counted(deployments):
     nstreams = len(seg.keyword_cols[STREAM].vocab)
     slots = next_pow2(nstreams)
     assert agg_ops.count_form(slots) == "dense"     # 400 streams here
-    got = _counted(client, specs["keyword-terms"]["body"])
+    # (each body once: a second asking is the request cache's)
+    counted = {shape: _counted(client, spec["body"])
+               for shape, spec in specs.items()}
+    got = counted["keyword-terms"]
     assert (got["terms.ordinals"], got["blocked.rows"],
             got["scatter.updates"], got["launches"]) == (slots, n, 0, 1)
     # the response's buckets are the records: not the vocabulary
     assert got["terms.records"] == min(500, nstreams)
-    got = _counted(client, specs["keyword-terms-low-cardinality"]["body"])
+    got = counted["keyword-terms-low-cardinality"]
     assert got["terms.records"] == 50
-    got = _counted(client, specs["multi_terms-keyword"]["body"])
+    got = counted["multi_terms-keyword"]
     assert got["terms.records"] == 10 and got["blocked.rows"] == n
     assert 0 < got["terms.ordinals"] <= next_pow2(12 * 26)
-    got = _counted(client, specs["composite-terms"]["body"])
+    got = counted["composite-terms"]
     assert got["terms.records"] == 10
     assert 0 < got["composite.combinations"] == got["terms.ordinals"] \
         <= 12 * 26
-    got = _counted(client, specs["composite_terms-keyword"]["body"])
+    got = counted["composite_terms-keyword"]
     assert got["terms.records"] == 10
     combos = got["composite.combinations"]
     assert 12 * 26 < combos <= NDOCS
@@ -256,9 +260,22 @@ def test_the_counters_say_what_a_launch_counted(deployments):
         (n, 0) if agg_ops.count_form(combos) == "scatter" else (0, n))
     # a keyword cardinality is the `terms_counts` under its registers
     for shape in ("cardinality-agg-low", "cardinality-agg-high"):
-        got = _counted(client, specs[shape]["body"])
+        got = counted[shape]
         assert got["blocked.rows"] + got["scatter.updates"] == n
         assert (got["terms.ordinals"], got["terms.records"]) == (0, 0)
+    # every body stands under a range on `@timestamp`, which is in row
+    # order and has a value in every row: the launch's row span is the
+    # window's rows, to the row (PR 49). The loops read the blocks that
+    # meet it, and one block of 2^15 rows is this whole segment (the
+    # blocks themselves: tests/test_row_span.py)
+    assert seg.numeric_cols["@timestamp"].in_row_order is not None
+    for shape, got in counted.items():
+        assert got["span.segment_rows"] == n == agg_ops.dense_block_rows(n)
+        assert 0 < got["span.rows"] == got["hits"] < NDOCS, shape
+        assert got["blocked.rows"] + got["scatter.updates"] == n
+    # 2 to 24 hours of 14 days, and half of them or more
+    assert counted["composite-terms"]["span.rows"] < 0.08 * NDOCS
+    assert counted["keyword-terms"]["span.rows"] > 0.45 * NDOCS
 
 
 @pytest.mark.parametrize("shape", reference.SHAPES)

@@ -177,11 +177,24 @@ def test_dense_sub_metric_compiles_for_v5e(shape_on_chip, nb, sumsq):
     assert compiled.memory_analysis().temp_size_in_bytes < 160 << 20
 
 
+def _counts_compiled(S, rows, nb, spanned):
+    """`bucket_counts` over `rows` rows into `nb` buckets compiled for the
+    chip, without a row span (a loop of static length) or with one (two
+    traced scalars bound it: what a launch that carries aggregations
+    runs since PR 49)."""
+    if not spanned:
+        return jax.jit(lambda b, w: agg_ops.bucket_counts(b, w, nb)).lower(
+            S((rows,), jnp.int32), S((rows,), jnp.float32)).compile()
+    return jax.jit(
+        lambda b, w, lo, hi: agg_ops.bucket_counts(b, w, nb, (lo, hi))
+    ).lower(S((rows,), jnp.int32), S((rows,), jnp.float32),
+            S((), jnp.int32), S((), jnp.int32)).compile()
+
+
+@pytest.mark.parametrize("spanned", [False, True])
 @pytest.mark.parametrize("nb", [101, 256, 366])
-def test_dense_bucket_counts_compile_for_v5e(shape_on_chip, nb):
-    S = shape_on_chip
-    compiled = jax.jit(lambda b, w: agg_ops.bucket_counts(b, w, nb)).lower(
-        S((N_TRIPS,), jnp.int32), S((N_TRIPS,), jnp.float32)).compile()
+def test_dense_bucket_counts_compile_for_v5e(shape_on_chip, nb, spanned):
+    compiled = _counts_compiled(shape_on_chip, N_TRIPS, nb, spanned)
     text = compiled.as_text()
     assert len(re.findall(r"\bwhile\(", text)) == 1
     assert "scatter(" not in text and RELAID not in text
@@ -192,18 +205,17 @@ def test_dense_bucket_counts_compile_for_v5e(shape_on_chip, nb):
 N_EVENTS = 1 << 24      # the big5 cell's padded rows
 
 
+@pytest.mark.parametrize("spanned", [False, True])
 @pytest.mark.parametrize("nb", [agg_ops._DENSE_BUCKETS, 16_384, 65_536,
                                 agg_ops._PRODUCT_BUCKETS - 1])
-def test_product_bucket_counts_compile_for_v5e(shape_on_chip, nb):
+def test_product_bucket_counts_compile_for_v5e(shape_on_chip, nb, spanned):
     """`bucket_counts` between the constants at the big5 cell's size: one
     loop over the blocks, no scatter, one convolution whose operands are
     the comparisons themselves (neither one-hot is written: 2^24 x 512 x 2
     bytes would be 17 GB), the plane viewed and not relaid; no temporary
     but the held ids (the scatter's own)."""
     assert agg_ops.count_form(nb) == "product"
-    S = shape_on_chip
-    compiled = jax.jit(lambda b, w: agg_ops.bucket_counts(b, w, nb)).lower(
-        S((N_EVENTS,), jnp.int32), S((N_EVENTS,), jnp.float32)).compile()
+    compiled = _counts_compiled(shape_on_chip, N_EVENTS, nb, spanned)
     text = compiled.as_text()
     assert len(re.findall(r"\bwhile\(", text)) == 1
     assert "scatter(" not in text

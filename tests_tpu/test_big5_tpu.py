@@ -7,7 +7,9 @@ two one-hots since PR 45; a `multi_terms` / `composite` plane into 312 and
 `count_form` names), each with its time, a keyword column's group-bys in
 both of its layouts, by document and by value, side by side; where the
 second constant stands: product against scatter from 2,048 to 524,288
-slots; then the seven request shapes through `RestClient.search` over
+slots; a count under a row span of 0.6% to 100% of the rows (PR 49: its
+time follows the span's blocks) and the product's block probed under it;
+then the seven request shapes through `RestClient.search` over
 1,048,576 generated events (20,968 streams, 5,242 agents: past
 `_DENSE_BUCKETS` as at the cell's size) against the kind's plain reference.
 Run on a real chip: `python -m pytest tests_tpu/test_big5_tpu.py -q -s`."""
@@ -156,6 +158,89 @@ def test_where_the_second_constant_stands(rows, monkeypatch, nb):
         assert chosen == "scatter" and p_ms > 0.5 * s_ms
 
 
+SPAN_SHARES = [(0.006, "2 hours of 14 days"), (0.07, "24 hours"),
+               (0.75, "the mean of the four wide operations"), (1.0, "whole")]
+
+
+def _window(share):
+    """(lo, hi, host match, device match): a window of `share` of the
+    cell's rows that starts inside a block, four fifths of its rows
+    matching."""
+    rows = int(share * NDOCS)
+    lo = min(5_000_077, NDOCS - rows)
+    hi = lo + rows
+    match = np.zeros(N, np.float32)
+    match[lo:hi] = np.random.default_rng(hi).random(hi - lo) < 0.8
+    return np.int32(lo), np.int32(hi), match, jnp.asarray(match)
+
+
+@pytest.mark.parametrize("nb,what", [
+    (312, "composite-terms: dense"),
+    (65_536, "terms over 40,000 streams: product, 256 x 256"),
+    (461_089, "composite_terms-keyword: product, 451 x 1,024")])
+def test_a_row_span_bounds_the_rows_a_count_reads(rows, nb, what):
+    """`bucket_counts` under a row span (PR 49) at the cell's rows: equal
+    to `np.bincount` at every window; its time follows the blocks the span
+    meets and not the plane; the whole span through traced bounds reads as
+    the call without one (a loop of static length: what the parent runs)
+    to a few percent."""
+    (ords_h, _m), (ords, _dm) = rows
+
+    def fn(ords, match, lo, hi):
+        return agg_ops.ord_counts(jnp.where(ords >= 0, ords % nb, -1),
+                                  match, nb, (lo, hi))
+
+    def whole(ords, match):
+        return agg_ops.ord_counts(jnp.where(ords >= 0, ords % nb, -1),
+                                  match, nb)
+    ms_by_share = {}
+    for share, label in SPAN_SHARES:
+        lo, hi, match_h, match = _window(share)
+        got, ms = _timed(fn, (ords, match, lo, hi))
+        assert got.dtype == np.int32
+        assert np.array_equal(got, _want(ords_h, match_h, nb))
+        ms_by_share[share] = ms
+        print(f"ord_counts n={N} slots={nb} ({what}) span {share:.1%} "
+              f"({label}): {ms:.2f} ms (launch + read, median of 5)")
+    got, ms = _timed(whole, (ords, match))
+    assert np.array_equal(got, _want(ords_h, match_h, nb))
+    print(f"ord_counts n={N} slots={nb} no span (a loop of static length, "
+          f"the parent's): {ms:.2f} ms; the whole span through traced "
+          f"bounds {ms_by_share[1.0]:.2f} ms")
+    assert ms_by_share[1.0] < 1.05 * ms + 0.3
+    assert ms_by_share[0.006] < ms_by_share[0.07] < ms_by_share[1.0]
+    assert ms_by_share[0.006] < 0.35 * ms_by_share[1.0]
+
+
+@pytest.mark.parametrize("block", [1 << 13, 1 << 14, 1 << 15, 1 << 16,
+                                   1 << 17, 1 << 18])
+def test_the_products_block_under_a_narrow_span(rows, monkeypatch, block):
+    """`_PRODUCT_BLOCK` probed again (PR 45 read whole planes): 461,089
+    slots under the narrowest, a middling and the whole span, by rows a
+    block; the answers do not move."""
+    (ords_h, _m), (ords, _dm) = rows
+    nb = 461_089
+    monkeypatch.setattr(agg_ops, "_PRODUCT_BLOCK", block)
+
+    def fn(ords, match, lo, hi):
+        return agg_ops.ord_counts(jnp.where(ords >= 0, ords % nb, -1),
+                                  match, nb, (lo, hi))
+    line = []
+    for share in (0.006, 0.026, 0.07, 1.0):
+        lo, hi, match_h, match = _window(share)
+        got, ms = _timed(fn, (ords, match, lo, hi))
+        assert np.array_equal(got, _want(ords_h, match_h, nb))
+        line.append(f"span {share:.1%} {ms:.2f} ms")
+    # the two `terms`' 256 x 256 over the whole plane, by the same block
+    got, ms = _timed(lambda ords, match: agg_ops.ord_counts(
+        jnp.where(ords >= 0, ords % 65_536, -1), match, 65_536),
+        (ords, match))
+    assert np.array_equal(got, _want(ords_h, match_h, 65_536))
+    print(f"product n={N} slots={nb} rows a block {block}: "
+          + ", ".join(line) + f"; slots=65536 no span {ms:.2f} ms "
+          "(launch + read, median of 5)")
+
+
 def test_the_seven_shapes_through_the_client_at_a_million_events():
     os.environ["OPENSEARCH_TPU_MESH"] = "0"
     import big5_reference as reference
@@ -195,7 +280,12 @@ def test_the_seven_shapes_through_the_client_at_a_million_events():
     # the cardinality the product; the composite's combinations whatever
     # `count_form` names at this size
     assert got["scatter.updates"] in (0, 2 * n)
-    assert got["scatter.updates"] + got["blocked.rows"] == 2 * 7 * n
+    # every body stands under a range on `@timestamp`, which is in row
+    # order: the loops read the blocks of each window (PR 49)
+    assert got["span.segment_rows"] == 2 * 7 * n
+    assert 0 < got["span.rows"] < got["span.segment_rows"]
+    assert (got["span.rows"] < got["scatter.updates"] + got["blocked.rows"]
+            < 2 * 7 * n)
     assert got["terms.records"] == 2 * (500 + 50 + 10 + 10 + 10)
     assert C.EXECUTOR_STATS["params_h2d_bytes"] - h2d < 14 * (1 << 18)
     for shape, ms in times.items():
