@@ -583,8 +583,11 @@ class NestedBlock:
     parent_of: np.ndarray  # i32[child.ndocs], nondecreasing (doc order)
 
     def children_of(self, parent_doc: int) -> Tuple[int, int]:
-        a = int(np.searchsorted(self.parent_of, parent_doc, side="left"))
-        b = int(np.searchsorted(self.parent_of, parent_doc, side="right"))
+        # the needle in the map's own dtype: a Python int makes numpy cast
+        # the whole map a call (38 ms at 18.9M children, twice a hit)
+        doc = self.parent_of.dtype.type(parent_doc)
+        a = int(np.searchsorted(self.parent_of, doc, side="left"))
+        b = int(np.searchsorted(self.parent_of, doc, side="right"))
         return a, b
 
 
@@ -925,10 +928,13 @@ class Segment:
         for path, blk in self.nested.items():
             carr = dict(blk.child.device_arrays(device))
             cpad = blk.child.ndocs_pad
-            # padded children map to parent 0 but carry live=0, so every
-            # scatter-reduce contribution from padding is identically zero
+            # padded children carry live=0, so every scatter-reduce
+            # contribution from padding is identically zero; they name the
+            # last child's parent, so the plane stays nondecreasing (the
+            # join declares its indices sorted: `compiler.emit`, "nested")
+            last = blk.parent_of[-1] if len(blk.parent_of) else 0
             carr["parent"] = jnp.asarray(
-                _pad_to(blk.parent_of.astype(np.int32), cpad, np.int32(0)))
+                _pad_to(blk.parent_of.astype(np.int32), cpad, np.int32(last)))
             nst[path] = carr
         self._device_cache[key] = {
             "postings": post, "numeric": ncols, "keyword": kcols, "geo": gcols,

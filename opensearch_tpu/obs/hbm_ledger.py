@@ -76,7 +76,7 @@ KINDS = (
     "filtered_postings",    # filter-specialized aligned copies
     "filter_list",          # cached FilterList device doc lists
     "quality_tier",         # static-pruning view masks/doc lists
-    "nested_sort",          # planes.nested_sort_values columns
+    "nested_sort",          # planes.nested_sort_plane: i32 ranks per (segment, field, path, mode)
     "sort_rank_plane",      # compiler prepare_sort: i32 ranks per (segment, field)
     "agg_bucket_plane",     # planes.date_bucket_plane: i32 bucket ids
     "position_planes",      # a text field's (doc, pos) planes, with the segment

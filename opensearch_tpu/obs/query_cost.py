@@ -17,6 +17,10 @@ Model (documented in docs/OBSERVABILITY.md):
   executor's `_cost_predicted` consults the segment codec per field).
   `predicted_bytes_gathered = Σ df × slot`, `predicted_scatter_adds =
   Σ df`, `predicted_topk_work = window` per planned segment.
+  A `nested` clause adds what its join reads whatever matches: 4 bytes
+  (the parent map) and one scatter update a scatter for every child row
+  of the segment's block, and its child clause is priced against the
+  child space's postings.
 - **Actual, from launched program shapes.** The programs gather PADDED
   shapes: the XLA path flattens a term group into a pow2 `bucket`
   (`ops.pick_bucket`), so it moves `bucket × 8` bytes and scatter-adds
@@ -52,10 +56,21 @@ from typing import List, Optional, Tuple
 from ..utils.metrics import METRICS
 
 __all__ = ["QueryCost", "current", "start", "finish", "enabled",
-           "POSTING_SLOT_BYTES", "spec_gather_shape"]
+           "POSTING_SLOT_BYTES", "NESTED_CHILD_BYTES", "nested_join_scatters",
+           "spec_gather_shape"]
 
 # bytes moved per posting slot: doc_id i32 + (tf f32 | packed tf·dl i32)
 POSTING_SLOT_BYTES = 8
+
+# bytes the block join reads a child row: its parent (i32)
+NESTED_CHILD_BYTES = 4
+
+
+def nested_join_scatters(score_mode: str) -> int:
+    """Scatters the to-parent join of `score_mode` takes over the child
+    space: the count, and the sum or extreme where children score."""
+    return 1 if score_mode == "none" else 2
+
 
 _current: contextvars.ContextVar = contextvars.ContextVar(
     "opensearch_tpu_query_cost", default=None)
@@ -233,5 +248,12 @@ def spec_gather_shape(spec) -> Tuple[int, int]:
                 read = bucket * (1 + (node[3] - 1) * depth)
                 bytes_ += read * POSTING_SLOT_BYTES
                 slots += read
+            elif kind == "nested" and len(node) > 5:
+                # the block join: the parent map (i32) of every padded
+                # child slot, and an update a slot of each of its scatters
+                # (`nested_join_scatters`); the child clause's own gathers
+                # are the nodes below
+                bytes_ += node[5] * NESTED_CHILD_BYTES
+                slots += node[5] * nested_join_scatters(node[3])
         stack.extend(node)
     return bytes_, slots

@@ -42,6 +42,7 @@ import numpy as np
 
 from ..index.segment import (CODEC_V1, CODEC_V2, IMPACT_BLOCK, Segment,
                              next_pow2, split_i64)
+from ..obs.query_cost import nested_join_scatters
 from ..ops import scoring as ops
 from ..script import painless_lite as pl
 from ..utils.metrics import METRICS, CounterGroup
@@ -58,8 +59,8 @@ from .plan import (LBool, LBoosting, LCombined, LConstScore, LDisMax,
                    LNode, LPercolate, LPhrase, LPinned, LRange, LRankFeature,
                    LScriptFilter, LScriptScore, LSourcePhrase, LSpanHost,
                    LSparseDot, LTerms, LTermsSet, ShardContext, prefix_rows)
-from .planes import (BUCKET_PLANE_STATS, FIXED_MS,  # noqa: F401
-                     RANK_PLANE_STATS, nested_sort_values, segment_plane)
+from .planes import (BUCKET_PLANE_STATS, FIXED_MS, NESTED_STATS,  # noqa: F401
+                     RANK_PLANE_STATS, nested_sort_plane, segment_plane)
 
 INT32_SENTINEL = np.int32(2**31 - 1)
 
@@ -691,7 +692,16 @@ def prepare(node: LNode, seg: Segment, ctx: ShardContext, params: dict):  # noqa
             return ("match_none", nid)
         child_spec = prepare(node.child, blk.child, node.child_ctx, params)
         scalar_f32(params, f"q{nid}_boost", node.boost)
-        return ("nested", nid, node.path, node.score_mode, child_spec)
+        cpad = blk.child.ndocs_pad
+        NESTED_STATS.inc("queries")
+        NESTED_STATS.inc("child_rows", cpad)
+        NESTED_STATS.inc("child_rows_real", blk.child.ndocs)
+        NESTED_STATS.inc("parents", seg.ndocs_pad)
+        NESTED_STATS.inc("join_updates",
+                         cpad * nested_join_scatters(node.score_mode))
+        # the padded child rows are static: what `obs/query_cost.py`
+        # prices the launched clause by
+        return ("nested", nid, node.path, node.score_mode, child_spec, cpad)
 
     if isinstance(node, LHasChild):
         if node.pre is None:
@@ -1623,31 +1633,50 @@ def emit(spec, seg_arrays: dict, params: dict) -> ops.ScoredMask:  # noqa: C901
         return ops.ScoredMask(scores, matched.astype(jnp.float32))
 
     if kind == "nested":
-        _, _, path, score_mode, child_spec = spec
-        carr = dict(seg_arrays["nested"][path])
+        import jax
+
+        _, _, path, score_mode, child_spec, _cpad = spec
+        carr = seg_arrays["nested"][path]
         parent = carr["parent"]
-        # child liveness inherits the parent's delete mask via a gather
-        carr["live"] = carr["live"] * live[parent]
-        sm = emit(child_spec, carr, params)
-        cmatch = sm.matched
-        cscore = jnp.where(cmatch, sm.scores, 0.0)
-        cnt = zeros.at[parent].add(cmatch.astype(jnp.float32))
-        pmatch = cnt > 0
-        if score_mode == "none":
-            pscores = pmatch.astype(jnp.float32)
-        elif score_mode == "max":
-            neg_inf = jnp.full(ndocs_pad, -jnp.inf, jnp.float32)
-            mx = neg_inf.at[parent].max(jnp.where(cmatch, sm.scores, -jnp.inf))
-            pscores = jnp.where(pmatch, mx, 0.0)
-        elif score_mode == "min":
-            pos_inf = jnp.full(ndocs_pad, jnp.inf, jnp.float32)
-            mn = pos_inf.at[parent].min(jnp.where(cmatch, sm.scores, jnp.inf))
-            pscores = jnp.where(pmatch, mn, 0.0)
-        else:
-            total = zeros.at[parent].add(cscore)
-            pscores = total / jnp.maximum(cnt, 1.0) if score_mode == "avg" else total
-        pmatch = pmatch & (live > 0)
-        pscores = jnp.where(pmatch, pscores * params[f"q{nid}_boost"], 0.0)
+        # the child clause over the child space, under the children's own
+        # liveness: a deleted parent's children may match, and what they
+        # add lands on their parent alone, which `live` masks below. (The
+        # parents' mask gathered to every child, `live[parent]`, said the
+        # same at the price of a gather the size of the child space.)
+        with jax.named_scope("executor.nested_child"):
+            sm = emit(child_spec, carr, params)
+            cmatch = sm.matched
+        # child -> parent: a scatter update a child slot, padding included
+        # (padded children match nothing). `parent` is nondecreasing and
+        # the scatters say so: undeclared, the compiler sorts the 2^25
+        # indices of the `nested` cell first, a request and a compile
+        with jax.named_scope("executor.nested_join"):
+            cnt = zeros.at[parent].add(cmatch.astype(jnp.float32),
+                                       indices_are_sorted=True)
+            pmatch = cnt > 0
+            if score_mode == "none":
+                pscores = pmatch.astype(jnp.float32)
+            elif score_mode == "max":
+                neg_inf = jnp.full(ndocs_pad, -jnp.inf, jnp.float32)
+                mx = neg_inf.at[parent].max(
+                    jnp.where(cmatch, sm.scores, -jnp.inf),
+                    indices_are_sorted=True)
+                pscores = jnp.where(pmatch, mx, 0.0)
+            elif score_mode == "min":
+                pos_inf = jnp.full(ndocs_pad, jnp.inf, jnp.float32)
+                mn = pos_inf.at[parent].min(
+                    jnp.where(cmatch, sm.scores, jnp.inf),
+                    indices_are_sorted=True)
+                pscores = jnp.where(pmatch, mn, 0.0)
+            else:
+                total = zeros.at[parent].add(
+                    jnp.where(cmatch, sm.scores, 0.0),
+                    indices_are_sorted=True)
+                pscores = total / jnp.maximum(cnt, 1.0) \
+                    if score_mode == "avg" else total
+            pmatch = pmatch & (live > 0)
+            pscores = jnp.where(pmatch, pscores * params[f"q{nid}_boost"],
+                                0.0)
         return ops.ScoredMask(pscores, pmatch.astype(jnp.float32))
 
     if kind == "has_child":
@@ -2056,20 +2085,14 @@ def prepare_sort(sort_specs: List[dict], seg: Segment, params: dict):
         return ("geo_dist", gfield, desc, missing_last)
     nspec = primary.get("nested")
     if nspec and nspec.get("path"):
-        vals, present = nested_sort_values(seg, field, nspec["path"],
-                                           primary.get("mode",
-                                                       "max" if desc
-                                                       else "min"))
-        if vals is None:
+        # the key is a resident plane of the segment, like a numeric
+        # field's ranks below: the request carries none of it
+        plane = nested_sort_plane(seg, field, nspec["path"],
+                                  primary.get("mode",
+                                              "max" if desc else "min"))
+        if plane is None:
             return ("missing_field", desc, missing_last)
-        ords = np.full(seg.ndocs, -1, np.int32)
-        if present.any():
-            uniq = np.unique(vals[present])
-            ords[present] = np.searchsorted(uniq, vals[present]).astype(np.int32)
-        import jax.numpy as _jnp
-        pad = np.full(seg.ndocs_pad, -1, dtype=np.int32)
-        pad[: seg.ndocs] = ords
-        params["sort_ords"] = _jnp.asarray(pad)
+        params["sort_ords"] = plane
         return ("field_ord", desc, missing_last)
     if field in seg.numeric_cols:
         params["sort_ords"], = segment_plane(
@@ -2350,7 +2373,12 @@ def _prepare_cached_filter(node: LNode, seg: Segment, ctx: ShardContext,
                            params: dict):
     """Prepare a filter-context clause through the mask cache: repeated
     filters (the classic "status:published + range" guardrails) reuse one
-    device-resident bool mask instead of re-running their program."""
+    device-resident bool mask instead of re-running their program. A
+    clause of a child space (`plan.nested_context`) is inlined: its mask is
+    an operand of the join in the same program, and the cache would read
+    it to the host and hand it back, a byte a child slot each way."""
+    if not ctx.cache_filters:
+        return prepare(node, seg, ctx, params)
     mask, key, spec, local = filter_mask_for(node, seg, ctx)
     if mask is None:
         params.update(local)
@@ -2431,23 +2459,28 @@ def prepare_collapse(collapse: Optional[dict], seg: Segment, ctx: ShardContext,
 
 
 @instrumented_program_cache("gather", maxsize=256)
-def _build_gather_executor(query_spec):
+def _build_gather_executor(query_spec, scope: Optional[str] = None):
     """Scores of a query at an explicit doc list (rescore second pass,
-    reference `search/rescore/QueryRescorer.java`)."""
+    reference `search/rescore/QueryRescorer.java`; the inner hits of a
+    page's blocks, under the stage `scope`)."""
+    import contextlib
+
     import jax
 
     def gather_program(seg_arrays, params):
-        sm = emit(query_spec, seg_arrays, params)
-        docs = params["gather_docs"]
-        return sm.scores[docs], sm.matched[docs]
+        with jax.named_scope(scope) if scope else contextlib.nullcontext():
+            sm = emit(query_spec, seg_arrays, params)
+            docs = params["gather_docs"]
+            return sm.scores[docs], sm.matched[docs]
 
     return jax.jit(gather_program)
 
 
-def run_gather_scores(query_spec, seg_arrays: dict, params: dict, docs: np.ndarray):
+def run_gather_scores(query_spec, seg_arrays: dict, params: dict,
+                      docs: np.ndarray, scope: Optional[str] = None):
     mapping: Dict[int, int] = {}
     canon = canon_spec(query_spec, mapping)
-    exe = _build_gather_executor(canon)
+    exe = _build_gather_executor(canon, scope)
     params = {canon_param_key(k, mapping): v for k, v in params.items()}
     params["gather_docs"] = docs
     return exe(seg_arrays, params)

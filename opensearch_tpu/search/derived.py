@@ -174,11 +174,8 @@ def _purge_query_caches(seg, names: List[str]) -> None:
     seg.__dict__.get("_fastpath_filters", {}).clear()
     for name in names:
         seg.__dict__.get("_fastpath_aligned", {}).pop(name, None)
-        PN.drop_segment_planes(seg, name)    # sort ranks, date buckets
-        c = seg.__dict__.get("_nested_sort_cache")
-        if c:
-            for k in [k for k in c if k[0] == name]:
-                del c[k]
+        # sort ranks (a nested sort's key among them), date buckets
+        PN.drop_segment_planes(seg, name)
 
 
 class _LazyDocCols(dict):

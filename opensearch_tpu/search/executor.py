@@ -158,10 +158,20 @@ def _cost_predicted(lroot, seg, window: int) -> None:
         return
     npost = 0
     nbytes = 0
-    stack = [lroot]
+    stack = [(lroot, seg)]
     while stack:
-        node = stack.pop()
+        node, at = stack.pop()
         if node is None:
+            continue
+        if isinstance(node, PL.LNested):
+            # the block join reads every child row's parent, whatever
+            # matches; the child clause is the child space's
+            blk = at.nested.get(node.path)
+            if blk is not None:
+                nbytes += blk.child.ndocs * _qcost.NESTED_CHILD_BYTES
+                npost += blk.child.ndocs * _qcost.nested_join_scatters(
+                    node.score_mode)
+                stack.append((node.child, blk.child))
             continue
         terms = None
         if isinstance(node, (PL.LTerms, PL.LPhrase, PL.LSourcePhrase)):
@@ -169,11 +179,11 @@ def _cost_predicted(lroot, seg, window: int) -> None:
         elif isinstance(node, PL.LSparseDot):
             terms = node.tokens
         if terms:
-            pb = seg.postings.get(node.field)
+            pb = at.postings.get(node.field)
             if pb is not None:
                 df = sum(pb.doc_freq(t) for t in terms)
                 npost += df
-                v2 = (getattr(seg, "codec_version", CODEC_V1)
+                v2 = (getattr(at, "codec_version", CODEC_V1)
                       >= CODEC_V2 and pb.impact is not None)
                 if v2 and ((isinstance(node, PL.LTerms)
                             and node.mode == "score")
@@ -189,10 +199,10 @@ def _cost_predicted(lroot, seg, window: int) -> None:
         for attr in _LNODE_CHILD_ATTRS:
             v = getattr(node, attr, None)
             if isinstance(v, (list, tuple)):
-                stack.extend(v)
+                stack.extend((c, at) for c in v)
             elif v is not None and not isinstance(v, (str, int, float,
                                                       bool)):
-                stack.append(v)
+                stack.append((v, at))
     qc.note_predicted(nbytes, npost, window, segment=seg)
 
 
@@ -717,6 +727,18 @@ class ShardSearcher:
         perc_multi = [pq for pq in _walk_query_nodes(qtree, dsl.PercolateQuery)
                       if len(pq.documents) > 1]
         ih_cache: Dict[Tuple[int, int], Any] = {}
+        # the matching children of the page's parents: one launch a nested
+        # clause and segment, over the page's blocks
+        inner = {}
+        if nested_ihs:
+            with TRACER.span("fetch.inner_hits", clauses=len(nested_ihs),
+                             hits=len(selected)):
+                for seg_ord in sorted({c.seg_ord for c in selected}):
+                    docs = [c.local_doc for c in selected
+                            if c.seg_ord == seg_ord]
+                    for nq in nested_ihs:
+                        inner[id(nq), seg_ord] = self._nested_inner_hits(
+                            nq, result.segments[seg_ord], docs, ctx)
         suppress = _suppress_score(body) if body.get("sort") else False
         hits = []
         for c in selected:
@@ -729,7 +751,7 @@ class ShardSearcher:
             if body.get("explain") and body.get("explain") != "device_plan":
                 hit["_explanation"] = explain_doc(lroot, seg, c.local_doc, ctx)
             for nq in nested_ihs:
-                self._add_inner_hits(hit, nq, seg, c, ctx, ih_cache)
+                self._add_inner_hits(hit, nq, seg, c, inner[id(nq), c.seg_ord])
             for jq in join_ihs:
                 self._add_join_inner_hits(hit, jq, seg, c, ctx, ih_cache)
             for pq in perc_multi:
@@ -832,30 +854,59 @@ class ShardSearcher:
                      "max_score": parent_hits[0]["_score"] if parent_hits else None,
                      "hits": parent_hits}}
 
-    def _add_inner_hits(self, hit: dict, nq: dsl.NestedQuery, seg: Segment,
-                        c: Candidate, ctx, ih_cache: dict) -> None:
-        """Matching child docs for one nested query (reference InnerHitsContext
-        / InnerHitsPhase): one device pass scores the whole child space per
-        segment, then each parent slices its block."""
+    def _nested_inner_hits(self, nq: dsl.NestedQuery, seg: Segment,
+                           docs: List[int], ctx) -> Optional[dict]:
+        """The matching children of the parents `docs` of one segment for
+        one nested query (reference InnerHitsContext / InnerHitsPhase):
+        parent -> (first child row, [(score, child row)] best first, the
+        earlier child first among equals). One launch: the child clause
+        over the child space, gathered at the rows of the parents' blocks
+        (`children_of`: a few rows a parent), and only those read back;
+        None where the segment has no such block."""
+        import jax
+
         blk = seg.nested.get(nq.path)
         if blk is None or blk.child.ndocs == 0:
+            return None
+        windows = [blk.children_of(d) for d in docs]
+        nrows = sum(b - a for a, b in windows)
+        found = {d: (a, []) for d, (a, _b) in zip(docs, windows)}
+        if not nrows:
+            return found
+        rows = np.zeros(next_pow2(nrows, floor=64), np.int32)
+        rows[:nrows] = np.concatenate(
+            [np.arange(a, b, dtype=np.int32) for a, b in windows])
+        child_ctx = PL.nested_context(ctx, nq.path)
+        inner_l = PL.rewrite(nq.query, child_ctx, scoring=True)
+        cparams: Dict[str, Any] = {}
+        cspec = C.prepare(inner_l, blk.child, child_ctx, cparams)
+        launched = C.run_gather_scores(
+            cspec, blk.child.device_arrays(self.device), cparams, rows,
+            scope="executor.nested_inner")
+        with TRACER.span("device.wait", program="gather"):
+            scores, matched = jax.device_get(launched)
+        PN.NESTED_STATS.inc("inner_hits_requests")
+        PN.NESTED_STATS.inc("inner_hits_child_rows", len(rows))
+        PN.NESTED_STATS.inc("inner_hits_readback_bytes",
+                            scores.nbytes + matched.nbytes)
+        at = 0
+        for d, (a, b) in zip(docs, windows):
+            sc, ok = scores[at: at + b - a], matched[at: at + b - a] > 0
+            at += b - a
+            kept = np.flatnonzero(ok)
+            kept = kept[np.argsort(-sc[kept], kind="stable")]
+            found[d] = (a, [(float(sc[i]), a + int(i)) for i in kept])
+        return found
+
+    def _add_inner_hits(self, hit: dict, nq: dsl.NestedQuery, seg: Segment,
+                        c: Candidate, found: Optional[dict]) -> None:
+        """One hit's `inner_hits` entry from `_nested_inner_hits`."""
+        if found is None:
             return
+        blk = seg.nested[nq.path]
         ih = nq.inner_hits or {}
         name = ih.get("name", nq.path)
-        key = (id(nq), c.seg_ord)
-        if key not in ih_cache:
-            child_ctx = PL.nested_context(ctx, nq.path)
-            inner_l = PL.rewrite(nq.query, child_ctx, scoring=True)
-            cparams: Dict[str, Any] = {}
-            cspec = C.prepare(inner_l, blk.child, child_ctx, cparams)
-            docs = np.arange(blk.child.ndocs_pad, dtype=np.int32)
-            scores, matched = C.run_gather_scores(
-                cspec, blk.child.device_arrays(self.device), cparams, docs)
-            ih_cache[key] = (np.asarray(scores), np.asarray(matched))
-        scores, matched = ih_cache[key]
-        a, b = blk.children_of(c.local_doc)
-        kids = [(float(scores[i]), i) for i in range(a, b) if matched[i]]
-        kids.sort(key=lambda t: -t[0])
+        a, kids = found[c.local_doc]
         frm = int(ih.get("from", 0))
         size = int(ih.get("size", 3))
         child_hits = []
@@ -2029,11 +2080,10 @@ def _host_sort_values(sort_specs: List[dict], seg: Segment, doc: int,
             continue
         nspec = spec.get("nested")
         if nspec and nspec.get("path"):
-            vals, present = PN.nested_sort_values(
+            v = PN.nested_sort_value(
                 seg, f, nspec["path"],
-                spec.get("mode", "max" if desc else "min"))
-            if vals is not None and present[doc]:
-                v = float(vals[doc])
+                spec.get("mode", "max" if desc else "min"), doc)
+            if v is not None:
                 comp.append((0, -v if desc else v))
                 raw.append(v)
             else:

@@ -35,13 +35,16 @@ class ShardContext:
 
     def __init__(self, mappings: Mappings, segments: List[Segment],
                  similarity=None, field_similarities: Optional[dict] = None,
-                 device=None):
+                 device=None, cache_filters: bool = True):
         self.mappings = mappings
         self.segments = segments
         # where the searcher's segments are hosted (None: the process
         # default; a replica's own device): `compiler.prepare` asks a
         # segment's resident planes of that residency, not of a second one
         self.device = device
+        # whether a `bool.filter` clause goes through the filter-mask cache
+        # (`compiler._prepare_cached_filter`); a child space's does not
+        self.cache_filters = cache_filters
         self.default_sim = resolve_similarity(similarity)
         self.field_sims = {f: resolve_similarity(s)
                            for f, s in (field_similarities or {}).items()}
@@ -1409,7 +1412,7 @@ def nested_context(ctx: ShardContext, path: str) -> ShardContext:
     return ShardContext(ctx.mappings, child_segs,
                         similarity=ctx.default_sim,
                         field_similarities=ctx.field_sims,
-                        device=ctx.device)
+                        device=ctx.device, cache_filters=False)
 
 
 def _rewrite_query_string(q, ctx: ShardContext, scoring: bool) -> LNode:

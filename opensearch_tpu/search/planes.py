@@ -30,6 +30,24 @@ BUCKET_PLANE_STATS = CounterGroup(METRICS, "aggs.bucket_plane",
                                   {"builds": 0, "hits": 0, "bytes": 0})
 RANK_PLANE_STATS = CounterGroup(METRICS, "sort.rank_plane",
                                 {"builds": 0, "hits": 0, "bytes": 0})
+# what the block join costs. Counted where a `nested` node is bound to a
+# segment (`compiler.prepare`, once a launch): `queries` the nodes,
+# `child_rows` the padded slots of the child space the child clause and
+# the join read, `child_rows_real` the children among them, `parents` the
+# padded parent rows the join writes, `join_updates` the updates its
+# scatters take (a slot a scatter: two for `avg` / `sum` / `max` / `min`,
+# one for `none`). `sort_plane_builds`: the nested sort keys built
+# (`nested_sort_plane`: 0 once a (field, path, mode) is resident).
+# `inner_hits_requests` / `_child_rows` / `_readback_bytes`: the inner-hits
+# launches (`executor._nested_inner_hits`, one a request, nested clause
+# and segment), the child rows they gather (the blocks of a page's
+# parents, padded) and the bytes read back for them. `programs`: the
+# distinct programs with a `nested` node compiled so far
+NESTED_STATS = CounterGroup(METRICS, "nested", {
+    "queries": 0, "child_rows": 0, "child_rows_real": 0, "parents": 0,
+    "join_updates": 0, "sort_plane_builds": 0, "inner_hits_requests": 0,
+    "inner_hits_child_rows": 0, "inner_hits_readback_bytes": 0,
+    "programs": 0})
 
 
 def segment_plane(seg: Segment, cache_name: str, key, kind: str, stats,
@@ -77,64 +95,75 @@ def segment_plane(seg: Segment, cache_name: str, key, kind: str, stats,
         return cache[key]
 
 
-def nested_sort_values(seg: Segment, field: str, path: str, mode: str):
-    """Per-parent aggregate of a nested child numeric column (reference
-    NestedSortBuilder): min/max/sum/avg over each parent's block children.
-    Cached per (field, path, mode). -> (values f64[ndocs], present bool) or
-    (None, None). The per-segment lock keeps concurrent first computations
-    of one key from double-charging the breaker (only one cache write
-    wins, but both finalizers would release)."""
-    cache = seg.__dict__.setdefault("_nested_sort_cache", {})
-    key = (field, path, mode)
-    if key in cache:
-        return cache[key]
-    lock = seg.__dict__.setdefault("_nested_sort_lock",
-                                   __import__("threading").Lock())
-    with lock:
-        if key in cache:
-            return cache[key]
-        return _nested_sort_values_build(seg, cache, key, field, path,
-                                         mode)
-
-
-def _nested_sort_values_build(seg: Segment, cache: dict, key, field: str,
-                              path: str, mode: str):
+def _nested_column(seg: Segment, field: str, path: str):
+    """(block, child numeric column) of `path`.`field`, or (None, None)."""
     blk = seg.nested.get(path)
     col = blk.child.numeric_cols.get(field) if blk is not None else None
-    if col is None:
-        cache[key] = (None, None)
-        return cache[key]
-    n = seg.ndocs
-    parent = blk.parent_of[: blk.child.ndocs]
-    pres_child = col.present[: blk.child.ndocs] & blk.child.live[: blk.child.ndocs]
-    vals_child = col.values[: blk.child.ndocs].astype(np.float64)
-    out = np.full(n, np.inf if mode == "min" else
-                  (-np.inf if mode == "max" else 0.0), np.float64)
-    present = np.zeros(n, bool)
-    p = parent[pres_child]
-    v = vals_child[pres_child]
+    return (blk, col) if col is not None else (None, None)
+
+
+def _run_reduce(values: np.ndarray, keep: np.ndarray, parent: np.ndarray,
+                mode: str):
+    """`mode` (min / max / sum / avg) of the kept `values` over the runs of
+    the nondecreasing `parent`: -> (parents that keep a value, ascending;
+    their aggregate, f64). A segmented reduction (`ufunc.reduceat` at the
+    runs' first rows), no scatter."""
+    p, v = parent[keep], values[keep].astype(np.float64)
+    if not len(p):
+        return p, v
+    first = np.flatnonzero(np.concatenate(([True], p[1:] != p[:-1])))
     if mode == "min":
-        np.minimum.at(out, p, v)
+        out = np.minimum.reduceat(v, first)
     elif mode == "max":
-        np.maximum.at(out, p, v)
+        out = np.maximum.reduceat(v, first)
     else:                              # sum / avg
-        np.add.at(out, p, v)
-    present[np.unique(p)] = True
-    if mode == "avg":
-        cnt = np.zeros(n, np.float64)
-        np.add.at(cnt, p, 1.0)
-        out = np.divide(out, np.maximum(cnt, 1.0))
-    out = np.where(present, out, 0.0)
-    # parent-docs-scale columns cached for the segment's lifetime:
-    # register with the HBM ledger (same fielddata budget the fastpath
-    # layouts charge, derived by the ledger), released when the
-    # (immutable) segment is GC'd — the cache dict lives on it
-    from ..obs.hbm_ledger import LEDGER
-    LEDGER.register("nested_sort", out.nbytes + present.nbytes, owner=seg,
-                    segment=seg,
-                    label=f"nested-sort[{seg.name}][{path}.{field}]")
-    cache[key] = (out, present)
-    return cache[key]
+        out = np.add.reduceat(v, first)
+        if mode == "avg":
+            out = out / np.diff(first, append=len(p))
+    return p[first], out
+
+
+def nested_sort_plane(seg: Segment, field: str, path: str, mode: str):
+    """The resident key of a nested sort (reference NestedSortBuilder): an
+    i32[ndocs_pad] plane on the device that holds, a parent, the rank of
+    its `mode` (min / max / sum / avg) over its block's live children's
+    `path`.`field` among the segment's distinct aggregates, -1 where no
+    child has a value. Built once a (segment, field, path, mode) through
+    `segment_plane` (the cache and the ledger's `nested_sort` category; a
+    request carries nothing of `ndocs_pad`), dropped by
+    `drop_segment_planes` or with the segment. None where the segment has
+    no such child column."""
+    blk, col = _nested_column(seg, field, path)
+    if col is None:
+        return None
+
+    def build():
+        NESTED_STATS.inc("sort_plane_builds")
+        n = blk.child.ndocs
+        parents, agg = _run_reduce(
+            col.values[:n], col.present[:n] & blk.child.live[:n],
+            blk.parent_of[:n], mode)
+        ords = np.full(seg.ndocs, -1, np.int32)
+        ords[parents] = np.searchsorted(np.unique(agg), agg)
+        return (ords,)
+    return segment_plane(seg, "_sort_dev_cache", (field, path, mode),
+                         "nested_sort", RANK_PLANE_STATS, build)[0]
+
+
+def nested_sort_value(seg: Segment, field: str, path: str, mode: str,
+                      doc: int) -> Optional[float]:
+    """What `nested_sort_plane` ranks, of one parent: the aggregate over
+    its own block (a hit's sort value is read from the few rows of its
+    block, not from a column over every parent), None where no live child
+    has a value."""
+    blk, col = _nested_column(seg, field, path)
+    if col is None:
+        return None
+    a, b = blk.children_of(doc)
+    keep = col.present[a:b] & blk.child.live[a:b]
+    _p, agg = _run_reduce(col.values[a:b], keep,
+                          np.zeros(b - a, np.int32), mode)
+    return float(agg[0]) if len(agg) else None
 
 
 def drop_segment_planes(seg: Segment, field: str) -> None:

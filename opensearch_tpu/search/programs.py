@@ -17,7 +17,8 @@ from ..ops import scoring as ops
 from ..utils.trace import TRACER
 from .agg_compiler import emit_agg
 from .aggregations import AGG_STATS
-from .compiler import (EXECUTOR_STATS, KNN_STATS, PHRASE_STATS, ROW_SPAN,
+from .compiler import (EXECUTOR_STATS, KNN_STATS, NESTED_STATS,
+                       PHRASE_STATS, ROW_SPAN,
                        canon_param_key, canon_spec, emit, emit_sort_key,
                        instrumented_program_cache)
 
@@ -38,6 +39,9 @@ def _build_executor(full_spec):
     # with a `phrase` node (the phrase shapes met so far)
     if next(_nodes_of(("phrase",), full_spec[0]), None) is not None:
         PHRASE_STATS.inc("programs")
+    # and `nested.programs` those of a spec with a `nested` node
+    if next(_nodes_of(("nested",), full_spec[0]), None) is not None:
+        NESTED_STATS.inc("programs")
     return jax.jit(_executor_run_fn(full_spec))
 
 

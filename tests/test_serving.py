@@ -781,14 +781,21 @@ class TestBreakerFolding:
             eng.refresh()
             seg = eng.segments[0]
             before = br.used
-            vals, present = PN.nested_sort_values(seg, "items.qty",
-                                                  "items", "min")
-            assert vals is not None
-            assert br.used > before
+            plane = PN.nested_sort_plane(seg, "items.qty", "items", "min")
+            assert plane is not None
+            # what is resident is what is charged: the i32 rank plane
+            assert br.used - before == plane.nbytes == 4 * seg.ndocs_pad
+            tenant = LEDGER.snapshot()["tenants"]["nested_sort"]
+            assert tenant["bytes"] >= plane.nbytes
             charged = br.used
-            PN.nested_sort_values(seg, "items.qty", "items", "min")
+            assert PN.nested_sort_plane(seg, "items.qty", "items",
+                                        "min") is plane
             assert br.used == charged         # cache hit: no re-charge
-            del seg, vals, present
+            assert PN.nested_sort_value(seg, "items.qty", "items", "min",
+                                        7) == 7.0
+            assert PN.nested_sort_plane(seg, "items.none", "items",
+                                        "min") is None
+            del seg, plane
             eng.close()
             del eng
             gc.collect()
